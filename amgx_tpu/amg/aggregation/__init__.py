@@ -237,7 +237,7 @@ class AggregationAMGLevel(AMGLevel):
         from ...ops import smooth as fused
         slabs = None
         if bool(int(self.cfg.get("cycle_fusion", self.scope))) \
-                and fused.fused_runtime_on() \
+                and fused.flat_gather_ok() \
                 and getattr(self, "aggregates", None) is not None \
                 and self.coarse_size:
             slabs = fused.build_transfer_slabs(

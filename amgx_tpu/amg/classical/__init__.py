@@ -243,7 +243,7 @@ class ClassicalAMGLevel(AMGLevel):
         from ...ops import smooth as fused
         slabs = None
         if bool(int(self.cfg.get("cycle_fusion", self.scope))) \
-                and fused.fused_runtime_on() \
+                and fused.flat_gather_ok() \
                 and getattr(self, "P", None) is not None \
                 and getattr(self, "R", None) is not None \
                 and self.coarse_size:

@@ -101,7 +101,7 @@ def _level_plan(level, Ac_structure):
         coffsets=coffsets, contribs=contribs, geo_plan=None,
         # device-resident ONCE at plan build: re-uploading these O(nnz)
         # gather indices per resetup call would pay a host->device
-        # transfer every cycle on tunneled rigs
+        # transfer every time step
         off_e=jnp.asarray(off_e), row_e=jnp.asarray(row_e),
         nc=Ac_structure.num_rows, kc=len(Ac_structure.dia_offsets))
 

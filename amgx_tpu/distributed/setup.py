@@ -61,7 +61,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from .._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..errors import BadParametersError

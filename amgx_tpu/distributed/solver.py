@@ -21,8 +21,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from .._compat import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from ..config import Config
 from ..errors import BadParametersError
@@ -140,7 +140,7 @@ class DistributedSolver:
                         "global matrix); see distributed_setup_mode")
             s.A = self.shard_A           # duck-typed operator view
             s = s.preconditioner
-        self._data = self._build_data()
+        self._data = self._place(self._build_data())
         self._fn = None
         self._comms_table = None      # filled at first (re)trace
         self._shard_stats = self._compute_shard_stats(part)
@@ -290,6 +290,15 @@ class DistributedSolver:
                 return False
         return True
 
+    def _place(self, tree):
+        """Put a stacked (n_ranks, ...) pytree on the mesh, slice r of
+        every leaf on device r. Without it the stacks sit where they
+        were built — the default device — and every solve re-shards
+        them: invisible on virtual CPU devices, one chip's HBM holding
+        all four chips' data on a real host."""
+        return jax.device_put(
+            tree, NamedSharding(self.mesh, P(self.axis)))
+
     def _build_data(self):
         """Hand-build the solve-data pytree (stacked arrays); per-shard
         Jacobi inverses come from the partitioned diagonal."""
@@ -346,9 +355,13 @@ class DistributedSolver:
                 # reduced code: a shard-local converged=1 must not
                 # survive a peer's failure (SolveResult treats
                 # converged as authoritative)
-                worst = jax.lax.pmax(stats[2], axis)
-                stats = stats.at[2].set(worst).at[1].set(
-                    (worst == 0).astype(stats.dtype))
+                # reduced as int32: the chip's compiler has no f64 max
+                # all-reduce ("UNIMPLEMENTED: Supported lowering only
+                # of Sum all reduce", compiling for v5e:2x2), and the
+                # packed stats of an f64 solve are f64
+                worst = jax.lax.pmax(stats[2].astype(jnp.int32), axis)
+                stats = stats.at[2].set(worst.astype(stats.dtype)).at[
+                    1].set((worst == 0).astype(stats.dtype))
             return x[None], stats
 
         pspec = jax.tree.map(lambda _: P(axis), self._data)
@@ -367,6 +380,7 @@ class DistributedSolver:
         xl = partition_vector(
             np.zeros(n, bl.dtype) if x0 is None else np.asarray(x0),
             self.n_ranks, self.part.n_local)
+        bl, xl = self._place((bl, xl))
         fresh_trace = self._fn is None or \
             getattr(self, "_fn_epoch", 0) != _fi.epoch()
         if fresh_trace:

@@ -143,8 +143,7 @@ class EigenSolver:
             lam, vec, resid = self.finalize(data, final)
             scale = jnp.maximum(jnp.max(jnp.abs(lam)), 1e-30)
             conv = jnp.all(resid <= tol * scale)
-            # pack scalars/small stats into ONE auxiliary output:
-            # remote/tunneled rigs pay a round trip per awaited buffer
+            # pack scalars/small stats into ONE auxiliary output
             # (see solvers/base.py)
             rdt = jnp.promote_types(jnp.asarray(lam).dtype, jnp.float32)
             if jnp.issubdtype(rdt, jnp.complexfloating):

@@ -8,11 +8,13 @@ with the system toolchain and bound via ctypes — no Python stand-ins for
 the serial hot paths.
 
 `lib()` compiles on first use and returns the loaded ctypes library, or
-None when no compiler is available — callers fall back to their
-pure-Python equivalents. Build artifacts live in _build/ (gitignored),
-keyed by a content hash of the sources so stale binaries are never
-loaded; the .so is written atomically so concurrent processes cannot
-load a half-written file.
+None when it cannot be built — callers that can do without fall back to
+their pure-Python equivalents (with one RuntimeWarning); callers that
+asked for the native form pass `required=True` and get the compiler's
+message as a RuntimeError instead. Build artifacts live in _build/
+(gitignored), keyed by a content hash of the sources so stale binaries
+are never loaded; the .so is written atomically so concurrent processes
+cannot load a half-written file.
 """
 from __future__ import annotations
 
@@ -30,6 +32,7 @@ _BUILD = os.path.join(_DIR, "_build")
 _lock = threading.Lock()
 _lib = None
 _attempted_hash = None    # content hash of the last build attempt
+_build_error = None       # why the last attempt left no library
 
 
 def _src_files():
@@ -38,7 +41,8 @@ def _src_files():
         if f.endswith(".cpp"))
 
 
-def _src_hash() -> str:
+def source_hash() -> str:
+    """Content hash of native/src/*.cpp — the library's build key."""
     h = hashlib.sha256()
     for path in _src_files():
         h.update(os.path.basename(path).encode())
@@ -52,6 +56,7 @@ def _lib_path(src_hash: str) -> str:
 
 
 def _build(target: str) -> bool:
+    global _build_error
     os.makedirs(_BUILD, exist_ok=True)
     tmp = target + f".tmp{os.getpid()}"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
@@ -60,13 +65,14 @@ def _build(target: str) -> bool:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, target)          # atomic publish
     except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
-        detail = ""
         stderr = getattr(e, "stderr", None)
-        if stderr:
-            detail = ": " + stderr.decode("utf-8", "replace")[-300:]
+        _build_error = f"{type(e).__name__}: {e}" + (
+            "\n" + stderr.decode("utf-8", "replace")[-2000:]
+            if stderr else "")
         warnings.warn(
             "native library build failed; native fast paths disabled, "
-            "pure-Python fallbacks in use" + detail, RuntimeWarning)
+            "pure-Python fallbacks in use: " + _build_error[-300:],
+            RuntimeWarning)
         try:
             os.unlink(tmp)
         except OSError:
@@ -86,23 +92,28 @@ def _build(target: str) -> bool:
     return True
 
 
-def lib():
+def lib(required: bool = False):
     """The loaded native library, or None if unavailable. A failed build
-    is cached per source hash — no repeated compiler spawns."""
-    global _lib, _attempted_hash
+    is cached per source hash — no repeated compiler spawns. With
+    `required=True` an unavailable library raises RuntimeError carrying
+    the compiler's output instead of returning None."""
+    global _lib, _attempted_hash, _build_error
     with _lock:
-        h = _src_hash()
-        if _attempted_hash == h:
-            return _lib
-        _attempted_hash = h
-        _lib = None
-        target = _lib_path(h)
-        if not os.path.exists(target) and not _build(target):
-            return None
-        try:
-            _lib = ctypes.CDLL(target)
-        except OSError:
+        h = source_hash()
+        if _attempted_hash != h:
+            _attempted_hash = h
             _lib = None
+            _build_error = None
+            target = _lib_path(h)
+            if os.path.exists(target) or _build(target):
+                try:
+                    _lib = ctypes.CDLL(target)
+                except OSError as e:
+                    _build_error = f"cannot load {target}: {e}"
+        if _lib is None and required:
+            raise RuntimeError(
+                "native library required but unavailable: "
+                + (_build_error or "unknown reason"))
     return _lib
 
 

@@ -18,6 +18,7 @@
 // relabel form, has_stage1 = 0). Summation is strict left-to-right
 // per segment, matching the numpy reduceat fallback's short-segment
 // order.
+#include <cstddef>
 #include <cstdint>
 #include <vector>
 

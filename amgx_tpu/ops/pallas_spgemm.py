@@ -41,7 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_spmv as _ps
 
 LANES = _ps.LANES
-_RAP_VMEM_BUDGET = 11 * 1024 * 1024
+_RAP_VMEM_BUDGET = _ps._SMOOTH_VMEM_BUDGET
 RAP_MAX_CONTRIB = 64        # largest per-entry contributor run the
 # kernel's masked j-loop unrolls; longer segments decline to the slab
 # route (segment_sum handles any length)
@@ -196,7 +196,7 @@ def build_rap_kernel(plan):
 
 def rap_kernel_ready(plan, dtype) -> bool:
     """Trace-time gate for the fused value-kernel route."""
-    if jax.default_backend() != "tpu" and not _ps._FORCE_INTERPRET:
+    if not _ps.flat_gather_ok():    # 1-D jnp.take: refused by Mosaic
         return False
     if jnp.dtype(dtype) != jnp.float32:
         return False
@@ -278,7 +278,7 @@ def _rap_kernel_program(specs, arrs, af, r_vals, p_vals,
         if spec.has_r:
             operands.append(a["sr"])
         operands += [a["s2"], a["l2"]]
-        out = pl.pallas_call(
+        out = _ps.kernel_call(
             _rap_kernel(key),
             grid=(1,),
             in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)

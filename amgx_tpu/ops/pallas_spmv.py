@@ -28,6 +28,7 @@ path covers f64/CPU).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -36,9 +37,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-_VMEM_BUDGET = 10 * 1024 * 1024  # leave headroom under ~16 MB/core
+# The ONE VMEM number: the scoped-VMEM limit every kernel hands the
+# compiler (kernel_call) — half of a v5e core's 128 MiB, four times
+# Mosaic's 16 MiB default. The plans below hold what they can count —
+# DMA windows, pipelined blocks, the matrix-free mask set — to
+# fractions of it (the budgets, which choose the block sizes), and the
+# fused-smoother plan holds windows PLUS the kernel body's live values
+# to the limit itself (dia_smooth_plan, smooth_body_planes), so a plan
+# that is returned is a kernel the compiler was given room for.
+# tests/test_chip_compile.py compiles the flagship's shapes, and a
+# 27-point matrix-free level, for a v5e under this limit.
+VMEM_LIMIT = 64 * 1024 * 1024
+_VMEM_BUDGET = VMEM_LIMIT * 5 // 32     # 10 MiB of windows/blocks
 
-# Testing hook: the CPU test rig runs the Pallas kernels through the
+# Testing hook: the CPU tests run the Pallas kernels through the
 # interpreter; flipping this (via force_pallas_interpret) makes the
 # trace-time gates report "supported" off-TPU and routes every kernel
 # call through interpret mode, so kernel-consuming code paths (spmv
@@ -46,13 +58,10 @@ _VMEM_BUDGET = 10 * 1024 * 1024  # leave headroom under ~16 MB/core
 _FORCE_INTERPRET = False
 
 
-import contextlib
-
-
 @contextlib.contextmanager
 def force_pallas_interpret():
-    """Route the DIA Pallas kernels through the interpreter and make
-    their support gates ignore the backend check (CPU test path)."""
+    """Route the Pallas kernels through the interpreter and make their
+    support gates ignore the backend check (CPU test path)."""
     global _FORCE_INTERPRET
     prev = _FORCE_INTERPRET
     _FORCE_INTERPRET = True
@@ -60,6 +69,66 @@ def force_pallas_interpret():
         yield
     finally:
         _FORCE_INTERPRET = prev
+
+
+def pallas_backend():
+    """The one capability question every Pallas support gate in ops/
+    asks. "interpret": forced through the Pallas interpreter (CPU
+    tests). "mosaic": the default backend is a TPU, kernels are
+    compiled by Mosaic — the branch where a kernel family the chip's
+    compiler refuses declines. None: no Pallas path, XLA forms only."""
+    if _FORCE_INTERPRET:
+        return "interpret"
+    return "mosaic" if jax.default_backend() == "tpu" else None
+
+
+def kernel_call(kernel, **kw):
+    """`pl.pallas_call` for every kernel in ops/, with the two things
+    the chip's compiler needs done in ONE place.
+
+    - The package turns x64 on at import, so a Python int inside a
+      kernel body, an index map or a DMA slice traces as int64, which
+      Mosaic has no type for ("'tpu.memref_slice' op operand #2 must be
+      variadic of 32-bit signless integer, but got 'i64'"). The call —
+      and with it the kernel body — is traced with x64 off.
+    - The compiler is told the VMEM limit the plans were held to
+      (VMEM_LIMIT), so plan and compiler never hold two different
+      numbers."""
+    call = pl.pallas_call(
+        kernel, compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT), **kw)
+
+    def run(*operands):
+        with jax.enable_x64(False):
+            return call(*operands)
+    return run
+
+
+# Per-block partial sums (dot epilogues): one (PART_ROWS, 128) output
+# block per grid step, the lane sums in row 0 and zeros below, summed
+# by the caller's cheap XLA combine. A (1, 128) block per step is what
+# the arithmetic needs, but Mosaic refuses it as soon as there is more
+# than one step ("the last two dimensions of your block shape are
+# divisible by 8 and 128 respectively, or be equal to the respective
+# dimensions of the overall array").
+PART_ROWS = 8
+
+
+def _part_spec():
+    return pl.BlockSpec((PART_ROWS, LANES), lambda i: (i, jnp.int32(0)),
+                        memory_space=pltpu.VMEM)
+
+
+def _part_shape(n_blocks: int):
+    return jax.ShapeDtypeStruct((n_blocks * PART_ROWS, LANES),
+                                jnp.float32)
+
+
+def _part_store(ref, prod):
+    """Store the row-sum of `prod` (rows, 128) as this block's partial."""
+    s = jnp.sum(prod, axis=0, keepdims=True).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (PART_ROWS, LANES), 0)
+    ref[...] = jnp.where(row == 0, s, jnp.zeros((), jnp.float32))
 
 
 def pick_block_rows(k: int, rows128: int) -> int:
@@ -142,7 +211,7 @@ def _layout(offsets, k: int, num_rows: int):
 
 def dia_spmv_supported(A, x_dtype) -> bool:
     """Trace-time gate for the Pallas path."""
-    if jax.default_backend() != "tpu" and not _FORCE_INTERPRET:
+    if pallas_backend() is None:
         return False
     if A.dia_vals is None or A.dia_vals.dtype != jnp.float32 \
             or x_dtype != jnp.float32:
@@ -174,7 +243,7 @@ def _dia_spmv_call(dia_vals, x, offsets, num_rows, interpret=False):
     xp = xp.reshape(xp_rows, LANES)
 
     kernel = _dia_kernel(offsets, left, br, halo_rows, n_blocks, dtype)
-    y2 = pl.pallas_call(
+    y2 = kernel_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[
@@ -243,7 +312,7 @@ def dia_spmv(A, x, interpret=False):
 # in the smoother's solve_data) so no per-cycle re-layout of A happens.
 # ---------------------------------------------------------------------------
 
-_SMOOTH_VMEM_BUDGET = 11 * 1024 * 1024
+_SMOOTH_VMEM_BUDGET = VMEM_LIMIT * 11 // 64   # 11 MiB, as above
 SMOOTH_MAX_APPS = 8          # sweeps + residual cap for one fused call
 _BR_CAP = 2048               # largest candidate block size
 
@@ -274,7 +343,30 @@ def smooth_dtype_ok(A, x_dtype) -> bool:
     if getattr(A, "dia_vals", None) is None:
         return False
     dt = jnp.dtype(A.dia_vals.dtype)
-    return dt == jnp.dtype(x_dtype) and dt.name in SMOOTH_DTYPES
+    return dt == jnp.dtype(x_dtype) and kernel_dtype_ok(dt)
+
+
+def kernel_dtype_ok(dtype) -> bool:
+    """Operand dtypes of the windowed DIA kernels (dia_smooth,
+    dia_spmv_dot, both twins). bf16 is on the whitelist, but compiled
+    for the chip its windows are refused: the plans size the DMA
+    windows in 8-row units (f32 tiling), bf16 tiles are 16 rows deep,
+    and Mosaic (jax 0.9.0, v5e; 7-pt 32^3..128^3, slab and stencil
+    twins) says
+
+        Mosaic failed to compile TPU kernel: Slice shape along
+        dimension 0 must be aligned to tiling (8), but is 307
+
+    for the (win_x, 128) bf16 window slices (cg_update and the SWELL
+    pair, which have no windows, compile in bf16 and stay). Until the
+    plans round windows to the operand's tiling (ROADMAP queue A),
+    bf16 declines here on the compiled-for-chip branch (callers count
+    fusion.declined_dtype and take the XLA forms); interpret mode
+    keeps it."""
+    name = jnp.dtype(dtype).name
+    if pallas_backend() == "mosaic":
+        return name == "float32"
+    return name in SMOOTH_DTYPES
 
 
 def smooth_halo_rows(offsets):
@@ -332,6 +424,21 @@ def smooth_quota_rows(offsets, num_rows: int):
 # coeffs mode's in-register coordinates and masks (idx + 3 grid coords
 # + mask temporaries, ~6 int32/bool planes)
 _MF_WORK_ROWS = 6
+
+
+def smooth_body_planes(k: int, coeffs: bool) -> int:
+    """(win_v, 128) f32 planes the fused smoother's BODY keeps live in
+    VMEM on top of its DMA windows: state, accumulator and shifted
+    views, and in the matrix-free form the k masked value planes the
+    compiler hoists out of the application loop. An upper bound from
+    compiling for v5e and searching the smallest vmem_limit_bytes that
+    is accepted: the slab form needed up to 8 planes (7-pt, 32^3 to
+    128^3), the matrix-free form up to 21 at k = 7 and 66 at k = 27
+    (19.5 MiB where its windows took 4.9 MiB at 7-pt 64^3, 5 sweeps +
+    residual — what XLA refused under the 16 MiB default with "Scoped
+    allocation with size 17.23M and limit 16.00M exceeded scoped vmem
+    limit" — and 48 MiB at 27-pt 128^3)."""
+    return 3 * k if coeffs else 10
 
 
 def _mf_coords(shape, idx):
@@ -460,7 +567,8 @@ def dia_smooth_plan(offsets, k: int, num_rows: int, n_steps: int,
             # sub-f32 operands: the f32 state + per-application upcast
             # temporaries ride on top of the narrow DMA buffers
             vmem += (win_x + 3 * win_v) * LANES * 4
-        if vmem > _SMOOTH_VMEM_BUDGET:
+        body = smooth_body_planes(k, coeffs) * win_v * LANES * 4
+        if vmem > _SMOOTH_VMEM_BUDGET or vmem + body > VMEM_LIMIT:
             continue
         # traffic guard: the fused windows must undercut the n_app
         # separate passes (matrix-free: A contributes no stream on
@@ -481,7 +589,7 @@ def dia_smooth_plan(offsets, k: int, num_rows: int, n_steps: int,
 def dia_smooth_supported(A, x_dtype, n_steps: int,
                          with_residual: bool) -> bool:
     """Trace-time gate for the fused smoother Pallas path."""
-    if jax.default_backend() != "tpu" and not _FORCE_INTERPRET:
+    if pallas_backend() is None:
         return False
     if not smooth_dtype_ok(A, x_dtype):
         return False
@@ -637,8 +745,7 @@ def _dia_smooth_kernel(offsets, br, n_app, mr0, Mr0, win_x, win_v,
                 s, n_app * mr0, n_app * mr0 + br, 1, 0)
             bb = jax.lax.slice_in_dim(
                 bw, (n_app - 1) * mr0, (n_app - 1) * mr0 + br, 1, 0)
-            d_ref[...] = jnp.sum(xb * bb, axis=0,
-                                 keepdims=True).astype(jnp.float32)
+            _part_store(d_ref, xb * bb)
 
     return kernel
 
@@ -743,13 +850,10 @@ def _dia_smooth_call(vals_q, dinv_q, taus, b, x, offsets, num_rows,
     out_specs_t = tuple([out_block] * n_out)
     out_shape_t = tuple([out_shape] * n_out)
     if with_dot:
-        out_specs_t = out_specs_t + (pl.BlockSpec(
-            (1, LANES), lambda i: (i, jnp.int32(0)),
-            memory_space=pltpu.VMEM),)
-        out_shape_t = out_shape_t + (jax.ShapeDtypeStruct(
-            (nb, LANES), jnp.float32),)
+        out_specs_t = out_specs_t + (_part_spec(),)
+        out_shape_t = out_shape_t + (_part_shape(nb),)
     multi_out = with_residual or with_dot
-    out = pl.pallas_call(
+    out = kernel_call(
         kernel,
         grid=(nb,),
         in_specs=in_specs,
@@ -1041,8 +1145,47 @@ def dia_prolong_plan(offsets, k: int, num_rows: int, n_steps: int,
     return None
 
 
+def flat_gather_ok() -> bool:
+    """Capability of every kernel that gathers from a flattened 1-D
+    vector with `jnp.take`: the cycle-fusion family (restriction
+    epilogue, prolongation prologue, coarse tail — slab and stencil
+    twins: child/aggregate index slabs into the residual or the coarse
+    correction) and the plan-split Galerkin value kernel
+    (ops/pallas_spgemm.py). The chip's compiler refuses that whatever
+    the shape (jax 0.9.0, Mosaic for v5e; flagship 7-pt 32^3..128^3
+    and an 8^3 RAP plan):
+
+        NotImplementedError: Only 2D gather is supported
+
+    so those families decline on the compiled-for-chip branch: the
+    cycle runs dia_smooth + the level's XLA restrict/prolongate (the
+    composition cycle_fusion=0 tests), the planned RAP values take the
+    XLA slab / native route. Interpret mode keeps the kernels (their
+    CPU tests guard the semantics until the gathers are redesigned for
+    Mosaic: ROADMAP queue A)."""
+    return pallas_backend() == "interpret"
+
+
+def declined_families():
+    """Kernel families that decline on the compiled-for-chip branch,
+    with the compiler's refusal: what chip_smoke.py prints beside the
+    kernel census. Empty off the chip and in interpret mode."""
+    if pallas_backend() != "mosaic":
+        return {}
+    gather = "NotImplementedError: Only 2D gather is supported"
+    return {
+        "cycle_fusion transfers (restrict epilogue, prolong prologue, "
+        "coarse tail; slab and stencil twins)": gather,
+        "pallas_spgemm plan-split RAP value kernel": gather,
+        "bf16 operand windows of dia_smooth / dia_spmv_dot (slab and "
+        "stencil twins)":
+        "Mosaic failed to compile TPU kernel: Slice shape along "
+        "dimension 0 must be aligned to tiling (8), but is 307",
+    }
+
+
 def _transfer_gate(A, x_dtype) -> bool:
-    if jax.default_backend() != "tpu" and not _FORCE_INTERPRET:
+    if not flat_gather_ok():
         return False
     if not smooth_dtype_ok(A, x_dtype):
         return False
@@ -1344,7 +1487,7 @@ def _dia_smooth_restrict_call(vals_q, dinv_q, taus, b, x, xfer,
     nbytes = ((k + 2) * win_v + win_x
               + (xfer.m * (2 if has_w else 1) + 1) * cw + br) \
         if mf is None else (2 * win_v + win_x + (xfer.m + 1) * cw + br)
-    y2, parts = pl.pallas_call(
+    y2, parts = kernel_call(
         kernel,
         grid=(nb,),
         in_specs=in_specs,
@@ -1574,8 +1717,7 @@ def _dia_prolong_smooth_kernel(offsets, br, n_app, mr0, Mr0, win_x,
                 s, n_app * mr0, n_app * mr0 + br, 1, 0)
             bb = jax.lax.slice_in_dim(
                 bw, (n_app - 1) * mr0, (n_app - 1) * mr0 + br, 1, 0)
-            d_ref[...] = jnp.sum(xb * bb, axis=0,
-                                 keepdims=True).astype(jnp.float32)
+            _part_store(d_ref, xb * bb)
 
     return kernel
 
@@ -1676,11 +1818,8 @@ def _dia_prolong_smooth_call(vals_q, dinv_q, taus, b, x, xc, xfer,
                              memory_space=pltpu.VMEM)
     out_shape = jax.ShapeDtypeStruct((nb * br, LANES), dtype)
     if with_dot:
-        out_specs = (out_specs, pl.BlockSpec(
-            (1, LANES), lambda i: (i, jnp.int32(0)),
-            memory_space=pltpu.VMEM))
-        out_shape = (out_shape, jax.ShapeDtypeStruct((nb, LANES),
-                                                     jnp.float32))
+        out_specs = (out_specs, _part_spec())
+        out_shape = (out_shape, _part_shape(nb))
     scratch = [pltpu.VMEM((2, win_x, LANES), dtype)]
     if mf is None:
         scratch.append(pltpu.VMEM((2, k, win_v, LANES), dtype))
@@ -1698,7 +1837,7 @@ def _dia_prolong_smooth_call(vals_q, dinv_q, taus, b, x, xc, xfer,
     nbytes = ((k + 2) * win_v + win_x + pcw + br
               + (2 * xfer.mp if has_w else 1) * win_x) if mf is None \
         else (2 * win_v + win_x + pcw + br + win_x)
-    y2 = pl.pallas_call(
+    y2 = kernel_call(
         kernel,
         grid=(nb,),
         in_specs=in_specs,
@@ -1924,7 +2063,7 @@ def _dia_coarse_tail_call(arrs, b, x, spec, with_dot=False,
             memory_space=pltpu.VMEM))
         out_shape = (out_shape, jax.ShapeDtypeStruct((1, LANES),
                                                      jnp.float32))
-    out = pl.pallas_call(
+    out = kernel_call(
         kernel,
         grid=(1,),
         in_specs=[_spec_of(v) for v in leaves] + [_spec_of(b2),
@@ -1974,7 +2113,7 @@ def dia_spmv_dot_supported(A, x_dtype) -> bool:
     """Trace-time gate for the SpMV+dot (Krylov shell) Pallas path.
     Wider than dia_spmv_supported: bf16 operands are admitted under
     the fused-suite rules (f32 accumulation)."""
-    if jax.default_backend() != "tpu" and not _FORCE_INTERPRET:
+    if pallas_backend() is None:
         return False
     if not smooth_dtype_ok(A, x_dtype):
         return False
@@ -2102,11 +2241,9 @@ def _dia_spmv_dot_kernel(offsets, left, br, halo_rows, n_blocks, dtype,
             pout_ref[...] = p_blk.astype(dtype)
         ap_ref[...] = acc.astype(dtype)
         dvec = d_ref[...].astype(cdt) if with_d else p_blk
-        dot_ref[...] = jnp.sum(dvec * acc, axis=0,
-                               keepdims=True).astype(jnp.float32)
+        _part_store(dot_ref, dvec * acc)
         if self_dot:
-            sdot_ref[...] = jnp.sum(acc * acc, axis=0,
-                                    keepdims=True).astype(jnp.float32)
+            _part_store(sdot_ref, acc * acc)
 
     return kernel
 
@@ -2176,10 +2313,9 @@ def _dia_spmv_dot_call(dia_vals, p, z, beta, d, offsets, num_rows,
 
     blk = pl.BlockSpec((br, LANES), lambda i: (i, jnp.int32(0)),
                        memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, LANES), lambda i: (i, jnp.int32(0)),
-                        memory_space=pltpu.VMEM)
+    part = _part_spec()
     vec_shape = jax.ShapeDtypeStruct((rows_pad, LANES), dtype)
-    part_shape = jax.ShapeDtypeStruct((nb, LANES), jnp.float32)
+    part_shape = _part_shape(nb)
     out_specs = ([blk] if with_beta else []) + [blk, part] \
         + ([part] if self_dot else [])
     out_shape = ([vec_shape] if with_beta else []) \
@@ -2196,7 +2332,7 @@ def _dia_spmv_dot_call(dia_vals, p, z, beta, d, offsets, num_rows,
     ib = jnp.dtype(dtype).itemsize
     streams = (0 if mf is not None else k) + 2 * (2 if with_beta else 1) \
         + (1 if with_d else 0)
-    outs = pl.pallas_call(
+    outs = kernel_call(
         kernel,
         grid=(nb,),
         in_specs=in_specs,
@@ -2236,7 +2372,7 @@ def dia_spmv_dot(A, p, z=None, beta=None, d=None, self_dot=False,
 
 def cg_update_supported(x_dtype) -> bool:
     """Trace-time gate for the single-pass CG update kernel."""
-    if jax.default_backend() != "tpu" and not _FORCE_INTERPRET:
+    if pallas_backend() is None:
         return False
     return jnp.dtype(x_dtype).name in SMOOTH_DTYPES
 
@@ -2251,8 +2387,7 @@ def _cg_update_kernel(dtype):
         rn = r_ref[...].astype(cdt) - a * ap_ref[...].astype(cdt)
         xo_ref[...] = xn.astype(dtype)
         ro_ref[...] = rn.astype(dtype)
-        rr_ref[...] = jnp.sum(rn * rn, axis=0,
-                              keepdims=True).astype(jnp.float32)
+        _part_store(rr_ref, rn * rn)
 
     return kernel
 
@@ -2279,9 +2414,8 @@ def _cg_update_call(x, p, r, ap, alpha, interpret=False):
 
     blk = pl.BlockSpec((br, LANES), lambda i: (i, jnp.int32(0)),
                        memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, LANES), lambda i: (i, jnp.int32(0)),
-                        memory_space=pltpu.VMEM)
-    xo, ro, rr = pl.pallas_call(
+    part = _part_spec()
+    xo, ro, rr = kernel_call(
         _cg_update_kernel(dtype),
         grid=(nb,),
         in_specs=[blk, blk, blk, blk,
@@ -2290,7 +2424,7 @@ def _cg_update_call(x, p, r, ap, alpha, interpret=False):
         out_specs=(blk, blk, part),
         out_shape=(jax.ShapeDtypeStruct((rows_pad, LANES), dtype),
                    jax.ShapeDtypeStruct((rows_pad, LANES), dtype),
-                   jax.ShapeDtypeStruct((nb, LANES), jnp.float32)),
+                   _part_shape(nb)),
         cost_estimate=pl.CostEstimate(
             flops=5 * nb * br * LANES,
             bytes_accessed=6 * nb * br * LANES

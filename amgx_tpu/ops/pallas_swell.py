@@ -40,14 +40,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import enable_x64
+from .pallas_spmv import _VMEM_BUDGET, kernel_call
 
 LANES = 128
 SUBS = 8                      # sublane groups per super-block
 BLOCK_ROWS = SUBS * LANES     # rows per super-block
 SWELL_MAX_W = 512 * 1024      # max window elements (2 MB f32 a buffer)
 SWELL_MAX_K = 256             # max padded slots per row
-_VMEM_BUDGET = 10 * 1024 * 1024
 
 
 def swell_budget(kmax, w128_raw, nb, nnz):
@@ -165,8 +164,8 @@ def swell_vals_host(ro, vals, num_rows, kpad):
 
 def _swell_runtime_payload_ok(A) -> bool:
     """Backend + payload-presence checks shared by the SWELL gates."""
-    from .pallas_spmv import _FORCE_INTERPRET
-    if jax.default_backend() != "tpu" and not _FORCE_INTERPRET:
+    from .pallas_spmv import pallas_backend
+    if pallas_backend() is None:
         return False
     return A.swell_cols is not None and A.swell_vals is not None
 
@@ -233,10 +232,7 @@ def _swell_kernel(w128, kpad, n_blocks):
                 c = base + jnp.int32(j)
                 chunk = xbuf[slot, pl.ds(c, 1)]   # (1, 128)
                 src = jnp.broadcast_to(chunk, (rows, LANES))
-                # keep the gather's index math int32 (Mosaic has no
-                # i64; the package-level x64 default would promote)
-                with enable_x64(False):
-                    g = jnp.take_along_axis(src, lo, axis=1)
+                g = jnp.take_along_axis(src, lo, axis=1)
                 acc = jnp.where(hi == c, g, acc)
             return acc
 
@@ -263,7 +259,7 @@ def _swell_spmv_call(cols4, vals4, c0row, nchunk, x, w128, num_rows,
     xp = xp.reshape(xp_rows, LANES)
 
     kernel = _swell_kernel(w128, kpad, nb)
-    y2 = pl.pallas_call(
+    y2 = kernel_call(
         kernel,
         grid=(nb,),
         in_specs=[
@@ -390,8 +386,7 @@ def _swell_smooth_kernel(w128, kpad, n_blocks, has_dinv):
                 c = base + jnp.int32(j)
                 chunk = xbuf[slot, pl.ds(c, 1)]
                 src = jnp.broadcast_to(chunk, (rows, LANES))
-                with enable_x64(False):
-                    g = jnp.take_along_axis(src, lo, axis=1)
+                g = jnp.take_along_axis(src, lo, axis=1)
                 acc = jnp.where(hi == c, g, acc)
             return acc
 
@@ -452,7 +447,7 @@ def _swell_smooth_call(cols4, vals4, c0row, nchunk, x, b, dinv, tau,
         in_specs.append(blk)
         operands.append(rowpad(dinv))
     kernel = _swell_smooth_kernel(w128, kpad, nb, has_dinv)
-    y2 = pl.pallas_call(
+    y2 = kernel_call(
         kernel,
         grid=(nb,),
         in_specs=in_specs,

@@ -48,10 +48,13 @@ import jax.numpy as jnp
 from . import pallas_spmv as _ps
 
 
+flat_gather_ok = _ps.flat_gather_ok
+
+
 def fused_runtime_on() -> bool:
-    """Would the fused Pallas kernels run on this rig (or under the
-    interpreter-forcing test hook)?"""
-    return jax.default_backend() == "tpu" or _ps._FORCE_INTERPRET
+    """Would the fused Pallas kernels run here (compiled on a TPU, or
+    under the interpreter-forcing test hook)?"""
+    return _ps.pallas_backend() is not None
 
 
 # ---------------------------------------------------------------------------
@@ -930,7 +933,7 @@ def coarse_tail_cycle(amg, shape: str, data, lvl: int, b, x,
     together. `want_dot` (Krylov shell) makes the megakernel also emit
     the x'.b dot — the whole-cycle-resident case's cycle-borne r.z —
     and the return becomes (x', dot)."""
-    if shape not in ("V", "W", "F") or not fused_runtime_on():
+    if shape not in ("V", "W", "F") or not _ps.flat_gather_ok():
         return None
     if jnp.dtype(x.dtype).name not in _ps.SMOOTH_DTYPES:
         return None

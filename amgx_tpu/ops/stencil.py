@@ -317,11 +317,11 @@ def _xla_corr(spec, coeffs, taus, b, x, xc, aggc):
 
 
 def _runtime_on() -> bool:
-    return jax.default_backend() == "tpu" or _ps._FORCE_INTERPRET
+    return _ps.pallas_backend() is not None
 
 
 def _dtype_ok(x_dtype) -> bool:
-    return jnp.dtype(x_dtype).name in _ps.SMOOTH_DTYPES
+    return _ps.kernel_dtype_ok(x_dtype)
 
 
 def stencil_smooth_supported(spec, x_dtype, n_steps: int,
@@ -336,8 +336,8 @@ def stencil_smooth_supported(spec, x_dtype, n_steps: int,
 
 def stencil_restrict_supported(spec, x_dtype, n_steps: int,
                                xfer) -> bool:
-    if xfer is None or xfer.cwt is not None or not _runtime_on() \
-            or not _dtype_ok(x_dtype):
+    if xfer is None or xfer.cwt is not None \
+            or not _ps.flat_gather_ok() or not _dtype_ok(x_dtype):
         return False
     return _ps.dia_restrict_plan(
         spec.offsets, len(spec.offsets), spec.n, n_steps, xfer.m,
@@ -347,8 +347,8 @@ def stencil_restrict_supported(spec, x_dtype, n_steps: int,
 
 def stencil_prolong_supported(spec, x_dtype, n_steps: int,
                               xfer) -> bool:
-    if xfer is None or xfer.ptab is not None or not _runtime_on() \
-            or not _dtype_ok(x_dtype):
+    if xfer is None or xfer.ptab is not None \
+            or not _ps.flat_gather_ok() or not _dtype_ok(x_dtype):
         return False
     return _ps.dia_prolong_plan(
         spec.offsets, len(spec.offsets), spec.n, n_steps, xfer.windows,

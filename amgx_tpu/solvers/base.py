@@ -666,9 +666,8 @@ class Solver:
             status = jnp.where(final["status"] == _ST_RUNNING,
                                jnp.int32(S.MAX_ITERS), final["status"])
             # pack every scalar/stat output into ONE auxiliary array:
-            # remote/tunneled TPU rigs pay a full round trip PER awaited
-            # output buffer, so (x, stats) costs two concurrent awaits
-            # where six separate outputs cost six serialized ones
+            # the caller awaits and copies out two buffers (x, stats)
+            # instead of six
             # at least f32 so iteration counts survive the cast exactly
             # even for bf16/f16 solves
             rdt = jnp.promote_types(jnp.asarray(norm0).dtype, jnp.float32)

@@ -11,11 +11,14 @@ TPU-native framework is single-controller SPMD: the "ranks" are mesh
 devices, and each AMGX_matrix_upload_distributed call contributes one
 rank's piece, exactly as each MPI rank's call would.
 
-    # 8 virtual CPU devices (no TPU needed):
-    python examples/amgx_mpi_poisson7.py -n 8 --nx 8 --ny 8 --nz 64
-
-    # on the real accelerator(s):
+    # all visible devices (the four chips of a TPU host, or one CPU):
     python examples/amgx_mpi_poisson7.py --mode dDDI -c configs/FGMRES_AGGREGATION.json
+
+    # -n R: R ranks on R accelerator devices when that many are
+    # visible. With no accelerator in use it runs on R virtual CPU
+    # devices and says so; with an accelerator of fewer than R devices
+    # it stops and says how to ask for the virtual ones:
+    JAX_PLATFORMS=cpu python examples/amgx_mpi_poisson7.py -n 8 --nx 8 --ny 8 --nz 64
 """
 import argparse
 import sys
@@ -27,8 +30,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("-n", "--ranks", type=int, default=0,
-                    help="mesh size; 0 = all visible devices. >1 on a "
-                         "CPU host forces that many virtual devices")
+                    help="mesh size; 0 = all visible devices. R>1 "
+                         "without an accelerator in use runs on R "
+                         "virtual CPU devices and says so")
     ap.add_argument("--nx", type=int, default=8)
     ap.add_argument("--ny", type=int, default=8)
     ap.add_argument("--nz", type=int, default=64)
@@ -37,9 +41,13 @@ def main():
     args = ap.parse_args()
 
     if args.ranks > 1:
-        # force virtual CPU devices BEFORE any jax import-time work
-        from _cpu_backend import force_cpu
-        force_cpu(args.ranks)
+        # R ranks want R devices: the accelerator's when it has that
+        # many, virtual CPU devices when no accelerator is in use —
+        # decided BEFORE any other jax operation, and never silently
+        from _cpu_backend import ensure_devices
+        note = ensure_devices(args.ranks)
+        if note:
+            print(note)
     import jax
     import numpy as np
     from amgx_tpu import capi

@@ -1,106 +1,6 @@
-"""Shared jaxpr census helpers for the kernel-fusion test suites.
-
-The fusion PRs prove their HBM-pass claims by *counting* what a traced
-program contains: how many Pallas kernels of which kind, which XLA
-primitives run standalone between them, and which reductions touch
-full-length vectors outside any kernel. These helpers used to be
-copy-pasted across tests/test_fused_smoother.py, test_cycle_fusion.py
-and test_matrix_free.py; they live here once so every census gate
-counts the same way.
-"""
-import re
-
-import numpy as np
-import jax
-
-KERNEL_NAME_RE = re.compile(r"name=\"?([A-Za-z_0-9]+)\"?")
-
-# the package's fused Pallas entry points, as their names appear on
-# pallas_call eqns (ops/pallas_spmv.py); extend here when a PR adds a
-# kernel so every suite's counts see it
-KERNEL_KEYS = (
-    "_dia_smooth_restrict_call",
-    "_dia_prolong_smooth_call",
-    "_dia_coarse_tail_call",
-    "_dia_smooth_call",
-    "_dia_spmv_call",
-    "_dia_spmv_dot_call",
-    "_cg_update_call",
-)
-
-
-def kernel_names(jaxpr):
-    """Every `name=...` occurrence in the stringified jaxpr, in trace
-    order (pallas_call kernel names plus any other named eqns)."""
-    return KERNEL_NAME_RE.findall(str(jaxpr))
-
-
-def kernel_counts(jaxpr, keys=KERNEL_KEYS):
-    """{kernel name: count} over `keys` (exact matches only; names not
-    present are absent from the dict, so use .get(k, 0))."""
-    out = {}
-    for nm in kernel_names(jaxpr):
-        if nm in keys:
-            out[nm] = out.get(nm, 0) + 1
-    return out
-
-
-def _subjaxprs(eqn):
-    for p in eqn.params.values():
-        for q in (p if isinstance(p, (tuple, list)) else (p,)):
-            if isinstance(q, jax.core.ClosedJaxpr):
-                yield q.jaxpr
-            elif isinstance(q, jax.core.Jaxpr):
-                yield q
-
-
-def outer_prims(closed_jaxpr):
-    """All primitive names reachable from the trace WITHOUT descending
-    into pallas_call bodies — what runs as standalone XLA ops between
-    the kernels."""
-    prims = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                continue
-            prims.append(eqn.primitive.name)
-            for sub in _subjaxprs(eqn):
-                walk(sub)
-
-    walk(closed_jaxpr.jaxpr)
-    return prims
-
-
-def full_vector_reductions(closed_jaxpr, n,
-                           prims=("reduce_sum", "reduce_max",
-                                  "reduce_min", "dot_general")):
-    """Reduction/contraction eqns OUTSIDE pallas_call bodies that
-    consume an operand of at least `n` elements — the standalone
-    full-vector HBM passes the Krylov-shell fusion removes. Returns
-    [(prim_name, [operand shapes])]."""
-    hits = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                continue
-            if eqn.primitive.name in prims and any(
-                    getattr(v, "aval", None) is not None
-                    and v.aval.size >= n for v in eqn.invars):
-                hits.append((eqn.primitive.name,
-                             [tuple(v.aval.shape) for v in eqn.invars
-                              if hasattr(v, "aval")]))
-            for sub in _subjaxprs(eqn):
-                walk(sub)
-
-    walk(closed_jaxpr.jaxpr)
-    return hits
-
-
-def slab_consts(jaxpr, k, lanes=128):
-    """Constants shaped like a k-diagonal DIA value slab (k, rows,
-    lanes) — the operand a matrix-free trace must not carry."""
-    return [v.aval.shape for v in jaxpr.consts
-            if np.ndim(v) == 3 and np.shape(v)[0] == k
-            and np.shape(v)[-1] == lanes]
+"""The jaxpr census helpers of the fusion suites; the walk itself lives
+in the package (amgx_tpu/telemetry/census.py) so chip_smoke.py and
+bench.py count the same way the tests do."""
+from amgx_tpu.telemetry.census import (  # noqa: F401
+    KERNEL_KEYS, KERNEL_NAME_RE, full_vector_reductions, kernel_counts,
+    kernel_names, outer_prims, slab_consts, subjaxprs)

@@ -13,15 +13,12 @@ from _cpu_backend import force_cpu  # noqa: E402
 
 force_cpu(8)
 
-import jax  # noqa: E402
+from amgx_tpu import compile_cache  # noqa: E402
 
-# persistent compilation cache makes repeated test runs cheap (eager setup
-# ops compile one XLA executable per shape bucket)
-jax.config.update("jax_compilation_cache_dir", "/tmp/amgx_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-jax.config.update("jax_persistent_cache_enable_xla_caches",
-                  "xla_gpu_per_fusion_autotune_cache_dir")
+# persistent compilation cache (eager setup ops compile one XLA
+# executable per shape bucket): placed by the one rule of
+# amgx_tpu/compile_cache.py, never here
+compile_cache.enable(every_program=True)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

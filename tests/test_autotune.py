@@ -38,6 +38,30 @@ def geo10():
     return gallery.poisson("7pt", 10, 10, 10).init()
 
 
+@pytest.fixture(autouse=True)
+def _noise_free_shadow_clock(monkeypatch):
+    """Shadow solves here are 10^3 systems whose warm solve wall (3-8
+    ms) is fixed overhead, not iterations: 21 against 12 iterations
+    measured 4.1 against 4.8 ms as often as the reverse, so the
+    promote gate's `tuned wall <= baseline wall` was a coin toss on a
+    busy machine (it only looked stable while the native library did
+    not build and the Python-fallback hierarchy gave a 37-iteration
+    baseline). These tests are about the search and promote LOGIC, so
+    the measured wall is replaced by a noise-free clock proportional
+    to the iterations the shadow solve really took."""
+    from amgx_tpu.serving.autotune import ConfigAutotuner
+    real = ConfigAutotuner._shadow_solve
+
+    def shadow(self, fp, rec, deltas, label):
+        m = real(self, fp, rec, deltas, label)
+        if m is not None:
+            m["wall_s"] = 1e-4 * m["iters"]
+            m["score"] = m["iters"] * m["wall_s"]
+        return m
+
+    monkeypatch.setattr(ConfigAutotuner, "_shadow_solve", shadow)
+
+
 def _rhs(A, seed=0):
     return np.random.default_rng(seed).standard_normal(A.num_rows)
 

@@ -157,7 +157,7 @@ class TestUploadDistributed:
         physical layout (pure slicing, no renumbering)."""
         A, b = system
         import jax
-        from amgx_tpu._compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from amgx_tpu.distributed.partition import (
             partition_from_pieces, partition_vector, unpartition_vector)

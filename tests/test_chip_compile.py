@@ -1,0 +1,269 @@
+"""The chip's compiler, asked in the sandbox.
+
+AOT-compiles, from shapes, the kernels of the main path for a DESCRIBED
+TPU v5e (no chip attached) at the flagship's real fine-level shape
+(7-pt 128^3) and one coarse-level shape, so a kernel Mosaic refuses —
+an int64 in a kernel body, a block shape off the (8, 128) tiling, more
+VMEM than the limit handed to the compiler — fails tier-1 instead of
+the first solve on a chip. A pass here is not a chip run:
+`python chip_smoke.py` on the chip is.
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture that skips when
+it cannot be, never at import and never autouse; everything compiles
+in this process (the TPU library is held by one process); all such
+tests live in this one file.
+"""
+import os
+import sys
+import types
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from amgx_tpu.ops import pallas_spmv as ps
+from amgx_tpu.ops import pallas_swell as sw
+from amgx_tpu.ops import smooth as fused
+from amgx_tpu.ops import stencil
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
+
+FINE, COARSE = 128, 32          # 7-pt n^3 grids: flagship L0, and L2
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the one capability function (ops.pallas_spmv.
+    pallas_backend) onto its compiled-for-chip branch, from inside the
+    test: the program has no option for this."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ps.pallas_backend() == "mosaic"
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one; keep it off around
+    these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _spec7(n):
+    """StencilSpec of the constant-coefficient 7-pt operator on n^3
+    (Chebyshev levels carry no dinv)."""
+    offs = (-n * n, -n, -1, 0, 1, n, n * n)
+    shifts = ((0, 0, -1), (0, -1, 0), (-1, 0, 0), (0, 0, 0),
+              (1, 0, 0), (0, 1, 0), (0, 0, 1))
+    return stencil.StencilSpec(offs, shifts, (n, n, n), n ** 3, None, 3)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s[0], s[1], sharding=one_chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n", [FINE, COARSE])
+def test_dia_spmv_compiles(n, one_chip, on_tpu, no_persistent_cache):
+    sp = _spec7(n)
+    rows_pad = ps.dia_padded_rows(7, sp.n)
+    _compile(lambda v, x: ps._dia_spmv_call(v, x, sp.offsets, sp.n),
+             one_chip, ((7, rows_pad, 128), F32), ((sp.n,), F32))
+
+
+# (sweeps, with_residual): the flagship's Chebyshev pre-smoother is 5
+# damped applications + the residual, its post-smoother 5 without
+@pytest.mark.parametrize("n", [FINE, COARSE])
+@pytest.mark.parametrize("ns,wr", [(5, True), (5, False)])
+def test_dia_smooth_stencil_twin_compiles(n, ns, wr, one_chip, on_tpu,
+                                          no_persistent_cache):
+    sp = _spec7(n)
+    assert stencil.stencil_smooth_supported(sp, F32, ns, wr)
+    _compile(lambda c, t, b, x: ps._dia_stencil_smooth_call(
+        c, t, b, x, sp, wr),
+        one_chip, ((7,), F32), ((ns,), F32), ((sp.n,), F32),
+        ((sp.n,), F32))
+
+
+def test_dia_smooth_stencil_twin_27pt_compiles(one_chip, on_tpu,
+                                               no_persistent_cache):
+    """A 27-point matrix-free level keeps 27 masked value planes live:
+    the widest body the plan's VMEM arithmetic has to cover
+    (ops.pallas_spmv.smooth_body_planes)."""
+    n = 64
+    shifts = tuple((dx, dy, dz) for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+                   for dx in (-1, 0, 1))
+    offs = tuple(dx + n * dy + n * n * dz for dx, dy, dz in shifts)
+    sp = stencil.StencilSpec(offs, shifts, (n, n, n), n ** 3, "l1", 13)
+    assert stencil.stencil_smooth_supported(sp, F32, 2, True)
+    _compile(lambda c, t, b, x: ps._dia_stencil_smooth_call(
+        c, t, b, x, sp, True),
+        one_chip, ((27,), F32), ((2,), F32), ((sp.n,), F32),
+        ((sp.n,), F32))
+
+
+# the slab twin as classical DIA levels run it: Jacobi, dinv slab
+@pytest.mark.parametrize("n", [FINE, COARSE])
+@pytest.mark.parametrize("ns,wr", [(2, True), (1, False)])
+def test_dia_smooth_slab_twin_compiles(n, ns, wr, one_chip, on_tpu,
+                                       no_persistent_cache):
+    sp = _spec7(n)
+    assert ps.dia_smooth_plan(sp.offsets, 7, sp.n, ns, wr) is not None
+    q = sum(ps.smooth_quota_rows(sp.offsets, sp.n))
+    _compile(lambda v, d, t, b, x: ps._dia_smooth_call(
+        v, d, t, b, x, sp.offsets, sp.n, wr),
+        one_chip, ((7, q, 128), F32), ((q, 128), F32), ((ns,), F32),
+        ((sp.n,), F32), ((sp.n,), F32))
+
+
+@pytest.mark.parametrize("n", [FINE, COARSE])
+@pytest.mark.parametrize("form", ["pdot_beta", "ddot_self"])
+def test_dia_spmv_dot_compiles(n, form, one_chip, on_tpu,
+                               no_persistent_cache):
+    sp = _spec7(n)
+    rows_pad = ps.dia_padded_rows(7, sp.n)
+    vec = ((sp.n,), F32)
+    if form == "pdot_beta":       # CG: p' = z + beta p, Ap', p'.Ap'
+        _compile(lambda v, p, z, beta: ps._dia_spmv_dot_call(
+            v, p, z, beta, None, sp.offsets, sp.n),
+            one_chip, ((7, rows_pad, 128), F32), vec, vec, ((), F32))
+    else:                         # BiCGStab: t = A s, t.s and t.t
+        _compile(lambda v, p, d: ps._dia_spmv_dot_call(
+            v, p, None, None, d, sp.offsets, sp.n, self_dot=True),
+            one_chip, ((7, rows_pad, 128), F32), vec, vec)
+
+
+@pytest.mark.parametrize("n", [FINE, COARSE])
+def test_stencil_spmv_dot_compiles(n, one_chip, on_tpu,
+                                   no_persistent_cache):
+    sp = _spec7(n)
+    assert stencil.stencil_spmv_dot_supported(sp, F32)
+    vec = ((sp.n,), F32)
+    _compile(lambda c, p, z, beta: ps._dia_spmv_dot_call(
+        None, p, z, beta, None, sp.offsets, sp.n, mf=sp, coeffs=c),
+        one_chip, ((7,), F32), vec, vec, ((), F32))
+
+
+@pytest.mark.parametrize("n", [FINE, COARSE])
+def test_cg_update_compiles(n, one_chip, on_tpu, no_persistent_cache):
+    vec = ((n ** 3,), F32)
+    _compile(lambda x, p, r, ap, a: ps._cg_update_call(x, p, r, ap, a),
+             one_chip, vec, vec, vec, vec, ((), F32))
+
+
+# SWELL shapes of a classical coarse level under 7-pt 64^3 (PMIS+D2):
+# 32 super-blocks of 1024 rows, 24 slots per row, a 96-row x window
+@pytest.mark.parametrize("kernel", ["spmv", "smooth"])
+def test_swell_kernels_compile(kernel, one_chip, on_tpu,
+                               no_persistent_cache):
+    nb, kpad, w128 = 32, 24, 96
+    n = nb * sw.BLOCK_ROWS
+    ent = ((nb, sw.SUBS, kpad, 128), jnp.int32)
+    val = ((nb, sw.SUBS, kpad, 128), F32)
+    blk = ((nb,), jnp.int32)
+    vec = ((n,), F32)
+    if kernel == "spmv":
+        _compile(lambda c, v, c0, nc, x: sw._swell_spmv_call(
+            c, v, c0, nc, x, w128, n),
+            one_chip, ent, val, blk, blk, vec)
+    else:
+        _compile(lambda c, v, c0, nc, x, b, d, t: sw._swell_smooth_call(
+            c, v, c0, nc, x, b, d, t, w128, n, True),
+            one_chip, ent, val, blk, blk, vec, vec, vec, ((1,), F32))
+
+
+def test_declined_families_decline_on_chip_only(on_tpu):
+    """Every gate of the family Mosaic refuses ("Only 2D gather is
+    supported": ops.pallas_spmv.flat_gather_ok) says no on the
+    compiled-for-chip branch, and yes again under the interpreter, so
+    the CPU interpret suites of those kernels keep running."""
+    import amgx_tpu as amgx
+    from amgx_tpu import gallery
+    from amgx_tpu.config import Config
+
+    def gates():
+        A = gallery.poisson("7pt", 16, 16, 16, dtype=np.float32).init()
+        slv = amgx.create_solver(Config.from_string(
+            "solver=AMG, algorithm=AGGREGATION, selector=GEO,"
+            " smoother=JACOBI_L1, presweeps=2, postsweeps=1,"
+            " max_iters=1, coarse_solver=DENSE_LU_SOLVER,"
+            " min_coarse_rows=16, max_levels=10, matrix_free=0,"
+            " cycle_fusion_tail_rows=100000"))
+        slv.setup(A)
+        amg, d = slv.amg, slv.solve_data()["amg"]
+        xfer = d["levels"][0].get("xfer")
+        b = jnp.ones(A.num_rows, F32)
+        tail = fused.coarse_tail_cycle(amg, "V", d, 0, b,
+                                       jnp.zeros_like(b))
+        return {
+            "family": ps.flat_gather_ok(),
+            "slabs_built": xfer is not None,
+            "slab_gate": ps._transfer_gate(amg.levels[0].A, F32),
+            "coarse_tail": tail is not None,
+        }
+
+    assert ps.declined_families()
+    assert gates() == {"family": False, "slabs_built": False,
+                       "slab_gate": False, "coarse_tail": False}
+    sp = _spec7(16)
+    xfer = types.SimpleNamespace(cwt=None, ptab=None)
+    assert not stencil.stencil_restrict_supported(sp, F32, 2, xfer)
+    assert not stencil.stencil_prolong_supported(sp, F32, 2, xfer)
+    # the plan-split RAP value kernel (1-D gathers too) and the bf16
+    # windows of the DIA kernels
+    from amgx_tpu.ops import pallas_spgemm as pk
+    assert not pk.rap_kernel_ready(object(), F32)
+    assert ps.kernel_dtype_ok(F32) and not ps.kernel_dtype_ok(jnp.bfloat16)
+    assert not stencil.stencil_smooth_supported(sp, jnp.bfloat16, 2, True)
+    with ps.force_pallas_interpret():
+        assert ps.declined_families() == {}
+        assert ps.kernel_dtype_ok(jnp.bfloat16)
+        assert stencil.stencil_smooth_supported(sp, jnp.bfloat16, 2, True)
+        assert gates() == {"family": True, "slabs_built": True,
+                           "slab_gate": True, "coarse_tail": True}
+
+
+def test_chip_smoke_flagship_phase_rehearsal():
+    """Rehearsal 1 of chip_smoke.py kept as a test so the script cannot
+    rot between chip runs: the flagship phase (setup, three solves,
+    plain path, resetup) at 16^3 under the Pallas interpreter."""
+    with ps.force_pallas_interpret():
+        chip_smoke.phase_flagship(16, on_chip=False)
+
+
+def test_chip_smoke_refuses_cpu(capsys):
+    """No accelerator: non-zero exit at the device phase, no result
+    line on stdout."""
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main([])
+    assert e.value.code not in (0, None)
+    assert capsys.readouterr().out == ""

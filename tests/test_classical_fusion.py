@@ -13,13 +13,13 @@ parity reference), the jaxpr HBM-pass proof (a smoothed classical DIA
 level runs EXACTLY two fused kernels per cycle with zero standalone
 SpMV/transfer primitives outside them), and the cycle_fusion=0 escape
 hatch reproducing the unfused composition bit-for-bit."""
-import re
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
+import _census
 import amgx_tpu as amgx
 from amgx_tpu import gallery
 from amgx_tpu.config import Config
@@ -272,36 +272,9 @@ def _trace_cycle(extra_cfg="", n=12):
     return pc.amg, jaxpr
 
 
-def _kernel_counts(jaxpr):
-    names = re.findall(r"name=\"?([A-Za-z_0-9]+)\"?", str(jaxpr))
-    out = {}
-    for nm in names:
-        for key in ("_dia_smooth_restrict_call",
-                    "_dia_prolong_smooth_call", "_dia_coarse_tail_call",
-                    "_dia_smooth_call", "_dia_spmv_call",
-                    "_swell_spmv_call", "_swell_smooth_call"):
-            if nm == key:
-                out[key] = out.get(key, 0) + 1
-    return out
-
-
-def _outer_prims(closed_jaxpr):
-    prims = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                continue
-            prims.append(eqn.primitive.name)
-            for p in eqn.params.values():
-                for q in (p if isinstance(p, (tuple, list)) else (p,)):
-                    if isinstance(q, jax.core.ClosedJaxpr):
-                        walk(q.jaxpr)
-                    elif isinstance(q, jax.core.Jaxpr):
-                        walk(q)
-
-    walk(closed_jaxpr.jaxpr)
-    return prims
+# shared census walk (amgx_tpu/telemetry/census.py via tests/_census.py)
+_kernel_counts = _census.kernel_counts
+_outer_prims = _census.outer_prims
 
 
 def test_jaxpr_proof_classical_fused_kernel_budget():

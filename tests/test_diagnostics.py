@@ -25,7 +25,6 @@ bench-regression sentinel (the observability PR's acceptance contracts):
 import json
 import os
 import re
-import shutil
 import subprocess
 import sys
 
@@ -562,14 +561,36 @@ def test_sentinel_single_round_judges_nothing(tmp_path):
         open(tmp_path / "BENCH_HISTORY.json"))["regressions"] == []
 
 
+def _five_round_history(root):
+    """A five-round history shaped like the rounds the sentinel was
+    written against, written here (the root's round files are records
+    the roadmap deletes): six tracked series, r03's 5.87 s warm setup
+    regressing to 17.37 s in r05, and r05's `parsed` lost to a
+    truncated tail."""
+    solve = {1: 0.330, 2: 0.307, 3: 0.314, 4: 0.315}
+    for n, s in solve.items():
+        extra = {"flagship_128^3_solve_s": s,
+                 "flagship_128^3_setup_warm_s": 1.1,
+                 "northstar_256^3_solve_s": 3.3 - 0.05 * n,
+                 "spmv_vs_ceiling": 0.8,
+                 "classical_128^3_solve_s": 7.3}
+        if n >= 3:
+            extra["northstar_256^3_setup_warm_s"] = 5.87
+        with open(os.path.join(root, f"BENCH_r{n:02d}.json"), "w") as f:
+            json.dump(_wrapper(n, extra), f)
+    tail = ('...cut... "northstar_256^3_setup_warm_s": 17.37,'
+            ' "northstar_256^3_solve_s": 3.057,'
+            ' "flagship_128^3_solve_s": 0.304,'
+            ' "flagship_128^3_setup_warm_s": 1.07, "cut_key": 1')
+    with open(os.path.join(root, "BENCH_r05.json"), "w") as f:
+        json.dump(_wrapper(5, {}, parsed=False, tail_extra=tail), f)
+
+
 def test_sentinel_flags_checked_in_r05_regression(tmp_path):
-    """The acceptance demo over COPIES of the checked-in r01-r05
-    artifacts (copies so the assertion stays stable as later rounds
-    land): >= 5 tracked series populate and the r05 warm-setup
+    """The acceptance demo over a five-round history written by the
+    test: >= 5 tracked series populate and the r05 warm-setup
     regression (17.37 s vs r03's 5.87 s) is flagged."""
-    for name in os.listdir(REPO):
-        if re.match(r"(BENCH|MULTICHIP)_r0[1-5]\.json$", name):
-            shutil.copy(os.path.join(REPO, name), tmp_path / name)
+    _five_round_history(tmp_path)
     p = _run_history(["--root", str(tmp_path)])
     assert p.returncode != 0
     hist = json.load(open(tmp_path / "BENCH_HISTORY.json"))
@@ -584,9 +605,12 @@ def test_sentinel_flags_checked_in_r05_regression(tmp_path):
 
 
 def test_sentinel_smoke_ok_and_catches_malformed(tmp_path):
-    """--smoke (the tier-1-reachable self-check): passes on the
-    checked-in artifacts, fails fast on a malformed one."""
-    p = _run_history(["--smoke"])
+    """--smoke (the tier-1-reachable self-check): passes on a
+    well-formed history, fails fast on a malformed artifact."""
+    good = tmp_path / "good"
+    good.mkdir()
+    _five_round_history(good)
+    p = _run_history(["--smoke", "--root", str(good)])
     assert p.returncode == 0, p.stdout + p.stderr
     assert "OK" in p.stdout
     with open(tmp_path / "BENCH_r01.json", "w") as f:

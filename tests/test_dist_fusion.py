@@ -20,9 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+import _census
 import amgx_tpu as amgx
 from amgx_tpu import gallery
-from amgx_tpu._compat import shard_map
+from jax import shard_map
 from amgx_tpu.config import Config
 from amgx_tpu.distributed import DistributedSolver, default_mesh
 from amgx_tpu.distributed import comms
@@ -273,12 +274,8 @@ def test_jaxpr_kernel_inputs_independent_of_collective():
                     assert id(v) not in tainted, (
                         "fused kernel consumes the halo collective "
                         "output — the overlap is broken")
-            for p in eqn.params.values():
-                for q in (p if isinstance(p, (tuple, list)) else (p,)):
-                    if isinstance(q, jax.core.ClosedJaxpr):
-                        walk(q.jaxpr)
-                    elif isinstance(q, jax.core.Jaxpr):
-                        walk(q)
+            for sub in _census.subjaxprs(eqn):
+                walk(sub)
 
     walk(jaxpr.jaxpr)
     assert kernels_seen >= 2

@@ -36,7 +36,7 @@ def dist_spmv_global(A, n_ranks, mesh, x):
         return local.spmv(xs[0])[None]
 
     pspec = jax.tree.map(lambda _: P("p"), sm)
-    from amgx_tpu._compat import shard_map
+    from jax import shard_map
     mapped = shard_map(fn, mesh=mesh, in_specs=(pspec, P("p")),
                        out_specs=P("p"), check_vma=False)
     yl = mapped(sm, xl)

@@ -14,13 +14,13 @@ per-precision iteration counts recorded), halved slab bytes (plan
 accounting) and halved modeled distributed exchange bytes on a 4-shard
 mesh, and the fusion.declined_dtype counter + per-level routing column
 that make falling off the fused path visible."""
-import re
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
+import _census
 import amgx_tpu as amgx
 from amgx_tpu import gallery
 from amgx_tpu.config import Config
@@ -336,35 +336,9 @@ def _trace_cycle(extra_cfg="", n=16):
     return pc.amg, jaxpr
 
 
-def _kernel_counts(jaxpr):
-    names = re.findall(r"name=\"?([A-Za-z_0-9]+)\"?", str(jaxpr))
-    out = {}
-    for nm in names:
-        for key in ("_dia_smooth_restrict_call",
-                    "_dia_prolong_smooth_call", "_dia_coarse_tail_call",
-                    "_dia_smooth_call", "_dia_spmv_call"):
-            if nm == key:
-                out[key] = out.get(key, 0) + 1
-    return out
-
-
-def _outer_prims(closed_jaxpr):
-    prims = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                continue
-            prims.append(eqn.primitive.name)
-            for p in eqn.params.values():
-                for q in (p if isinstance(p, (tuple, list)) else (p,)):
-                    if isinstance(q, jax.core.ClosedJaxpr):
-                        walk(q.jaxpr)
-                    elif isinstance(q, jax.core.Jaxpr):
-                        walk(q)
-
-    walk(closed_jaxpr.jaxpr)
-    return prims
+# shared census walk (amgx_tpu/telemetry/census.py via tests/_census.py)
+_kernel_counts = _census.kernel_counts
+_outer_prims = _census.outer_prims
 
 
 def test_jaxpr_bf16_cycle_kernel_census():
@@ -555,7 +529,7 @@ def test_bf16_solve_fusion_counters_clean():
 
 def _dist_cycle_rig(n_dev=4):
     from jax.sharding import PartitionSpec as P
-    from amgx_tpu._compat import shard_map
+    from jax import shard_map
     from amgx_tpu.distributed import DistributedSolver, default_mesh
     from amgx_tpu.distributed import comms
     from amgx_tpu.amg.cycles import run_cycle

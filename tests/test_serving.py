@@ -897,10 +897,6 @@ def test_bench_serving_smoke():
     slice of the acceptance gates (cache-hit rate > 0, value-resetup
     routing, zero retraces after AOT warmup, deadline statuses)."""
     import bench
-    # bench.py switches the process compile-cache dir at import; point
-    # it back at the suite's cache so later tests stay warm
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/amgx_tpu_jax_cache")
     res = bench.bench_serving(smoke=True)
     assert res["all_completed"]
     assert res["solves_per_s"] > 0
@@ -923,8 +919,6 @@ def test_bench_chaos_smoke():
     service scenarios — the per-scenario unit tests above are the
     tier-1 subset.)"""
     import bench
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/amgx_tpu_jax_cache")
     res = bench.bench_chaos(smoke=True)
     assert res["killed_inflight"] > 0
     assert res["recover_replayed"] > 0 and res["recover_resumed"] > 0
