@@ -6,9 +6,21 @@ v/w/f/cg_cycle.cu choose the recursion shape; registry
 src/core.cu:631-635). Here the recursion is plain Python unrolled at
 trace time over the static hierarchy depth, so a whole cycle is one XLA
 program.
+
+Every stage is traced under a `jax.named_scope` so that a device op can
+be put down to its level and stage (telemetry/programs.py reads the
+scopes back from the compiled program; benchmark/scope_metrics.py joins
+them with a trace): `amg.L<k>` round everything a level does,
+`amg.L<k>.presmooth` / `.restrict` / `.prolong` / `.postsmooth` round
+the stages (a fused kernel that spans two takes both names:
+`.presmooth_restrict`, `.prolong_postsmooth`), `amg.coarse` round the
+coarsest solve, `amg.tail.L<k>` round the VMEM-resident coarse tail
+entered at level k. A scope is metadata: it adds no op and renames no
+kernel.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..ops import blas
@@ -56,7 +68,7 @@ def _fusion_caps(level, data):
     return fn(level, data)
 
 
-def _smooth_restrict(amg, level, data, b, x, sweeps: int):
+def _smooth_restrict(amg, level, data, b, x, sweeps: int, lvl: int):
     """Presmooth + restriction: with cycle_fusion, aggregation/DIA
     levels emit the segment-summed coarse rhs from the presmoother
     kernel's epilogue (ops/smooth.py) — the residual never round-trips
@@ -70,14 +82,17 @@ def _smooth_restrict(amg, level, data, b, x, sweeps: int):
     pair."""
     if amg.cycle_fusion and sweeps > 0 and \
             "restrict" in _fusion_caps(level, data):
-        out = level.restrict_fused(data, b, x, sweeps)
+        with jax.named_scope(f"amg.L{lvl}.presmooth_restrict"):
+            out = level.restrict_fused(data, b, x, sweeps)
         if out is not None:
             return out
-    x, r = _smooth_residual(level, data, b, x, sweeps)
-    return x, level.restrict(data, r)
+    with jax.named_scope(f"amg.L{lvl}.presmooth"):
+        x, r = _smooth_residual(level, data, b, x, sweeps)
+    with jax.named_scope(f"amg.L{lvl}.restrict"):
+        return x, level.restrict(data, r)
 
 
-def _prolongate_smooth(amg, level, data, b, x, xc, sweeps: int,
+def _prolongate_smooth(amg, level, data, b, x, xc, sweeps: int, lvl: int,
                        want_dot: bool = False):
     """Prolongation + correction + postsmooth: with cycle_fusion,
     aggregation AND classical DIA levels fold x + P xc into the
@@ -94,15 +109,18 @@ def _prolongate_smooth(amg, level, data, b, x, xc, sweeps: int,
     signatures that predate it keep working un-updated."""
     if amg.cycle_fusion and sweeps > 0 and \
             "prolongate" in _fusion_caps(level, data):
-        if want_dot:
-            out = level.prolongate_smooth(data, b, x, xc, sweeps,
-                                          want_dot=True)
-        else:
-            out = level.prolongate_smooth(data, b, x, xc, sweeps)
+        with jax.named_scope(f"amg.L{lvl}.prolong_postsmooth"):
+            if want_dot:
+                out = level.prolongate_smooth(data, b, x, xc, sweeps,
+                                              want_dot=True)
+            else:
+                out = level.prolongate_smooth(data, b, x, xc, sweeps)
         if out is not None:
             return out
-    x = x + level.prolongate(data, xc)
-    x = _smooth(level, data, b, x, sweeps)
+    with jax.named_scope(f"amg.L{lvl}.prolong"):
+        x = x + level.prolongate(data, xc)
+    with jax.named_scope(f"amg.L{lvl}.postsmooth"):
+        x = _smooth(level, data, b, x, sweeps)
     return (x, None) if want_dot else x
 
 
@@ -123,17 +141,18 @@ def apply_coarse_solver(cs, data, bc, xc, coarsest_sweeps: int):
 
 
 def _coarse_solve(amg, data, bc, xc):
-    if bc.dtype == jnp.bfloat16:
-        # the coarse tail stays f32+ (precision.py policy keeps the
-        # coarse-solver payload at f32): a bf16 cycle upcasts the
-        # coarse rhs around the solve and rounds the correction back
-        out = apply_coarse_solver(
-            amg.coarse_solver, data["coarse"],
-            bc.astype(jnp.float32), xc.astype(jnp.float32),
-            amg.coarsest_sweeps)
-        return out.astype(bc.dtype)
-    return apply_coarse_solver(amg.coarse_solver, data["coarse"], bc, xc,
-                               amg.coarsest_sweeps)
+    with jax.named_scope("amg.coarse"):
+        if bc.dtype == jnp.bfloat16:
+            # the coarse tail stays f32+ (precision.py policy keeps the
+            # coarse-solver payload at f32): a bf16 cycle upcasts the
+            # coarse rhs around the solve and rounds the correction back
+            out = apply_coarse_solver(
+                amg.coarse_solver, data["coarse"],
+                bc.astype(jnp.float32), xc.astype(jnp.float32),
+                amg.coarsest_sweeps)
+            return out.astype(bc.dtype)
+        return apply_coarse_solver(amg.coarse_solver, data["coarse"], bc,
+                                   xc, amg.coarsest_sweeps)
 
 
 def _cycle(amg, shape: str, data, lvl: int, b, x, want_dot: bool = False):
@@ -158,112 +177,115 @@ def _cycle(amg, shape: str, data, lvl: int, b, x, want_dot: bool = False):
         # -> ... -> coarsest solve -> ... -> prolongate -> smooth) is
         # ONE pallas_call instead of ~10 tiny dispatches per cycle
         from ..ops.smooth import coarse_tail_cycle
-        out = coarse_tail_cycle(amg, shape, data, lvl, b, x,
-                                want_dot=want_dot)
+        with jax.named_scope(f"amg.tail.L{lvl}"):
+            out = coarse_tail_cycle(amg, shape, data, lvl, b, x,
+                                    want_dot=want_dot)
         if out is not None:
             return out
-    level = levels[lvl]
-    ldata = data["levels"][lvl]
-    if rec is not None:
-        rec.record(lvl, 0, _level_A(ldata), x, b)
-    x, bc = _smooth_restrict(amg, level, ldata, b, x,
-                             amg._sweeps(lvl, pre=True))
-    if rec is not None:
-        rec.record(lvl, 1, _level_A(ldata), x, b)
-    xc = jnp.zeros_like(bc)
-    if shape == "V":
-        xc = _cycle(amg, "V", data, lvl + 1, bc, xc)
-    elif shape == "W":
-        xc = _cycle(amg, "W", data, lvl + 1, bc, xc)
-        if lvl + 1 < len(levels):   # second visit (W shape)
-            xc = _cycle(amg, "W", data, lvl + 1, bc, xc)
-    elif shape == "F":
-        xc = _cycle(amg, "F", data, lvl + 1, bc, xc)
-        if lvl + 1 < len(levels):   # F = one F-visit then one V-visit
+    with jax.named_scope(f"amg.L{lvl}"):
+        level = levels[lvl]
+        ldata = data["levels"][lvl]
+        if rec is not None:
+            rec.record(lvl, 0, _level_A(ldata), x, b)
+        x, bc = _smooth_restrict(amg, level, ldata, b, x,
+                                 amg._sweeps(lvl, pre=True), lvl)
+        if rec is not None:
+            rec.record(lvl, 1, _level_A(ldata), x, b)
+        xc = jnp.zeros_like(bc)
+        if shape == "V":
             xc = _cycle(amg, "V", data, lvl + 1, bc, xc)
-    else:
-        raise ValueError(f"unknown fixed cycle {shape!r}")
-    if rec is not None:
-        x = x + level.prolongate(ldata, xc)
-        rec.record(lvl, 2, _level_A(ldata), x, b)
-        x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
-        rec.record(lvl, 3, _level_A(ldata), x, b)
-        return (x, None) if want_dot else x
-    return _prolongate_smooth(amg, level, ldata, b, x, xc,
-                              amg._sweeps(lvl, pre=False),
-                              want_dot=want_dot)
+        elif shape == "W":
+            xc = _cycle(amg, "W", data, lvl + 1, bc, xc)
+            if lvl + 1 < len(levels):   # second visit (W shape)
+                xc = _cycle(amg, "W", data, lvl + 1, bc, xc)
+        elif shape == "F":
+            xc = _cycle(amg, "F", data, lvl + 1, bc, xc)
+            if lvl + 1 < len(levels):   # F = one F-visit then one V-visit
+                xc = _cycle(amg, "V", data, lvl + 1, bc, xc)
+        else:
+            raise ValueError(f"unknown fixed cycle {shape!r}")
+        if rec is not None:
+            x = x + level.prolongate(ldata, xc)
+            rec.record(lvl, 2, _level_A(ldata), x, b)
+            x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
+            rec.record(lvl, 3, _level_A(ldata), x, b)
+            return (x, None) if want_dot else x
+        return _prolongate_smooth(amg, level, ldata, b, x, xc,
+                                  amg._sweeps(lvl, pre=False), lvl,
+                                  want_dot=want_dot)
 
 
 def _kcycle(amg, data, lvl: int, b, x, flex: bool):
     """CG / CGF cycle (cg_cycle.cu, cg_flex_cycle.cu): the coarse-grid
     correction is accelerated by `cycle_iters` steps of (flexible) CG
     whose preconditioner is the next-coarser cycle."""
-    levels = amg.levels
-    if lvl == len(levels):
+    if lvl == len(amg.levels):
         return _coarse_solve(amg, data, b, x)
-    level = levels[lvl]
-    ldata = data["levels"][lvl]
-    rec = _diag.current()
-    if rec is not None:
-        rec.record(lvl, 0, _level_A(ldata), x, b)
-    x, bc = _smooth_restrict(amg, level, ldata, b, x,
-                             amg._sweeps(lvl, pre=True))
-    if rec is not None:
-        rec.record(lvl, 1, _level_A(ldata), x, b)
-    Ac_data_lvl = lvl + 1
+    with jax.named_scope(f"amg.L{lvl}"):
+        levels = amg.levels
+        level = levels[lvl]
+        ldata = data["levels"][lvl]
+        rec = _diag.current()
+        if rec is not None:
+            rec.record(lvl, 0, _level_A(ldata), x, b)
+        x, bc = _smooth_restrict(amg, level, ldata, b, x,
+                                 amg._sweeps(lvl, pre=True), lvl)
+        if rec is not None:
+            rec.record(lvl, 1, _level_A(ldata), x, b)
+        Ac_data_lvl = lvl + 1
 
-    def M(v):
-        return _kcycle(amg, data, Ac_data_lvl, v, jnp.zeros_like(v), flex)
+        def M(v):
+            return _kcycle(amg, data, Ac_data_lvl, v, jnp.zeros_like(v), flex)
 
-    def Ac_mv(v):
-        if Ac_data_lvl == len(levels):
-            if v.dtype == jnp.bfloat16:
-                # the coarsest operator stays f32+ under a bf16 cycle
-                # (precision policy) — upcast the matvec and round
-                # back so the K-cycle recurrence keeps one dtype
-                return spmv_coarsest(
-                    amg, data, v.astype(jnp.float32)).astype(v.dtype)
-            return spmv_coarsest(amg, data, v)
-        # matrix-free coarse levels materialize in-trace for the
-        # K-cycle matvec (VPU work instead of a resident slab)
-        return spmv(_level_A(data["levels"][Ac_data_lvl]), v)
+        def Ac_mv(v):
+            if Ac_data_lvl == len(levels):
+                if v.dtype == jnp.bfloat16:
+                    # the coarsest operator stays f32+ under a bf16 cycle
+                    # (precision policy) — upcast the matvec and round
+                    # back so the K-cycle recurrence keeps one dtype
+                    return spmv_coarsest(
+                        amg, data, v.astype(jnp.float32)).astype(v.dtype)
+                return spmv_coarsest(amg, data, v)
+            # matrix-free coarse levels materialize in-trace for the
+            # K-cycle matvec (VPU work instead of a resident slab)
+            return spmv(_level_A(data["levels"][Ac_data_lvl]), v)
 
-    # a few steps of preconditioned CG on the coarse equation
-    xc = jnp.zeros_like(bc)
-    rc = bc
-    z = M(rc)
-    p = z
-    rz = blas.dot(rc, z)
-    k_iters = max(amg.cycle_iters, 1)
-    for it in range(k_iters):
-        Ap = Ac_mv(p)
-        denom = blas.dot(p, Ap)
-        alpha = rz / jnp.where(denom == 0, 1.0, denom) * (denom != 0)
-        xc = xc + alpha * p
-        rc_old = rc
-        rc = rc - alpha * Ap
-        if it + 1 == k_iters:
-            break   # last update: skip the unused trailing M()/beta/p
+        # a few steps of preconditioned CG on the coarse equation
+        xc = jnp.zeros_like(bc)
+        rc = bc
         z = M(rc)
-        rz_new = blas.dot(rc, z)
-        if flex:
-            # flexible (Polak-Ribiere) beta tolerates a varying M
-            num = blas.dot(rc - rc_old, z)
-        else:
-            # Fletcher-Reeves: the beta numerator IS the next rz —
-            # reuse it instead of computing the same reduction twice
-            num = rz_new
-        beta = num / jnp.where(rz == 0, 1.0, rz) * (rz != 0)
-        rz = rz_new
-        p = z + beta * p
-    if rec is not None:
-        x = x + level.prolongate(ldata, xc)
-        rec.record(lvl, 2, _level_A(ldata), x, b)
-        x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
-        rec.record(lvl, 3, _level_A(ldata), x, b)
-        return x
-    return _prolongate_smooth(amg, level, ldata, b, x, xc,
-                              amg._sweeps(lvl, pre=False))
+        p = z
+        rz = blas.dot(rc, z)
+        k_iters = max(amg.cycle_iters, 1)
+        for it in range(k_iters):
+            Ap = Ac_mv(p)
+            denom = blas.dot(p, Ap)
+            alpha = rz / jnp.where(denom == 0, 1.0, denom) * (denom != 0)
+            xc = xc + alpha * p
+            rc_old = rc
+            rc = rc - alpha * Ap
+            if it + 1 == k_iters:
+                break   # last update: skip the unused trailing M()/beta/p
+            z = M(rc)
+            rz_new = blas.dot(rc, z)
+            if flex:
+                # flexible (Polak-Ribiere) beta tolerates a varying M
+                num = blas.dot(rc - rc_old, z)
+            else:
+                # Fletcher-Reeves: the beta numerator IS the next rz —
+                # reuse it instead of computing the same reduction twice
+                num = rz_new
+            beta = num / jnp.where(rz == 0, 1.0, rz) * (rz != 0)
+            rz = rz_new
+            p = z + beta * p
+        if rec is not None:
+            x = x + level.prolongate(ldata, xc)
+            rec.record(lvl, 2, _level_A(ldata), x, b)
+            x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
+            rec.record(lvl, 3, _level_A(ldata), x, b)
+            return x
+        return _prolongate_smooth(amg, level, ldata, b, x, xc,
+                                  amg._sweeps(lvl, pre=False), lvl)
 
 
 def spmv_coarsest(amg, data, v):

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .errors import BadParametersError
+from .telemetry import metrics as _tm
+from .telemetry.spans import span
 
 
 class _DeviceSetupState(threading.local):
@@ -589,19 +591,23 @@ class CsrMatrix:
         if self.initialized and self.ell_cols is not None:
             # structure auxiliaries (row_ids, diag_idx, ell_cols) survive;
             # only the padded ELL values depend on the coefficients
-            max_k = self.ell_cols.shape[1]
-            flat = out._ell_slots(self.row_ids, max_k)
-            out = dataclasses.replace(
-                out, ell_vals=out._scatter_ell_vals(flat, max_k))
+            with span("matrix.refill_host", counter="matrix.refill_host_s"):
+                max_k = self.ell_cols.shape[1]
+                flat = out._ell_slots(self.row_ids, max_k)
+                out = dataclasses.replace(
+                    out, ell_vals=out._scatter_ell_vals(flat, max_k))
         if self.initialized and self.dia_offsets is not None:
             out = out._refill_dia(values)
         if self.initialized and self.swell_cols is not None:
             if host_resident(self.row_offsets, values):
                 from .ops.pallas_swell import swell_vals_host
-                out = dataclasses.replace(
-                    out, swell_vals=swell_vals_host(
-                        np.asarray(self.row_offsets), np.asarray(values),
-                        self.num_rows, self.swell_cols.shape[2]))
+                with span("matrix.refill_host",
+                          counter="matrix.refill_host_s"):
+                    out = dataclasses.replace(
+                        out, swell_vals=swell_vals_host(
+                            np.asarray(self.row_offsets),
+                            np.asarray(values),
+                            self.num_rows, self.swell_cols.shape[2]))
             else:
                 # structure kept but values not re-scatterable off-host;
                 # drop the fast-path layout rather than serve stale data
@@ -626,18 +632,20 @@ class CsrMatrix:
         if isinstance(values, np.ndarray) and ro is not None \
                 and ci is not None and not np.iscomplexobj(values):
             from .ops.pallas_spmv import LANES, dia_padded_rows
-            k = len(self.dia_offsets)
-            n = self.num_rows
-            row_ids = np.repeat(np.arange(n, dtype=np.int64),
-                                np.diff(ro))
-            offs = np.asarray(self.dia_offsets, np.int64)
-            d_idx = np.searchsorted(offs, ci.astype(np.int64) - row_ids)
-            rows_pad = dia_padded_rows(k, n)
-            flat = np.bincount(d_idx * (rows_pad * LANES) + row_ids,
-                               weights=values,
-                               minlength=k * rows_pad * LANES)
-            dia_np = flat.astype(values.dtype).reshape(k, rows_pad,
-                                                       LANES)
+            with span("matrix.refill_host", counter="matrix.refill_host_s"):
+                k = len(self.dia_offsets)
+                n = self.num_rows
+                row_ids = np.repeat(np.arange(n, dtype=np.int64),
+                                    np.diff(ro))
+                offs = np.asarray(self.dia_offsets, np.int64)
+                d_idx = np.searchsorted(offs,
+                                        ci.astype(np.int64) - row_ids)
+                rows_pad = dia_padded_rows(k, n)
+                flat = np.bincount(d_idx * (rows_pad * LANES) + row_ids,
+                                   weights=values,
+                                   minlength=k * rows_pad * LANES)
+                dia_np = flat.astype(values.dtype).reshape(k, rows_pad,
+                                                           LANES)
             # device of the (unchanged) structure arrays — the new
             # values may be host numpy at this point
             try:
@@ -645,16 +653,22 @@ class CsrMatrix:
                 on_accel = dev.platform != "cpu"
             except Exception:
                 on_accel = False
-            if on_accel:
-                import jax as _jax
-                vals_c = np.ascontiguousarray(values)
-                d_vals = _jax.device_put(vals_c, dev)
-                _register_host_mirror(d_vals, vals_c)
-                d_dia = _jax.device_put(dia_np, dev)
-                _register_host_mirror(d_dia, dia_np)
-                return dataclasses.replace(self, values=d_vals,
-                                           dia_vals=d_dia)
-            return dataclasses.replace(self, dia_vals=jnp.asarray(dia_np))
+            # no wait on what was put: the call never had one
+            with span("matrix.upload", counter="matrix.upload_s"):
+                if on_accel:
+                    import jax as _jax
+                    vals_c = np.ascontiguousarray(values)
+                    d_vals = _jax.device_put(vals_c, dev)
+                    _register_host_mirror(d_vals, vals_c)
+                    d_dia = _jax.device_put(dia_np, dev)
+                    _register_host_mirror(d_dia, dia_np)
+                    _tm.inc("matrix.upload_bytes",
+                            vals_c.nbytes + dia_np.nbytes)
+                    return dataclasses.replace(self, values=d_vals,
+                                               dia_vals=d_dia)
+                _tm.inc("matrix.upload_bytes", dia_np.nbytes)
+                return dataclasses.replace(self,
+                                           dia_vals=jnp.asarray(dia_np))
         return dataclasses.replace(
             self, dia_vals=self._build_dia_vals(self.dia_offsets,
                                                 self.row_ids))

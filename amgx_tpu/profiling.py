@@ -44,19 +44,6 @@ _tracing = False
 trace_region = _spans.span
 
 
-def annotate(name: str):
-    """Decorator form of trace_region."""
-    def deco(fn):
-        import functools
-
-        @functools.wraps(fn)
-        def wrapper(*a, **k):
-            with trace_region(name):
-                return fn(*a, **k)
-        return wrapper
-    return deco
-
-
 def start_trace(logdir: str):
     """Begin a device profile capture (jax.profiler.start_trace)."""
     global _tracing

@@ -239,8 +239,11 @@ class Solver:
         # two literal span names (not one computed string) so the
         # static registry check (tools/check_spans.py) covers them
         if reuse:
-            with trace_region(f"{self.name}.resetup"):
-                out = self.__setup_impl(A, reuse)
+            # filled by the body where the resetup drops the cached
+            # solve programs; the span reads it as it closes
+            span_args: Dict[str, Any] = {}
+            with trace_region(f"{self.name}.resetup", args=span_args):
+                out = self.__setup_impl(A, reuse, span_args)
         else:
             with trace_region(f"{self.name}.setup"):
                 out = self.__setup_impl(A, reuse)
@@ -250,7 +253,8 @@ class Solver:
             _tm.max_gauge("memory.setup_peak_bytes", peak_bytes())
         return out
 
-    def __setup_impl(self, A: CsrMatrix, reuse: bool):
+    def __setup_impl(self, A: CsrMatrix, reuse: bool,
+                     span_args: Optional[Dict[str, Any]] = None):
         t0 = time.perf_counter()
         snap = self._resetup_debug_snapshot() if reuse else None
         if not A.initialized:
@@ -278,6 +282,13 @@ class Solver:
         # the new coefficients flow through as arguments; clearing
         # would force a full Python re-trace per coefficient cycle
         if not (reuse and self._resetup_kept_static()):
+            if reuse:
+                cause = self._retrace_cause()
+                span_args["retrace_cause"] = cause
+                if self._jit_cache:
+                    # this resetup costs the next solve a retrace
+                    from ..telemetry import metrics as _tm
+                    _tm.inc(f"resetup.retrace_cause.{cause}")
             self._jit_cache.clear()
             # batched wrappers close over this tree's traces, so they
             # go stale together (same-structure replays would serve
@@ -316,6 +327,20 @@ class Solver:
         unchanged, and new coefficients must surface as new leaves."""
         return (self.preconditioner is None
                 or self.preconditioner._resetup_kept_static())
+
+    def _retrace_cause(self) -> str:
+        """Which solver of this (sub)tree answered
+        `_resetup_kept_static()` with False: the deepest one that says
+        so (a solver above it only passes the answer up). Its name
+        where `resetup.retrace_cause.<name>` is a declared counter,
+        else "other"."""
+        from ..telemetry import metrics as _tm
+        cause, node = self, self.preconditioner
+        while node is not None and not node._resetup_kept_static():
+            cause, node = node, node.preconditioner
+        name = cause.name.upper()
+        return name if f"resetup.retrace_cause.{name}" in _tm.COUNTERS \
+            else "other"
 
     # -- resetup contract checking (AMGX_TPU_DEBUG_RESETUP=1) ------------
     @staticmethod
@@ -563,13 +588,21 @@ class Solver:
         stall_w = self.stall_window if guards else 0
         stall_tol = self.stall_tolerance
         S = SolveStatus
+        # device-side stage names (metadata only: telemetry/programs.py
+        # reads them back from the compiled program's op_names). `init`
+        # and `finalize` are here so that the shell's share is whole:
+        # the first residual (an f64 SpMV under REFINEMENT), its norm
+        # and the way back to x are the shell's device time, and would
+        # read as unscoped without a name
+        scope = f"krylov.{self.name}"
 
         def solve_fn(data, b, x0):
             A = data["A"]
-            r0 = _residual(A, x0, b)
-            norm0 = self._norm(r0)
-            state = {"x": x0, "r": r0}
-            state.update(self.solve_init(data, b, x0, r0))
+            with jax.named_scope(f"{scope}.init"):
+                r0 = _residual(A, x0, b)
+                norm0 = self._norm(r0)
+                state = {"x": x0, "r": r0}
+                state.update(self.solve_init(data, b, x0, r0))
             state["iters"] = jnp.asarray(0, jnp.int32)
             # zero RHS / zero initial residual: x0 solves the system
             # exactly — CONVERGED at 0 iterations instead of feeding
@@ -595,7 +628,8 @@ class Solver:
                 core = {k: v for k, v in st.items()
                         if k not in ("iters", "done", "converged",
                                      "res_norm", "res_hist", "status")}
-                with _fi.iteration_scope(iters):
+                with _fi.iteration_scope(iters), \
+                        jax.named_scope(f"{scope}.iter"):
                     core = self.solve_iteration(data, b, core)
                 new = dict(st)
                 new.update(core)
@@ -608,10 +642,13 @@ class Solver:
                         # norms are per-component vectors)
                         rn = jnp.broadcast_to(jnp.asarray(rn_int),
                                               np.shape(norm0))
-                    elif self.computes_residual():
-                        rn = self._norm(core["r"])
                     else:
-                        rn = self._norm(_residual(A, core["x"], b))
+                        with jax.named_scope(f"{scope}.monitor"):
+                            if self.computes_residual():
+                                rn = self._norm(core["r"])
+                            else:
+                                rn = self._norm(
+                                    _residual(A, core["x"], b))
                     new["res_norm"] = rn
                     new["res_hist"] = st["res_hist"].at[iters + 1].set(rn)
                     cvg = conv.check(rn, norm0)
@@ -662,7 +699,8 @@ class Solver:
                 # transient fault compiles clean (epoch is in the jit
                 # cache keys)
                 _fi.consume_loop_faults()
-            x_final = self.finalize(data, b, final)
+            with jax.named_scope(f"{scope}.finalize"):
+                x_final = self.finalize(data, b, final)
             status = jnp.where(final["status"] == _ST_RUNNING,
                                jnp.int32(S.MAX_ITERS), final["status"])
             # pack every scalar/stat output into ONE auxiliary array:
@@ -881,82 +919,122 @@ class Solver:
 
     def _solve_traced(self, b, x0=None, zero_initial_guess: bool = False
                       ) -> SolveResult:
+        """The host side of a solve, in four disjoint stages that are
+        spans under `<NAME>.solve` and counters of seconds
+        (`solve.stage_s.<stage>`): prepare, run, readback, report.
+        Nested solvers are traced into the one program and never come
+        through here, so the stages are the called solver's own."""
+        from ..telemetry import metrics as _tm
+        from ..telemetry.spans import span
         if self.A is None:
             raise BadParametersError(
                 f"solver {self.name}: solve() before setup()")
-        b = jnp.asarray(b)
-        if x0 is None or zero_initial_guess:
-            x0 = jnp.zeros_like(b)
-        else:
-            x0 = jnp.asarray(x0)
-        if self.scaler is not None:
-            # solve (LAR) x' = L b, return x = R x' (monitored residuals
-            # are in the scaled system — reference caveat solver.cu:449)
-            b = self.scaler.scale_rhs(b)
-            x0 = self.scaler.to_scaled_x(x0)
-        # the faultinject epoch keys the cache so arming/consuming a
-        # fault retraces instead of replaying a (possibly poisoned)
-        # cached program; it is 0 forever when injection is unused
-        key = (b.shape, str(b.dtype), _fi.epoch())
-        if key not in self._jit_cache:
-            from ..telemetry import metrics as _tm
-            _tm.inc("solver.retrace.solve")
-            _fi.evict_stale_epochs(self._jit_cache, key[-1])
-            self._jit_cache[key] = jax.jit(self._build_solve_fn())
-        t0 = time.perf_counter()
-        x, stats = jax.block_until_ready(self._jit_cache[key](
-            self.solve_data(), b, x0))
-        if self.scaler is not None:
-            x = self.scaler.from_scaled_x(x)
-        solve_time = time.perf_counter() - t0
-        # diagnostics probe output rides the stats tail (same buffer,
-        # no extra transfer); strip it by the same spec the trace used
-        # before the bare-layout unpack
-        diag_spec = self._diag_probe_spec()
-        diag_raw = None
-        stats = np.asarray(stats)
-        if diag_spec is not None:
-            from ..telemetry import diagnostics as _dg
-            dlen = _dg.slots_len(diag_spec[0])
-            if dlen:
-                diag_raw = stats[stats.size - dlen:]
-                stats = stats[:stats.size - dlen]
-        # solver-declared extras sit just before the diagnostics tail;
-        # strip by the same spec the trace packed them with
-        extra_names = self._extra_stats_spec()
-        extras = None
-        if extra_names:
-            raw = stats[stats.size - len(extra_names):]
-            stats = stats[:stats.size - len(extra_names)]
-            extras = {k: float(v) for k, v in zip(extra_names, raw)}
-        iters_i, converged, status, norm0, res_norm, hist = \
-            self.unpack_stats(stats, self.max_iters + 1)
-        res = SolveResult(
-            x=x, iterations=iters_i, converged=converged,
-            res_norm=np.asarray(res_norm), norm0=np.asarray(norm0),
-            res_history=np.asarray(hist)
-            if self.store_res_history else None,
-            setup_time=self.setup_time, solve_time=solve_time,
-            status_code=status, extra_stats=extras)
-        if self.telemetry:
-            # structured report (telemetry/report.py): built from the
-            # stats numpy already unpacked above + static hierarchy
-            # metadata — no device data is touched
-            from ..memory_info import peak_bytes
-            from ..telemetry import build_report, metrics as _tm
-            diag_struct = None
-            if diag_raw is not None:
+        with span("solve.prepare", counter="solve.stage_s.prepare"):
+            b = jnp.asarray(b)
+            if x0 is None or zero_initial_guess:
+                x0 = jnp.zeros_like(b)
+            else:
+                x0 = jnp.asarray(x0)
+            if self.scaler is not None:
+                # solve (LAR) x' = L b, return x = R x' (monitored
+                # residuals are in the scaled system — reference caveat
+                # solver.cu:449)
+                b = self.scaler.scale_rhs(b)
+                x0 = self.scaler.to_scaled_x(x0)
+            data = self.solve_data()
+            # the faultinject epoch keys the cache so arming/consuming
+            # a fault retraces instead of replaying a (possibly
+            # poisoned) cached program; it is 0 forever when injection
+            # is unused
+            key = (b.shape, str(b.dtype), _fi.epoch())
+            new_program = key not in self._jit_cache
+            if new_program:
+                _tm.inc("solver.retrace.solve")
+                _fi.evict_stale_epochs(self._jit_cache, key[-1])
+                self._jit_cache[key] = jax.jit(self._build_solve_fn())
+            solve_fn = self._jit_cache[key]
+        with span("solve.run", counter="solve.stage_s.run"):
+            t0 = time.perf_counter()
+            if new_program:
+                x, stats = self._first_solve(solve_fn, key, data, b, x0)
+            else:
+                x, stats = jax.block_until_ready(solve_fn(data, b, x0))
+        with span("solve.readback", counter="solve.stage_s.readback"):
+            if self.scaler is not None:
+                x = self.scaler.from_scaled_x(x)
+            solve_time = time.perf_counter() - t0
+            # diagnostics probe output rides the stats tail (same
+            # buffer, no extra transfer); strip it by the same spec the
+            # trace used before the bare-layout unpack
+            diag_spec = self._diag_probe_spec()
+            diag_raw = None
+            stats = np.asarray(stats)
+            if diag_spec is not None:
                 from ..telemetry import diagnostics as _dg
-                diag_struct = _dg.derive(
-                    diag_raw, len(diag_spec[0].levels),
-                    res_hist=np.asarray(hist))
-            res.report = build_report(self, res, hist=np.asarray(hist),
-                                      diagnostics=diag_struct,
-                                      precision=self._precision_block(res))
-            _tm.max_gauge("memory.solve_peak_bytes", peak_bytes())
-        if self.print_solve_stats:
-            self._print_stats(res, np.asarray(hist))
+                dlen = _dg.slots_len(diag_spec[0])
+                if dlen:
+                    diag_raw = stats[stats.size - dlen:]
+                    stats = stats[:stats.size - dlen]
+            # solver-declared extras sit just before the diagnostics
+            # tail; strip by the same spec the trace packed them with
+            extra_names = self._extra_stats_spec()
+            extras = None
+            if extra_names:
+                raw = stats[stats.size - len(extra_names):]
+                stats = stats[:stats.size - len(extra_names)]
+                extras = {k: float(v) for k, v in zip(extra_names, raw)}
+            iters_i, converged, status, norm0, res_norm, hist = \
+                self.unpack_stats(stats, self.max_iters + 1)
+            res = SolveResult(
+                x=x, iterations=iters_i, converged=converged,
+                res_norm=np.asarray(res_norm), norm0=np.asarray(norm0),
+                res_history=np.asarray(hist)
+                if self.store_res_history else None,
+                setup_time=self.setup_time, solve_time=solve_time,
+                status_code=status, extra_stats=extras)
+        if self.telemetry or self.print_solve_stats:
+            with span("solve.report", counter="solve.stage_s.report"):
+                if self.telemetry:
+                    # structured report (telemetry/report.py): built
+                    # from the stats numpy already unpacked above +
+                    # static hierarchy metadata — no device data is
+                    # touched
+                    from ..memory_info import peak_bytes
+                    from ..telemetry import build_report
+                    diag_struct = None
+                    if diag_raw is not None:
+                        from ..telemetry import diagnostics as _dg
+                        diag_struct = _dg.derive(
+                            diag_raw, len(diag_spec[0].levels),
+                            res_hist=np.asarray(hist))
+                    res.report = build_report(
+                        self, res, hist=np.asarray(hist),
+                        diagnostics=diag_struct,
+                        precision=self._precision_block(res))
+                    _tm.max_gauge("memory.solve_peak_bytes",
+                                  peak_bytes())
+                if self.print_solve_stats:
+                    self._print_stats(res, np.asarray(hist))
         return res
+
+    def _first_solve(self, solve_fn, key, data, b, x0):
+        """A solve program's first call, which traces, lowers and
+        compiles it, and the hand-over of what ran to
+        `telemetry.programs`, which names the stages of its
+        instructions later.
+
+        The call is `jax.jit`'s own, so the program compiles once and
+        dispatches as ever. `lower().compile()` afterwards finds the
+        jaxpr, the lowering and the executable of that call in JAX's
+        caches (about 2 ms; tests/test_stage_scopes.py holds it to no
+        compile event) and returns the executable that runs."""
+        from ..compile_cache import op_names_in_key
+        from ..telemetry import programs
+        with op_names_in_key():
+            out = jax.block_until_ready(solve_fn(data, b, x0))
+            programs.register(f"{self.name}.solve", key,
+                              solve_fn.lower(data, b, x0).compile())
+        return out
 
     def _print_stats(self, res: SolveResult, hist):
         from ..memory_info import update_max_memory_usage

@@ -41,6 +41,7 @@ extra state leaf, jaxpr-identical to the pre-knob build.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .. import registry
@@ -98,15 +99,20 @@ class RefinementSolver(Solver):
         return st
 
     def solve_iteration(self, data, b, st):
+        # scopes: the outer loop's own f64 work, apart from the inner
+        # solve's (which carries its own krylov.* / amg.* names)
         x = st["x"]
         r = st["r"]        # f64 defect (maintained by the previous step)
-        r32 = r.astype(self.inner_dtype)
+        with jax.named_scope("refine.update"):
+            r32 = r.astype(self.inner_dtype)
         d32, istats = self._inner_fn(data["inner"], r32,
                                      jnp.zeros_like(r32))
-        x = x + d32.astype(x.dtype)
+        with jax.named_scope("refine.update"):
+            x = x + d32.astype(x.dtype)
         out = dict(st)
         out["x"] = x
-        out["r"] = residual(data["A"], x, b)             # true f64 residual
+        with jax.named_scope("refine.defect"):
+            out["r"] = residual(data["A"], x, b)         # true f64 residual
         if "inner_iters" in st:
             # istats[0] is the inner fn's iteration count (the packed
             # stats layout _build_solve_fn emits)

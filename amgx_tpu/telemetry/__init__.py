@@ -21,6 +21,13 @@ solve tables; ours is structured and machine-readable):
   `profiling.trace_region`, exported as Chrome/Perfetto trace-event
   JSON (`spans.export_chrome_trace`); `telemetry_sync=1` fences device
   work at span boundaries so host spans bound device occupancy.
+- `telemetry.programs` — JAX's compile events as counters and spans
+  (`compile.trace_s` / `.lower_s` / `.backend_s`, `compile.programs`,
+  each span with the program's `fun_name`), and the scope tables of
+  the solve programs: {HLO instruction: `amg.L<k>.<stage>` /
+  `krylov.<NAME>.<stage>` / `refine.<stage>`}, read on request from
+  the executable a solve runs, which is what joins a profiler trace's
+  device ops to the cycle's levels and stages.
 - `telemetry.flightrec` — crash-surviving flight recorder: a bounded
   append-and-rotate structured event log of state transitions (bucket
   builds/quarantines/requeues, shed decisions with their feasibility
@@ -43,5 +50,5 @@ way, so `telemetry=0` and `telemetry=1` compile identical XLA).
 """
 from __future__ import annotations
 
-from . import diagnostics, flightrec, metrics, spans  # noqa: F401
+from . import diagnostics, flightrec, metrics, programs, spans  # noqa: F401
 from .report import SolveReport, build_report, validate_report  # noqa: F401

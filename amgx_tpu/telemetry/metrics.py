@@ -12,7 +12,9 @@ Design rules:
   doubles as the documentation of what the library measures; an
   undeclared name raises with a did-you-mean suggestion instead of
   silently forking a typo'd time series;
-- counters are monotonic within a process (`inc`); gauges are
+- counters are monotonic within a process: `inc` counts whole events,
+  `add` accumulates a non-negative quantity (host seconds of a stage:
+  such a counter's name ends in `_s`); gauges are
   last-value (`set_gauge`) or high-water (`max_gauge`); histograms are
   fixed-bucket-edge distributions (`observe`) with optional labels
   (per-tenant latency series) and bucket-interpolated quantiles
@@ -33,8 +35,11 @@ the GEO Galerkin structure-cache (amg/aggregation/galerkin.py), the
 setup/resetup routing (amg/hierarchy.py), the RequestBatcher
 (batch/queue.py), the fallback engine (resilience/policy.py), jit
 retraces per solver entry point (solvers/base.py, batch/core.py,
-distributed/solver.py), and device-memory watermarks per phase
-(memory_info sampled from solvers/base.py).
+distributed/solver.py), device-memory watermarks per phase
+(memory_info sampled from solvers/base.py), and where a call's host
+time goes: the stages of `Solver.solve` and of
+`CsrMatrix.with_values`, and JAX's compile events by stage
+(telemetry/programs.py).
 """
 from __future__ import annotations
 
@@ -52,7 +57,7 @@ _lock = threading.Lock()
 # construction calls set_replica_label)
 _replica: Optional[str] = None
 _replica_env_checked = False
-_counters: Dict[str, int] = {}
+_counters: Dict[str, Union[int, float]] = {}
 _gauges: Dict[str, float] = {}
 # (name, sorted-label-items tuple) -> {"counts": [..], "sum": ., "count": .}
 _hists: Dict[Tuple[str, tuple], dict] = {}
@@ -99,6 +104,16 @@ def inc(name: str, n: int = 1):
         _unknown(name, COUNTERS, "counter")
     with _lock:
         _counters[name] = _counters.get(name, 0) + int(n)
+
+
+def add(name: str, value: float):
+    """Accumulate a non-negative quantity on a declared counter: the
+    host seconds of a stage (`solve.stage_s.*`, `compile.*_s`). The
+    counter reads as a float; `inc` stays the whole-event form."""
+    if name not in COUNTERS:
+        _unknown(name, COUNTERS, "counter")
+    with _lock:
+        _counters[name] = _counters.get(name, 0) + float(value)
 
 
 def set_gauge(name: str, value: Union[int, float]):
@@ -608,6 +623,84 @@ declare_counter("solver.retrace.solve_batched",
 declare_counter("solver.retrace.distributed",
                 "distributed-solve shard_map rebuilds "
                 "(DistributedSolver.solve)")
+
+# which solver of the tree made a resetup drop the cached solve
+# programs (Solver._retrace_cause). NOT under solver.retrace.: readers
+# that sum that family would count the retrace twice
+declare_counter("resetup.retrace_cause.AMG",
+                "resetups that dropped cached solve programs because "
+                "the AMG hierarchy was rebuilt (the fused value-only "
+                "resetup did not run)")
+declare_counter("resetup.retrace_cause.CHEBYSHEV",
+                "resetups that dropped cached solve programs because "
+                "CHEBYSHEV bakes value-derived spectrum bounds into "
+                "its trace")
+declare_counter("resetup.retrace_cause.other",
+                "resetups that dropped cached solve programs for any "
+                "other solver's _resetup_kept_static() == False")
+
+# host stages of the outermost Solver.solve (solvers/base.py): host
+# wall seconds, disjoint, summing to the <NAME>.solve span less the
+# few microseconds between them
+declare_counter("solve.stage_s.prepare",
+                "host seconds before the solve program is called: "
+                "asarray / zeros_like of b and x0, the scaler, "
+                "solve_data(), the program-cache lookup")
+declare_counter("solve.stage_s.run",
+                "host seconds in the solve program's call and its "
+                "block_until_ready; a new program's first call "
+                "traces, lowers and compiles in here (compile.*_s "
+                "say how long)")
+declare_counter("solve.stage_s.readback",
+                "host seconds reading the packed stats back "
+                "(np.asarray), unpacking them, and the scaler's way "
+                "back")
+declare_counter("solve.stage_s.report",
+                "host seconds building the SolveReport and printing "
+                "solve stats (telemetry=1 or print_solve_stats=1)")
+
+# CsrMatrix.with_values (matrix.py): the coefficient replacement of a
+# time step
+declare_counter("matrix.refill_host_s",
+                "host seconds re-scattering new coefficients into the "
+                "DIA / ELL / SWELL value layouts")
+declare_counter("matrix.upload_s",
+                "host seconds inside the device_puts of the new "
+                "values and value slabs (the transfer itself may "
+                "still be in flight when the call returns: no wait is "
+                "added)")
+declare_counter("matrix.upload_bytes",
+                "bytes handed to device_put by with_values: how much "
+                "of the operator a coefficient replacement ships to "
+                "the device again (no bandwidth: matrix.upload_s "
+                "ends before the transfer does)")
+
+# JAX's own compile events (telemetry/programs.py listens on
+# jax.monitoring): every program of the process, eager ones included
+declare_counter("compile.trace_s",
+                "seconds tracing Python to jaxprs "
+                "(/jax/core/compile/jaxpr_trace_duration), each "
+                "event's own time: a trace nested in another is "
+                "counted once")
+declare_counter("compile.lower_s",
+                "seconds lowering jaxprs to MLIR modules "
+                "(jaxpr_to_mlir_module_duration)")
+declare_counter("compile.backend_s",
+                "seconds in the backend's compile call, a persistent-"
+                "cache retrieval included (backend_compile_duration)")
+declare_counter("compile.programs",
+                "programs handed to the backend compiler (one per "
+                "backend_compile_duration event, a persistent-cache "
+                "hit among them): stands still in steady state; its "
+                "rate is how many programs a time step still builds")
+declare_counter("compile.cache_hits",
+                "programs the persistent compilation cache answered "
+                "(/jax/compilation_cache/cache_hits)")
+declare_counter("compile.cache_misses",
+                "programs XLA compiled in full with the persistent "
+                "cache on (/jax/compilation_cache/cache_misses): "
+                "growth after warm-up is a cold compile on the "
+                "serving path")
 
 # serving subsystem (amgx_tpu/serving/): the production solve service —
 # continuous batching, hierarchy cache routing, AOT warm paths and

@@ -50,6 +50,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+from . import metrics as _metrics
+
 # ---------------------------------------------------------------------------
 # span-name registry
 # ---------------------------------------------------------------------------
@@ -144,6 +146,23 @@ DECLARED_SPANS: Tuple[str, ...] = (
     # distributed comms/shard telemetry: one synthetic track per
     # shard in the Perfetto export (record_span with a per-shard tid)
     "shard.solve",
+    # host stages of the outermost Solver.solve, disjoint children of
+    # <NAME>.solve (solvers/base.py); each also adds its seconds to
+    # the counter solve.stage_s.<stage>
+    "solve.prepare",
+    "solve.run",
+    "solve.readback",
+    "solve.report",
+    # CsrMatrix.with_values (matrix.py): the host re-scatter of the new
+    # coefficients and the device_puts of what it made (counters
+    # matrix.refill_host_s, matrix.upload_s, matrix.upload_bytes)
+    "matrix.refill_host",
+    "matrix.upload",
+    # JAX's compile events, recorded retroactively with fun_name in
+    # args (telemetry/programs.py)
+    "compile.trace",
+    "compile.lower",
+    "compile.backend",
     # solver-tree entry points (dynamic solver names: CG.solve, ...).
     # NO catch-all patterns belong here: a `<anything>.*` entry would
     # let any typo'd two-segment name pass the static registry check
@@ -218,14 +237,19 @@ def _stack() -> list:
 
 @contextlib.contextmanager
 def span(name: str, annotate: bool = True,
-         args: Optional[Dict[str, Any]] = None):
+         args: Optional[Dict[str, Any]] = None,
+         counter: Optional[str] = None):
     """Record one hierarchical span (and accumulate the flat timer).
     With annotate=True the region is also a jax.profiler
     TraceAnnotation, so it shows up in captured device profiles — the
     nvtxRange analog `profiling.trace_region` has always been.
-    `args` attaches extra key/values to the exported event; a
+    `args` attaches extra key/values to the exported event (read when
+    the span closes, so the body may fill the dict); a
     `trace`/`traces` entry additionally enrolls the span in that
-    request's Perfetto flow chain (module docs)."""
+    request's Perfetto flow chain (module docs). `counter` names a
+    declared seconds counter (telemetry/metrics.py) that the span's
+    host wall is added to, so a scrape reads the stage without the
+    span buffer."""
     if _sync:
         _fence()
     stack = _stack()
@@ -254,6 +278,8 @@ def span(name: str, annotate: bool = True,
         if args:
             rec["args"] = dict(args)
         _commit(rec, name, dt)
+        if counter is not None:
+            _metrics.add(counter, dt)
 
 
 def _commit(rec: dict, name: str, dt: float):

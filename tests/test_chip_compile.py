@@ -252,6 +252,54 @@ def test_declined_families_decline_on_chip_only(on_tpu):
                            "slab_gate": True, "coarse_tail": True}
 
 
+def test_scope_table_puts_every_kernel_of_the_solve_under_a_stage(
+        one_chip, on_tpu, no_persistent_cache):
+    """The flagship's whole solve program, compiled for the chip at
+    16^3: the instruction names a profiler trace carries
+    (`_dia_smooth_call.<n>`, `pad.<n>`) are in the compiled text, and
+    `telemetry.programs` maps every Pallas kernel among them to the
+    cycle level or Krylov stage that issues it."""
+    import fnmatch
+    import json
+    import re
+    import amgx_tpu as amgx
+    from amgx_tpu import gallery
+    from amgx_tpu.config import Config
+    from amgx_tpu.telemetry import programs
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           "flagship-p7-128.json")) as f:
+        options = json.load(f)["solver"]["options"]
+    A = gallery.poisson("7pt", 16, 16, 16).init()
+    slv = amgx.create_solver(Config.from_string(options))
+    slv.setup(A)
+
+    def shape(x):
+        x = jnp.asarray(x)
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    data = jax.tree_util.tree_map(shape, slv.solve_data())
+    b = jax.ShapeDtypeStruct((A.num_rows,), jnp.float64, sharding=one_chip)
+    text = jax.jit(slv._build_solve_fn()).lower(data, b, b).compile(
+        ).as_text()
+    names = programs.parse_op_names(text)
+    kernels = [n for n in names if fnmatch.fnmatchcase(n, "_dia_*_call*")]
+    smooth = [n for n in kernels if n.startswith("_dia_smooth")]
+    levels = len(slv.preconditioner.preconditioner.amg.levels)
+    assert levels >= 2 and len(smooth) == 2 * levels, kernels
+    for n in smooth:
+        assert re.fullmatch(r"amg\.L\d+\.(pre|post)smooth",
+                            programs.scope_of(names[n])), names[n]
+    assert {programs.scope_of(names[n]) for n in smooth} == {
+        f"amg.L{k}.{stage}" for k in range(levels)
+        for stage in ("presmooth", "postsmooth")}
+    # the shell's SpMVs are kernels too: under a krylov.* stage
+    assert len(kernels) > len(smooth)
+    for n in set(kernels) - set(smooth):
+        assert programs.scope_of(names[n]).startswith("krylov."), names[n]
+
+
 def test_chip_smoke_flagship_phase_rehearsal():
     """Rehearsal 1 of chip_smoke.py kept as a test so the script cannot
     rot between chip runs: the flagship phase (setup, three solves,

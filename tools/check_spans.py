@@ -18,8 +18,9 @@ checkable without running anything:
    fractions > honest.
 
 3. METRIC-NAME COVERAGE — every literal metric name recorded through
-   the registry (`_tm.inc(...)` / `metrics.observe(...)` /
-   `set_gauge` / `max_gauge` on the package's conventional receivers)
+   the registry (`_tm.inc(...)` / `_tm.add(...)` /
+   `metrics.observe(...)` / `set_gauge` / `max_gauge` on the package's
+   conventional receivers)
    must be declared in the matching catalog
    (telemetry.metrics.COUNTERS / GAUGES / HISTOGRAMS). The registry
    raises at runtime too, but only when the line executes — this
@@ -78,7 +79,7 @@ _CALL_NAMES = {"trace_region", "span", "mark", "record_span",
 # Receiver-qualified on purpose: other objects legitimately own methods
 # with these names (determinism.DeterminismChecker.observe)
 _METRIC_RECEIVERS = {"_tm", "metrics", "_metrics"}
-_METRIC_KINDS = {"inc": "counter", "set_gauge": "gauge",
+_METRIC_KINDS = {"inc": "counter", "add": "counter", "set_gauge": "gauge",
                  "max_gauge": "gauge", "observe": "histogram",
                  "quantile": "histogram"}
 _METRIC_EXEMPT = (
@@ -148,7 +149,7 @@ def extract_metric_literals(root: str = PKG):
 
 # the RECORDING half of the receiver surface (quantile is a read —
 # contract 3 checks its name, contract 4 must not count it as a site)
-_WRITE_ATTRS = {"inc", "set_gauge", "max_gauge", "observe"}
+_WRITE_ATTRS = {"inc", "add", "set_gauge", "max_gauge", "observe"}
 
 
 def _extract_metric_calls(root: str = PKG):
