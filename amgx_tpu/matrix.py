@@ -20,8 +20,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import os
 import threading
 import weakref
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
 import jax
@@ -119,6 +121,74 @@ def host_arrays(*arrays):
             pass
         return None
     return out
+
+
+# The DIA refill map of a sparsity pattern: where every CSR entry sits
+# in the tiled (k, rows_pad, LANES) slab. It is structure, so it is
+# built by the first with_values on a pattern and kept here, NOT in the
+# CsrMatrix pytree (a data field would become an argument of every
+# solve program). Keyed like _HOST_MIRROR by the identity of the arrays
+# it was built from, (id(col_indices), id(row_offsets), dia_offsets),
+# and evicted by weakref.finalize when either array dies:
+# dataclasses.replace keeps both across with_values, so every matrix of
+# a time loop finds the same map, and another pattern of the same size
+# does not.
+_REFILL_MAPS: dict = {}
+_REFILL_MAPS_LOCK = threading.Lock()
+
+
+class _RefillMap:
+    """Host numpy, applied on the host: at 7-pt 256^3 the chip's gather
+    through such a map reads 5.0 s a call, this one 0.4 s (PERF.md,
+    PR 31). Without duplicate (row, column) entries `index` is the
+    inverse map, for every slab slot the index of its entry in
+    `values`, and a refill is one np.take; the padding slots (`pad`)
+    hold nnz, which the take clips, and are zeroed after it. With
+    duplicates `index` is the slot of every entry and a refill is a
+    bincount, which sums them as init() does."""
+
+    # the take is cut into pieces of at least this many slots, one
+    # thread each: most of its time is the page faults of the fresh
+    # slab, which threads take in parallel (numpy drops the GIL)
+    PIECE = 1 << 22
+    MAX_THREADS = 8
+
+    def __init__(self, ci, row_ids, offsets, shape):
+        nnz = ci.shape[0]
+        self.shape = shape
+        self.size = size = shape[0] * shape[1] * shape[2]
+        d_idx = np.searchsorted(np.asarray(offsets, ci.dtype), ci - row_ids)
+        slots = d_idx * (shape[1] * shape[2]) + row_ids
+        entry = np.full(size, nnz, np.intp)
+        entry[slots] = np.arange(nnz, dtype=np.intp)
+        self.pad = np.flatnonzero(entry == nnz)
+        # two entries in one slot leave fewer slots taken than entries
+        self.duplicates = size - self.pad.shape[0] < nnz
+        self.index = slots if self.duplicates else entry
+
+    def slab(self, values: np.ndarray) -> np.ndarray:
+        """The DIA slab of these coefficients: the same numbers in the
+        same places as init() gives."""
+        if self.duplicates:
+            return np.bincount(
+                self.index, weights=values, minlength=self.size
+            ).astype(values.dtype).reshape(self.shape)
+        flat = np.empty(self.size, values.dtype)
+        pieces = min(self.MAX_THREADS, os.cpu_count() or 1,
+                     max(self.size // self.PIECE, 1))
+        cuts = np.linspace(0, self.size, pieces + 1).astype(np.intp)
+
+        def take(i):
+            a, b = cuts[i], cuts[i + 1]
+            np.take(values, self.index[a:b], mode="clip", out=flat[a:b])
+
+        if pieces == 1:
+            take(0)
+        else:
+            with ThreadPoolExecutor(pieces) as pool:
+                list(pool.map(take, range(pieces)))   # raises a piece's error
+        flat[self.pad] = 0
+        return flat.reshape(self.shape)
 
 
 def lexsort_rc(rows, cols):
@@ -499,8 +569,9 @@ class CsrMatrix:
         """Scatter-add CSR values onto per-diagonal rows (duplicates sum,
         matching the segsum/ELL paths), stored tile-aligned as
         (k, rows_pad, 128) so the Pallas SpMV kernel streams them with
-        zero re-layout (see ops/pallas_spmv.py). Shared by init and
-        with_values."""
+        zero re-layout (see ops/pallas_spmv.py). Serves init() on the
+        device, and with_values where the values are traced or complex
+        (every other refill goes through the kept map, _refill_dia)."""
         from .ops.pallas_spmv import LANES, dia_padded_rows
         offs = jnp.asarray(offsets, jnp.int32)
         d_idx = jnp.searchsorted(offs, self.col_indices.astype(jnp.int32)
@@ -581,7 +652,13 @@ class CsrMatrix:
     def with_values(self, values: Array, diag: Optional[Array] = None
                     ) -> "CsrMatrix":
         """Replace coefficients keeping structure
-        (AMGX_matrix_replace_coefficients analog)."""
+        (AMGX_matrix_replace_coefficients analog). The value layouts
+        follow: ELL re-scatters on the device; DIA goes through the
+        pattern's kept refill map on the host (_refill_dia; traced or
+        complex values and structure without host mirrors rebuild the
+        slab on the device with _build_dia_vals); SWELL re-packs host
+        values natively and drops its layout for values that live on
+        the device."""
         if values.shape != self.values.shape:
             raise BadParametersError(
                 f"replace_coefficients: value shape {values.shape} != "
@@ -616,62 +693,74 @@ class CsrMatrix:
                     swell_c0row=None, swell_nchunk=None, swell_w128=0)
         return out
 
-    def _refill_dia(self, values) -> "CsrMatrix":
-        """Values-only DIA refill for replace_coefficients. With host
-        (numpy) values and mirror-backed structure the scatter runs in
-        numpy and ships as one put instead of an eager device
-        scatter-add + searchsorted chain per resetup (the same choice
-        as _init_from_mirrors)."""
-        def host_of(a):
-            if isinstance(a, np.ndarray):
-                return a
-            return _HOST_MIRROR.get(id(a))
+    def _dia_shape(self):
+        from .ops.pallas_spmv import LANES, dia_padded_rows
+        k = len(self.dia_offsets)
+        return (k, dia_padded_rows(k, self.num_rows), LANES)
 
-        ro = host_of(self.row_offsets)
-        ci = host_of(self.col_indices)
-        if isinstance(values, np.ndarray) and ro is not None \
-                and ci is not None and not np.iscomplexobj(values):
-            from .ops.pallas_spmv import LANES, dia_padded_rows
+    def _refill_map(self) -> "Optional[_RefillMap]":
+        """The pattern's refill map from the side table; the first call
+        on a pattern builds it from the host mirrors of col_indices and
+        row_ids. None where the structure cannot be served on the host
+        (built on an accelerator, or under a forced device setup)."""
+        key = (id(self.col_indices), id(self.row_offsets),
+               self.dia_offsets)
+        with _REFILL_MAPS_LOCK:
+            kept = _REFILL_MAPS.get(key)
+            if kept is not None:
+                _tm.inc("matrix.refill_map.reuse")
+                return kept
+            host = host_arrays(self.col_indices, self.row_ids)
+            if host is None:
+                return None
+            kept = _RefillMap(host[0], host[1], self.dia_offsets,
+                              self._dia_shape())
+            for a in (self.col_indices, self.row_offsets):
+                weakref.finalize(a, _REFILL_MAPS.pop, key, None)
+            _REFILL_MAPS[key] = kept
+            _tm.inc("matrix.refill_map.build")
+            return kept
+
+    def _refill_dia(self, values) -> "CsrMatrix":
+        """Values-only DIA refill for replace_coefficients. Concrete
+        real values on a pattern whose structure has host mirrors go
+        through the pattern's kept refill map (_refill_map): one numpy
+        pass on the host (values that live on the device come to the
+        host for it, through their mirror where they have one), then
+        the slab is put, beside host values. Traced values (with_values
+        under jit or vmap), complex ones and structure without host
+        mirrors rebuild the slab with _build_dia_vals, as init() does
+        on the device."""
+        traced = any(isinstance(a, jax.core.Tracer)
+                     for a in (values, self.col_indices, self.row_ids))
+        kept = None
+        if not traced and not jnp.iscomplexobj(values):
             with span("matrix.refill_host", counter="matrix.refill_host_s"):
-                k = len(self.dia_offsets)
-                n = self.num_rows
-                row_ids = np.repeat(np.arange(n, dtype=np.int64),
-                                    np.diff(ro))
-                offs = np.asarray(self.dia_offsets, np.int64)
-                d_idx = np.searchsorted(offs,
-                                        ci.astype(np.int64) - row_ids)
-                rows_pad = dia_padded_rows(k, n)
-                flat = np.bincount(d_idx * (rows_pad * LANES) + row_ids,
-                                   weights=values,
-                                   minlength=k * rows_pad * LANES)
-                dia_np = flat.astype(values.dtype).reshape(k, rows_pad,
-                                                           LANES)
-            # device of the (unchanged) structure arrays — the new
-            # values may be host numpy at this point
-            try:
-                dev = next(iter(self.row_offsets.devices()))
-                on_accel = dev.platform != "cpu"
-            except Exception:
-                on_accel = False
-            # no wait on what was put: the call never had one
-            with span("matrix.upload", counter="matrix.upload_s"):
-                if on_accel:
-                    import jax as _jax
-                    vals_c = np.ascontiguousarray(values)
-                    d_vals = _jax.device_put(vals_c, dev)
-                    _register_host_mirror(d_vals, vals_c)
-                    d_dia = _jax.device_put(dia_np, dev)
-                    _register_host_mirror(d_dia, dia_np)
-                    _tm.inc("matrix.upload_bytes",
-                            vals_c.nbytes + dia_np.nbytes)
-                    return dataclasses.replace(self, values=d_vals,
-                                               dia_vals=d_dia)
-                _tm.inc("matrix.upload_bytes", dia_np.nbytes)
-                return dataclasses.replace(self,
-                                           dia_vals=jnp.asarray(dia_np))
+                kept = self._refill_map()
+                if kept is not None:
+                    host_vals = np.ascontiguousarray(
+                        host_mirror_asarray(values))
+                    dia_np = kept.slab(host_vals)
+        if kept is None:
+            return dataclasses.replace(
+                self, dia_vals=self._build_dia_vals(self.dia_offsets,
+                                                    self.row_ids))
+        # to the device of the (unchanged) structure arrays (numpy ones:
+        # the default device); no wait on what is put: the call never
+        # had one
+        ci = self.col_indices
+        dev = None if isinstance(ci, np.ndarray) else next(iter(ci.devices()))
+        from_host = isinstance(values, np.ndarray)
+        with span("matrix.upload", counter="matrix.upload_s"):
+            put = (dia_np, host_vals) if from_host else (dia_np,)
+            on_dev = [jax.device_put(x, dev) for x in put]
+            if next(iter(on_dev[0].devices())).platform != "cpu":
+                for d, x in zip(on_dev, put):
+                    _register_host_mirror(d, x)
+            _tm.inc("matrix.upload_bytes", sum(x.nbytes for x in put))
         return dataclasses.replace(
-            self, dia_vals=self._build_dia_vals(self.dia_offsets,
-                                                self.row_ids))
+            self, values=on_dev[1] if from_host else values,
+            dia_vals=on_dev[0])
 
     def interior_exterior_split(self, num_owned_cols: int):
         """INTERIOR/BOUNDARY view split (include/matrix.h:82-88 views):

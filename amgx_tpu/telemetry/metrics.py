@@ -662,8 +662,18 @@ declare_counter("solve.stage_s.report",
 # CsrMatrix.with_values (matrix.py): the coefficient replacement of a
 # time step
 declare_counter("matrix.refill_host_s",
-                "host seconds re-scattering new coefficients into the "
-                "DIA / ELL / SWELL value layouts")
+                "host seconds bringing the DIA / ELL / SWELL value "
+                "layouts after new coefficients: for DIA the refill "
+                "map's lookup (its build, on a pattern's first "
+                "replacement) and one numpy pass of the values "
+                "through it; the SWELL re-pack")
+declare_counter("matrix.refill_map.build",
+                "DIA refill maps built: the first with_values on a "
+                "sparsity pattern (the map is structure, kept beside "
+                "the structure arrays and evicted with them)")
+declare_counter("matrix.refill_map.reuse",
+                "DIA refills served by a kept map: one per coefficient "
+                "replacement of a time loop after its first")
 declare_counter("matrix.upload_s",
                 "host seconds inside the device_puts of the new "
                 "values and value slabs (the transfer itself may "

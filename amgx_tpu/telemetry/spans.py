@@ -153,8 +153,9 @@ DECLARED_SPANS: Tuple[str, ...] = (
     "solve.run",
     "solve.readback",
     "solve.report",
-    # CsrMatrix.with_values (matrix.py): the host re-scatter of the new
-    # coefficients and the device_puts of what it made (counters
+    # CsrMatrix.with_values (matrix.py): the host pass of the new
+    # coefficients into the value layouts (for DIA through the kept
+    # refill map) and the device_puts of what it made (counters
     # matrix.refill_host_s, matrix.upload_s, matrix.upload_bytes)
     "matrix.refill_host",
     "matrix.upload",

@@ -26,7 +26,7 @@ import pytest
 import jax.numpy as jnp
 
 import amgx_tpu as amgx
-from amgx_tpu import capi, gallery, matrix
+from amgx_tpu import capi, gallery
 from amgx_tpu.config import Config
 from amgx_tpu.matrix import CsrMatrix
 from amgx_tpu.telemetry import metrics, programs, spans
@@ -87,30 +87,41 @@ def test_solve_stages_nest_once_and_account_for_the_solve(flagship16):
 
 
 def test_with_values_records_refill_upload_and_bytes():
-    # as on an accelerator, where the uploaded structure keeps its host
-    # mirror and the refill runs in numpy (a CPU upload keeps none)
     ro, ci, host_vals = poisson_csr("7pt", (12, 12, 12))
     n = ro.shape[0] - 1
     A = CsrMatrix.from_scipy_like(ro, ci, host_vals, n, n).init()
     assert A.dia_vals is not None
-    matrix._register_host_mirror(A.row_offsets, ro)
-    matrix._register_host_mirror(A.col_indices, ci)
+    names = ("matrix.refill_host_s", "matrix.upload_s",
+             "matrix.upload_bytes", "matrix.refill_map.build",
+             "matrix.refill_map.reuse")
+    # the pattern's first replacement builds the refill map
+    before = metrics.snapshot()
+    B = A.with_values(1.25 * host_vals)
+    first = growth(before, names)
+    assert (first["matrix.refill_map.build"],
+            first["matrix.refill_map.reuse"]) == (1, 0)
+    # every later one finds it; both times the new values and the slab
+    # made from them on the host are put, whatever the platform
     vals = 1.5 * host_vals
     spans.reset()
-    names = ("matrix.refill_host_s", "matrix.upload_s",
-             "matrix.upload_bytes")
     before = metrics.snapshot()
-    B = A.with_values(vals)
+    B = B.with_values(vals)
     grown = growth(before, names)
     by_name = {r["name"]: r for r in spans.records()}
     assert by_name["matrix.refill_host"]["dur"] == pytest.approx(
         grown["matrix.refill_host_s"])
     assert by_name["matrix.upload"]["dur"] == pytest.approx(
         grown["matrix.upload_s"])
-    # on a CPU the structure is host resident: the DIA slab alone is put
-    assert grown["matrix.upload_bytes"] == B.dia_vals.nbytes
-    np.testing.assert_allclose(np.asarray(B.dia_vals),
-                               1.5 * np.asarray(A.dia_vals))
+    assert grown["matrix.upload_bytes"] == vals.nbytes + B.dia_vals.nbytes \
+        == first["matrix.upload_bytes"]
+    assert (grown["matrix.refill_map.build"],
+            grown["matrix.refill_map.reuse"]) == (0, 1)
+    np.testing.assert_array_equal(np.asarray(B.dia_vals),
+                                  1.5 * np.asarray(A.dia_vals))
+    # values that live on the device already are not put again
+    before = metrics.snapshot()
+    C = B.with_values(jnp.asarray(vals))
+    assert growth(before, names)["matrix.upload_bytes"] == C.dia_vals.nbytes
 
 
 def test_retrace_raises_compile_counters_and_names_the_program():
@@ -538,7 +549,7 @@ def test_host_stage_readers_are_deltas_per_operation():
         "solve.stage_s.prepare": 0.08, "solve.stage_s.run": 4.0,
         "solve.stage_s.readback": 0.012, "solve.stage_s.report": 0.008,
         "matrix.refill_host_s": 36.0, "matrix.upload_s": 6.0,
-        "matrix.upload_bytes": 8 * 2 ** 30,
+        "matrix.upload_bytes": 8 * 2 ** 30, "matrix.refill_map.reuse": 4,
         "compile.trace_s": 2.0, "compile.lower_s": 1.0,
         "compile.backend_s": 3.0, "compile.programs": 9})
     assert layer_metrics.read("entry.solve_host_s", obs) == \
@@ -548,19 +559,20 @@ def test_host_stage_readers_are_deltas_per_operation():
     assert layer_metrics.read("step.compile_s", obs) == 1.5
     assert layer_metrics.read("step.upload_bytes", obs) == 2 * 2 ** 30
     assert layer_metrics.read("step.compiled_programs", obs) == 2.25
+    assert layer_metrics.read("step.refill_map_reuses", obs) == 1.0
     # a program without the counters (the parent): left out, no raise
     bare = layer_metrics.Observed(ops=4, counter_growth={
         "solver.retrace.solve": 4})
     for name in ("entry.solve_host_s", "step.refill_host_s",
                  "step.upload_s", "step.compile_s", "step.upload_bytes",
-                 "step.compiled_programs"):
+                 "step.compiled_programs", "step.refill_map_reuses"):
         assert layer_metrics.read(name, bare) is None
 
 
 def test_benchmark_selfcheck_passes_with_the_new_cell(capsys):
     selfcheck.main()
     out = capsys.readouterr().out
-    assert "files: 4 cells, 5 end-to-end and 24 per-layer" in out
+    assert "files: 4 cells, 5 end-to-end and 25 per-layer" in out
     assert out.rstrip().endswith("selfcheck ok")
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
