@@ -700,9 +700,22 @@ class AMG:
         if getattr(level.smoother, "needs_cf_map", False) and \
                 getattr(level, "cf_map", None) is not None:
             level.smoother.set_cf_map(level.cf_map)
+        self._color_ahead(level.smoother, level.A, level.level_index)
         with trace_region(f"amg.L{level.level_index}.smoother_setup"):
             level.smoother.setup(level.A)
         self._maybe_install_stencil(level)
+
+    @staticmethod
+    def _color_ahead(solver, A, level_index: int):
+        """A colored smoother's coloring, made before its setup and
+        under a span of its own (amg.L<k>.coloring, a leaf beside
+        smoother_setup): a JPL pass over a fine level's edges is
+        seconds of host work that setup_s should be able to name."""
+        color = getattr(solver, "color", None)
+        if color is not None:
+            from ..profiling import trace_region
+            with trace_region(f"amg.L{level_index}.coloring"):
+                color(A)
 
     def _maybe_install_stencil(self, level: AMGLevel):
         """Matrix-free install (`matrix_free` knob): when this level's
@@ -742,6 +755,8 @@ class AMG:
         cs_name, cs_scope = self.cfg.get_solver("coarse_solver", self.scope)
         self.coarse_solver = make_solver(cs_name, self.cfg, cs_scope)
         self.coarse_solver._owns_scaling = False
+        self._color_ahead(self.coarse_solver, self.coarsest_A,
+                          len(self.levels))
         with trace_region("amg.coarse_solver_setup"):
             self.coarse_solver.setup(self.coarsest_A)
         if self._ship_device is not None:
@@ -935,6 +950,23 @@ class AMG:
         if self.intensive_smoothing:
             s = max(4 * s, 4)
         return s
+
+    def color_steps_per_cycle(self) -> int:
+        """Ordered color steps one cycle is made of: over the levels,
+        sweeps x the smoother's steps a sweep, and the coarsest level's
+        where a colored smoother stands in for a solve. (A V cycle's
+        count: W and F visit coarse levels more than once.)"""
+        def steps(solver):
+            fn = getattr(solver, "color_steps_per_iteration", None)
+            return fn() if fn is not None else 0
+        total = sum(
+            (self._sweeps(k, True) + self._sweeps(k, False))
+            * steps(lv.smoother) for k, lv in enumerate(self.levels))
+        cs = getattr(self, "coarse_solver", None)
+        if cs is not None and cs.is_smoother \
+                and cs.name != "DENSE_LU_SOLVER":
+            total += self.coarsest_sweeps * steps(cs)
+        return total
 
     def cycle(self, data, b, x):
         """One multigrid cycle (CycleFactory::generate analog). With

@@ -40,6 +40,9 @@ class AlgebraicMultigridSolver(Solver):
     def computes_residual(self):
         return False
 
+    def color_steps_per_iteration(self):
+        return self.amg.color_steps_per_cycle()
+
     def solve_init(self, data, b, x, r):
         return self._guard_init()
 

@@ -151,6 +151,11 @@ def _shard_smoother_data(sm, A_sh: ShardMatrix, n_ranks: int, axis: str):
             # path carries its own halo-folded per-shard form instead
             # ("dist_fused", attach_shard_fused below the caller)
             continue
+        if k == "parity":
+            # MULTICOLOR_GS's row-set slabs (ops/parity_sweep.py)
+            # are cut from the global grid; a shard sweeps by the row
+            # colors, which partition row-wise
+            continue
         if isinstance(v, CsrMatrix):
             out[k] = _shard(v, n_ranks, axis)
             continue

@@ -487,6 +487,15 @@ class Solver:
             s = s.preconditioner
         return None
 
+    _color_steps = 0     # color_steps_per_iteration() of the cached programs
+
+    def color_steps_per_iteration(self) -> int:
+        """Ordered color steps of the colored smoothers one iteration
+        of this solver runs (its preconditioner's, applied once an
+        iteration); 0 where the tree has none. Static after setup."""
+        pc = self.preconditioner
+        return 0 if pc is None else pc.color_steps_per_iteration()
+
     def _extra_stats_spec(self) -> tuple:
         """Names of solver-specific SCALARS appended to the packed
         stats vector, in order (after res_hist, before the diagnostics
@@ -952,6 +961,8 @@ class Solver:
                 _tm.inc("solver.retrace.solve")
                 _fi.evict_stale_epochs(self._jit_cache, key[-1])
                 self._jit_cache[key] = jax.jit(self._build_solve_fn())
+                # static like the program: read once with it
+                self._color_steps = self.color_steps_per_iteration()
             solve_fn = self._jit_cache[key]
         with span("solve.run", counter="solve.stage_s.run"):
             t0 = time.perf_counter()
@@ -992,6 +1003,11 @@ class Solver:
                 if self.store_res_history else None,
                 setup_time=self.setup_time, solve_time=solve_time,
                 status_code=status, extra_stats=extras)
+            if self._color_steps:
+                # the iterations that ran the colored cycle: an outer
+                # shell's inner count where it keeps one, else its own
+                _tm.inc("smoother.color_steps", self._color_steps * int(
+                    round((extras or {}).get("inner_iters", iters_i))))
         if self.telemetry or self.print_solve_stats:
             with span("solve.report", counter="solve.stage_s.report"):
                 if self.telemetry:

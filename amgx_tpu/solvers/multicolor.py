@@ -10,9 +10,11 @@ cf_jacobi_solver.cu:1).
 
 Execution model redesign for XLA: the reference launches one kernel per
 color over the rows of that color. Here each color step is a *masked
-dense update* over the full vector driven by one SpMV — the per-color
-loop is unrolled at trace time over the (static) color count, so a whole
-sweep is one fused XLA program:
+dense update* over the full vector driven by one SpMV, in a rolled loop
+over the (static) color count. MULTICOLOR_GS on a grid colored by
+parity takes a color's points by the grid rows they lie in instead
+(ops/parity_sweep.py), so that a step reads its own rows' coefficients
+only; it is the same sweep in the same order.
 
 - colored GS sweep:  for c: x  <- where(color==c, x + w*D^-1(b-Ax), x)
   (exact Gauss-Seidel in the color ordering: the SpMV sees the already-
@@ -45,7 +47,8 @@ import jax.numpy as jnp
 from .. import registry
 from ..errors import BadParametersError
 from ..matrix import CsrMatrix
-from ..ops.coloring import color_matrix
+from ..ops import parity_sweep
+from ..ops.coloring import Coloring, color_matrix
 from ..ops.dense import abs_det, inverse, safe_inverse
 from ..ops.spmv import spmv
 from .base import Solver
@@ -106,10 +109,29 @@ class _ColoredSolver(Solver):
         super().__init__(cfg, scope, name)
         self.relaxation_factor = float(cfg.get("relaxation_factor", scope))
 
+    # the colors of this matrix, where the owner of the solver colored
+    # it ahead of setup (the hierarchy does, under its own span)
+    _colored = None
+
+    def color(self, A):
+        """Color A now; the setup on the same matrix then finds the
+        coloring made."""
+        self._colored = (A, color_matrix(A, self.cfg, self.scope))
+
     def _color(self):
-        coloring = color_matrix(self.A, self.cfg, self.scope)
-        self.row_colors = coloring.row_colors
-        self.num_colors = int(coloring.num_colors)
+        if self._colored is None or self._colored[0] is not self.A:
+            self.color(self.A)
+        self.coloring = self._colored[1]
+        self._colored = None
+        self.row_colors = self.coloring.row_colors
+        self.num_colors = int(self.coloring.num_colors)
+
+    # ordered color steps of one sweep: what a solve is made of
+    # (counter smoother.color_steps)
+    sweep_passes = 1
+
+    def color_steps_per_iteration(self):
+        return self.sweep_passes * getattr(self, "num_colors", 0)
 
     def computes_residual(self):
         return False
@@ -124,16 +146,24 @@ class MulticolorGSSolver(_ColoredSolver):
     def __init__(self, cfg, scope="default", name="MULTICOLOR_GS"):
         super().__init__(cfg, scope, name)
         self.symmetric = bool(int(cfg.get("symmetric_GS", scope)))
+        self.sweep_passes = 2 if self.symmetric else 1
 
     def solver_setup(self):
         self._color()
         d = self.A.diagonal()
         self._dinv = safe_inverse(d) if self.A.is_block else safe_recip(d)
+        # parity colors on a DIA box stencil: the sweep by row sets
+        self._parity = parity_sweep.make_plan(self.A, self.coloring)
+        self._parity_slabs = None if self._parity is None else \
+            parity_sweep.build_slabs(self.A.dia_vals, self._dinv,
+                                     self._parity)
 
     def solve_data(self):
         d = super().solve_data()
         d["dinv"] = self._dinv
         d["colors"] = self.row_colors
+        if self._parity is not None:
+            d["parity"] = self._parity_slabs
         return d
 
     def _color_update(self, data, b, x, c):
@@ -147,8 +177,11 @@ class MulticolorGSSolver(_ColoredSolver):
                               total_repeat_length=x.shape[0])
         return jnp.where(mask, upd, x)
 
-    def solve_iteration(self, data, b, st):
-        x = st["x"]
+    def _sweep(self, data, b, x):
+        if "parity" in data:
+            return parity_sweep.sweep(self._parity, data["parity"], b, x,
+                                      self.relaxation_factor,
+                                      self.symmetric)
         nc = self.num_colors
         # rolled color loop (traced color index — see the DILU sweep)
         x = jax.lax.fori_loop(
@@ -158,9 +191,18 @@ class MulticolorGSSolver(_ColoredSolver):
                 0, nc,
                 lambda i, x: self._color_update(data, b, x, nc - 1 - i),
                 x)
+        return x
+
+    def solve_iteration(self, data, b, st):
         out = dict(st)
-        out["x"] = x
+        out["x"] = self._sweep(data, b, st["x"])
         return out
+
+    def smooth(self, data, b, x, sweeps: int):
+        # a sweep needs no residual of its own: not the base's, which
+        # computes one first
+        return jax.lax.fori_loop(
+            0, sweeps, lambda _, x: self._sweep(data, b, x), x)
 
 
 @registry.solvers.register("FIXCOLOR_GS")
@@ -171,10 +213,11 @@ class FixcolorGSSolver(MulticolorGSSolver):
 
     FIXED_COLORS = 4
 
-    def _color(self):
-        n = self.A.num_rows
-        self.row_colors = jnp.arange(n, dtype=jnp.int32) % self.FIXED_COLORS
-        self.num_colors = min(self.FIXED_COLORS, max(n, 1))
+    def color(self, A):
+        n = A.num_rows
+        self._colored = (A, Coloring(
+            jnp.arange(n, dtype=jnp.int32) % self.FIXED_COLORS,
+            min(self.FIXED_COLORS, max(n, 1))))
 
 
 @registry.solvers.register("GS")
@@ -243,6 +286,8 @@ class MulticolorDILUSolver(_ColoredSolver):
 
         E_i = A_ii - sum_{color_j < color_i} A_ij E_j^{-1} A_ji.
     """
+
+    sweep_passes = 2            # forward and backward
 
     def solver_setup(self):
         from ..matrix import host_arrays
@@ -395,6 +440,8 @@ class MulticolorILUSolver(_ColoredSolver):
     ilu_sparsity_level=k extends the pattern by k rounds of level-fill;
     fill edges must stay properly colored, so k>0 requires a distance-2
     coloring (coloring_level=2) — validated at setup."""
+
+    sweep_passes = 2            # L solve and U solve
 
     def __init__(self, cfg, scope="default", name="MULTICOLOR_ILU"):
         super().__init__(cfg, scope, name)

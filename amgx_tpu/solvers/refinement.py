@@ -90,11 +90,12 @@ class RefinementSolver(Solver):
 
     def solve_init(self, data, b, x0, r0):
         st = super().solve_init(data, b, x0, r0)
-        if self._precision_policy.active:
-            # per-precision accounting: the accumulated inner-Krylov
-            # iteration count rides the state (and, via _extra_stats,
-            # the packed stats vector). Keyed on the policy so the
-            # default build carries no extra leaf (bitwise-off)
+        if self._extra_stats_spec():
+            # the accumulated inner-Krylov iteration count rides the
+            # state (and, via _extra_stats, the packed stats vector):
+            # per-precision accounting, and the count of colored cycles
+            # a solve ran. Keyed on who reads it, so the default build
+            # carries no extra leaf (bitwise-off)
             st["inner_iters"] = jnp.zeros((), jnp.float32)
         return st
 
@@ -122,7 +123,11 @@ class RefinementSolver(Solver):
 
     # -- per-precision accounting (solve_precision policy) --------------
     def _extra_stats_spec(self):
-        return ("inner_iters",) if self._precision_policy.active else ()
+        # who reads the inner count: the precision report, and the
+        # counter of color steps (which are per INNER iteration)
+        counted = self._precision_policy.active \
+            or self.color_steps_per_iteration() > 0
+        return ("inner_iters",) if counted else ()
 
     def _extra_stats(self, final_state):
         if "inner_iters" not in final_state:

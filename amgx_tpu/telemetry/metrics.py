@@ -613,6 +613,12 @@ declare_counter("resilience.config_fallback",
                 "-> the documented JACOBI_L1 fallback) instead of "
                 "failing at solve time")
 
+declare_counter("smoother.color_steps",
+                "ordered color steps of the colored smoothers "
+                "(MULTICOLOR_GS/DILU/ILU), raised after each solve by "
+                "the iterations that ran the cycle x the steps a cycle "
+                "is made of (over the levels: sweeps x passes x colors)")
+
 # jit retraces per solver entry point: a retrace in steady-state serving
 # is a latency cliff (first-request trace cost paid again)
 declare_counter("solver.retrace.solve",

@@ -88,6 +88,9 @@ DECLARED_SPANS: Tuple[str, ...] = (
     "amg.L*.galerkin",
     "amg.L*.layout",
     "amg.L*.smoother_setup",
+    # a colored smoother's coloring, made ahead of its setup by the
+    # hierarchy (a sibling of smoother_setup, not inside it)
+    "amg.L*.coloring",
     "amg.coarse_solver_setup",
     "amg.ship_resolve",
     "amg.device_sync",

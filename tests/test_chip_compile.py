@@ -173,6 +173,65 @@ def test_stencil_spmv_dot_compiles(n, one_chip, on_tpu,
         one_chip, ((7,), F32), vec, vec, ((), F32))
 
 
+# the HPCG configuration's fine level and its first coarse level: 27
+# diagonals with a halo of nx*ny + nx + 1 rows either way
+HPCG_LEVELS = [192, 96]
+
+
+def _box27(n):
+    shifts = tuple((dx, dy, dz) for dz in (-1, 0, 1) for dy in (-1, 0, 1)
+                   for dx in (-1, 0, 1))
+    return shifts, tuple(dx + n * dy + n * n * dz for dx, dy, dz in shifts)
+
+
+@pytest.mark.parametrize("n", HPCG_LEVELS)
+@pytest.mark.parametrize("kernel", ["spmv", "spmv_dot"])
+def test_dia_27pt_kernels_compile(n, kernel, one_chip, on_tpu,
+                                  no_persistent_cache):
+    """The probe's and the residual's `_dia_spmv_call`, and PCG's fused
+    SpMV + dot, on 27 offsets at the configuration's level shapes."""
+    _shifts, offs = _box27(n)
+    rows_pad = ps.dia_padded_rows(27, n ** 3)
+    slab, vec = ((27, rows_pad, 128), F32), ((n ** 3,), F32)
+    if kernel == "spmv":
+        _compile(lambda v, x: ps._dia_spmv_call(v, x, offs, n ** 3),
+                 one_chip, slab, vec)
+    else:
+        _compile(lambda v, p, z, beta: ps._dia_spmv_dot_call(
+            v, p, z, beta, None, offs, n ** 3),
+            one_chip, slab, vec, vec, ((), F32))
+
+
+@pytest.mark.parametrize("n", HPCG_LEVELS)
+def test_parity_sweep_compiles(n, one_chip, no_persistent_cache):
+    """The color step on its own rows (ops/parity_sweep.py), a whole
+    symmetric sweep: plain XLA, no gather and no matrix product for the
+    chip's compiler to make of a cut, and one rolled loop whose steps
+    are traced once."""
+    import itertools
+    from amgx_tpu.ops import parity_sweep
+    shifts, _offs = _box27(n)
+    rows = tuple(itertools.product((0, 1), repeat=2))
+    plan = parity_sweep.ParityPlan((n, n, n), shifts, rows, False)
+    m = n // 2
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, F32, sharding=one_chip)
+
+    slabs = {"vals": tuple(shape(27, m, m, n) for _ in rows),
+             "dinv": tuple(shape(m, m, n) for _ in rows)}
+    compiled = jax.jit(lambda sl, b, x: parity_sweep.sweep(
+        plan, sl, b, x, 1.0, True)).lower(
+        slabs, shape(n ** 3), shape(n ** 3)).compile()
+    text = compiled.as_text()
+    assert " gather(" not in text and "Gather" not in text
+    assert " convolution(" not in text and " dot(" not in text
+    assert text.count(" while(") == 1
+    # the program holds what it is given and little more
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < mem.argument_size_in_bytes / 2
+
+
 @pytest.mark.parametrize("n", [FINE, COARSE])
 def test_cg_update_compiles(n, one_chip, on_tpu, no_persistent_cache):
     vec = ((n ** 3,), F32)

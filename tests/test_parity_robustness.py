@@ -14,6 +14,7 @@ Robustness: NaN rhs, zero diagonal, and zero-row inputs must not hang
 or crash — mirroring src/tests/smoother_nan_random.cu and the
 zero_in_diagonal tests of the reference.
 """
+import dataclasses
 import json
 import os
 
@@ -47,7 +48,11 @@ _PARITY = [
 
 def _run(config_name, fixture):
     stencil, dims = fixture
-    A = gallery.poisson(stencil, *dims).init()
+    # without the gallery's grid_shape: the counts were recorded under
+    # JPL's colors, and with a grid MIN_MAX serves the parity coloring
+    # (2 colors here, under which FGMRES_AGGREGATION's DILU takes 8)
+    A = dataclasses.replace(gallery.poisson(stencil, *dims),
+                            grid_shape=None).init()
     cfg = Config.from_file(os.path.join(_CONFIG_DIR, config_name))
     slv = amgx.create_solver(cfg)
     slv.setup(A)
