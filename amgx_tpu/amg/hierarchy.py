@@ -230,6 +230,11 @@ class AMG:
         # distributed setup builds the replicated tail through
         # _build_levels but owns its smoother assignment
         self._defer_smoothers = False
+        # amg/signature.py: what a solve program traced against this
+        # hierarchy read besides its arguments, as of the last setup,
+        # and whether the last resetup rebuilt the levels to the same
+        self._static_sig = None
+        self._resetup_same_static = False
 
     # -- setup -----------------------------------------------------------
     def _host_setup_device(self, A: CsrMatrix):
@@ -271,6 +276,18 @@ class AMG:
         self._ghost_levels = list(ghost_levels)
 
     def setup(self, A: CsrMatrix):
+        self._resetup_same_static = False
+        self._setup_route(A)
+        self._static_sig = self._signature()
+        return self
+
+    def _signature(self):
+        from .signature import static_signature
+        from ..profiling import trace_region
+        with trace_region("amg.static_signature"):
+            return static_signature(self)
+
+    def _setup_route(self, A: CsrMatrix):
         import jax
         from ..telemetry import metrics as _tm
         ghosts = getattr(self, "_ghost_levels", None)
@@ -493,11 +510,44 @@ class AMG:
         (AMG_Setup structure-reuse path, src/amg.cu:232-262): the first
         `structure_reuse_levels` levels (-1 = all) keep their coarsening
         structure (aggregates / CF-split + transfer operators) and only
-        recompute the Galerkin products; deeper levels rebuild fully."""
+        recompute the Galerkin products; deeper levels rebuild fully.
+
+        Whichever route rebuilt levels, the new hierarchy's static
+        signature (amg/signature.py) is compared with the one before:
+        where they are equal a solve program traced against the old
+        levels is the program a trace against the new ones would give,
+        `_resetup_same_static` says so, and what that trace left on the
+        hierarchy as a side effect is carried over (no trace will
+        record it again)."""
+        before = self._static_sig
+        traced = (self._tail_entry_level, self._telemetry_level_cache)
+        self._resetup_same_static = False
+        self._resetup_route(A)
+        if self._last_resetup_value_only:
+            return self     # the levels, and all of the above, stand
+        self._static_sig = self._signature()
+        if before is not None and before == self._static_sig:
+            self._resetup_same_static = True
+            self._carry_trace_records(*traced)
+        return self
+
+    def _carry_trace_records(self, tail, table):
+        """The VMEM tail's entry level is written by coarse_tail_cycle
+        while the cycle is TRACED (ops/smooth.py) and the report's
+        level table is keyed on it and on the level list: a rebuild
+        that keeps the program runs no trace, so both come over from
+        the hierarchy the program was traced against (equal signatures
+        make them equal: rows, sizes, layouts, fused payloads and
+        dtypes are all in it)."""
+        from ..telemetry.report import carry_level_table
+        self._tail_entry_level = tail
+        carry_level_table(self, table)
+
+    def _resetup_route(self, A: CsrMatrix):
         reuse = int(self.cfg.get("structure_reuse_levels", self.scope))
         if reuse == 0 or not self.levels or \
                 A.num_rows != self.levels[0].A.num_rows:
-            return self.setup(A)
+            return self._setup_route(A)
         self._last_resetup_value_only = False
         from ..telemetry import metrics as _tm
         if (reuse < 0 or reuse >= len(self.levels)) \
@@ -872,14 +922,20 @@ class AMG:
             pieces.append(level.smoother.solve_data())
         self._prefetch_leaves(pieces)
 
+    def _solve_tree(self) -> Dict[str, Any]:
+        """The solve-data tree before placement and precision casts:
+        what solve_data() ships or casts, and what the static signature
+        (amg/signature.py) reads the shapes from."""
+        return {
+            "levels": [lv.level_data() for lv in self.levels],
+            "coarse": self.coarse_solver.solve_data(),
+        }
+
     def solve_data(self) -> Dict[str, Any]:
         import jax
         if self._ship_device is not None and self._data_cache is not None:
             return self._data_cache
-        data = {
-            "levels": [lv.level_data() for lv in self.levels],
-            "coarse": self.coarse_solver.solve_data(),
-        }
+        data = self._solve_tree()
         if self._ship_device is not None:
             # host-built hierarchy: transfer the UNIQUE arrays (each
             # level's matrix arrays appear twice in the tree by object

@@ -28,9 +28,23 @@ class AlgebraicMultigridSolver(Solver):
         self.amg.resetup(self.A)
 
     def _resetup_kept_static(self):
-        # the hierarchy's depth/level shapes depend on the values; only
-        # the fused value-only resetup guarantees they were kept
-        return bool(getattr(self.amg, "_last_resetup_value_only", False))
+        # the hierarchy's depth and level shapes depend on the values.
+        # The fused value-only resetup keeps the levels themselves;
+        # any other route rebuilds them, and the hierarchy then says
+        # whether what a solve program read from the old ones besides
+        # its arguments is what the new ones hold (amg/signature.py)
+        if getattr(self.amg, "_last_resetup_value_only", False):
+            return True
+        return self._resetup_rebuilt_same()
+
+    def _resetup_rebuilt_same(self):
+        amg = self.amg
+        if not amg._resetup_same_static:
+            return False
+        # a smoother or coarse solver that bakes value-derived scalars
+        # into the trace answers for itself, as anywhere in a tree
+        solvers = [lv.smoother for lv in amg.levels] + [amg.coarse_solver]
+        return all(s is None or s._resetup_kept_static() for s in solvers)
 
     def solve_data(self):
         d = super().solve_data()

@@ -276,11 +276,13 @@ class Solver:
             (self.preconditioner.resetup if reuse
              else self.preconditioner.setup)(self.precond_operator(A))
         (self.solver_resetup if reuse else self.solver_setup)()
-        # a value-only resetup changes no static solve state (shapes,
-        # level counts, color counts all derive from the structure,
-        # which is kept) — the traced solve functions stay valid and
-        # the new coefficients flow through as arguments; clearing
-        # would force a full Python re-trace per coefficient cycle
+        # a resetup that changed no static input of the traced solve
+        # functions (shapes, level counts, color counts: all derived
+        # from the structure where that was kept, and compared with
+        # what they were where a hierarchy was rebuilt) leaves them
+        # valid, and the new coefficients flow through as arguments;
+        # clearing would force a full Python re-trace and lowering per
+        # coefficient cycle
         if not (reuse and self._resetup_kept_static()):
             if reuse:
                 cause = self._retrace_cause()
@@ -299,19 +301,37 @@ class Solver:
             for b in tuple(getattr(self, "_batched_wrappers", ())):
                 if not b._suppress_invalidation:
                     b._jit_cache.clear()
-        elif snap is not None:
-            self._assert_resetup_contract(snap)
+        else:
+            if self._jit_cache:
+                # the next solve runs the program it has
+                from ..telemetry import metrics as _tm
+                _tm.inc("resetup.program_kept")
+                if self._resetup_rebuilt_same():
+                    # ... across a rebuild of a hierarchy, where the
+                    # span named a retrace_cause before
+                    span_args["program_kept"] = True
+            if snap is not None:
+                self._assert_resetup_contract(snap)
         self.setup_time = time.perf_counter() - t0
         return self
 
     def _resetup_kept_static(self) -> bool:
         """Did the last resetup keep every static ingredient of this
-        (sub)tree's traced solve functions? Standard solvers' static
-        state derives from the matrix PATTERN (shapes, colorings, ELL
-        widths), which replace_coefficients keeps by contract — so the
-        default is True and the question recurses down the chain. The
-        AMG wrapper overrides: its hierarchy depth/level shapes depend
-        on the VALUES unless the fused value-only resetup ran.
+        (sub)tree's traced solve functions? What decides is whether the
+        program's static input is the same, not which route the
+        re-setup took. Standard solvers' static state derives from the
+        matrix PATTERN (shapes, colorings, ELL widths), which
+        replace_coefficients keeps by contract — so the default is True
+        and the question recurses down the chain. The AMG wrapper
+        overrides: its hierarchy's depth and level shapes depend on the
+        VALUES, so it answers True where the fused value-only resetup
+        ran (the levels themselves were kept) and, where levels were
+        rebuilt (a full re-setup under structure_reuse_levels=0
+        included), where the rebuilt hierarchy's static signature
+        (amg/signature.py: the solve_data treedef with its meta fields,
+        every leaf's shape and dtype, and what the cycle reads from the
+        level, smoother and coarse-solver objects at trace time) equals
+        the one the cached programs were traced against.
 
         CONTRACT (load-bearing for resetup trace reuse AND for the
         batched subsystem's per-system value splice, batch/core.py):
@@ -321,12 +341,22 @@ class Solver:
         flow through `solve_data()` leaves. A solver that bakes
         value-derived Python scalars into its trace (CHEBYSHEV's _d/_c)
         must override this to return False, or the replayed trace serves
-        stale coefficients. Debug builds verify the observable half of
-        the contract (set AMGX_TPU_DEBUG_RESETUP=1): solve_data's pytree
-        structure/shapes/dtypes must survive a static-kept resetup
+        stale coefficients; inside a hierarchy (as a smoother or coarse
+        solver) its answer drops the program just the same. Debug
+        builds verify the observable half of the contract on every
+        route that keeps the programs (set AMGX_TPU_DEBUG_RESETUP=1):
+        solve_data's pytree structure/shapes/dtypes must survive
         unchanged, and new coefficients must surface as new leaves."""
         return (self.preconditioner is None
                 or self.preconditioner._resetup_kept_static())
+
+    def _resetup_rebuilt_same(self) -> bool:
+        """Did the last resetup REBUILD a hierarchy of this (sub)tree
+        to the static signature it had (the AMG wrapper's answer;
+        passed up the chain)? Only names the `program_kept` arg of the
+        resetup span; `_resetup_kept_static` decides."""
+        return (self.preconditioner is not None
+                and self.preconditioner._resetup_rebuilt_same())
 
     def _retrace_cause(self) -> str:
         """Which solver of this (sub)tree answered

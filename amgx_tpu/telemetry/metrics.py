@@ -645,6 +645,14 @@ declare_counter("resetup.retrace_cause.other",
                 "resetups that dropped cached solve programs for any "
                 "other solver's _resetup_kept_static() == False")
 
+# the other outcome: the resetup left the solver's cached solve
+# programs in place (the value-only route, a pattern-derived tree, or a
+# rebuilt hierarchy whose static signature is what it was,
+# amg/signature.py), so the next solve traces and lowers nothing
+declare_counter("resetup.program_kept",
+                "resetups that left a non-empty cache of solve "
+                "programs in place")
+
 # host stages of the outermost Solver.solve (solvers/base.py): host
 # wall seconds, disjoint, summing to the <NAME>.solve span less the
 # few microseconds between them

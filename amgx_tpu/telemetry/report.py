@@ -159,6 +159,21 @@ def _effective_dtype(amg, A) -> Optional[str]:
     return str(dv.dtype) if dv is not None else None
 
 
+def _level_table_key(amg, tail):
+    levels = getattr(amg, "levels", None) or []
+    return (id(levels), len(levels), tail)
+
+
+def carry_level_table(amg, cached):
+    """Put the level table memoized for the hierarchy's previous level
+    list under its new one: for a rebuild whose static signature is
+    unchanged (amg/signature.py holds every column of the table), so
+    that a time loop's reports stay a list copy."""
+    if cached is not None:
+        amg._telemetry_level_cache = (
+            _level_table_key(amg, cached[0][2]), cached[1])
+
+
 def _level_table(amg):
     """Per-level static activity table: rows/nnz/layout plus which
     kernel form the cycle runs this level through — including the
@@ -177,7 +192,7 @@ def _level_table(amg):
     from ..ops.pallas_spmv import SMOOTH_DTYPES
     levels = getattr(amg, "levels", None) or []
     tail0 = getattr(amg, "_tail_entry_level", None)
-    key = (id(levels), len(levels), tail0)
+    key = _level_table_key(amg, tail0)
     cached = getattr(amg, "_telemetry_level_cache", None)
     if cached is not None and cached[0] == key:
         return [dict(r) for r in cached[1]], tail0

@@ -94,6 +94,10 @@ DECLARED_SPANS: Tuple[str, ...] = (
     "amg.coarse_solver_setup",
     "amg.ship_resolve",
     "amg.device_sync",
+    # the static signature of the finished hierarchy (amg/signature.py):
+    # host work at the end of AMG.setup / AMG.resetup, after every
+    # other leaf has closed
+    "amg.static_signature",
     # overlapped ship worker (reports on its own thread; NOT summed
     # into the amg.* accounted fraction)
     "ship.cast_put",
