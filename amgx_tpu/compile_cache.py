@@ -1,6 +1,7 @@
 """Where JAX's persistent compilation cache lives: decided outside.
 
-One rule for chip_smoke.py, bench.py, the examples and tests/conftest.py.
+One rule for chip_smoke.py, the benchmark, the examples and
+tests/conftest.py.
 If `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and nothing
 is set in code — a path set in code would win over the environment and
 whoever placed the cache (a chip tool that keeps it between calls)

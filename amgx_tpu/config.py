@@ -522,8 +522,8 @@ def _register_default_parameters():
       "connected Perfetto flow chain per request "
       "(spans.export_chrome_trace); the journal persists trace ids so "
       "a crash-recovered resume links its spans to the ORIGINAL "
-      "trace. Host-side dict appends only — bench.py obs gates the "
-      "on/off overhead at <= 2%; 0 restores the pre-tracing span set",
+      "trace. Host-side dict appends only; 0 restores the pre-tracing "
+      "span set",
       1, BOOL01)
     R("serving_replica_id", str, "replica/shard label stamped on "
       "every OpenMetrics sample (replica=\"...\") so multi-replica "

@@ -16,16 +16,13 @@ thin API over it:
   `jax.profiler.TraceAnnotation` (visible in TensorBoard/Perfetto
   traces), recording a hierarchical span, and accumulating host
   wall-clock per name.
-- `start_trace(logdir)` / `stop_trace()`: capture a device profile for
-  the enclosed region (jax.profiler wrapper; the XLA/TPU answer to
-  nsight ranges).
 - `timers()` / `reset_timers()`: the accumulated (calls, seconds) per
   region, printed by AMGX_print_timers via the output callback.
 
 Regions are cheap no-ops for device latency (annotation only); the
 wall-clock numbers measure host-observed span, which for async
 dispatch means "time until the region's Python body returned", not
-device occupancy — use start_trace for real device timelines, or set
+device occupancy — use `jax.profiler` for real device timelines, or set
 `telemetry_sync=1` to fence device work at span boundaries (debugging
 mode; it defeats the overlapped shipping/dispatch pipelining).
 """
@@ -33,29 +30,11 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
-import jax
-
 from .telemetry import spans as _spans
-
-_tracing = False
 
 # the recording engine: hierarchical span + flat accumulator + optional
 # device fencing (telemetry/spans.py)
 trace_region = _spans.span
-
-
-def start_trace(logdir: str):
-    """Begin a device profile capture (jax.profiler.start_trace)."""
-    global _tracing
-    jax.profiler.start_trace(logdir)
-    _tracing = True
-
-
-def stop_trace():
-    global _tracing
-    if _tracing:
-        jax.profiler.stop_trace()
-        _tracing = False
 
 
 def timers() -> Dict[str, Tuple[int, float]]:

@@ -4,8 +4,7 @@ The fusion suites prove their HBM-pass claims by *counting*: how many
 Pallas kernels of which kind, which XLA primitives run standalone
 between them, and which reductions touch full-length vectors outside
 any kernel. The walk lives in the package so the tests
-(tests/_census.py re-exports it), bench.py and chip_smoke.py all count
-the same way.
+(tests/_census.py re-exports it) and chip_smoke.py count the same way.
 """
 import re
 

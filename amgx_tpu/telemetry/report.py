@@ -21,7 +21,7 @@ Sinks:
   C API (`AMGX_solver_get_report`);
 - `validate_report()` checks a report dict against the checked-in
   JSON schema (`telemetry/report_schema.json`) with a dependency-free
-  validator — the `bench.py obs` acceptance gate.
+  validator.
 """
 from __future__ import annotations
 
@@ -116,7 +116,7 @@ class SolveReport:
 
 def _amg_of(solver):
     """Walk the (possibly wrapped) solver tree to the AMG hierarchy
-    owner, mirroring bench.py's chain walk."""
+    owner."""
     s = solver
     for _ in range(6):
         if s is None:

@@ -22,7 +22,7 @@ debugging mode, not a production default.
 `export_chrome_trace(path)` writes the recorded spans as Chrome
 trace-event JSON ("X" complete events, microseconds), loadable by
 Perfetto / chrome://tracing — the host-side timeline that sits next to
-the device timeline `profiling.start_trace` captures via jax.profiler.
+the device timeline `jax.profiler` captures.
 
 REQUEST TRACING: spans (and instant `mark()` events) accept an `args`
 dict; an args entry `trace=<id>` (or `traces=[ids]` for batched

@@ -6,7 +6,8 @@ the classical `AMGLevel` fusion hooks consumed through the existing
 `_fusion_caps` dispatch in `amg/cycles.py`.
 
 Kernels run through the Pallas interpreter (force_pallas_interpret, the
-CPU test path); the compiled path runs on real TPU via bench.py.
+CPU test path); what the chip's compiler accepts of them is in
+tests/test_chip_compile.py.
 Mirrors tests/test_cycle_fusion.py's aggregation proofs: kernel parity
 f32 (interpret) and f64 (the XLA slab fallback in ops/batched.py — the
 parity reference), the jaxpr HBM-pass proof (a smoothed classical DIA

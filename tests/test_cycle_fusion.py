@@ -3,7 +3,8 @@ ops/pallas_spmv.py dia_smooth_restrict / dia_prolong_smooth /
 dia_coarse_tail kernels, amg/cycles.py hooks).
 
 Kernels run through the Pallas interpreter (force_pallas_interpret, the
-CPU test path); the compiled path runs on real TPU via bench.py.
+CPU test path); what the chip's compiler accepts of them is in
+tests/test_chip_compile.py.
 Covers: kernel parity for the restriction epilogue and the
 prolongation/correction prologue vs the unfused reference (f32 through
 the kernels, f64 through the XLA slab fallback in ops/batched.py),

@@ -1,5 +1,5 @@
 """Convergence diagnostics, histogram metrics + OpenMetrics, and the
-bench-regression sentinel (the observability PR's acceptance contracts):
+metric-name lint (the observability PR's acceptance contracts):
 
 - the diagnostics probe's per-level stage norms match a MANUALLY
   composed cycle on the same hierarchy (the recorded numbers are the
@@ -17,16 +17,12 @@ bench-regression sentinel (the observability PR's acceptance contracts):
   labels split series; snapshots include histograms;
 - the OpenMetrics exposition parses under the format's line grammar,
   has monotone cumulative buckets, and terminates with `# EOF`;
-- `tools/bench_history.py` flags a seeded synthetic regression (exit
-  nonzero, offending metric named), flags the known r05 warm-setup
-  regression over copies of the checked-in artifacts, and its --smoke
-  self-check passes on well-formed artifacts / fails on malformed ones.
+- `tools/check_spans.py` lints the package's metric names clean and
+  catches a typo'd literal.
 """
 import json
 import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +38,6 @@ from amgx_tpu.telemetry import diagnostics, metrics, validate_report
 amgx.initialize()
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_HISTORY = os.path.join(REPO, "tools", "bench_history.py")
 
 AMG_PCG = (
     "solver(s)=PCG, s:max_iters=60, s:tolerance=1e-8,"
@@ -480,201 +475,6 @@ def test_serving_latency_histograms_wired():
     assert st["solve_latency_p50_s"] is not None
     assert st["solve_latency_p99_s"] >= st["solve_latency_p50_s"]
     assert st["queue_wait_p50_s"] is not None
-
-
-# ---------------------------------------------------------------------------
-# bench-regression sentinel
-# ---------------------------------------------------------------------------
-
-
-def _wrapper(n, extra, parsed=True, tail_extra=""):
-    payload = {"schema_version": 2, "round": n,
-               "metric": "m", "value": 1.0, "unit": "s",
-               "vs_baseline": 0.0, "extra": extra}
-    w = {"n": n, "cmd": "bench", "rc": 0,
-         "tail": tail_extra or json.dumps(payload),
-         "parsed": payload if parsed else None}
-    return w
-
-
-def _run_history(args):
-    return subprocess.run(
-        [sys.executable, BENCH_HISTORY] + args,
-        capture_output=True, text=True, timeout=120)
-
-
-def test_sentinel_flags_synthetic_regression(tmp_path):
-    """Seed a two-round history where the tracked warm-setup series
-    regresses 3x: exit must be nonzero and the offending metric named
-    in both stdout and the written history."""
-    good = {"northstar_256^3_setup_warm_s": 5.0,
-            "flagship_128^3_solve_s": 0.30}
-    bad = {"northstar_256^3_setup_warm_s": 15.0,
-           "flagship_128^3_solve_s": 0.31}
-    for n, extra in ((1, good), (2, bad)):
-        with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-            json.dump(_wrapper(n, extra), f)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode != 0
-    assert "northstar_256^3_setup_warm_s" in p.stdout
-    assert "flagship_128^3_solve_s" not in \
-        [r["metric"] for r in json.load(
-            open(tmp_path / "BENCH_HISTORY.json"))["regressions"]]
-    hist = json.load(open(tmp_path / "BENCH_HISTORY.json"))
-    assert [r["metric"] for r in hist["regressions"]] == \
-        ["northstar_256^3_setup_warm_s"]
-    assert (tmp_path / "BENCH_HISTORY.md").exists()
-    # an improvement round clears the flag
-    with open(tmp_path / "BENCH_r03.json", "w") as f:
-        json.dump(_wrapper(3, good), f)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode == 0
-
-
-def test_sentinel_recovers_metrics_from_truncated_tail(tmp_path):
-    """A round whose `parsed` came back null (the r05 failure mode)
-    still contributes every scalar its captured tail kept."""
-    with open(tmp_path / "BENCH_r01.json", "w") as f:
-        json.dump(_wrapper(1, {"northstar_256^3_setup_warm_s": 5.0}), f)
-    tail = ('...log noise... "northstar_256^3_setup_warm_s": 17.37,'
-            ' "northstar_256^3_solve_s": 3.0, "truncated_key": 1')
-    with open(tmp_path / "BENCH_r02.json", "w") as f:
-        json.dump(_wrapper(2, {}, parsed=False, tail_extra=tail), f)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode != 0
-    assert "northstar_256^3_setup_warm_s" in p.stdout
-    hist = json.load(open(tmp_path / "BENCH_HISTORY.json"))
-    pts = hist["series"]["northstar_256^3_setup_warm_s"]["points"]
-    assert pts == [{"round": 1, "value": 5.0},
-                   {"round": 2, "value": 17.37}]
-
-
-def test_sentinel_single_round_judges_nothing(tmp_path):
-    """A history of ONE round has nothing to regress against — every
-    direction (the absolute-bound obs gate included) stays quiet."""
-    with open(tmp_path / "BENCH_r01.json", "w") as f:
-        json.dump(_wrapper(1, {"northstar_256^3_setup_warm_s": 99.0,
-                               "obs_overhead_pct": 50.0}), f)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode == 0, p.stdout
-    assert json.load(
-        open(tmp_path / "BENCH_HISTORY.json"))["regressions"] == []
-
-
-def _five_round_history(root):
-    """A five-round history shaped like the rounds the sentinel was
-    written against, written here (the root's round files are records
-    the roadmap deletes): six tracked series, r03's 5.87 s warm setup
-    regressing to 17.37 s in r05, and r05's `parsed` lost to a
-    truncated tail."""
-    solve = {1: 0.330, 2: 0.307, 3: 0.314, 4: 0.315}
-    for n, s in solve.items():
-        extra = {"flagship_128^3_solve_s": s,
-                 "flagship_128^3_setup_warm_s": 1.1,
-                 "northstar_256^3_solve_s": 3.3 - 0.05 * n,
-                 "spmv_vs_ceiling": 0.8,
-                 "classical_128^3_solve_s": 7.3}
-        if n >= 3:
-            extra["northstar_256^3_setup_warm_s"] = 5.87
-        with open(os.path.join(root, f"BENCH_r{n:02d}.json"), "w") as f:
-            json.dump(_wrapper(n, extra), f)
-    tail = ('...cut... "northstar_256^3_setup_warm_s": 17.37,'
-            ' "northstar_256^3_solve_s": 3.057,'
-            ' "flagship_128^3_solve_s": 0.304,'
-            ' "flagship_128^3_setup_warm_s": 1.07, "cut_key": 1')
-    with open(os.path.join(root, "BENCH_r05.json"), "w") as f:
-        json.dump(_wrapper(5, {}, parsed=False, tail_extra=tail), f)
-
-
-def test_sentinel_flags_checked_in_r05_regression(tmp_path):
-    """The acceptance demo over a five-round history written by the
-    test: >= 5 tracked series populate and the r05 warm-setup
-    regression (17.37 s vs r03's 5.87 s) is flagged."""
-    _five_round_history(tmp_path)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode != 0
-    hist = json.load(open(tmp_path / "BENCH_HISTORY.json"))
-    populated = [k for k, s in hist["series"].items() if s["points"]]
-    assert len(populated) >= 5
-    flagged = {r["metric"]: r for r in hist["regressions"]}
-    assert "northstar_256^3_setup_warm_s" in flagged
-    r = flagged["northstar_256^3_setup_warm_s"]
-    assert r["value"] == pytest.approx(17.37)
-    assert r["best_prior"] == pytest.approx(5.87)
-    assert r["best_prior_round"] == 3 and r["round"] == 5
-
-
-def test_sentinel_smoke_ok_and_catches_malformed(tmp_path):
-    """--smoke (the tier-1-reachable self-check): passes on a
-    well-formed history, fails fast on a malformed artifact."""
-    good = tmp_path / "good"
-    good.mkdir()
-    _five_round_history(good)
-    p = _run_history(["--smoke", "--root", str(good)])
-    assert p.returncode == 0, p.stdout + p.stderr
-    assert "OK" in p.stdout
-    with open(tmp_path / "BENCH_r01.json", "w") as f:
-        f.write("{not json")
-    p = _run_history(["--smoke", "--root", str(tmp_path)])
-    assert p.returncode != 0
-    assert "BENCH_r01.json" in p.stdout
-
-
-def test_bench_stamps_round_and_schema(tmp_path, monkeypatch):
-    """bench.py's artifact writer stamps schema_version + the driver's
-    round id (satellite: bench_history keys rounds without parsing
-    filenames)."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    monkeypatch.setenv("AMGX_BENCH_ROUND", "17")
-    assert bench._round_stamp() == 17
-    monkeypatch.delenv("AMGX_BENCH_ROUND")
-    assert bench._round_stamp() is None
-    assert bench.BENCH_SCHEMA_VERSION >= 2
-
-
-def test_phase_artifacts_feed_series(tmp_path):
-    """BENCH_serving.json / BENCH_fleet.json phase artifacts (round
-    stamp + `extra` scalars) contribute series points alongside the
-    wrapper rounds; an unstamped artifact contributes nothing; a
-    malformed one fails --smoke by name."""
-    with open(tmp_path / "BENCH_r06.json", "w") as f:
-        json.dump(_wrapper(6, {"northstar_256^3_setup_warm_s": 5.0}), f)
-    with open(tmp_path / "BENCH_fleet.json", "w") as f:
-        json.dump({"metric": "fleet scaling", "value": 2.0, "unit": "x",
-                   "round": 6,
-                   "extra": {"fleet_scaling_efficiency": 1.3,
-                             "fleet_p99_at_2x_ms": 900.0,
-                             "fleet_ok": True}}, f)
-    # unstamped (standalone run outside the driver): ignored, not fatal
-    with open(tmp_path / "BENCH_serving.json", "w") as f:
-        json.dump({"metric": "serving", "value": 9.0,
-                   "extra": {"serving_solves_per_s": 9.0}}, f)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode == 0, p.stdout + p.stderr
-    hist = json.load(open(tmp_path / "BENCH_HISTORY.json"))
-    assert hist["series"]["fleet_scaling_efficiency"]["points"] == \
-        [{"round": 6, "value": 1.3}]
-    assert hist["series"]["fleet_p99_at_2x_ms"]["points"] == \
-        [{"round": 6, "value": 900.0}]
-    assert hist["series"]["serving_solves_per_s"]["points"] == []
-    assert "BENCH_fleet.json" in hist["rounds"][0]["files"]
-    # a wrapper round carrying the same key wins over the artifact
-    with open(tmp_path / "BENCH_r06.json", "w") as f:
-        json.dump(_wrapper(6, {"fleet_scaling_efficiency": 1.9}), f)
-    p = _run_history(["--root", str(tmp_path)])
-    assert p.returncode == 0, p.stdout + p.stderr
-    hist = json.load(open(tmp_path / "BENCH_HISTORY.json"))
-    assert hist["series"]["fleet_scaling_efficiency"]["points"] == \
-        [{"round": 6, "value": 1.9}]
-    with open(tmp_path / "BENCH_fleet.json", "w") as f:
-        f.write("{not json")
-    p = _run_history(["--smoke", "--root", str(tmp_path)])
-    assert p.returncode != 0
-    assert "BENCH_fleet.json" in p.stdout
 
 
 # ---------------------------------------------------------------------------

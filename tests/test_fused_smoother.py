@@ -2,7 +2,8 @@
 ops/pallas_spmv.py dia_smooth, ops/pallas_swell.py swell_smooth_step).
 
 The kernels run through the Pallas interpreter (force_pallas_interpret,
-the CPU test path); the compiled path runs on real TPU via bench.py.
+the CPU test path); the compiled path runs on the chip in the
+benchmark's cells.
 Covers: multi-sweep parity vs the sweep-by-sweep reference for
 Jacobi-L1 and Chebyshev tau schedules on DIA and SWELL layouts, f32
 (kernel) and f64 (the XLA slab fallback the custom_vmap routes to),

@@ -3,7 +3,8 @@ ops/spmv.spmv_pdot / spmv_ddot, ops/blas.cg_update / psum_bundle, the
 cycle-borne r.z dot through amg/cycles.run_cycle_dot).
 
 Kernels run through the Pallas interpreter (force_pallas_interpret, the
-CPU test path); the compiled path runs on real TPU via bench.py.
+CPU test path); what the chip's compiler accepts of them is in
+tests/test_chip_compile.py.
 Covers: iterate-for-iterate parity of the fused shell against the
 unfused SpMV + BLAS-1 composition for CG/PCG/PCGF/BiCGStab/PBiCGStab
 (f32 through the kernels, f64 through the exact-expression XLA

@@ -1,6 +1,7 @@
 """Pallas DIA SpMV kernel tests (interpreter mode — the compiled path
-runs on real TPU via bench.py). Mirrors the role of the reference's
-csrmv fast-path coverage (src/multiply.cu:74-121)."""
+runs on the chip through chip_smoke.py and the benchmark's cells).
+Mirrors the role of the reference's csrmv fast-path coverage
+(src/multiply.cu:74-121)."""
 import numpy as np
 import pytest
 import jax

@@ -890,45 +890,3 @@ def test_serving_metrics_declared():
                  "amg.setup.restored", "resilience.config_fallback"):
         assert name in snap
     assert "serving.exec_s" in metrics.HISTOGRAMS
-
-
-def test_bench_serving_smoke():
-    """The `bench.py serving --smoke` fast path: the tier-1-runnable
-    slice of the acceptance gates (cache-hit rate > 0, value-resetup
-    routing, zero retraces after AOT warmup, deadline statuses)."""
-    import bench
-    res = bench.bench_serving(smoke=True)
-    assert res["all_completed"]
-    assert res["solves_per_s"] > 0
-    assert res["p50_ms"] > 0 and res["p50_ms"] <= res["p99_ms"]
-    assert res["cache_hit_rate"] > 0
-    assert res["value_resetups_routed"] > 0
-    assert res["retraces_after_warmup"] == 0
-    assert res["aot_loads"] >= 1
-    assert res["deadline_requests"] > 0
-    assert res["deadline_statuses_ok"]
-
-
-@pytest.mark.slow
-def test_bench_chaos_smoke():
-    """The `bench.py chaos --smoke` acceptance gates: kill-and-recover
-    resumes bit-identically with zero full setups / zero retraces,
-    every scripted fault scenario ends all-tickets-terminal, and the
-    2x-saturation shed load keeps admitted work inside its deadline
-    with sheds classified OVERLOADED. (slow: ~1 min of scripted
-    service scenarios — the per-scenario unit tests above are the
-    tier-1 subset.)"""
-    import bench
-    res = bench.bench_chaos(smoke=True)
-    assert res["killed_inflight"] > 0
-    assert res["recover_replayed"] > 0 and res["recover_resumed"] > 0
-    assert res["recover_bitwise_ok"]
-    assert res["restart_full_setups"] == 0
-    assert res["restart_hier_restored"] >= 1
-    assert res["restart_retraces"] == 0
-    assert res["recover_all_terminal"]
-    assert res["chaos_recover_wall_s"] > 0
-    assert res["chaos_all_terminal"], res["chaos_scenarios"]
-    assert res["shed_all_overloaded"]
-    assert res["shed_admitted_deadline_misses"] == 0
-    assert res["shed_ok"]
