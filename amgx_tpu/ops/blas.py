@@ -65,16 +65,6 @@ def dot(x, y, axis_name: Optional[str] = None, num_owned: Optional[int] = None):
     return _psum(jnp.vdot(x, y), axis_name)
 
 
-def mdot(V, w, axis_name: Optional[str] = None,
-         num_owned: Optional[int] = None):
-    """Row-wise dots <V[j], w> as ONE (m, n) @ (n,) matvec (the
-    MXU-friendly shape for Gram-Schmidt panels); distributed-safe via
-    psum like `dot`."""
-    if num_owned is not None:
-        V, w = V[:, :num_owned], w[:num_owned]
-    return _psum(V @ w, axis_name)
-
-
 def nrm1(x, axis_name: Optional[str] = None, num_owned: Optional[int] = None):
     if num_owned is not None:
         x = x[:num_owned]
@@ -184,3 +174,115 @@ def psum_bundle(scalars, axis_name: Optional[str] = None):
     packed = jax.lax.psum(jnp.stack([jnp.asarray(s) for s in scalars]),
                           axis_name)
     return tuple(packed[i] for i in range(len(scalars)))
+
+
+# ---------------------------------------------------------------------------
+# The Krylov basis of GMRES / FGMRES: row-bounded passes over a slab
+#
+# A basis is an (m + 1, R, 128) array: row k is one n-vector laid out
+# as R rows of 128 lanes, so that a row is dense and contiguous on the
+# chip (an (m + 1, n) array tiles its rows by eight: reading one row
+# reads eight) and the leading index is a plain address. Every reader
+# takes the number of LIVE rows, a traced value, and touches no row
+# beyond it: a stale row may hold anything, finite or not.
+# ---------------------------------------------------------------------------
+
+# readings of the basis one CGS2 step makes (cgs2_step below)
+CGS2_BASIS_READS = 3
+
+
+def basis_rows128(n_rows: int, n: int) -> int:
+    """R of an (n_rows, R, 128) basis for n-vectors: whole (8, 128)
+    tiles, and whole column blocks of the chip's kernel."""
+    from . import pallas_spmv as _ps
+    return _ps.basis_padded_rows(n_rows, n)
+
+
+def to_slab(v, rows128: int):
+    """(n,) -> (rows128, 128), zero-filled behind n."""
+    size = rows128 * 128
+    if v.shape[0] != size:
+        v = jnp.pad(v, (0, size - v.shape[0]))
+    return v.reshape(rows128, 128)
+
+
+def from_slab(s, n: int):
+    """(rows128, 128) -> (n,)."""
+    v = s.reshape(-1)
+    return v if v.shape[0] == n else v[:n]
+
+
+def _basis_pass_xla(V, w, coef, nlive):
+    """The plain twin of the chip's kernel (pallas_spmv._basis_pass_call),
+    pass for pass; `where` and not a product with 0 bounds the rows."""
+    live = (jnp.arange(V.shape[0]) < nlive)[:, None, None]
+    Vl = jnp.where(live, V, jnp.zeros((), V.dtype))
+    if coef is not None:
+        w = w - jnp.sum(coef[:, None, None] * Vl, axis=0)
+    return w, jnp.sum(Vl * w, axis=(1, 2)), jnp.sum(w * w)
+
+
+@functools.lru_cache(maxsize=None)
+def _basis_pass_fn(project: bool):
+    """custom_vmap-wrapped kernel pass: a vmap batch (BatchedSolver)
+    takes the plain twin, batched by XLA."""
+
+    def twin(V, w, coef, nlive):
+        return _basis_pass_xla(V, w, coef if project else None, nlive)
+
+    @jax.custom_batching.custom_vmap
+    def call(V, w, coef, nlive):
+        from . import pallas_spmv as _ps
+        return _ps._basis_pass_call(V, w, coef, nlive, project=project,
+                                    interpret=_ps._FORCE_INTERPRET)
+
+    @call.def_vmap
+    def _rule(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(
+            a, (axis_size,) + jnp.shape(a))
+            for a, b in zip(args, in_batched)]
+        return jax.vmap(twin)(*args), (True, True, True)
+
+    return call
+
+
+def basis_pass(V, w, coef, nlive):
+    """One reading of the live rows of a basis:
+
+        w'      = w - sum_{k < nlive} coef[k] V[k]    (coef None: w' = w)
+        dots[k] = <V[k], w'> for k < nlive, else 0
+        nrm     = <w', w'>
+
+    V (rows, R, 128), w (R, 128), coef (rows,), nlive a traced count.
+    dots and nrm are LOCAL sums: a distributed caller psums what it
+    uses. On the chip in f32 this is one Pallas kernel that fetches
+    the live rows alone; everywhere else the plain twin."""
+    from . import pallas_spmv as _ps
+    if _ps.basis_pass_supported(V, w):
+        c = jnp.zeros((V.shape[0],), w.dtype) if coef is None else coef
+        return _basis_pass_fn(coef is not None)(
+            V, w, c, jnp.asarray(nlive, jnp.int32))
+    return _basis_pass_xla(V, w, coef, nlive)
+
+
+def cgs2_step(V, w, nlive, axis_name: Optional[str] = None):
+    """Classical Gram-Schmidt with reorthogonalisation of the slab w
+    against rows 0..nlive-1 of the basis V, in CGS2_BASIS_READS
+    readings of those rows: h = V w; then w' = w - V^T h together
+    with h2 = V w' (w' is elementwise in the column, so both come from
+    one reading of a column block); then w'' = w' - V^T h2 together
+    with its norm. Returns (h + h2, w'', ||w''||); h is zero from
+    nlive on. Distributed-safe: each reading's sums finish with one
+    psum, as the dots of `dot` and `nrm2` do."""
+    _, h, _ = basis_pass(V, w, None, nlive)
+    h = _psum(h, axis_name)
+    w, h2, _ = basis_pass(V, w, h, nlive)
+    h2 = _psum(h2, axis_name)
+    w, _, nrm = basis_pass(V, w, h2, nlive)
+    return h + h2, w, jnp.sqrt(_psum(nrm, axis_name))
+
+
+def basis_combine(V, y, nlive, x):
+    """x + sum_{k < nlive} y[k] V[k] as a slab: the way back from the
+    Krylov coordinates, in one reading of the live rows."""
+    return basis_pass(V, x, -y, nlive)[0]

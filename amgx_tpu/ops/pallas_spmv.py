@@ -2439,3 +2439,156 @@ def _cg_update_call(x, p, r, ap, alpha, interpret=False):
         xv = xv[:n]
         rv = rv[:n]
     return xv, rv, jnp.sum(rr)
+
+
+# ---------------------------------------------------------------------------
+# Row-bounded pass over a Krylov basis (ops/blas.py basis_pass: GMRES /
+# FGMRES's CGS2 step and the way back from the Krylov coordinates)
+#
+# The basis is (rows, R, 128), `nlive` of its rows live, and nlive is a
+# traced value: a grid over the rows would pay a grid step for every
+# dead row, and a block over all rows would fetch them. So the grid is
+# the column blocks alone, the basis stays in HBM (pl.ANY), and each
+# step DMAs the nlive live (br, 128) row blocks of its column block
+# into one of two VMEM slots, the next block's while this one
+# computes. With every live row of a column block resident, the
+# projection w' = w - sum coef[k] V[k] and the dots <V[k], w'> of the
+# NEXT Gram-Schmidt pass come from the same reading.
+# ---------------------------------------------------------------------------
+
+_BASIS_CHUNK = 64            # rows of 128 lanes the arithmetic holds
+
+
+def basis_block_rows(n_rows: int, rows128: int) -> int:
+    """Rows of 128 lanes in a column block of an n_rows-row basis, as
+    pick_block_rows sizes a block: the two slots of n_rows row blocks
+    fit the budget; a basis of fewer rows128 is one block of whole
+    tiles."""
+    budget_rows = _VMEM_BUDGET // (2 * n_rows * LANES * 4)
+    br = 512
+    while br > 8 and br > budget_rows:
+        br //= 2
+    return min(br, -(-rows128 // 8) * 8)
+
+
+def basis_padded_rows(n_rows: int, n: int) -> int:
+    """R of an (n_rows, R, 128) basis of n-vectors: whole column blocks."""
+    rows128 = max(1, -(-n // LANES))
+    br = basis_block_rows(n_rows, rows128)
+    return -(-rows128 // br) * br
+
+
+def basis_pass_supported(V, w) -> bool:
+    """Trace-time gate of the kernel pass: f32 through Mosaic or the
+    interpreter, on a slab cut into whole column blocks. Everything
+    else (f64, the CPU rig) takes the plain twin in ops/blas.py."""
+    if pallas_backend() is None:
+        return False
+    if V.dtype != jnp.float32 or w.dtype != jnp.float32:
+        return False
+    n_rows, rows128, _ = V.shape
+    return rows128 % basis_block_rows(n_rows, rows128) == 0
+
+
+def _basis_pass_kernel(n_rows, br, n_blocks, project):
+    ch = min(_BASIS_CHUNK, br)
+
+    def fold(p):
+        # (ch, 128) -> (8, 128): whole-vreg adds, no cross-lane work
+        return jnp.sum(p.reshape(ch // 8, 8, LANES), axis=0)
+
+    def kernel(nlive_ref, coef_ref, v_ref, w_ref, *rest):
+        if project:
+            wo_ref, dots_ref, vbuf, sems = rest
+        else:
+            dots_ref, vbuf, sems = rest
+        c = pl.program_id(0)
+        slot = jax.lax.rem(c, jnp.int32(2))
+        nlive = nlive_ref[0]
+
+        def rows_dma(s, blk, wait):
+            def one(k, carry):
+                cp = pltpu.make_async_copy(
+                    v_ref.at[k, pl.ds(blk * jnp.int32(br), br)],
+                    vbuf.at[s, k], sems.at[s])
+                cp.wait() if wait else cp.start()
+                return carry
+            jax.lax.fori_loop(jnp.int32(0), nlive, one, jnp.int32(0))
+
+        @pl.when(c == 0)
+        def _():
+            dots_ref[...] = jnp.zeros(dots_ref.shape, jnp.float32)
+            rows_dma(jnp.int32(0), jnp.int32(0), False)
+
+        @pl.when(c + 1 < n_blocks)
+        def _():
+            rows_dma(jax.lax.rem(c + 1, jnp.int32(2)), c + 1, False)
+
+        rows_dma(slot, c, True)
+
+        def chunk(t, carry):
+            rows = pl.ds(pl.multiple_of(t * jnp.int32(ch), ch), ch)
+            acc = w_ref[rows, :]
+            if project:
+                acc = jax.lax.fori_loop(
+                    jnp.int32(0), nlive,
+                    lambda k, a: a - coef_ref[k] * vbuf[slot, k, rows, :],
+                    acc)
+                wo_ref[rows, :] = acc
+
+            def dot_row(k, carry):
+                dots_ref[k] += fold(vbuf[slot, k, rows, :] * acc)
+                return carry
+            jax.lax.fori_loop(jnp.int32(0), nlive, dot_row, jnp.int32(0))
+            dots_ref[n_rows] += fold(acc * acc)
+            return carry
+
+        jax.lax.fori_loop(jnp.int32(0), jnp.int32(br // ch), chunk,
+                          jnp.int32(0))
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("project", "interpret"))
+def _basis_pass_call(V, w, coef, nlive, project, interpret=False):
+    """(w', dots, nrm) of blas.basis_pass in one reading of the live
+    rows; `project=False` leaves w as it is (coef is not read) and
+    writes no vector. dots and nrm are the LOCAL f32 sums. Caller must
+    have checked basis_pass_supported."""
+    n_rows, rows128, _ = V.shape
+    br = basis_block_rows(n_rows, rows128)
+    n_blocks = rows128 // br
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    blk = pl.BlockSpec((br, LANES), lambda c: (c, jnp.int32(0)),
+                       memory_space=pltpu.VMEM)
+    # row k < n_rows: the (8, 128) partial sums of <V[k], w'>; row
+    # n_rows: those of <w', w'>. One block, resident over the grid.
+    parts = jax.ShapeDtypeStruct((n_rows + 1, PART_ROWS, LANES),
+                                 jnp.float32)
+    parts_spec = pl.BlockSpec(
+        parts.shape, lambda c: (jnp.int32(0),) * 3,
+        memory_space=pltpu.VMEM)
+    vec = jax.ShapeDtypeStruct((rows128, LANES), w.dtype)
+    live = n_rows // 2 + 1      # the estimate's mean of nlive
+    out = kernel_call(
+        _basis_pass_kernel(n_rows, br, n_blocks, project),
+        grid=(n_blocks,),
+        in_specs=[smem((1,), lambda c: (jnp.int32(0),)),
+                  smem((n_rows,), lambda c: (jnp.int32(0),)),
+                  pl.BlockSpec(memory_space=pl.ANY), blk],
+        out_specs=(blk, parts_spec) if project else parts_spec,
+        out_shape=(vec, parts) if project else parts,
+        scratch_shapes=[
+            pltpu.VMEM((2, n_rows, br, LANES), V.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        cost_estimate=pl.CostEstimate(
+            flops=(4 if project else 2) * live * rows128 * LANES,
+            bytes_accessed=(live + (2 if project else 1))
+            * rows128 * LANES * 4,
+            transcendentals=0),
+        interpret=interpret,
+    )(jnp.reshape(nlive, (1,)).astype(jnp.int32), coef, V, w)
+    w_out, sums = out if project else (w, out)
+    sums = jnp.sum(sums, axis=(1, 2))
+    return w_out, sums[:n_rows], sums[n_rows]

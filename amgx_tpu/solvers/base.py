@@ -596,9 +596,12 @@ class Solver:
         return self.apply(data, rhs), None
 
     # -- the jitted driver ----------------------------------------------
-    def _build_solve_fn(self, diag: bool = True):
+    def _build_solve_fn(self, diag: bool = True, extras=None):
         """Return the raw (unjitted) solve function; jit happens in
         solve(), and the distributed layer shard_maps it instead.
+        `extras` (default: as `diag`) appends the solver's
+        `_extra_stats` to the packed stats; an outer shell that sums
+        its inner solver's asks for them without the probe.
 
         Health guards (resilience/): the convergence check folds NaN
         detection, breakdown classification, divergence and stall
@@ -618,6 +621,7 @@ class Solver:
         unpacking assumes the bare layout); with the knob off the
         emitted jaxpr is identical either way."""
         diag_spec = self._diag_probe_spec() if diag else None
+        extras = diag if extras is None else extras
         max_iters = self.max_iters
         monitor = self.monitor_residual
         hist_len = max_iters + 1
@@ -759,10 +763,10 @@ class Solver:
             # iteration count under an active solve_precision policy)
             # ride the same packed buffer — zero added transfers; the
             # spec is empty by default so the layout is unchanged.
-            # Gated on `diag` exactly like the probe tail: the batched
-            # / distributed / inner-fn consumers (diag=False) unpack
-            # the BARE stats layout
-            if diag:
+            # Off with the probe tail by default: the batched and
+            # distributed consumers (diag=False) unpack the BARE stats
+            # layout
+            if extras:
                 for v in self._extra_stats(final):
                     pieces.append(jnp.reshape(
                         jnp.asarray(v).astype(rdt), (1,)))
@@ -1038,6 +1042,12 @@ class Solver:
                 # shell's inner count where it keeps one, else its own
                 _tm.inc("smoother.color_steps", self._color_steps * int(
                     round((extras or {}).get("inner_iters", iters_i))))
+            for name, value in (extras or {}).items():
+                # an extra stat that a counter is named after (GMRES /
+                # FGMRES's account of a solve, its own or summed by
+                # the shell round it) is raised once a solve
+                if f"krylov.{name}" in _tm.COUNTERS:
+                    _tm.inc(f"krylov.{name}", int(round(value)))
         if self.telemetry or self.print_solve_stats:
             with span("solve.report", counter="solve.stage_s.report"):
                 if self.telemetry:

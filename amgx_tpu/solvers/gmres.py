@@ -6,22 +6,38 @@ TPU re-formulation:
 
 - one `solve_iteration` = one Arnoldi step (iteration-count parity with
   the reference, which counts inner steps);
-- the Krylov basis V lives as a dense (m+1, n) buffer updated with
-  `dynamic_update_slice`; modified-Gram-Schmidt runs as a fori_loop over
-  all m rows — rows beyond the current inner index are zero, so their
-  projections vanish and no dynamic bounds are needed (static shapes for
-  XLA, and the projections are (m+1, n) x (n,) matvecs on the MXU);
+- the Krylov basis V lives as an (m+1, R, 128) slab, a row one n-vector
+  as R rows of 128 lanes (ops/blas.py: dense and contiguous on the
+  chip, the leading index a plain address). A step WRITES ONE ROW of it
+  (and, flexible, one row of Z) with `dynamic_update_slice` on the
+  loop's own buffer, so the write is in place: V and Z are returned by
+  no `lax.cond`, and a restart writes row 0 and resets the small state
+  (R, cs, sn, g, i), never the slabs;
+- step i READS ROWS 0..i and no other: classical Gram-Schmidt with
+  reorthogonalisation (CGS2) in three readings of the live rows
+  (`blas.cgs2_step`: h = V w; w' = w - V^T h with h2 = V w' from the
+  same reading; w'' = w' - V^T h2 with its norm). The live-row count
+  is a traced value; on the chip in f32 each reading is one Pallas
+  kernel that fetches the live rows of a column block by DMA
+  (ops/pallas_spmv.py `_basis_pass_call`), elsewhere its plain twin
+  masks the rows. Rows beyond the live ones are stale, never read;
 - the Hessenberg column is rotated by all m stored Givens rotations
   (identity-initialized, so "not yet created" rotations are no-ops);
 - the estimated residual |g[i+1]| drives convergence (exact for the
   true residual in exact arithmetic), so no extra SpMV per step;
 - x is reconstructed only at restart boundaries and once after the loop
   (`finalize`), via a masked m x m triangular solve (R is identity-
-  initialized, so unused columns solve to y_j = 0).
+  initialized; y is cut to the live columns) and one reading of the
+  live rows (`blas.basis_combine`).
 
 GMRES applies the preconditioner at reconstruction time (right
 preconditioning with a fixed linear M: x = x0 + M (V^T y)); FGMRES stores
 the preconditioned vectors Z (flexible: M may vary per step).
+
+The state counts the basis rows its steps read and wrote
+(`basis_rows`); with the step count it rides the packed stats vector
+(`_extra_stats_spec`) to the counters `krylov.arnoldi_steps` and
+`krylov.basis_rows`.
 """
 from __future__ import annotations
 
@@ -60,15 +76,11 @@ class _GmresBase(Solver):
         return state["est_res"]
 
     # -- state -----------------------------------------------------------
-    def solve_init(self, data, b, x, r):
-        m, n = self.m, x.shape[0]
-        dt = x.dtype
-        beta = blas.nrm2(r)
-        V = jnp.zeros((m + 1, n), dt).at[0].set(
-            r / jnp.where(beta == 0, 1.0, beta))
-        st = {
-            "x0": x,
-            "V": V,
+    def _cycle_start(self, beta):
+        """The small state of a restart cycle that starts from a
+        residual of norm beta."""
+        m, dt = self.m, beta.dtype
+        return {
             "R": jnp.eye(m, dtype=dt),
             "cs": jnp.ones((m,), dt),
             "sn": jnp.zeros((m,), dt),
@@ -76,46 +88,40 @@ class _GmresBase(Solver):
             "i": jnp.zeros((), jnp.int32),
             "est_res": beta,
         }
+
+    def solve_init(self, data, b, x, r):
+        m, n = self.m, x.shape[0]
+        dt = x.dtype
+        rows128 = blas.basis_rows128(m + 1, n)
+        beta = blas.nrm2(r)
+        # the one whole-slab write of a solve: a step writes one row
+        V = jnp.zeros((m + 1, rows128, 128), dt).at[0].set(
+            blas.to_slab(r / jnp.where(beta == 0, 1.0, beta), rows128))
+        st = {"V": V, "basis_rows": jnp.zeros((), jnp.float32)}
+        st.update(self._cycle_start(beta))
         st.update(self._guard_init())
         if self.flexible:
-            st["Z"] = jnp.zeros((m, n), dt)
+            st["Z"] = jnp.zeros((m, rows128, 128), dt)
         return st
 
     # -- helpers ---------------------------------------------------------
-    def _y(self, st):
-        """Solve the (masked) m x m triangular system R y = g[:m]."""
-        return jsl.solve_triangular(st["R"], st["g"][: self.m], lower=False)
+    def _basis_of_x(self, st):
+        """The slab the way back to x reads: Z, flexible, else V."""
+        return st["Z"] if self.flexible else st["V"]
 
-    def _reconstruct(self, data, st):
-        """x = x0 + correction from the current Krylov data."""
-        y = self._y(st)
+    def _reconstruct(self, data, x, basis, R, g, nlive):
+        """x + the correction of the cycle's first nlive columns. R y =
+        g[:m] is solved whole (R is the identity beyond the live
+        columns) and y cut to them: the rows behind are stale."""
+        m, n = self.m, x.shape[0]
+        y = jsl.solve_triangular(R, g[:m], lower=False)
+        y = jnp.where(jnp.arange(m) < nlive, y, jnp.zeros((), y.dtype))
         if self.flexible:
-            corr = st["Z"].T @ y
-        else:
-            u = st["V"][: self.m].T @ y
-            corr = self._precond(data, u)
-        return st["x0"] + corr
-
-    def _restart(self, data, b, st, x_new):
-        """Reset the cycle state around a new initial guess."""
-        m = self.m
-        dt = x_new.dtype
-        r = residual(data["A"], x_new, b)
-        beta = blas.nrm2(r)
-        n = x_new.shape[0]
-        new = dict(st)
-        new["x0"] = x_new
-        new["V"] = jnp.zeros((m + 1, n), dt).at[0].set(
-            r / jnp.where(beta == 0, 1.0, beta))
-        new["R"] = jnp.eye(m, dtype=dt)
-        new["cs"] = jnp.ones((m,), dt)
-        new["sn"] = jnp.zeros((m,), dt)
-        new["g"] = jnp.zeros((m + 1,), dt).at[0].set(beta)
-        new["i"] = jnp.zeros((), jnp.int32)
-        new["est_res"] = beta
-        if self.flexible:
-            new["Z"] = jnp.zeros((m, n), dt)
-        return new
+            return blas.from_slab(blas.basis_combine(
+                basis, y, nlive, blas.to_slab(x, basis.shape[1])), n)
+        u = blas.basis_combine(basis, jnp.pad(y, (0, 1)), nlive,
+                               jnp.zeros(basis.shape[1:], x.dtype))
+        return x + self._precond(data, blas.from_slab(u, n))
 
     # -- one Arnoldi step -------------------------------------------------
     def solve_iteration(self, data, b, st):
@@ -123,29 +129,22 @@ class _GmresBase(Solver):
         m = self.m
         i = st["i"]
         V = st["V"]
-        v_i = V[i]
+        n, rows128 = st["x"].shape[0], V.shape[1]
+        v_i = blas.from_slab(
+            jax.lax.dynamic_index_in_dim(V, i, 0, keepdims=False), n)
         z = self._precond(data, v_i)
+        new = dict(st)
         if self.flexible:
-            Z = jax.lax.dynamic_update_index_in_dim(st["Z"], z, i, 0)
+            new["Z"] = jax.lax.dynamic_update_index_in_dim(
+                st["Z"], blas.to_slab(z, rows128), i, 0)
         w = spmv(A, z)
 
-        # classical Gram-Schmidt with reorthogonalization (CGS2) against
-        # all rows (zero rows are no-ops): each pass is ONE (m+1, n)
-        # matvec pair on the MXU instead of m serialized dot/axpy round
-        # trips — the TPU-native reformulation of the reference's MGS
-        # loop (fgmres_solver.cu), with CGS2 restoring MGS-level
-        # orthogonality. The row-dot matvec finishes with a psum when
-        # running inside shard_map (the MPI_Allreduce analog), exactly
-        # like blas.dot.
-        h = blas.mdot(V, w)
-        w = w - V.T @ h
-        h2 = blas.mdot(V, w)
-        w = w - V.T @ h2
-        h = h + h2
-        h_last = blas.nrm2(w)
+        # CGS2 against the i + 1 rows built so far, in three readings
+        # of them — the TPU-native reformulation of the reference's MGS
+        # loop (fgmres_solver.cu), with the second pass restoring
+        # MGS-level orthogonality
+        h, w, h_last = blas.cgs2_step(V, blas.to_slab(w, rows128), i + 1)
         h = h.at[i + 1].set(h_last)
-        V = jax.lax.dynamic_update_index_in_dim(
-            V, w / jnp.where(h_last == 0, 1.0, h_last), i + 1, 0)
 
         # previously stored rotations (identity where not yet created)
         def rot_body(j, h):
@@ -163,8 +162,6 @@ class _GmresBase(Solver):
         c = jnp.where(denom == 0, 1.0, hi / jnp.where(denom == 0, 1.0, denom))
         s = jnp.where(denom == 0, 0.0, hi1 / jnp.where(denom == 0, 1.0, denom))
         h = h.at[i].set(c * h[i] + s * h[i + 1]).at[i + 1].set(0.0)
-        cs = st["cs"].at[i].set(c)
-        sn = st["sn"].at[i].set(s)
         g = st["g"]
         gi = g[i]
         # a degenerate rotation (rotated Hessenberg column entirely
@@ -173,42 +170,67 @@ class _GmresBase(Solver):
         # instant (false) convergence
         g = g.at[i].set(c * gi).at[i + 1].set(
             jnp.where(denom == 0, gi, -s * gi))
-        est = jnp.abs(g[i + 1])
-
-        R = jax.lax.dynamic_update_slice_in_dim(
-            st["R"], h[:m][:, None], i, axis=1)
-
-        new = dict(st)
-        new.update(V=V, R=R, cs=cs, sn=sn, g=g, est_res=est)
+        small = {
+            "R": jax.lax.dynamic_update_slice_in_dim(
+                st["R"], h[:m][:, None], i, axis=1),
+            "cs": st["cs"].at[i].set(c),
+            "sn": st["sn"].at[i].set(s),
+            "g": g,
+            "i": i + 1,
+            "est_res": jnp.abs(g[i + 1]),
+        }
         if self.health_guards:
             # Givens/Hessenberg degeneracy with an unconverged residual:
             # the Arnoldi process produced a zero column — exit cleanly
             new["breakdown"] = (denom == 0) & (jnp.abs(gi) > 0)
-        if self.flexible:
-            new["Z"] = Z
 
-        # cycle boundary: reconstruct x and restart
-        def at_restart(new):
-            x_new = self._reconstruct(data, new)
-            out = self._restart(data, b, new, x_new)
-            out["x"] = x_new
-            return out
+        # cycle boundary: reconstruct x and start the next cycle from
+        # its residual. The branches hand back x, the small state and
+        # the ONE row the step writes (each normalises its own, so
+        # the row is made where it is handed back); the slabs are read
+        # here and returned by neither branch
+        restart = i + 1 >= m
 
-        def mid_cycle(new):
-            out = dict(new)
-            out["i"] = new["i"] + 1
-            return out
+        def at_restart(x, basis, w):
+            x_new = self._reconstruct(
+                data, x, basis, small["R"], small["g"], m)
+            r = residual(A, x_new, b)
+            beta = blas.nrm2(r)
+            return (x_new, self._cycle_start(beta), blas.to_slab(
+                r / jnp.where(beta == 0, 1.0, beta), rows128))
 
-        new["x"] = st["x"]
-        return jax.lax.cond(i + 1 >= m, at_restart, mid_cycle, new)
+        def mid_cycle(x, basis, w):
+            return x, small, w / jnp.where(h_last == 0, 1.0, h_last)
+
+        x, small, row = jax.lax.cond(
+            restart, at_restart, mid_cycle,
+            st["x"], self._basis_of_x(new), w)
+        new.update(small)
+        new["x"] = x
+        new["V"] = jax.lax.dynamic_update_index_in_dim(V, row, small["i"], 0)
+        # rows this step read (the three readings of CGS2, at a restart
+        # the way back) and wrote
+        new["basis_rows"] = st["basis_rows"] + (
+            blas.CGS2_BASIS_READS * (i + 1) + (2 if self.flexible else 1)
+            + jnp.where(restart, m, 0)).astype(jnp.float32)
+        return new
+
+    # -- the counters' source -------------------------------------------
+    def _extra_stats_spec(self):
+        return ("arnoldi_steps", "basis_rows")
+
+    def _extra_stats(self, final_state):
+        return (final_state["iters"], final_state["basis_rows"])
 
     def finalize(self, data, b, state):
         # mid-cycle exit: reconstruct from the live Krylov data; exactly at
-        # a restart boundary i==0 and the reconstruction is x0 itself.
+        # a restart boundary i==0 and x is the reconstruction itself.
         return jax.lax.cond(
             state["i"] > 0,
-            lambda st: self._reconstruct(data, st),
-            lambda st: st["x0"],
+            lambda st: self._reconstruct(
+                data, st["x"], self._basis_of_x(st), st["R"], st["g"],
+                st["i"]),
+            lambda st: st["x"],
             state)
 
 

@@ -76,7 +76,10 @@ class RefinementSolver(Solver):
         # diag=False: the inner fn's stats are discarded each outer step
         # (only d matters); the diagnostics probe belongs to the OUTER
         # driver, which walks the tree to the AMG itself
-        self._inner_fn = self.preconditioner._build_solve_fn(diag=False)
+        # its own extra stats (GMRES / FGMRES's step and basis-row
+        # counts) are summed over the outer steps and handed on
+        self._inner_fn = self.preconditioner._build_solve_fn(
+            diag=False, extras=True)
 
     def solve_data(self):
         # overrides the base: the inner data is the f32 solve tree; the
@@ -90,13 +93,16 @@ class RefinementSolver(Solver):
 
     def solve_init(self, data, b, x0, r0):
         st = super().solve_init(data, b, x0, r0)
-        if self._extra_stats_spec():
+        if "inner_iters" in self._extra_stats_spec():
             # the accumulated inner-Krylov iteration count rides the
             # state (and, via _extra_stats, the packed stats vector):
             # per-precision accounting, and the count of colored cycles
             # a solve ran. Keyed on who reads it, so the default build
             # carries no extra leaf (bitwise-off)
             st["inner_iters"] = jnp.zeros((), jnp.float32)
+        if self._inner_extras:
+            st["inner_extras"] = jnp.zeros(
+                (len(self._inner_extras),), jnp.float32)
         return st
 
     def solve_iteration(self, data, b, st):
@@ -119,7 +125,15 @@ class RefinementSolver(Solver):
             # stats layout _build_solve_fn emits)
             out["inner_iters"] = st["inner_iters"] + \
                 istats[0].astype(jnp.float32)
+        if self._inner_extras:
+            # the tail of the inner stats, by the spec that packed it
+            out["inner_extras"] = st["inner_extras"] + \
+                istats[-len(self._inner_extras):].astype(jnp.float32)
         return out
+
+    @property
+    def _inner_extras(self) -> tuple:
+        return tuple(self.preconditioner._extra_stats_spec())
 
     # -- per-precision accounting (solve_precision policy) --------------
     def _extra_stats_spec(self):
@@ -127,12 +141,12 @@ class RefinementSolver(Solver):
         # counter of color steps (which are per INNER iteration)
         counted = self._precision_policy.active \
             or self.color_steps_per_iteration() > 0
-        return ("inner_iters",) if counted else ()
+        return (("inner_iters",) if counted else ()) + self._inner_extras
 
     def _extra_stats(self, final_state):
-        if "inner_iters" not in final_state:
-            return ()
-        return (final_state["inner_iters"],)
+        own = (final_state["inner_iters"],) \
+            if "inner_iters" in final_state else ()
+        return own + tuple(final_state.get("inner_extras", ()))
 
     def _precision_block(self, res):
         block = super()._precision_block(res)

@@ -24,6 +24,7 @@ KERNEL_KEYS = (
     "_dia_spmv_call",
     "_dia_spmv_dot_call",
     "_cg_update_call",
+    "_basis_pass_call",
     "_swell_spmv_call",
     "_swell_smooth_call",
 )

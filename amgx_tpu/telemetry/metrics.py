@@ -562,6 +562,19 @@ declare_counter("krylov.fused_declined",
                 "operator, off-whitelist dtype, or VMEM gate) — same "
                 "results, more HBM passes per iteration")
 
+# GMRES / FGMRES's account of its basis traffic (solvers/gmres.py): a
+# step's readings are bounded to the live rows and its writes are one
+# row each, so rows per step says whether that still holds
+declare_counter("krylov.arnoldi_steps",
+                "Arnoldi steps of GMRES / FGMRES, raised after each "
+                "solve (under REFINEMENT: the inner steps of all its "
+                "outer steps); rides the packed stats vector")
+declare_counter("krylov.basis_rows",
+                "rows of the Krylov basis (V, and Z when flexible) "
+                "those steps read and wrote: three readings of the "
+                "i + 1 live rows and the one-row writes a step, the m "
+                "rows of the way back to x at a restart")
+
 # GEO Galerkin CSR-structure device cache (amg/aggregation/galerkin.py):
 # a miss at 256^3 re-uploads ~1 GB of structure arrays per warm setup
 declare_counter("amg.geo_struct_cache.hit",

@@ -239,6 +239,53 @@ def test_cg_update_compiles(n, one_chip, on_tpu, no_persistent_cache):
              one_chip, vec, vec, vec, vec, ((), F32))
 
 
+# the flagship's basis at 128^3 and at the 256^3 that fills a chip
+# (gmres_n_restart 10: V has 11 rows, Z 10), and a restart of 30: the
+# column block shrinks with the rows, the live-row count is an operand
+@pytest.mark.parametrize("n_rows,n", [(11, FINE), (10, FINE), (11, 256),
+                                      (31, FINE)])
+@pytest.mark.parametrize("project", [False, True])
+def test_basis_pass_compiles(n_rows, n, project, one_chip, on_tpu,
+                             no_persistent_cache):
+    rows128 = ps.basis_padded_rows(n_rows, n ** 3)
+    shapes = (((n_rows, rows128, 128), F32), ((rows128, 128), F32),
+              ((n_rows,), F32), ((), jnp.int32))
+    args = [jax.ShapeDtypeStruct(s[0], s[1]) for s in shapes]
+    assert ps.basis_pass_supported(*args[:2])
+    compiled = _compile(lambda V, w, c, nl: ps._basis_pass_call(
+        V, w, c, nl, project=project), one_chip, *shapes)
+    # the basis stays where it is: the kernel's own memory is VMEM
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 * 1024
+
+
+def test_cgs2_step_compiles_under_shard_map(topo, on_tpu,
+                                            no_persistent_cache):
+    """A distributed f32 solve takes the kernel inside shard_map (as
+    distributed/solver.py maps it: check_vma off): one program across
+    the four chips of the described host, each shard's three readings
+    ending in one all-reduce each."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from amgx_tpu.ops import blas
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("p",))
+    n_rows = 11
+    rows128 = ps.basis_padded_rows(n_rows, FINE ** 3 // 4)
+
+    def step(V, w, nlive):
+        return blas.cgs2_step(V[0], w[0], nlive, axis_name="p")
+
+    fn = jax.shard_map(step, mesh=mesh, in_specs=(P("p"), P("p"), P()),
+                       out_specs=(P(), P("p"), P()), check_vma=False)
+    args = [jax.ShapeDtypeStruct((4, n_rows, rows128, 128), F32,
+                                 sharding=NamedSharding(mesh, P("p"))),
+            jax.ShapeDtypeStruct((4, rows128, 128), F32,
+                                 sharding=NamedSharding(mesh, P("p"))),
+            jax.ShapeDtypeStruct((), jnp.int32,
+                                 sharding=NamedSharding(mesh, P()))]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert text.count(" all-reduce(") + text.count(" all-reduce-start(") == 3
+
+
 # SWELL shapes of a classical coarse level under 7-pt 64^3 (PMIS+D2):
 # 32 super-blocks of 1024 rows, 24 slots per row, a 96-row x window
 @pytest.mark.parametrize("kernel", ["spmv", "smooth"])

@@ -13,7 +13,7 @@ the cycle's fused kernels plus EXACTLY two shell kernels with zero
 standalone full-vector reductions, and `krylov_fusion=0` emits a jaxpr
 identical to the pre-fusion composition; the CG dead-norm regression
 (internal_res_norm kills the monitor's standalone blas.norm(r) pass on
-BOTH routes); the GMRES CGS2 projection vs the sequential MGS loop at
+BOTH routes); the GMRES CGS2 step routine vs the sequential MGS loop at
 1e-12 f64; solve_many slab-route parity; the pAp <= 0 breakdown read
 from the kernel epilogue scalar; and the distributed packed-psum
 contract — parity on a multi-shard mesh with the per-iteration
@@ -289,22 +289,21 @@ def test_cg_monitor_norm_dead():
 
 
 def test_gmres_cgs2_matches_sequential_mgs_f64():
-    """The batched CGS2 projection (two blas.mdot matvec pairs — the
-    solver's Arnoldi step, solvers/gmres.py) agrees with the
+    """The solver's Arnoldi projection (`blas.cgs2_step`: CGS2 in three
+    readings of the live rows, solvers/gmres.py) agrees with the
     reference's sequential MGS loop to 1e-12 in f64 on both the
-    Hessenberg coefficients and the deflated vector."""
+    Hessenberg coefficients and the deflated vector
+    (tests/test_gmres_basis.py holds it to the live rows)."""
     rng = np.random.default_rng(7)
     n, m, j = 500, 10, 6
     Q, _ = np.linalg.qr(rng.standard_normal((n, j)))
-    V = jnp.zeros((m + 1, n), jnp.float64).at[:j].set(Q.T)
+    rows128 = blas.basis_rows128(m + 1, n)
+    V = jnp.zeros((m + 1, rows128 * 128), jnp.float64).at[:j, :n].set(
+        Q.T).reshape(m + 1, rows128, 128)
     w0 = jnp.asarray(rng.standard_normal(n))
 
-    # solver expressions (gmres.py solve_iteration), zero rows no-ops
-    h = blas.mdot(V, w0)
-    w = w0 - V.T @ h
-    h2 = blas.mdot(V, w)
-    w = w - V.T @ h2
-    h = h + h2
+    h, w, nrm = blas.cgs2_step(V, blas.to_slab(w0, rows128), j)
+    w = blas.from_slab(w, n)
 
     # sequential modified Gram-Schmidt (the reference's fgmres loop)
     w_ref = np.asarray(w0, np.float64)
@@ -318,6 +317,8 @@ def test_gmres_cgs2_matches_sequential_mgs_f64():
                                atol=1e-12 * scale)
     np.testing.assert_allclose(np.asarray(w), w_ref, rtol=0,
                                atol=1e-12 * scale)
+    np.testing.assert_allclose(float(nrm), np.linalg.norm(w_ref),
+                               rtol=0, atol=1e-12 * scale)
 
 
 def test_gmres_solve_parity_f64():
