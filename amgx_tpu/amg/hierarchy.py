@@ -26,14 +26,16 @@ from ..matrix import CsrMatrix
 from ..ops import pallas_spmv as _ps
 
 
-def _record_route(route: str, A):
+def _record_route(route: str, A, **why):
     """Flight-recorder trail of the setup-routing decision (full build
     vs value/structure resetup vs restored-from-snapshot) — ONE event
     shape for all four routes (telemetry/flightrec.py; lazy import:
-    telemetry must stay importable without the amg package)."""
+    telemetry must stay importable without the amg package). A
+    structure resetup that the value route declined carries the test
+    that failed as `reason`."""
     from ..telemetry import flightrec
     flightrec.record("resetup.route", route=route,
-                     rows=int(A.num_rows))
+                     rows=int(A.num_rows), **why)
 
 
 class AMGLevel:
@@ -550,18 +552,21 @@ class AMG:
             return self._setup_route(A)
         self._last_resetup_value_only = False
         from ..telemetry import metrics as _tm
-        if (reuse < 0 or reuse >= len(self.levels)) \
-                and self._ship_device is None:
+        why = {}        # the value route's reason, where it declined
+        if reuse < 0 or reuse >= len(self.levels):
             from .value_resetup import try_value_resetup
             from ..profiling import trace_region
-            with trace_region("amg.value_resetup"):
-                if try_value_resetup(self, A):
+            with trace_region("amg.value_resetup", args=why):
+                if self._ship_device is not None:
+                    why["reason"] = "host_built"
+                elif try_value_resetup(self, A, why):
                     self._last_resetup_value_only = True
                     _tm.inc("amg.resetup.value")
                     _record_route("value", A)
                     return self
+            _tm.inc("amg.resetup.value_declined")
         _tm.inc("amg.resetup.structure")
-        _record_route("structure", A)
+        _record_route("structure", A, **why)
         # a structure resetup rebuilds levels and retraces the cycle:
         # the recorded tail boundary and the memoized report level
         # table are for the OLD hierarchy (the value-only path above

@@ -529,6 +529,17 @@ declare_counter("amg.resetup.value",
 declare_counter("amg.resetup.structure",
                 "structure-reuse resetups (kept levels re-valued, "
                 "deeper levels rebuilt)")
+declare_counter("amg.resetup.value_declined",
+                "resetups that asked for the value-only route "
+                "(structure_reuse_levels covers every level) and took "
+                "the structure-reuse loop instead; the test that "
+                "failed is the `reason` arg of the amg.value_resetup "
+                "span and of the resetup.route flight-recorder event")
+declare_counter("amg.value_resetup.wait_s",
+                "host seconds in the value-only resetup's one sync "
+                "(span value_resetup.sync): the fetch of the wrap / "
+                "constancy flag, where the host waits for the upload "
+                "and the whole chained value phase")
 declare_counter("amg.setup.restored",
                 "setups served from a persisted structure snapshot "
                 "(serving/hstore.py: load + structure-reuse rebuild — "

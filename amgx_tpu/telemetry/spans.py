@@ -78,6 +78,13 @@ DECLARED_SPANS: Tuple[str, ...] = (
     # OUTSIDE the amg.* accounted prefix (summing both would
     # double-count the selector wall)
     "selector.device_sweep",
+    # the value-only resetup's host stages (amg/value_resetup.py): run
+    # INSIDE the amg.value_resetup leaf, so they too are declared
+    # outside the accounted prefix
+    "value_resetup.plan",
+    "value_resetup.dispatch",
+    "value_resetup.sync",
+    "value_resetup.splice",
     "amg.L*.rap",
     # plan-split RAP (ops/spgemm.py): structure-phase plan build/lookup
     # and the fused value phase — disjoint siblings of amg.L*.rap (the
