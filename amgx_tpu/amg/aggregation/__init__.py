@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.numpy as jnp
 import numpy as np
 
 from ... import registry
@@ -23,38 +22,9 @@ from ...config import Config
 from ...matrix import CsrMatrix
 from ..hierarchy import AMGLevel
 from . import selectors  # noqa: F401  (registers selectors)
-from .galerkin import (coarse_a_from_aggregates, geo_shapes,
-                       pair_sum_axis, prolongate_corr, restrict_vector)
-
-
-def _geo_restrict(r, fine_shape, axis):
-    """Pair-sum along one grid axis: the piecewise-constant restriction
-    of a structured pairing, as a reshape + sum (no scatter). Shares
-    pair_sum_axis with the structured Galerkin so the transfer operators
-    and the coarse operator can never drift apart."""
-    nx, ny, nz = fine_shape
-    v = r.reshape(nz, ny, nx)                  # linear index: x fastest
-    return pair_sum_axis(v, fine_shape[axis], axis).reshape(-1)
-
-
-def _geo_prolongate(xc, fine_shape, coarse_shape, axis):
-    """Broadcast along the paired grid axis (P = pairwise-constant).
-    Implemented as two interior-padded copies (even + odd positions)
-    instead of jnp.repeat: repeat's internal `(..., 2)` reshape puts the
-    pair in the minor dimension, which TPU tiling pads 128x."""
-    import jax
-    nx, ny, nz = coarse_shape
-    v = xc.reshape(nz, ny, nx)
-    dims = 2 - axis
-    fine_e = fine_shape[axis]
-    cn = v.shape[dims]
-    zero = jnp.zeros((), v.dtype)
-    cfg_e = [(0, 0, 0)] * 3
-    cfg_o = [(0, 0, 0)] * 3
-    cfg_e[dims] = (0, fine_e - (2 * cn - 1), 1)   # values at even slots
-    cfg_o[dims] = (1, fine_e - 2 * cn, 1)         # values at odd slots
-    out = jax.lax.pad(v, zero, cfg_e) + jax.lax.pad(v, zero, cfg_o)
-    return out.reshape(-1)
+from . import transfer
+from .galerkin import (coarse_a_from_aggregates, prolongate_corr,
+                       restrict_vector)
 
 
 @registry.amg_levels.register("AGGREGATION")
@@ -77,10 +47,6 @@ class AggregationAMGLevel(AMGLevel):
             self.geo_axes = sel.pair_axes
             self.geo_fine_shape = sel.fine_shape
             self.geo_coarse_shape = sel.coarse_shape
-
-    def _geo_shapes(self):
-        """Intermediate grid shapes for the per-axis transfer sequence."""
-        return geo_shapes(self.geo_fine_shape, self.geo_axes)
 
     def create_coarse_matrix(self) -> CsrMatrix:
         from ...ops import spgemm
@@ -293,10 +259,7 @@ class AggregationAMGLevel(AMGLevel):
             from ...ops.spmv import spmv
             return spmv(data["R"], r)
         if self.geo_axes is not None:
-            shapes = self._geo_shapes()
-            for k, a in enumerate(self.geo_axes):
-                r = _geo_restrict(r, shapes[k], a)
-            return r
+            return transfer.restrict(r, self.geo_fine_shape, self.geo_axes)
         return restrict_vector(data["aggregates"], self.coarse_size, r,
                                self.A.block_dimx)
 
@@ -305,9 +268,22 @@ class AggregationAMGLevel(AMGLevel):
             from ...ops.spmv import spmv
             return spmv(data["P"], xc)
         if self.geo_axes is not None:
-            shapes = self._geo_shapes()
-            for k in range(len(self.geo_axes) - 1, -1, -1):
-                xc = _geo_prolongate(xc, shapes[k], shapes[k + 1],
-                                     self.geo_axes[k])
-            return xc
+            return transfer.prolongate_xla(xc, self.geo_fine_shape,
+                                           self.geo_axes)
         return prolongate_corr(data["aggregates"], xc, self.A.block_dimx)
+
+    def prolongate_correct(self, data, x, xc):
+        """x + P xc (the cycle's coarse-grid correction): a GEO level
+        does the add inside the transfer's own pass (transfer.py)."""
+        if "P" in data or self.geo_axes is None:
+            return x + self.prolongate(data, xc)
+        return transfer.prolong_correct(x, xc, self.geo_fine_shape,
+                                        self.geo_axes)
+
+    def geo_transfer_road(self, dtype):
+        """"onepass" / "xla": the schedule this level's two transfers
+        take in an unbatched single-device cycle of `dtype` vectors
+        (transfer.road); None for a level that is not GEO."""
+        if self.geo_axes is None:
+            return None
+        return transfer.road(self.geo_fine_shape, self.geo_axes, dtype)

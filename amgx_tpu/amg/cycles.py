@@ -68,6 +68,18 @@ def _fusion_caps(level, data):
     return fn(level, data)
 
 
+def _prolongate_correct(level, data, x, xc):
+    """x + P xc, the coarse-grid correction. A level class that defines
+    `prolongate_correct` does the add inside its own transfer (GEO
+    aggregation levels: one pass over x instead of a prolongation and
+    an add); resolved through the CLASS, as _fusion_caps is, so a
+    `__getattr__`-delegating wrapper keeps its own `prolongate`."""
+    fn = getattr(type(level), "prolongate_correct", None)
+    if fn is None:
+        return x + level.prolongate(data, xc)
+    return fn(level, data, x, xc)
+
+
 def _smooth_restrict(amg, level, data, b, x, sweeps: int, lvl: int):
     """Presmooth + restriction: with cycle_fusion, aggregation/DIA
     levels emit the segment-summed coarse rhs from the presmoother
@@ -118,7 +130,7 @@ def _prolongate_smooth(amg, level, data, b, x, xc, sweeps: int, lvl: int,
         if out is not None:
             return out
     with jax.named_scope(f"amg.L{lvl}.prolong"):
-        x = x + level.prolongate(data, xc)
+        x = _prolongate_correct(level, data, x, xc)
     with jax.named_scope(f"amg.L{lvl}.postsmooth"):
         x = _smooth(level, data, b, x, sweeps)
     return (x, None) if want_dot else x
@@ -205,7 +217,7 @@ def _cycle(amg, shape: str, data, lvl: int, b, x, want_dot: bool = False):
         else:
             raise ValueError(f"unknown fixed cycle {shape!r}")
         if rec is not None:
-            x = x + level.prolongate(ldata, xc)
+            x = _prolongate_correct(level, ldata, x, xc)
             rec.record(lvl, 2, _level_A(ldata), x, b)
             x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
             rec.record(lvl, 3, _level_A(ldata), x, b)
@@ -279,7 +291,7 @@ def _kcycle(amg, data, lvl: int, b, x, flex: bool):
             rz = rz_new
             p = z + beta * p
         if rec is not None:
-            x = x + level.prolongate(ldata, xc)
+            x = _prolongate_correct(level, ldata, x, xc)
             rec.record(lvl, 2, _level_A(ldata), x, b)
             x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
             rec.record(lvl, 3, _level_A(ldata), x, b)

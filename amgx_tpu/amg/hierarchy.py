@@ -1029,6 +1029,16 @@ class AMG:
             total += self.coarsest_sweeps * steps(cs)
         return total
 
+    def geo_transfers_per_cycle(self):
+        """(levels on the one-pass road, levels on the XLA road) of the
+        GEO levels one single-device cycle runs through, from what the
+        road is chosen by (aggregation/transfer.road): static after
+        setup. (A V cycle's count, as color_steps_per_cycle's.)"""
+        dt = self._PRECISIONS[self.precision]
+        roads = [lv.geo_transfer_road(dt if dt is not None else lv.A.dtype)
+                 for lv in self.levels if hasattr(lv, "geo_transfer_road")]
+        return roads.count("onepass"), roads.count("xla")
+
     def cycle(self, data, b, x):
         """One multigrid cycle (CycleFactory::generate analog). With
         amg_precision=float/bfloat16 the cycle computes in the reduced

@@ -51,7 +51,7 @@ _AMG_ATTRS = ("algorithm", "cycle_name", "cycle_iters", "cycle_fusion",
 # into attributes at construction), clocks, and the caches of programs
 _NOT_TRACED = frozenset({
     "A", "cfg", "setup_time", "_jit_cache", "_batched",
-    "_batched_wrappers", "_color_steps"})
+    "_batched_wrappers", "_color_steps", "_geo_transfers"})
 
 
 def _aval(x):

@@ -57,6 +57,9 @@ class AlgebraicMultigridSolver(Solver):
     def color_steps_per_iteration(self):
         return self.amg.color_steps_per_cycle()
 
+    def geo_transfers_per_iteration(self):
+        return self.amg.geo_transfers_per_cycle()
+
     def solve_init(self, data, b, x, r):
         return self._guard_init()
 

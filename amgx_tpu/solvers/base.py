@@ -518,6 +518,7 @@ class Solver:
         return None
 
     _color_steps = 0     # color_steps_per_iteration() of the cached programs
+    _geo_transfers = None   # geo_transfers_per_iteration(), likewise
 
     def color_steps_per_iteration(self) -> int:
         """Ordered color steps of the colored smoothers one iteration
@@ -525,6 +526,13 @@ class Solver:
         iteration); 0 where the tree has none. Static after setup."""
         pc = self.preconditioner
         return 0 if pc is None else pc.color_steps_per_iteration()
+
+    def geo_transfers_per_iteration(self):
+        """(one-pass, XLA) GEO levels one iteration's cycle runs its
+        transfers through (the preconditioner's), or None where the
+        tree has no multigrid cycle. Static after setup."""
+        pc = self.preconditioner
+        return None if pc is None else pc.geo_transfers_per_iteration()
 
     def _extra_stats_spec(self) -> tuple:
         """Names of solver-specific SCALARS appended to the packed
@@ -997,6 +1005,7 @@ class Solver:
                 self._jit_cache[key] = jax.jit(self._build_solve_fn())
                 # static like the program: read once with it
                 self._color_steps = self.color_steps_per_iteration()
+                self._geo_transfers = self.geo_transfers_per_iteration()
             solve_fn = self._jit_cache[key]
         with span("solve.run", counter="solve.stage_s.run"):
             t0 = time.perf_counter()
@@ -1042,6 +1051,16 @@ class Solver:
                 # shell's inner count where it keeps one, else its own
                 _tm.inc("smoother.color_steps", self._color_steps * int(
                     round((extras or {}).get("inner_iters", iters_i))))
+            if self._geo_transfers is not None:
+                # the cycles a solve ran: FGMRES's Arnoldi steps (its
+                # own or summed by the shell round it), else the
+                # inner count where one is kept, else this solver's
+                ex = extras or {}
+                cycles = int(round(ex.get(
+                    "arnoldi_steps", ex.get("inner_iters", iters_i))))
+                for road, levels in zip(("onepass", "xla"),
+                                        self._geo_transfers):
+                    _tm.inc(f"amg.geo_transfer.{road}", cycles * levels)
             for name, value in (extras or {}).items():
                 # an extra stat that a counter is named after (GMRES /
                 # FGMRES's account of a solve, its own or summed by

@@ -20,6 +20,8 @@ KERNEL_KEYS = (
     "_dia_smooth_restrict_call",
     "_dia_prolong_smooth_call",
     "_dia_coarse_tail_call",
+    "_dia_geo_restrict_call",
+    "_dia_geo_prolong_call",
     "_dia_smooth_call",
     "_dia_spmv_call",
     "_dia_spmv_dot_call",

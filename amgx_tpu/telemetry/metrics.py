@@ -643,6 +643,20 @@ declare_counter("smoother.color_steps",
                 "the iterations that ran the cycle x the steps a cycle "
                 "is made of (over the levels: sweeps x passes x colors)")
 
+# which schedule a GEO level's transfers took (amg/aggregation/transfer.py)
+declare_counter("amg.geo_transfer.onepass",
+                "GEO levels whose restriction and prolong-and-correct "
+                "ran as the one-pass kernels (ops/pallas_geo.py), "
+                "raised after each solve by the iterations that ran "
+                "the cycle x such levels of the hierarchy; 0 where a "
+                "solve's cycle has none. Static, as the road is: where "
+                "the fused restrict-epilogue / prolong-prologue family "
+                "engages (the interpreter only) it runs instead")
+declare_counter("amg.geo_transfer.xla",
+                "GEO levels whose transfers ran as the XLA form (f64, "
+                "extents off the kernels' grid, a CPU), raised "
+                "likewise")
+
 # jit retraces per solver entry point: a retrace in steady-state serving
 # is a latency cliff (first-request trace cost paid again)
 declare_counter("solver.retrace.solve",

@@ -288,6 +288,106 @@ def test_cgs2_step_compiles_under_shard_map(topo, on_tpu,
 
 # SWELL shapes of a classical coarse level under 7-pt 64^3 (PMIS+D2):
 # 32 super-blocks of 1024 rows, 24 slots per row, a 96-row x window
+@pytest.mark.parametrize("n", [256, FINE, 192])
+@pytest.mark.parametrize("op", ["restrict", "prolong_correct"])
+def test_geo_transfer_compiles(n, op, one_chip, on_tpu,
+                               no_persistent_cache):
+    """A GEO level's two transfers through the road they choose
+    (amg/aggregation/transfer.py) at the flagship's 256^3 -> 128^3 and
+    128^3 -> 64^3: each is one `_dia_geo_*_call` and nothing of the
+    fine vector's size beside it. 192^3 (x rows off the 128-lane rows)
+    declines and lowers the XLA form."""
+    from amgx_tpu.amg.aggregation import transfer
+    fs, axes = (n, n, n), (0, 1, 2)
+    fine = jax.ShapeDtypeStruct((n ** 3,), F32, sharding=one_chip)
+    coarse = jax.ShapeDtypeStruct((n ** 3 // 8,), F32, sharding=one_chip)
+    if op == "restrict":
+        lowered = jax.jit(
+            lambda r: transfer.restrict(r, fs, axes)).lower(fine)
+    else:
+        lowered = jax.jit(
+            lambda x, xc: transfer.prolong_correct(x, xc, fs, axes),
+            donate_argnums=0).lower(fine, coarse)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    if n == 192:
+        assert transfer.road(fs, axes, F32) == "xla"
+        assert "tpu_custom_call" not in text
+        return
+    assert transfer.road(fs, axes, F32) == "onepass"
+    kernel = {"restrict": "_dia_geo_restrict_call",
+              "prolong_correct": "_dia_geo_prolong_call"}[op]
+    assert text.count("tpu_custom_call") == 1 and kernel in text
+    # one pass: no temporary, and x's buffer is the result's
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    if op == "prolong_correct":
+        assert mem.alias_size_in_bytes == 4 * n ** 3
+
+
+def _strided_or_padding(closed_jaxpr):
+    """Eqns outside the kernels that move a vector between a grid and
+    its paired grid the XLA way: interior pads, strided slices (as
+    `slice` or as the `gather` jnp's stepped indexing traces to)."""
+    from amgx_tpu.telemetry import census as _census
+    hits = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                continue
+            strides = eqn.params.get("strides") if name == "slice" else None
+            if name in ("pad", "gather") or (
+                    strides is not None and any(s != 1 for s in strides)):
+                hits.append(name)
+            for sub in _census.subjaxprs(eqn):
+                walk(sub)
+
+    walk(closed_jaxpr.jaxpr)
+    return hits
+
+
+@pytest.mark.parametrize("grid,onepass", [((128, 8, 8), True),
+                                          ((192, 8, 8), False)])
+def test_geo_cycle_on_the_chips_branch_has_no_xla_transfer(grid, onepass,
+                                                           on_tpu):
+    """Jaxpr census of the flagship's cycle steered onto the chip's
+    branch, two levels (L0 and the coarse solve): where L0 is on the
+    kernels' grid the cycle is the two smoother kernels and the two
+    transfer kernels, with no pad and no strided slice outside them;
+    at nx = 192 the same census finds the XLA restriction's."""
+    import amgx_tpu as amgx
+    from amgx_tpu.config import Config
+    from amgx_tpu.presets import FLAGSHIP
+    from amgx_tpu.telemetry import census as _census
+
+    A = amgx.gallery.poisson("7pt", *grid).init()
+    slv = amgx.create_solver(Config.from_string(
+        FLAGSHIP + ", amg:max_levels=2"))
+    slv.setup(A)
+    amg = slv.preconditioner.preconditioner.amg
+    assert [lv.geo_fine_shape for lv in amg.levels] == [grid]
+    d = amg.solve_data()
+    v = jnp.ones(A.num_rows, F32)
+    jaxpr = jax.make_jaxpr(lambda b, x: amg.cycle(d, b, x))(v, v)
+    assert not any(c["interpret"] for c in _census.pallas_calls(jaxpr))
+    counts = _census.kernel_counts(jaxpr)
+    assert counts.get("_dia_smooth_call", 0) == 2
+    moved = _strided_or_padding(jaxpr)
+    if onepass:
+        assert amg.geo_transfers_per_cycle() == (1, 0)
+        assert counts.get("_dia_geo_restrict_call", 0) == 1
+        assert counts.get("_dia_geo_prolong_call", 0) == 1
+        assert moved == []
+    else:
+        assert amg.geo_transfers_per_cycle() == (0, 1)
+        assert not any(k.startswith("_dia_geo") for k in counts)
+        # the XLA restriction's stepped sums; its f32 prolongation
+        # spreads x through a 0/1 matrix and pads nothing
+        assert moved and set(moved) == {"gather"}
+
+
 @pytest.mark.parametrize("kernel", ["spmv", "smooth"])
 def test_swell_kernels_compile(kernel, one_chip, on_tpu,
                                no_persistent_cache):
