@@ -226,6 +226,9 @@ class AMG:
         # remaining host compute
         self._put_cache: Dict[int, tuple] = {}
         self._ship_pool = None
+        # True from a structure-reuse rebuild to the next full setup:
+        # what is shipped for that hierarchy goes on amg.resetup.ship_*
+        self._ship_counted = False
         # which implementations the last setup used ("host" pull-and-ship,
         # "device" forced pipeline, "auto" residency-driven)
         self._setup_backend_used = None
@@ -303,6 +306,7 @@ class AMG:
         self.levels = []
         self._data_cache = None
         self._put_cache = {}
+        self._ship_counted = False
         self._l0_seed = None     # dropped unless this setup re-registers
         self._resetup_precast = None
         self._vr_plan = None     # value-resetup plan re-derives lazily
@@ -596,14 +600,26 @@ class AMG:
         old_levels, self.levels = self.levels, []
         self._resetup_precast = None
         self._vr_plan = None
-        self._put_cache = {}
-        self._seed_put_cache()
+        self._ship_counted = True
+        shipped = self._put_cache
         from .aggregation.galerkin import (deferred_wrap_checks,
                                            geo_dia_disabled)
 
         from ..matrix import forced_device_setup
+        from ..profiling import trace_region
+        from ..telemetry import metrics as _tm
+        from ..telemetry.spans import flat_timers
+
+        def rap_spans(lvl):
+            # (seconds of the level's Galerkin value phases, plans
+            # built) so far, from the level's own span timers
+            t = flat_timers()
+            return (t.get(f"amg.L{lvl}.rap_values", (0, 0.0))[1],
+                    t.get(f"amg.L{lvl}.rap_plan", (0, 0.0))[0])
 
         def reuse_loop(Af):
+            self._put_cache = {}
+            self._seed_put_cache()
             lvl = 0
             while lvl < k:
                 old = old_levels[lvl]
@@ -611,18 +627,31 @@ class AMG:
                     break
                 level = type(old)(Af, self.cfg, self.scope, lvl)
                 level.reuse_structure(old)
+                _tm.inc("amg.resetup.reused_levels")
+                # structure kept means kept on the device too: what
+                # reuse_structure carried over stays in the put cache,
+                # so nothing casts and ships it again; every other
+                # entry was of the old values and is gone
+                self._put_cache.update(self._carried_puts(shipped, level))
                 forced = self._level_device_forced(Af.num_rows)
                 from ..matrix import host_resident
                 level.built_backend = "device" if forced or \
                     not host_resident(Af.row_offsets, Af.values) else "host"
                 with forced_device_setup(forced):
+                    rap_s, plans = rap_spans(lvl)
                     Ac = level.create_coarse_matrix()
+                    now_s, now_plans = rap_spans(lvl)
+                    _tm.add("amg.resetup.rap_values_s", now_s - rap_s)
+                    _tm.inc("amg.resetup.rap_plans_built",
+                            now_plans - plans)
                     self.levels.append(level)
                     if not self._defer_smoothers:
                         self._attach_level_smoother(level)
                     self._prefetch_level(level)
-                    Af = (Ac.build_spmv_layout() if Ac.initialized
-                          else Ac.init())
+                    with trace_region(f"amg.L{lvl}.layout",
+                                      counter="amg.resetup.layout_s"):
+                        Af = (Ac.build_spmv_layout() if Ac.initialized
+                              else Ac.init())
                 lvl += 1
             return Af, lvl
 
@@ -635,13 +664,28 @@ class AMG:
             # geometric invariant — redo the reuse loop with the generic
             # relabel Galerkin (same reused aggregates, one extra pass)
             self.levels = []
-            self._put_cache = {}
-            self._seed_put_cache()
             with geo_dia_disabled():
                 Af, lvl = reuse_loop(Af0)
         self._build_levels_checked(Af, lvl)
         self._finalize_setup(t0)
         return self
+
+    @staticmethod
+    def _carried_puts(shipped: Dict[int, tuple], level) -> Dict[int, tuple]:
+        """The entries of a put cache whose host source is a leaf this
+        level took over by `reuse_structure`: the transfer operators (P
+        and R hold the coefficients of the first setup, layout slabs
+        included) and the transfer-slab memo where it was carried.
+        Matched by identity, as the cache is keyed."""
+        memo = getattr(level, "_xfer_memo", None)
+        carried = [getattr(level, "P", None), getattr(level, "R", None),
+                   memo[0] if memo else None]
+        kept = {}
+        for leaf in jax.tree.leaves(carried):
+            entry = shipped.get(id(leaf))
+            if entry is not None and entry[0] is leaf:
+                kept[id(leaf)] = entry
+        return kept
 
     def _build_levels(self, Af: CsrMatrix, lvl: int):
         from ..matrix import forced_device_setup, host_resident
@@ -817,7 +861,9 @@ class AMG:
         if self._ship_device is not None:
             # completion barrier of the per-level ship pipeline: every
             # prefetched transfer resolves before setup returns
-            with trace_region("amg.ship_resolve"):
+            with trace_region("amg.ship_resolve",
+                              counter="amg.resetup.ship_s"
+                              if self._ship_counted else None):
                 self._resolve_put_cache()
         self.num_levels = len(self.levels) + 1
         self.setup_time = time.perf_counter() - t0
@@ -869,6 +915,7 @@ class AMG:
             self._ship_pool = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="amgx-ship")
         dev = self._ship_device
+        counted = self._ship_counted
 
         def _ship(leaves=todo):
             # leaves are numpy on the native host path, so the casts are
@@ -882,9 +929,14 @@ class AMG:
             # (the non-overlapped remainder shows up in
             # amg.ship_resolve instead).
             from ..profiling import trace_region
-            with trace_region("ship.cast_put"):
-                return jax.device_put(
-                    [self._cast_leaf(x) for x in leaves], dev)
+            from ..telemetry import metrics as _tm
+            with trace_region("ship.cast_put", counter="amg.resetup.ship_s"
+                              if counted else None):
+                cast = [self._cast_leaf(x) for x in leaves]
+                if counted:
+                    _tm.inc("amg.resetup.ship_bytes",
+                            sum(int(x.nbytes) for x in cast))
+                return jax.device_put(cast, dev)
 
         fut = self._ship_pool.submit(_ship)
         for i, src in enumerate(todo):

@@ -48,10 +48,14 @@ _AMG_ATTRS = ("algorithm", "cycle_name", "cycle_iters", "cycle_fusion",
 
 # instance attributes that are no input of a trace: the matrix and the
 # config object (the first is the observable half's, the second is read
-# into attributes at construction), clocks, and the caches of programs
+# into attributes at construction), clocks, the caches of programs, and
+# `_reused`, which names the ROUTE a level was built by (reuse_structure
+# sets it for create_coarse_matrix to read during setup) and nothing of
+# the tree: with it in, a loop's first resetup differed from the setup
+# by that flag alone and dropped a program that was still right
 _NOT_TRACED = frozenset({
     "A", "cfg", "setup_time", "_jit_cache", "_batched",
-    "_batched_wrappers", "_color_steps", "_geo_transfers"})
+    "_batched_wrappers", "_color_steps", "_geo_transfers", "_reused"})
 
 
 def _aval(x):

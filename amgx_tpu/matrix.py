@@ -210,6 +210,17 @@ def _seg_sum(data, seg_ids, num_segments):
                                indices_are_sorted=True)
 
 
+@functools.lru_cache(maxsize=None)
+def _warn_swell_dropped():
+    """Once a process: with_values dropped a SWELL layout."""
+    from .output import amgx_output
+    amgx_output(
+        "amgx_tpu warning: replace_coefficients with values that live "
+        "on the device dropped the matrix's SWELL layout (only host "
+        "values re-pack); its SpMV leaves the SWELL kernels until the "
+        "next init() (matrix.swell_layout_dropped)\n")
+
+
 def host_resident(*arrays) -> bool:
     """True when every given array is concrete host-CPU data (numpy or a
     CPU-backend jax array). Tracers and accelerator arrays return False.
@@ -688,6 +699,8 @@ class CsrMatrix:
             else:
                 # structure kept but values not re-scatterable off-host;
                 # drop the fast-path layout rather than serve stale data
+                _tm.inc("matrix.swell_layout_dropped")
+                _warn_swell_dropped()
                 out = dataclasses.replace(
                     out, swell_cols=None, swell_vals=None,
                     swell_c0row=None, swell_nchunk=None, swell_w128=0)

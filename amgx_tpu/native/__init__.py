@@ -319,20 +319,26 @@ def rap_plan_values_native(stage1, sr, st, starts2, n_u, a_val, p_val,
                            r_val):
     """Values-only Galerkin RAP sweep through a RapPlan's precomputed
     indices (src/rap_values.cpp): two flat FMA passes, no structure
-    discovery. `stage1` is the plan's stage-1 dict or None (the
-    aggregation relabel form); `sr`/`r_val` / `p_val` may be None.
-    Returns the (n_u,) float64 value vector or None when the native
+    discovery, in the operands' own precision (float64, or float32
+    where every operand is). `stage1` is the plan's stage-1 dict or
+    None (the aggregation relabel form); `sr`/`r_val` / `p_val` may be
+    None. Returns the (n_u,) value vector or None when the native
     library is unavailable (callers fall back to the numpy reduceat
     route — same sums, same order)."""
     import numpy as np
     L = lib()
     if L is None:
         return None
+    dt = np.result_type(*[x.dtype for x in (a_val, p_val, r_val)
+                          if x is not None])
+    if dt == np.float32:
+        fn, ctype = L.amgx_rap_plan_values_f32, ctypes.c_float
+    else:
+        dt = np.dtype(np.float64)
+        fn, ctype = L.amgx_rap_plan_values, ctypes.c_double
     i32p = ctypes.POINTER(ctypes.c_int32)
-    f64p = ctypes.POINTER(ctypes.c_double)
-    fn = L.amgx_rap_plan_values
+    fp = ctypes.POINTER(ctype)
     fn.restype = ctypes.c_int32
-    av = np.ascontiguousarray(a_val, np.float64)
     keep = []        # retain converted temporaries across the call
 
     def ip32(x):
@@ -340,31 +346,29 @@ def rap_plan_values_native(stage1, sr, st, starts2, n_u, a_val, p_val,
         keep.append(x)
         return x.ctypes.data_as(i32p)
 
+    def vals(x):
+        if x is None:
+            return ctypes.cast(None, fp)
+        x = np.ascontiguousarray(x, dt)
+        keep.append(x)
+        return x.ctypes.data_as(fp)
+
     null32 = ctypes.cast(None, i32p)
-    null64f = ctypes.cast(None, f64p)
     if stage1 is not None:
-        pv = np.ascontiguousarray(p_val, np.float64)
         args1 = (ctypes.c_int64(int(stage1["nT"])), ip32(stage1["sa"]),
                  ip32(stage1["sp"]), ip32(stage1["starts1"]),)
-        pvp = pv.ctypes.data_as(f64p)
     else:
-        pv = None
         args1 = (ctypes.c_int64(0), null32, null32, null32)
-        pvp = null64f
-    if sr is not None:
-        rv = np.ascontiguousarray(r_val, np.float64)
-        rvp = rv.ctypes.data_as(f64p)
-        srp = ip32(sr)
-    else:
-        rv = None
-        rvp = null64f
-        srp = null32
-    out = np.empty(int(n_u), np.float64)
-    rc = fn(*args1, ctypes.c_int64(int(n_u)), srp, ip32(st),
-            ip32(starts2), av.ctypes.data_as(f64p), pvp, rvp,
+        p_val = None
+    if sr is None:
+        r_val = None
+    out = np.empty(int(n_u), dt)
+    rc = fn(*args1, ctypes.c_int64(int(n_u)),
+            null32 if sr is None else ip32(sr), ip32(st),
+            ip32(starts2), vals(a_val), vals(p_val), vals(r_val),
             ctypes.c_int32(1 if stage1 is not None else 0),
             ctypes.c_int32(1 if sr is not None else 0),
-            out.ctypes.data_as(f64p))
+            out.ctypes.data_as(fp))
     if rc != 0:
         return None
     return out

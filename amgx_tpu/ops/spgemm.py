@@ -517,9 +517,9 @@ def _rap_values_numpy(plan: RapPlan, af, r_vals, p_vals):
     the native RAP it replaces), or two numpy reduceat passes when the
     toolchain is unavailable. Both sum each segment strictly
     left-to-right, so the routes agree to the last bit."""
-    if af.dtype == np.float64 \
-            and (r_vals is None or r_vals.dtype == np.float64) \
-            and (p_vals is None or p_vals.dtype == np.float64):
+    if af.dtype in (np.float64, np.float32) \
+            and (r_vals is None or r_vals.dtype == af.dtype) \
+            and (p_vals is None or p_vals.dtype == af.dtype):
         from .. import native
         out = native.rap_plan_values_native(
             plan.stage1, plan.sr, plan.st, plan.starts2, plan.nU,

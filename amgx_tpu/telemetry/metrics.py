@@ -535,6 +535,38 @@ declare_counter("amg.resetup.value_declined",
                 "the structure-reuse loop instead; the test that "
                 "failed is the `reason` arg of the amg.value_resetup "
                 "span and of the resetup.route flight-recorder event")
+# the structure-reuse rebuild (AMG._resetup_impl: a resetup the value
+# route declined, or a setup restored from a snapshot), by stage
+declare_counter("amg.resetup.reused_levels",
+                "levels that took reuse_structure in a structure-reuse "
+                "rebuild (strength, C/F split or aggregates, P and R "
+                "kept): the level count where the whole structure was "
+                "kept, less where the reuse loop broke off")
+declare_counter("amg.resetup.rap_values_s",
+                "host seconds of the amg.L<k>.rap_values spans of the "
+                "reused levels: the value phase of each planned "
+                "Galerkin product (read from the level's span timers "
+                "round create_coarse_matrix)")
+declare_counter("amg.resetup.rap_plans_built",
+                "amg.L<k>.rap_plan spans entered by reused levels: a "
+                "Galerkin plan looked up or built although the "
+                "structure was kept; sound value 0, the plan memo "
+                "carried with the structure")
+declare_counter("amg.resetup.layout_s",
+                "host seconds a structure-reuse rebuild spends in "
+                "build_spmv_layout / init of the coarse operators of "
+                "its reused levels (span amg.L<k>.layout)")
+declare_counter("amg.resetup.ship_s",
+                "seconds of host-to-device shipping for a hierarchy "
+                "built by a structure-reuse rebuild: the ship worker's "
+                "cast-and-put (ship.cast_put, overlapped with the "
+                "build) plus the build thread's wait in "
+                "amg.ship_resolve; work, not wall")
+declare_counter("amg.resetup.ship_bytes",
+                "bytes, after the precision cast, of the leaves shipped "
+                "for a hierarchy built by a structure-reuse rebuild; "
+                "leaves reuse_structure carried over (P, R, transfer "
+                "slabs) stay on the device and are not in it")
 declare_counter("amg.value_resetup.wait_s",
                 "host seconds in the value-only resetup's one sync "
                 "(span value_resetup.sync): the fetch of the wrap / "
@@ -726,6 +758,11 @@ declare_counter("matrix.refill_map.build",
 declare_counter("matrix.refill_map.reuse",
                 "DIA refills served by a kept map: one per coefficient "
                 "replacement of a time loop after its first")
+declare_counter("matrix.swell_layout_dropped",
+                "with_values calls that dropped a SWELL layout because "
+                "the new values live on the device (only host values "
+                "re-pack natively): the matrix's SpMV leaves the SWELL "
+                "kernels; sound value 0, warned once a process")
 declare_counter("matrix.upload_s",
                 "host seconds inside the device_puts of the new "
                 "values and value slabs (the transfer itself may "

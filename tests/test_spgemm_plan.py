@@ -157,6 +157,38 @@ def test_host_vs_slab_route_parity():
     assert _rel(np_vals, slab) < 1e-13
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
+                         ids=["f32", "f64"])
+def test_host_route_takes_the_native_sweep_in_its_own_precision(
+        dtype, monkeypatch):
+    """A float32 (dFFI) host hierarchy re-values through the native
+    sweep too, in float32, and not through numpy's gathers and
+    reduceat (three temporaries of the expansion's length a call):
+    the result is the float64 sweep's to float32 roundoff, and with
+    the native route taken away the reduceat route gives the same."""
+    from amgx_tpu import native
+    if native.lib() is None:
+        pytest.skip("no native toolchain")
+    lv = _classical_level(dtype=dtype)
+    plan = spgemm.build_rap_plan(lv.R, lv.A, lv.P)
+    a, r, p = (np.asarray(x) for x in
+               (lv.A.values, lv.R.values, lv.P.values))
+    calls = []
+    sweep = native.rap_plan_values_native
+    monkeypatch.setattr(
+        native, "rap_plan_values_native",
+        lambda *args: calls.append(1) or sweep(*args))
+    out = spgemm._rap_values_numpy(plan, a, r, p)
+    assert calls and out.dtype == np.dtype(dtype)
+    exact = spgemm._rap_values_numpy(
+        plan, *(x.astype(np.float64) for x in (a, r, p)))
+    tol = 1e-13 if out.dtype == np.float64 else 1e-6
+    assert _rel(out, exact) < tol
+    monkeypatch.setattr(native, "rap_plan_values_native",
+                        lambda *args: None)
+    assert _rel(spgemm._rap_values_numpy(plan, a, r, p), exact) < tol
+
+
 # ---------------------------------------------------------------------------
 # the fused value kernel (interpret route)
 # ---------------------------------------------------------------------------
