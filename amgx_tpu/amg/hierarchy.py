@@ -24,6 +24,7 @@ from ..config import Config
 from ..errors import BadConfigurationError
 from ..matrix import CsrMatrix
 from ..ops import pallas_spmv as _ps
+from ..solve_data import SolveDataOwner
 
 
 def _record_route(route: str, A, **why):
@@ -108,7 +109,7 @@ class AMGLevel:
         if self.smoother is not None:
             # the smoother's solve_data already slims its own A when its
             # sweeps only SpMV (Solver.slim_A_ok)
-            d["smoother"] = self.smoother.solve_data()
+            d["smoother"] = self.smoother.solve_data_part()
             st = d["smoother"].get("stencil") if isinstance(
                 d["smoother"], dict) else None
             if st is not None:
@@ -172,7 +173,7 @@ class AMGLevel:
 _PENDING = object()    # _put_cache placeholder: (src, (_PENDING, fut, i))
 
 
-class AMG:
+class AMG(SolveDataOwner):
     """Hierarchy owner + setup loop (AMG<>::setup analog, src/amg.cu)."""
 
     def __init__(self, cfg: Config, scope: str = "default"):
@@ -218,7 +219,7 @@ class AMG:
         self.levels: List[AMGLevel] = []
         self.coarse_solver = None
         self.setup_time = 0.0
-        self._data_cache = None
+        self._data_cache = None     # solve_data.py
         self._ship_device = None
         # host-setup transfer overlap: id(host leaf) -> (host leaf,
         # device leaf); filled by _prefetch_level as levels finish
@@ -282,6 +283,7 @@ class AMG:
 
     def setup(self, A: CsrMatrix):
         self._resetup_same_static = False
+        self.drop_solve_data()
         self._setup_route(A)
         self._static_sig = self._signature()
         return self
@@ -304,7 +306,6 @@ class AMG:
         _record_route("full", A)
         t0 = time.perf_counter()
         self.levels = []
-        self._data_cache = None
         self._put_cache = {}
         self._ship_counted = False
         self._l0_seed = None     # dropped unless this setup re-registers
@@ -357,7 +358,6 @@ class AMG:
         _tm.inc("amg.setup.restored")
         _record_route("restored", A)
         self.levels = list(ghosts)
-        self._data_cache = None
         self._put_cache = {}
         self._l0_seed = None
         self._resetup_precast = None
@@ -499,10 +499,14 @@ class AMG:
         the fast path."""
         from .aggregation.galerkin import (deferred_wrap_checks,
                                            geo_dia_disabled)
+        from ..profiling import trace_region
         base = list(self.levels)
         with deferred_wrap_checks() as flush:
             self._build_levels(Af, lvl)
-            if flush():
+            # where the main thread waits for the levels' device work
+            with trace_region("amg.wrap_check"):
+                wrapped = flush()
+            if wrapped:
                 self.levels = base
                 # drop transfers prefetched for the abandoned build (they
                 # pin both host and HBM copies of every shipped level)
@@ -528,6 +532,9 @@ class AMG:
         before = self._static_sig
         traced = (self._tail_entry_level, self._telemetry_level_cache)
         self._resetup_same_static = False
+        # the old values' tree (its cast twins with it) goes before
+        # whichever route makes the new leaves
+        self.drop_solve_data()
         self._resetup_route(A)
         if self._last_resetup_value_only:
             return self     # the levels, and all of the above, stand
@@ -577,7 +584,6 @@ class AMG:
         # keeps both valid — structure and traces survive)
         self._tail_entry_level = None
         self._telemetry_level_cache = None
-        self._data_cache = None
         if self._ship_device is not None:
             host = jax.devices("cpu")[0]
             l0_dev = self._l0_device_cast(A)        # see setup()
@@ -976,22 +982,29 @@ class AMG:
         if memo is not None and memo[0] is not None:
             pieces.append(memo[0])
         if level.smoother is not None:
-            pieces.append(level.smoother.solve_data())
+            pieces.append(level.smoother.solve_data_part())
         self._prefetch_leaves(pieces)
+
+    def _solve_data_children(self) -> tuple:
+        nodes = [lv.smoother for lv in self.levels] + [self.coarse_solver]
+        return tuple(s for s in nodes if isinstance(s, SolveDataOwner))
 
     def _solve_tree(self) -> Dict[str, Any]:
         """The solve-data tree before placement and precision casts:
         what solve_data() ships or casts, and what the static signature
-        (amg/signature.py) reads the shapes from."""
+        (amg/signature.py) reads the shapes from. Host work alone once
+        the smoothers and the coarse solver keep their trees: the two
+        readers each make it, and share what those keep."""
         return {
             "levels": [lv.level_data() for lv in self.levels],
-            "coarse": self.coarse_solver.solve_data(),
+            "coarse": self.coarse_solver.solve_data_part(),
         }
 
-    def solve_data(self) -> Dict[str, Any]:
-        import jax
-        if self._ship_device is not None and self._data_cache is not None:
-            return self._data_cache
+    def _build_solve_data(self) -> Dict[str, Any]:
+        """Where the leaves come from is the one difference between a
+        host-built and a device-built hierarchy: the ship worker's
+        transfers, or the device arrays themselves (cast, under a
+        reduced amg_precision). Either way once a (re)setup."""
         data = self._solve_tree()
         if self._ship_device is not None:
             # host-built hierarchy: transfer the UNIQUE arrays (each
@@ -1011,10 +1024,9 @@ class AMG:
             with trace_region("ship.resolve_stragglers"):
                 self._prefetch_leaves(data)
                 self._resolve_put_cache()
-                self._data_cache = jax.tree.map(
+                return jax.tree.map(
                     lambda leaf: self._put_cache[id(leaf)][1]
                     if hasattr(leaf, "dtype") else leaf, data)
-            return self._data_cache
         dt = self._PRECISIONS[self.precision]
         if dt is not None:
             # mixed-precision preconditioning (the dDFI-mode analog,

@@ -48,14 +48,16 @@ _AMG_ATTRS = ("algorithm", "cycle_name", "cycle_iters", "cycle_fusion",
 
 # instance attributes that are no input of a trace: the matrix and the
 # config object (the first is the observable half's, the second is read
-# into attributes at construction), clocks, the caches of programs, and
+# into attributes at construction), clocks, the caches of programs, the
+# kept solve-data tree (solve_data.py: the observable half's again), and
 # `_reused`, which names the ROUTE a level was built by (reuse_structure
 # sets it for create_coarse_matrix to read during setup) and nothing of
 # the tree: with it in, a loop's first resetup differed from the setup
 # by that flag alone and dropped a program that was still right
 _NOT_TRACED = frozenset({
     "A", "cfg", "setup_time", "_jit_cache", "_batched",
-    "_batched_wrappers", "_color_steps", "_geo_transfers", "_reused"})
+    "_batched_wrappers", "_color_steps", "_geo_transfers", "_reused",
+    "_data_cache"})
 
 
 def _aval(x):
@@ -112,8 +114,9 @@ def _encode(v: Any, depth: int = 0):
 
 def static_signature(amg) -> tuple:
     """The static signature of a set-up AMG hierarchy (see the module
-    docstring). Host work only; builds what the next solve_data() would
-    build anyway (the levels' memoized slabs) and nothing else."""
+    docstring). Host work only; assembles what the next solve_data()
+    ships or casts (the smoothers' and the coarse solver's trees, which
+    they keep for that call) and nothing else."""
     # the tree first: level_data() memoizes the transfer and smoother
     # slabs the attribute sweep below then finds on both sides
     observable = _tree(amg._solve_tree())

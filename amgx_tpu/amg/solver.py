@@ -46,10 +46,13 @@ class AlgebraicMultigridSolver(Solver):
         solvers = [lv.smoother for lv in amg.levels] + [amg.coarse_solver]
         return all(s is None or s._resetup_kept_static() for s in solvers)
 
-    def solve_data(self):
-        d = super().solve_data()
-        d["amg"] = self.amg.solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
+        d["amg"] = self.amg.solve_data_part()
         return d
+
+    def _solve_data_children(self):
+        return super()._solve_data_children() + (self.amg,)
 
     def computes_residual(self):
         return False

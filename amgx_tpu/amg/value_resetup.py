@@ -365,6 +365,7 @@ def _splice(amg, A: CsrMatrix, plan, outs):
         if cast:
             precast[id(Ac.dia_vals)] = cast["dia"][i]
         sm = lv.smoother
+        sm.drop_solve_data()     # its leaves are replaced below
         sm.A = fine
         st = getattr(sm, "_mf_stencil", None)
         if st is not None and outs["mf"][i] is not None:
@@ -378,10 +379,11 @@ def _splice(amg, A: CsrMatrix, plan, outs):
                 precast[id(sm._taus)] = cast["taus"][i]
         fine = Ac
     cs = amg.coarse_solver
+    cs.drop_solve_data()
     cs.A = amg.coarsest_A
     cs._qt, cs._r = outs["qt"], outs["r"]
     if cast:
         precast[id(cs._qt)] = cast["qt"]
         precast[id(cs._r)] = cast["r"]
-    amg._data_cache = None
+    amg.drop_solve_data()
     amg._resetup_precast = precast

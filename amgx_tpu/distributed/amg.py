@@ -134,7 +134,7 @@ def _transfer_ops(level):
 def _shard_smoother_data(sm, A_sh: ShardMatrix, n_ranks: int, axis: str):
     """Partition a smoother's solve-data pytree row-wise; CsrMatrix
     entries (triangular ILU factors) become halo-exchanging shards."""
-    data = sm.solve_data()
+    data = sm.solve_data_part()
     out = {"A": A_sh}
     # smoother per-row arrays are per BLOCK row (dinv (nb,bx,by),
     # colors (nb,)); the shard stores scalar-expanded rows
@@ -300,7 +300,7 @@ def shard_amg(amg, n_ranks: int, axis: str):
     # in scalar unknowns (block rows never split across shards, so the
     # equal-block slicing stays block-aligned)
     nc = amg.coarsest_A.num_rows * amg.coarsest_A.block_dimx
-    coarse_data = _replicate(amg.coarse_solver.solve_data(), n_ranks)
+    coarse_data = _replicate(amg.coarse_solver.solve_data_part(), n_ranks)
     if boundary < len(amg.levels):
         # vectors are already global below the boundary: the coarse
         # solver applies directly, and the boundary level's transfers

@@ -1441,7 +1441,7 @@ def _finish_sharded(amg, mesh, axis, M, offsets, lvl, levels,
     amg.coarse_solver._owns_scaling = False
     amg.coarse_solver.setup(amg.coarsest_A)
     amg.num_levels = len(amg.levels) + 1
-    coarse_data = _replicate(amg.coarse_solver.solve_data(), R)
+    coarse_data = _replicate(amg.coarse_solver.solve_data_part(), R)
     # wrap the last sharded level: gather/compact into the tail's space
     amg.levels[boundary - 1] = ShardedConsolidationLevel(
         levels[-1], axis, offsets_last, ncl_last)

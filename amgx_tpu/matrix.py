@@ -243,6 +243,26 @@ def host_resident(*arrays) -> bool:
     return True
 
 
+_PLACEHOLDERS: dict = {}
+
+
+def _placeholder(dtype):
+    """The one-element array a slim view (`CsrMatrix.slim_for_spmv`)
+    carries where a CSR payload was that nothing reads: one a dtype for
+    the process (and a default device, and an x64 mode: it is made
+    where, and as, a fresh `jnp.zeros` would have been), so that a slim
+    view is host work alone and its placeholders are the same leaves in
+    every solve-data tree."""
+    key = (jnp.dtype(dtype).name, jax.config.jax_default_device,
+           jax.config.jax_enable_x64)
+    out = _PLACEHOLDERS.get(key)
+    if out is None:
+        # concrete even where a trace asks for the view
+        with jax.ensure_compile_time_eval():
+            out = _PLACEHOLDERS.setdefault(key, jnp.zeros((1,), dtype))
+    return out
+
+
 def _np_row_reduce(op, data, ro, n, empty_val):
     """Per-row reduce over CSR-ordered data via ufunc.reduceat, with
     empty rows patched to `empty_val` (reduceat's equal-index semantics
@@ -882,22 +902,22 @@ class CsrMatrix:
         consumers (diagonal, coo, Galerkin) need the full matrix."""
         if not self.initialized:
             return self
-        dummy_i = jnp.zeros((1,), jnp.int32)
+        dummy_i, dummy_v = _placeholder(jnp.int32), _placeholder(self.dtype)
         if self.dia_vals is not None:
             return dataclasses.replace(
-                self, values=jnp.zeros((1,), self.dtype),
+                self, values=dummy_v,
                 col_indices=dummy_i, row_ids=None, diag_idx=None,
                 row_offsets=dummy_i, ell_cols=None, ell_vals=None,
                 swell_cols=None, swell_vals=None, swell_c0row=None,
                 swell_nchunk=None, swell_w128=0)
         if self.swell_cols is not None:
             return dataclasses.replace(
-                self, values=jnp.zeros((1,), self.dtype),
+                self, values=dummy_v,
                 col_indices=dummy_i, row_ids=None, diag_idx=None,
                 row_offsets=dummy_i, ell_cols=None, ell_vals=None)
         if self.ell_cols is not None:
             return dataclasses.replace(
-                self, values=jnp.zeros((1,), self.dtype),
+                self, values=dummy_v,
                 col_indices=dummy_i, row_ids=None, diag_idx=None,
                 row_offsets=dummy_i)
         return self

@@ -39,6 +39,7 @@ from ..output import amgx_printf
 from ..resilience import faultinject as _fi
 from ..resilience.status import RUNNING as _ST_RUNNING
 from ..resilience.status import SolveStatus, status_string
+from ..solve_data import SolveDataOwner
 
 # ---------------------------------------------------------------------------
 # convergence criteria (src/convergence/, registry src/core.cu:680-685)
@@ -129,9 +130,11 @@ class SolveResult:
 # ---------------------------------------------------------------------------
 
 
-class Solver:
+class Solver(SolveDataOwner):
     """Base solver. Subclasses implement `solver_setup`, `solve_init`,
-    `solve_iteration`, and may override `apply` (preconditioner action).
+    `solve_iteration`, and may override `apply` (preconditioner action);
+    what the jitted solve reads of them goes into `_build_solve_data`
+    (solve_data.py: the tree is assembled once a (re)setup).
 
     Reference skeleton: include/solvers/solver.h:126-156.
     """
@@ -257,6 +260,10 @@ class Solver:
                      span_args: Optional[Dict[str, Any]] = None):
         t0 = time.perf_counter()
         snap = self._resetup_debug_snapshot() if reuse else None
+        # the tree of the old coefficients goes before the new leaves
+        # are made (top-down through the chain: nothing of it outlives
+        # the build)
+        self.drop_solve_data()
         if not A.initialized:
             A = A.init()
         if self._owns_scaling and self.scaling not in ("NONE", ""):
@@ -451,8 +458,9 @@ class Solver:
         self.solver_setup()
 
     # -- functional pieces (pure, jittable) ------------------------------
-    def solve_data(self) -> Dict[str, Any]:
-        """The pytree of device data the jitted solve needs. Includes the
+    def _build_solve_data(self) -> Dict[str, Any]:
+        """The pytree of device data the jitted solve needs, as
+        `solve_data()` keeps and serves it. Includes the
         preconditioner's data under 'precond'. Solvers whose iterations
         only SpMV against A (slim_A_ok) pass a layout-only view so
         unused CSR payloads stay out of the solve program's HBM."""
@@ -461,8 +469,12 @@ class Solver:
             A = A.slim_for_spmv()
         d: Dict[str, Any] = {"A": A}
         if self.preconditioner is not None:
-            d["precond"] = self.preconditioner.solve_data()
+            d["precond"] = self.preconditioner.solve_data_part()
         return d
+
+    def _solve_data_children(self) -> tuple:
+        pc = self.preconditioner
+        return () if pc is None else (pc,)
 
     def solve_init(self, data, b, x, r) -> Dict[str, Any]:
         """Extra solver state (beyond x/r) before the first iteration."""

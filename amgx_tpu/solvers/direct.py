@@ -46,8 +46,8 @@ class DenseLUSolver(Solver):
     # padded inverse lives in VMEM during the whole tail sub-cycle
     _TAIL_INV_MAX_ROWS = 1024
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["qt"] = self._qt
         d["r"] = self._r
         if self.cycle_fusion and self.A is not None \
@@ -57,18 +57,10 @@ class DenseLUSolver(Solver):
                 # explicit inverse A^{-1} = R^{-1} Q^T for the
                 # VMEM-resident coarse tail (ops/smooth.py): the tail
                 # kernel applies the coarsest solve as one MXU matmul.
-                # Memoized on the CURRENT factors' identity, so a value
-                # resetup that swaps _qt/_r refreshes it while repeated
-                # solve_data calls (e.g. hierarchies whose tail never
-                # fuses) don't redo the n^2-RHS triangular solve.
-                memo = getattr(self, "_inv_memo", None)
-                if memo is None or memo[0] is not self._qt \
-                        or memo[1] is not self._r:
-                    memo = (self._qt, self._r,
-                            jsl.solve_triangular(self._r, self._qt,
-                                                 lower=False))
-                    self._inv_memo = memo
-                d["inv"] = memo[2]
+                # The n^2-RHS triangular solve runs where the tree is
+                # assembled: once a (re)setup (solve_data.py)
+                d["inv"] = jsl.solve_triangular(self._r, self._qt,
+                                                lower=False)
         return d
 
     def _direct(self, data, rhs):

@@ -48,8 +48,8 @@ class IDRSolver(_KrylovBase):
         P, _ = np.linalg.qr(P)
         self._P = jnp.asarray(P, dtype=self.A.dtype)
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["P"] = self._P
         return d
 

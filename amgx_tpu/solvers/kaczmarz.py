@@ -65,8 +65,8 @@ class KaczmarzSolver(Solver):
             self.row_colors = jnp.zeros((A.num_rows,), jnp.int32)
             self.num_colors = 1
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["inv_rn2"] = self._inv_rownorm2
         d["colors"] = self.row_colors
         return d

@@ -430,7 +430,7 @@ class ChebyshevSolver(_KrylovBase):
         if mode in (0, 1):
             precond_apply = None
             if has_precond:
-                pdata = self.preconditioner.solve_data()
+                pdata = self.preconditioner.solve_data_part()
                 precond_apply = lambda v: self.preconditioner.apply(pdata, v)
             lmax = _power_lambda_max(self.A, precond_apply)
             self.lmax = float(lmax) * 1.05

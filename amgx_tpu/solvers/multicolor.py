@@ -158,8 +158,8 @@ class MulticolorGSSolver(_ColoredSolver):
             parity_sweep.build_slabs(self.A.dia_vals, self._dinv,
                                      self._parity)
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["dinv"] = self._dinv
         d["colors"] = self.row_colors
         if self._parity is not None:
@@ -252,8 +252,8 @@ class GSSolver(Solver):
         self._diag = d
         self._dinv = safe_recip(d)
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d.update(ell_cols=self._ell_cols, ell_vals=self._ell_vals,
                  gs_diag=self._diag, dinv=self._dinv)
         return d
@@ -377,8 +377,8 @@ class MulticolorDILUSolver(_ColoredSolver):
                 Einv = jnp.where(colors == c, safe_recip(d - e), Einv)
         self._Einv = Einv
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["Einv"] = self._Einv
         d["colors"] = self.row_colors
         return d
@@ -538,8 +538,8 @@ class MulticolorILUSolver(_ColoredSolver):
             Ap = csr_add(Ap, fill)
         return Ap
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d.update(ilu_L=self._Lp, ilu_U=self._Up, u_diag=self._u_diag,
                  colors=self.row_colors)
         return d
@@ -602,8 +602,8 @@ class CFJacobiSolver(Solver):
                 "(use it as a smoother under algorithm=CLASSICAL)")
         self._dinv = safe_recip(self.A.diagonal())
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["dinv"] = self._dinv
         d["is_coarse"] = self.cf_map == 1
         return d

@@ -102,8 +102,8 @@ class PolynomialSolver(Solver):
         self.lmax = 1.1 * rho
         self.lmin = rho / 30.0
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["lmin"] = jnp.asarray(self.lmin, self.A.dtype)
         d["lmax"] = jnp.asarray(self.lmax, self.A.dtype)
         return d
@@ -157,8 +157,8 @@ class KPZPolynomialSolver(Solver):
             colsum = colsum + jnp.abs(self.A.diag)
         self.l_inf = float(jnp.max(colsum))
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["l_inf"] = jnp.asarray(self.l_inf, self.A.dtype)
         return d
 
@@ -219,8 +219,8 @@ class ChebyshevPolySolver(Solver):
         self._taus = jnp.asarray(chebyshev_poly_coeffs(self.order),
                                  self.A.dtype) / lam.astype(self.A.dtype)
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         d["taus"] = self._taus
         st = getattr(self, "_mf_stencil", None)
         if st is not None:

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from .. import registry
 from ..errors import BadParametersError
 from ..ops.spmv import residual
+from ..profiling import trace_region
 from .base import Solver
 
 
@@ -65,7 +66,10 @@ class RefinementSolver(Solver):
     def precond_operator(self, A):
         # the inner chain (and its own preconditioner tree, e.g. the AMG
         # hierarchy) builds against the reduced-precision operator
-        self._A32 = A.astype(self.inner_dtype)
+        # a leaf of the accounted set-up (telemetry/spans.py): it runs
+        # before the inner chain's, and the hierarchy's, own
+        with trace_region("amg.operator_cast"):
+            self._A32 = A.astype(self.inner_dtype)
         return self._A32
 
     def solver_setup(self):
@@ -81,12 +85,12 @@ class RefinementSolver(Solver):
         self._inner_fn = self.preconditioner._build_solve_fn(
             diag=False, extras=True)
 
-    def solve_data(self):
+    def _build_solve_data(self):
         # overrides the base: the inner data is the f32 solve tree; the
         # outer operator is only ever SpMV'd (defect computation), so a
         # layout-only view suffices
         return {"A": self.A.slim_for_spmv(),
-                "inner": self.preconditioner.solve_data()}
+                "inner": self.preconditioner.solve_data_part()}
 
     def computes_residual(self):
         return True

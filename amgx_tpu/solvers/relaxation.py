@@ -52,8 +52,8 @@ class _FusedJacobiMixin:
         return jnp.asarray(
             np.full(max(sweeps, 0), self.relaxation_factor), dtype)
 
-    def solve_data(self):
-        d = super().solve_data()
+    def _build_solve_data(self):
+        d = super()._build_solve_data()
         st = getattr(self, "_mf_stencil", None)
         if st is not None:
             # matrix-free level: the stencil payload replaces BOTH the

@@ -743,6 +743,18 @@ declare_counter("solve.stage_s.report",
                 "host seconds building the SolveReport and printing "
                 "solve stats (telemetry=1 or print_solve_stats=1)")
 
+# the solve-data tree a set-up solver owns (solve_data.py)
+declare_counter("solve_data.build",
+                "calls of solve_data() on a set-up solver (the caller's "
+                "entry: what reads a node as a part of another tree takes "
+                "solve_data_part() and is not counted) that had to "
+                "assemble a part of its tree: "
+                "one after each setup / resetup (the caller's own call, "
+                "or the next solve's), none a steady solve")
+declare_counter("solve_data.reuse",
+                "such calls served the kept tree whole: the same "
+                "object with the same leaves, no dispatch")
+
 # CsrMatrix.with_values (matrix.py): the coefficient replacement of a
 # time step
 declare_counter("matrix.refill_host_s",

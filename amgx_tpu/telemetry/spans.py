@@ -65,6 +65,12 @@ DECLARED_SPANS: Tuple[str, ...] = (
     # amg.* accounted setup leaves (disjoint by contract)
     "amg.l0_layout",
     "amg.host_pull",
+    # REFINEMENT's reduced-precision copy of the operator, which the
+    # inner chain and its hierarchy are then built against
+    "amg.operator_cast",
+    # the one fetch of the GEO levels' deferred wrap flags: where the
+    # main thread waits for the level build's device work
+    "amg.wrap_check",
     "amg.value_resetup",
     "amg.L*.selector",
     "amg.L*.strength",
@@ -105,6 +111,10 @@ DECLARED_SPANS: Tuple[str, ...] = (
     # host work at the end of AMG.setup / AMG.resetup, after every
     # other leaf has closed
     "amg.static_signature",
+    # an assembly of a set-up solver's solve-data tree for a caller
+    # of solve_data() (solve_data.py): it may run under a caller's
+    # amg.device_sync, so it is named outside the accounted prefix
+    "solve_data.build",
     # overlapped ship worker (reports on its own thread; NOT summed
     # into the amg.* accounted fraction)
     "ship.cast_put",

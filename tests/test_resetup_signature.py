@@ -353,7 +353,7 @@ def test_debug_resetup_contract_on_the_kept_route(monkeypatch):
     assert slv.solve(b).converged
     # a leaf's dtype swapped behind the signature's back
     from amgx_tpu.solvers.polynomial import ChebyshevPolySolver
-    plain = ChebyshevPolySolver.solve_data
+    plain = ChebyshevPolySolver._build_solve_data
     amg = _amg_of(slv)
     old = [lv.smoother for lv in amg.levels]    # the snapshot's side
 
@@ -364,7 +364,8 @@ def test_debug_resetup_contract_on_the_kept_route(monkeypatch):
         return d
 
     sig = amg._static_sig
-    monkeypatch.setattr(ChebyshevPolySolver, "solve_data", narrowed)
+    monkeypatch.setattr(ChebyshevPolySolver, "_build_solve_data",
+                        narrowed)
     monkeypatch.setattr(signature, "static_signature", lambda amg: sig)
     with pytest.raises(AssertionError, match="leaf shapes/dtypes"):
         slv.resetup(scaled(A, 1.7))

@@ -268,6 +268,50 @@ def test_registering_the_program_that_ran_compiles_nothing():
     assert "krylov.CG.iter" in compiled.as_text()
 
 
+def test_the_kept_solve_data_tree_is_the_argument_list_it_was():
+    """The solve program takes `solve_data()` as its first argument, and
+    a resetup that keeps the program (PR 33) replays it on the new
+    tree. Keeping the tree between solves (ISSUE 41) changes neither
+    the program nor its argument list: the tree a solver has kept over
+    its solves and the first assembly of a fresh solver have one
+    treedef, the same leaf shapes and dtypes and one jaxpr, and neither
+    a steady solve nor the solve after a resetup traces or compiles."""
+    import jax
+    from amgx_tpu import presets
+
+    def described(tree):
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        return treedef, [(l.shape, str(l.dtype)) for l in leaves]
+
+    def jaxpr(slv, tree):
+        return str(jax.make_jaxpr(slv._build_solve_fn())(tree, b, x0).jaxpr)
+
+    A = gallery.poisson("7pt", 16, 16, 16).init()
+    A = A.with_values(np.asarray(A.values))
+    b = jnp.ones(A.num_rows)
+    x0 = jnp.zeros_like(b)
+    slv = amgx.create_solver(Config.from_string(presets.FLAGSHIP))
+    slv.setup(A)
+    for _ in range(3):
+        assert slv.solve(b).converged
+    kept = slv.solve_data()
+    fresh_slv = amgx.create_solver(Config.from_string(presets.FLAGSHIP))
+    fresh_slv.setup(A)
+    fresh = fresh_slv.solve_data()
+    assert described(fresh) == described(kept)
+    assert jaxpr(fresh_slv, fresh) == jaxpr(slv, kept)
+    names = ("solver.retrace.solve", "compile.programs",
+             "resetup.program_kept")
+    before = metrics.snapshot()
+    assert slv.solve(b).converged                   # steady
+    slv.resetup(A.with_values(1.5 * np.asarray(A.values)))
+    assert described(slv.solve_data()) == described(kept)
+    assert slv.solve(b).converged                   # the kept program
+    assert growth(before, names) == {
+        "solver.retrace.solve": 0, "compile.programs": 0,
+        "resetup.program_kept": 1}
+
+
 def lowered_elsewhere(solve_fn, args):
     """The same program lowered from another call site."""
     return solve_fn.lower(*args).compile()
@@ -572,7 +616,7 @@ def test_host_stage_readers_are_deltas_per_operation():
 def test_benchmark_selfcheck_passes_with_the_new_cell(capsys):
     selfcheck.main()
     out = capsys.readouterr().out
-    assert "files: 8 cells, 5 end-to-end and 44 per-layer" in out
+    assert "files: 8 cells, 5 end-to-end and 46 per-layer" in out
     assert out.rstrip().endswith("selfcheck ok")
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
