@@ -354,16 +354,14 @@ class GeoSelector(AggregationSelector):
         cnz = (nz + 1) // 2 if 2 in axes else nz
         # pure index arithmetic: host numpy (a single device transfer)
         # instead of ~10 eager device ops, each its own compile and
-        # dispatch
-        i = np.arange(n, dtype=np.int32)
-        x = i % nx
-        t = i // nx
-        y = t % ny
-        z = t // ny
-        cx = x // 2 if 0 in axes else x
-        cy = y // 2 if 1 in axes else y
-        cz = z // 2 if 2 in axes else z
-        agg = (cz * cny + cy) * cnx + cx
+        # dispatch. Per-axis coarse indices broadcast to the grid (z
+        # slowest), so the map is the only n-sized array made here:
+        # n-sized temporaries in a time step's re-setup are served fast
+        # or slow by glibc's mmap threshold (PERF.md, PR 43)
+        cx, cy, cz = (np.arange(e, dtype=np.int32) // (2 if a in axes else 1)
+                      for a, e in enumerate((nx, ny, nz)))
+        agg = ((cz[:, None, None] * cny + cy[None, :, None]) * cnx
+               + cx[None, None, :]).reshape(n)
         self.fine_shape = shape
         self.pair_axes = axes
         self.coarse_shape = (cnx, cny, cnz)
@@ -375,7 +373,7 @@ class GeoSelector(AggregationSelector):
         # 256^3). The generic-fallback consumers
         # (coarse_a_from_aggregates, restrict_vector) accept numpy and
         # upload on first use only when that slow path actually runs.
-        return agg.astype(np.int32), int(cnx * cny * cnz)
+        return agg, int(cnx * cny * cnz)
 
 
 @registry.aggregation_selectors.register("SERIAL_GREEDY")

@@ -34,9 +34,11 @@ def _record_route(route: str, A, **why):
     telemetry must stay importable without the amg package). A
     structure resetup that the value route declined carries the test
     that failed as `reason`."""
-    from ..telemetry import flightrec
+    from ..telemetry import flightrec, spans
     flightrec.record("resetup.route", route=route,
                      rows=int(A.num_rows), **why)
+    # the same name in the span buffer, for spans.resetup_rows()
+    spans.mark("resetup.route", args={"route": route})
 
 
 class AMGLevel:
@@ -964,26 +966,30 @@ class AMG(SolveDataOwner):
         slabs, damping tables, color maps)."""
         if self._ship_device is None:
             return
-        A_slim = level.A.slim_for_spmv()
-        if getattr(level.smoother, "_mf_stencil", None) is not None:
-            # matrix-free level: never ship the value slab — the
-            # solve-data tree carries only the stencil coefficients
-            from ..ops.stencil import mf_slim
-            A_slim = mf_slim(A_slim)
-        pieces = [A_slim]
-        for name in ("P", "R"):
-            op = getattr(level, name, None)
-            if op is not None and op.initialized:
-                pieces.append(op.slim_for_spmv())
-        # fused-cycle transfer slabs (built at setup by the level
-        # classes): ship with the level instead of as a first-solve
-        # straggler
-        memo = getattr(level, "_xfer_memo", None)
-        if memo is not None and memo[0] is not None:
-            pieces.append(memo[0])
-        if level.smoother is not None:
-            pieces.append(level.smoother.solve_data_part())
-        self._prefetch_leaves(pieces)
+        from ..profiling import trace_region
+        # the build thread's share of the ship: slim views, the
+        # smoother's solve-data tree, the hand-over to the ship worker
+        with trace_region(f"amg.L{level.level_index}.prefetch"):
+            A_slim = level.A.slim_for_spmv()
+            if getattr(level.smoother, "_mf_stencil", None) is not None:
+                # matrix-free level: never ship the value slab — the
+                # solve-data tree carries only the stencil coefficients
+                from ..ops.stencil import mf_slim
+                A_slim = mf_slim(A_slim)
+            pieces = [A_slim]
+            for name in ("P", "R"):
+                op = getattr(level, name, None)
+                if op is not None and op.initialized:
+                    pieces.append(op.slim_for_spmv())
+            # fused-cycle transfer slabs (built at setup by the level
+            # classes): ship with the level instead of as a first-solve
+            # straggler
+            memo = getattr(level, "_xfer_memo", None)
+            if memo is not None and memo[0] is not None:
+                pieces.append(memo[0])
+            if level.smoother is not None:
+                pieces.append(level.smoother.solve_data_part())
+            self._prefetch_leaves(pieces)
 
     def _solve_data_children(self) -> tuple:
         nodes = [lv.smoother for lv in self.levels] + [self.coarse_solver]

@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import pallas_spmv as _ps
+from ..profiling import trace_region
 
 # Hashable static twin of a StencilOperator (everything but the
 # coefficients) — the lru/jit cache key for the kernel factories and
@@ -164,7 +165,10 @@ def detect_stencil(A, dinv_mode: Optional[str] = None,
     ok, coeffs = stencil_candidate(vals2d, shifts, shape)
     if coeffs_hint is not None:
         coeffs = coeffs_hint
-    if not bool(ok):
+    # the flag's fetch: the host waits here for the level's values
+    with trace_region("mf_detect.sync"):
+        ok = bool(ok)
+    if not ok:
         return None
     offsets = tuple(int(d) for d in A.dia_offsets)
     return StencilOperator(

@@ -245,7 +245,10 @@ class Solver(SolveDataOwner):
             # filled by the body where the resetup drops the cached
             # solve programs; the span reads it as it closes
             span_args: Dict[str, Any] = {}
-            with trace_region(f"{self.name}.resetup", args=span_args):
+            # the outermost resetup span of the tree keeps the
+            # re-setup's account (telemetry/spans.py)
+            with trace_region(f"{self.name}.resetup", args=span_args,
+                              account=True):
                 out = self.__setup_impl(A, reuse, span_args)
         else:
             with trace_region(f"{self.name}.setup"):
@@ -258,6 +261,7 @@ class Solver(SolveDataOwner):
 
     def __setup_impl(self, A: CsrMatrix, reuse: bool,
                      span_args: Optional[Dict[str, Any]] = None):
+        from ..profiling import trace_region
         t0 = time.perf_counter()
         snap = self._resetup_debug_snapshot() if reuse else None
         # the tree of the old coefficients goes before the new leaves
@@ -276,7 +280,16 @@ class Solver(SolveDataOwner):
             A = self.scaler.scale_matrix(A)
             if not A.initialized:
                 A = A.init()
-        self.A = A
+        if reuse:
+            # the last reference to the operator of the call before
+            # goes here: its arrays are released, and with them the
+            # host mirrors matrix.py kept of what was uploaded (at
+            # 256^3 the munmap of 1.9 GB: a leaf of the re-setup's
+            # account, telemetry/spans.py)
+            with trace_region("solver.release_operator"):
+                self.A = A
+        else:
+            self.A = A
         # preconditioner first: solvers whose setup probes the
         # preconditioned operator (e.g. Chebyshev eigen-estimation) need it
         if self.preconditioner is not None:

@@ -723,6 +723,29 @@ declare_counter("resetup.program_kept",
                 "resetups that left a non-empty cache of solve "
                 "programs in place")
 
+# the re-setup's account (telemetry/spans.py): as the outermost
+# <NAME>.resetup span of a thread closes, its wall and how the self
+# times of the spans recorded inside it on that thread divide it.
+# call_s = the leaves' seconds (device_wait_s and selector_s are parts
+# of them) + unnamed_s
+declare_counter("resetup.call_s",
+                "host wall seconds of the outermost <NAME>.resetup "
+                "span: the program's call, which returns when the "
+                "rebuild is dispatched; what a caller waits beyond it "
+                "for the hierarchy to be ready is the device's backlog")
+declare_counter("resetup.device_wait_s",
+                "seconds of a re-setup's leaf spans in which the host "
+                "blocks on the device (spans.WAIT_SPANS: mf_detect.sync, "
+                "amg.wrap_check, amg.host_pull, value_resetup.sync)")
+declare_counter("resetup.unnamed_s",
+                "seconds of a re-setup's call under no leaf span: the "
+                "own time (wall less children) of the <NAME>.resetup "
+                "spans that hand the call down the solver chain, on "
+                "the calling thread")
+declare_counter("amg.resetup.selector_s",
+                "seconds of the amg.L<k>.selector leaves inside a "
+                "re-setup (0 where the route runs no selector)")
+
 # host stages of the outermost Solver.solve (solvers/base.py): host
 # wall seconds, disjoint, summing to the <NAME>.solve span less the
 # few microseconds between them

@@ -98,6 +98,11 @@ DECLARED_SPANS: Tuple[str, ...] = (
     "amg.L*.rap_plan",
     "amg.L*.rap_values",
     "amg.L*.mf_detect",
+    # the one fetch of a level's constancy flag (ops/stencil.py): where
+    # the build's host thread waits for the level's device work; runs
+    # INSIDE the amg.L*.mf_detect leaf, so it is declared outside the
+    # accounted prefix
+    "mf_detect.sync",
     "amg.L*.galerkin",
     "amg.L*.layout",
     "amg.L*.smoother_setup",
@@ -105,12 +110,21 @@ DECLARED_SPANS: Tuple[str, ...] = (
     # hierarchy (a sibling of smoother_setup, not inside it)
     "amg.L*.coloring",
     "amg.coarse_solver_setup",
+    # a host-built level's hand-over to the ship worker, on the build
+    # thread (hierarchy._prefetch_level): slim views of its operators
+    # and the smoother's solve-data tree; a sibling of smoother_setup
+    # and layout
+    "amg.L*.prefetch",
     "amg.ship_resolve",
     "amg.device_sync",
     # the static signature of the finished hierarchy (amg/signature.py):
     # host work at the end of AMG.setup / AMG.resetup, after every
     # other leaf has closed
     "amg.static_signature",
+    # the setup route a (re)setup took (amg/hierarchy.py): an instant
+    # event beside the flight recorder's of the same name, so that a
+    # reader of this buffer (resetup_rows) knows each re-setup's road
+    "resetup.route",
     # an assembly of a set-up solver's solve-data tree for a caller
     # of solve_data() (solve_data.py): it may run under a caller's
     # amg.device_sync, so it is named outside the accounted prefix
@@ -170,6 +184,10 @@ DECLARED_SPANS: Tuple[str, ...] = (
     # distributed comms/shard telemetry: one synthetic track per
     # shard in the Perfetto export (record_span with a per-shard tid)
     "shard.solve",
+    # Solver.resetup's hand-over from the operator of the call before
+    # to the new one (solvers/base.py): where the old one's arrays and
+    # their host mirrors are released
+    "solver.release_operator",
     # host stages of the outermost Solver.solve, disjoint children of
     # <NAME>.solve (solvers/base.py); each also adds its seconds to
     # the counter solve.stage_s.<stage>
@@ -213,6 +231,7 @@ _lock = threading.Lock()
 _tls = threading.local()
 _records: List[dict] = []
 _MAX_RECORDS = 100_000      # oldest half dropped past this
+_dropped_to = -1.0          # start of the newest record dropped so far
 _flat: Dict[str, Tuple[int, float]] = {}
 _t0 = time.perf_counter()   # trace epoch (ts offsets in the export)
 
@@ -254,16 +273,59 @@ def _fence():
 
 
 def _stack() -> list:
+    """This thread's open spans, outermost first, a frame each (see
+    span())."""
     st = getattr(_tls, "stack", None)
     if st is None:
         st = _tls.stack = []
     return st
 
 
+# ---------------------------------------------------------------------------
+# the re-setup's account
+# ---------------------------------------------------------------------------
+
+# The outermost `<NAME>.resetup` span of a thread (Solver._setup_impl
+# opens it with account=True) keeps books while it is open. Every span
+# that closes inside it on that thread adds its SELF time (its wall
+# less its children's) under its name: to the leaves, or, where the
+# span is the re-setup's own or a solver's re-setup directly inside
+# one (REFINEMENT.resetup > FGMRES.resetup > AMG.resetup: the chain
+# that only hands the call down), to what no leaf covers. As the
+# re-setup's span closes, its wall is the leaves' seconds, of which
+# some are waits for the device, plus what is unnamed; each goes to a
+# counter, and the two tables go into the span's record, where
+# resetup_rows() finds them step by step.
+
+# the leaves in which the host blocks on the device
+WAIT_SPANS: Tuple[str, ...] = (
+    "amg.wrap_check",
+    "amg.host_pull",
+    "value_resetup.sync",
+    "mf_detect.sync",
+)
+SELECTOR_SPANS = "amg.L*.selector"
+
+
+def _close_account(books: dict, wall: float) -> dict:
+    leaves, under = books["leaves"], books["under"]
+    wait = sum(s for n, s in leaves.items() if n in WAIT_SPANS)
+    selector = sum(s for n, s in leaves.items()
+                   if fnmatch.fnmatchcase(n, SELECTOR_SPANS))
+    unnamed = sum(under.values())
+    _metrics.add("resetup.call_s", wall)
+    _metrics.add("resetup.device_wait_s", wait)
+    _metrics.add("resetup.unnamed_s", unnamed)
+    _metrics.add("amg.resetup.selector_s", selector)
+    return {"leaves": leaves, "under": under, "wait": wait,
+            "unnamed": unnamed}
+
+
 @contextlib.contextmanager
 def span(name: str, annotate: bool = True,
          args: Optional[Dict[str, Any]] = None,
-         counter: Optional[str] = None):
+         counter: Optional[str] = None,
+         account: bool = False):
     """Record one hierarchical span (and accumulate the flat timer).
     With annotate=True the region is also a jax.profiler
     TraceAnnotation, so it shows up in captured device profiles — the
@@ -274,12 +336,19 @@ def span(name: str, annotate: bool = True,
     request's Perfetto flow chain (module docs). `counter` names a
     declared seconds counter (telemetry/metrics.py) that the span's
     host wall is added to, so a scrape reads the stage without the
-    span buffer."""
+    span buffer. `account` marks a re-setup's span: the outermost such
+    span of a thread keeps the re-setup's account (above)."""
     if _sync:
         _fence()
     stack = _stack()
-    parent = stack[-1] if stack else None
-    stack.append(name)
+    parent = stack[-1][0] if stack else None
+    books = getattr(_tls, "account", None)
+    keeps = account and books is None
+    if keeps:
+        books = _tls.account = {"leaves": {}, "under": {}}
+    # name, seconds of the children closed so far, hands the call down
+    frame = [name, 0.0, keeps or (account and stack[-1][2])]
+    stack.append(frame)
     t_start = time.perf_counter()
     ctx = contextlib.nullcontext()
     if annotate:
@@ -297,20 +366,32 @@ def span(name: str, annotate: bool = True,
         t_end = time.perf_counter()
         stack.pop()
         dt = t_end - t_start
+        if stack:
+            stack[-1][1] += dt
         rec = {"name": name, "ts": t_start - _t0, "dur": dt,
+               "self": dt - frame[1],
                "depth": len(stack), "parent": parent,
                "tid": threading.get_ident()}
         if args:
             rec["args"] = dict(args)
+        if books is not None:
+            table = books["under" if frame[2] else "leaves"]
+            table[name] = table.get(name, 0.0) + dt - frame[1]
+            if keeps:
+                _tls.account = None
+                rec["account"] = _close_account(books, dt)
         _commit(rec, name, dt)
         if counter is not None:
             _metrics.add(counter, dt)
 
 
 def _commit(rec: dict, name: str, dt: float):
+    global _dropped_to
     with _lock:
         _records.append(rec)
         if len(_records) > _MAX_RECORDS:
+            _dropped_to = max(
+                r["ts"] for r in _records[: _MAX_RECORDS // 2])
             del _records[: _MAX_RECORDS // 2]
         calls, tot = _flat.get(name, (0, 0.0))
         _flat[name] = (calls + 1, tot + dt)
@@ -324,7 +405,8 @@ def mark(name: str, args: Optional[Dict[str, Any]] = None):
     tagging via args."""
     stack = _stack()
     rec = {"name": name, "ts": time.perf_counter() - _t0, "dur": 0.0,
-           "depth": len(stack), "parent": stack[-1] if stack else None,
+           "depth": len(stack),
+           "parent": stack[-1][0] if stack else None,
            "tid": threading.get_ident(), "ph": "i"}
     if args:
         rec["args"] = dict(args)
@@ -391,9 +473,50 @@ def timers_total(prefix: str) -> float:
 def reset():
     """Drop recorded spans and flat accumulations (open spans on any
     thread keep recording into the fresh buffers when they close)."""
+    global _dropped_to
     with _lock:
         _records.clear()
         _flat.clear()
+        _dropped_to = -1.0
+
+
+def clock() -> float:
+    """Now, on the clock of the records' `ts`."""
+    return time.perf_counter() - _t0
+
+
+def resetup_rows(since: float = 0.0) -> Tuple[List[dict], bool]:
+    """The re-setups' accounts step by step: one row per outermost
+    re-setup still in the buffer that started at or after `since`
+    (`clock()` units), oldest first: {"start", "wall", "route",
+    "leaves": {name: seconds}, "wait", "unnamed", "under": {name:
+    seconds}}: wall = the leaves + unnamed, `under` divides unnamed by
+    the span whose self time it is, and `route` is what the
+    hierarchy's `resetup.route` event inside it said (None where no
+    hierarchy was re-set-up). A pure reader of records().
+    The second value is True where the buffer has dropped records that
+    started at or after `since`: rows of the window asked for may then
+    be missing from the list."""
+    with _lock:
+        recs = [r for r in _records if r["ts"] >= since
+                and ("account" in r or r["name"] == "resetup.route")]
+        wrapped = _dropped_to >= since
+    rows = []
+    for r in recs:
+        if "account" not in r:
+            continue
+        end = r["ts"] + r["dur"]
+        routes = [m["args"]["route"] for m in recs
+                  if m["name"] == "resetup.route" and m["tid"] == r["tid"]
+                  and r["ts"] <= m["ts"] <= end]
+        acct = r["account"]
+        rows.append({"start": r["ts"], "wall": r["dur"],
+                     "route": routes[-1] if routes else None,
+                     "leaves": dict(acct["leaves"]), "wait": acct["wait"],
+                     "unnamed": acct["unnamed"],
+                     "under": dict(acct["under"])})
+    rows.sort(key=lambda row: row["start"])
+    return rows, wrapped
 
 
 # ---------------------------------------------------------------------------

@@ -65,6 +65,29 @@ class TestAggregates:
         assert nc == 16
         assert np.array_equal(np.asarray(agg), np.arange(64) // 4)
 
+    @pytest.mark.parametrize("shape", [(8, 6, 4), (5, 4, 3), (7, 7, 1),
+                                       (8, 1, 6), (1, 9, 2)])
+    def test_geo_map_is_the_block_of_each_point(self, shape):
+        """GEO pairs every axis of extent >= 2: point (x, y, z) goes to
+        the coarse point (x//2, y//2, z//2), x fastest, as one int32
+        host array."""
+        from types import SimpleNamespace
+        from amgx_tpu.registry import aggregation_selectors
+        nx, ny, nz = shape
+        n = nx * ny * nz
+        sel = aggregation_selectors.create("GEO", agg_cfg(), "amg")
+        agg, nc = sel.set_aggregates(
+            SimpleNamespace(grid_shape=shape, num_rows=n))
+        half = [2 if e >= 2 else 1 for e in shape]
+        cnx, cny, cnz = ((e + h - 1) // h for e, h in zip(shape, half))
+        ref = [((z // half[2]) * cny + y // half[1]) * cnx + x // half[0]
+               for z in range(nz) for y in range(ny) for x in range(nx)]
+        assert isinstance(agg, np.ndarray) and agg.dtype == np.int32
+        assert np.array_equal(agg, ref)
+        assert nc == cnx * cny * cnz == max(ref) + 1
+        assert sel.coarse_shape == (cnx, cny, cnz)
+        assert sel.pair_axes == tuple(a for a in range(3) if shape[a] >= 2)
+
     def test_galerkin_matches_explicit_rap(self):
         """Aggregation coarse A == R A P with piecewise-constant P
         (low_deg determinism/correctness analog)."""
