@@ -48,6 +48,7 @@ import numpy as np
 
 from ..matrix import CsrMatrix
 from ..profiling import trace_region
+from ..solvers.polynomial import chebyshev_poly_coeffs, dia_abs_row_sums
 
 
 class Declined(Exception):
@@ -148,10 +149,13 @@ def _mf_on(amg):
             for lv in amg.levels]
 
 
-def _lam_rowmax(vals2d):
-    # Gershgorin bound from the DIA slab: row abs-sum = sum over stored
-    # diagonals (out-of-grid slots are zero-filled)
-    return jnp.max(jnp.sum(jnp.abs(vals2d), axis=0))
+def _lam_rowmax(dia_vals, num_rows: int):
+    # Gershgorin bound of a level from its DIA slab, by the expression a
+    # rebuilt level's CHEBYSHEV_POLY.solver_setup takes for a DIA operator
+    # (solvers/polynomial.dia_abs_row_sums; a matrix with no slab sums its
+    # COO triplets there, and never comes here: the plan declines it).
+    # Sound only while the slab's off-grid and pad slots hold zero
+    return jnp.max(dia_abs_row_sums(dia_vals, num_rows))
 
 
 def build_plan(amg):
@@ -163,7 +167,6 @@ def build_plan(amg):
 
 
 def _build_plan(amg):
-    from ..solvers.polynomial import chebyshev_poly_coeffs
     if not amg.levels or getattr(amg, "coarse_solver", None) is None:
         raise Declined("no_levels")
     if getattr(amg.coarse_solver, "name", "") != "DENSE_LU_SOLVER":
@@ -261,7 +264,7 @@ def _build_plan(amg):
                 wrapped = wrapped | ~ok_i
         outs["mf"].append(c)
         if sm_plans[i][0] == "cheb":
-            lam = _lam_rowmax(vals2d)
+            lam = _lam_rowmax(dia_vals, p["n"])
             taus = cheb_tabs[sm_plans[i][1]].astype(
                 vals2d.dtype) / lam
         else:

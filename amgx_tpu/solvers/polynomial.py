@@ -21,6 +21,8 @@ short straight-line program.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +30,7 @@ import numpy as np
 from .. import registry
 from ..errors import BadParametersError
 from ..ops.spmv import spmv
+from ..telemetry import metrics as _tm
 from .base import Solver
 
 
@@ -43,10 +46,34 @@ def chebyshev_poly_coeffs(m: int):
     ])
 
 
+@functools.partial(jax.jit, static_argnames=("num_rows",))
+def dia_abs_row_sums(dia_vals, num_rows: int):
+    """Row abs-sums of a scalar DIA slab (k, rows_pad, LANES): one dense
+    pass over the diagonals in the layout they are stored in, no scatter.
+    Sound only while the slab's off-grid and pad slots hold ZERO, which
+    every builder keeps (matrix._build_dia_vals and the host bincount
+    fill a zeroed slab; galerkin._geo_value_phase packs with
+    zeros(...).at[:, :nc].set). The ONE expression behind the Gershgorin
+    bound of a rebuilt level (`_abs_row_sums`) and of a value-re-set-up
+    one (amg/value_resetup._lam_rowmax): same values, same lam."""
+    return jnp.sum(jnp.abs(dia_vals), axis=0).reshape(-1)[:num_rows]
+
+
 def _abs_row_sums(A):
-    rows, cols, vals = A.coo()
-    s = jax.ops.segment_sum(jnp.abs(vals), rows, num_segments=A.num_rows,
-                            indices_are_sorted=True)
+    """Row abs-sums of a scalar matrix for a smoother's set-up, by the
+    layout the matrix holds: a built DIA slab is reduced in place
+    (`dia_abs_row_sums`); every other matrix (CSR, ELL, SWELL, not yet
+    initialised) sums its COO triplets by row. Duplicate entries, which
+    the slab holds summed, read |a + b| there and |a| + |b| here: the
+    slab's is the bound of the operator the SpMV kernels apply."""
+    if A.dia_vals is not None:
+        _tm.inc("smoother.row_sums.slab")
+        s = dia_abs_row_sums(A.dia_vals, A.num_rows)
+    else:
+        _tm.inc("smoother.row_sums.coo")
+        rows, cols, vals = A.coo()
+        s = jax.ops.segment_sum(jnp.abs(vals), rows, num_segments=A.num_rows,
+                                indices_are_sorted=True)
     if A.has_external_diag:
         s = s + jnp.abs(A.diag)
     return s

@@ -675,6 +675,16 @@ declare_counter("smoother.color_steps",
                 "the iterations that ran the cycle x the steps a cycle "
                 "is made of (over the levels: sweeps x passes x colors)")
 
+# which road a smoother set-up's row abs-sums took
+# (solvers/polynomial._abs_row_sums)
+declare_counter("smoother.row_sums.slab",
+                "smoother set-ups whose row abs-sums (CHEBYSHEV_POLY's "
+                "Gershgorin bound) were one dense reduction of the "
+                "operator's DIA slab")
+declare_counter("smoother.row_sums.coo",
+                "smoother set-ups whose row abs-sums were a segment sum "
+                "over the COO triplets (the operator holds no DIA slab)")
+
 # which schedule a GEO level's transfers took (amg/aggregation/transfer.py)
 declare_counter("amg.geo_transfer.onepass",
                 "GEO levels whose restriction and prolong-and-correct "

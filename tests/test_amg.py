@@ -215,8 +215,9 @@ class TestValueOnlyResetup:
         assert np.linalg.norm(resid) < 1e-6 * max(
             1.0, np.linalg.norm(b))
         # iteration parity with a from-scratch setup on the new values
-        # (±1: the fused path sums the Gershgorin bound over DIA slabs,
-        # the eager path over CSR entries — not bit-associated)
+        # (both read the Gershgorin bound from the DIA slab by one
+        # expression, tests/test_row_sums.py; +-1 is kept for what the
+        # two routes still derive apart)
         s2 = amgx.create_solver(self._flagship())
         s2.setup(A2)
         r2 = s2.solve(b)

@@ -325,6 +325,27 @@ def test_geo_transfer_compiles(n, op, one_chip, on_tpu,
         assert mem.alias_size_in_bytes == 4 * n ** 3
 
 
+@pytest.mark.parametrize("n", [256, FINE, COARSE])
+def test_slab_row_sums_compile_to_one_pass(n, one_chip,
+                                           no_persistent_cache):
+    """CHEBYSHEV_POLY's Gershgorin row sums on a DIA level
+    (solvers/polynomial.dia_abs_row_sums) at the flagship's 256^3,
+    128^3 and a coarse level: one fusion that streams the slab where it
+    lies, with no temporary, no relayout copy and no scatter (the COO
+    road's scatter-add held the host for half of a 256^3 time step)."""
+    from amgx_tpu.solvers.polynomial import dia_abs_row_sums
+    rows = n ** 3
+    slab = jax.ShapeDtypeStruct(
+        (7, ps.dia_padded_rows(7, rows), ps.LANES), F32, sharding=one_chip)
+    compiled = dia_abs_row_sums.lower(slab, num_rows=rows).compile()
+    text = compiled.as_text()
+    assert "scatter" not in text and " copy(" not in text
+    assert text.count(" fusion(") == 1
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes == 4 * rows
+
+
 def _strided_or_padding(closed_jaxpr):
     """Eqns outside the kernels that move a vector between a grid and
     its paired grid the XLA way: interior pads, strided slices (as

@@ -8,7 +8,7 @@
   flight recorder names, and says when the buffer wrapped;
 - a steady `solve` touches the span names and counters it touched at
   the parent commit, and no other: the solve path gained nothing;
-- the five reader files return None on an empty `Observed`, and the
+- the reader files return None on an empty `Observed`, and the
   drain is the benchmark's span less the program's call;
 - `tools/step_account.py`: the join of the benchmark's walls with the
   program's rows, the split into modes, the programs of a recorded
@@ -36,7 +36,7 @@ ACCOUNT = ("resetup.call_s", "resetup.device_wait_s", "resetup.unnamed_s",
            "amg.resetup.selector_s")
 READERS = ("step.resetup_call_s", "step.resetup_drain_s",
            "step.resetup_wait_s", "step.resetup_unnamed_s",
-           "step.selector_s")
+           "step.selector_s", "step.slab_row_sums")
 
 
 def _config(which):
@@ -280,7 +280,9 @@ def test_benchmark_json_lists_the_readers_in_the_time_step_cells():
     for name in READERS:
         assert entries[name]["workloads"] == steps
         assert entries[name]["moves"] == "step_s"
-    assert [m["name"] for m in bench["per_layer"][-5:]] == list(READERS)
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index(READERS[0])
+    assert names[at:at + len(READERS)] == list(READERS)
 
 
 # -- tools/step_account.py --------------------------------------------------
