@@ -2,9 +2,17 @@
 
 An entry wraps one solver behind five calls: upload, setup, solve,
 replace, resetup. The traffic code times those calls and nothing else
-of an entry; `last()` reads the finished solve outside the clock. A configuration names its entry by `"entry"`; the table at
-the bottom maps the name to a class, and a later PR adds an entry by
-adding a module that registers itself there (see README.md).
+of an entry; `last()` reads the finished solve outside the clock,
+`solver_tree()` hands the probe the program's solver (or None),
+`close()` frees what the entry made, and `vector_dtype` is the dtype
+the right-hand sides are handed over in. The constructor takes the
+configuration's `(solver, operator)` blocks. A configuration names its
+entry by `"entry"`: a name of the table at the bottom, or, with
+`"entry_module"` beside it, a class of that module of the benchmark
+package (`entry_capi_distributed.py` is the first), so a later PR adds
+an entry by adding a file (see README.md). An entry that runs on more
+than one chip names, as `chips_key`, the key of its `solver` block that
+says how many; the harness holds the cell's `chips` to it.
 
 What `last()` returns is a `Solved`: the answer where it
 lands for that API (a device array for the Python API, a host array for
@@ -42,7 +50,7 @@ class PythonEntry:
     def __init__(self, solver: dict, operator: dict):
         self.options = solver["options"]
         self.dtype = np.dtype(operator["dtype"])
-        self.grid = tuple(operator["grid"])
+        self.grid = tuple(operator["grid"]) if "grid" in operator else None
         self.vector_dtype = self.dtype
 
     def upload(self, ro, ci, vals, rhs):
@@ -53,7 +61,9 @@ class PythonEntry:
                                       n, n)
         # the structured-grid annotation a caller with a grid gives
         # (gallery.poisson sets the same field)
-        self.A = dataclasses.replace(A, grid_shape=self.grid).init()
+        if self.grid is not None:
+            A = dataclasses.replace(A, grid_shape=self.grid)
+        self.A = A.init()
         self.rhs = [jax.device_put(b.astype(self.vector_dtype))
                     for b in rhs]
         _block((self.A, self.rhs))
@@ -114,7 +124,9 @@ class CApiEntry:
         self.handles.append((destroy, handle))
         return handle
 
-    def upload(self, ro, ci, vals, rhs):
+    def _open(self):
+        """Library, config, resources, and the matrix and solution
+        handles: what every upload starts with."""
         from amgx_tpu import capi
         ok = self._ok
         ok(capi.AMGX_initialize())
@@ -136,6 +148,11 @@ class CApiEntry:
             *capi.AMGX_matrix_create(self.rsc, self.mode)))
         self.sol = self._made(capi.AMGX_vector_destroy, ok(
             *capi.AMGX_vector_create(self.rsc, self.mode)))
+
+    def upload(self, ro, ci, vals, rhs):
+        from amgx_tpu import capi
+        ok = self._ok
+        self._open()
         self.n = int(ro.shape[0] - 1)
         self.nnz = int(vals.shape[0])
         ok(capi.AMGX_matrix_upload_all(
@@ -152,7 +169,7 @@ class CApiEntry:
         self.slv = self._made(capi.AMGX_solver_destroy, self._ok(
             *capi.AMGX_solver_create(self.rsc, self.mode, self.cfg)))
         self._ok(capi.AMGX_solver_setup(self.slv, self.mtx))
-        _block(self.solver_tree().solve_data())
+        _block(self._solve_data())
 
     def solve(self, i: int):
         from amgx_tpu import capi
@@ -175,11 +192,15 @@ class CApiEntry:
     def resetup(self):
         from amgx_tpu import capi
         self._ok(capi.AMGX_solver_resetup(self.slv, self.mtx))
-        _block(self.solver_tree().solve_data())
+        _block(self._solve_data())
 
     def solver_tree(self):
         from amgx_tpu import capi
         return capi._get(self.slv, capi._CSolver).solver
+
+    def _solve_data(self):
+        """What a (re)setup has to leave ready on the device."""
+        return self.solver_tree().solve_data()
 
     def close(self):
         while self.handles:

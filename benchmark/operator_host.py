@@ -6,6 +6,19 @@ made. Finite-difference Poisson stencils on a regular grid, Dirichlet
 boundaries, x fastest (the operator of the reference's
 examples/amgx_mpi_poisson7.c): diagonal = stencil size - 1, every
 off-diagonal -1.
+
+A configuration whose `operator` gives `"stencil"` gets `stencil` below.
+One whose operator is something else names a generator of its own
+(`"generator"` and `"module"`, a module of the benchmark package):
+
+    fn(operator: dict, seed: int) -> (row_offsets int32, col_indices int32, values)
+
+with the columns ascending in each row, made with numpy alone: the
+check multiplies by these arrays, so the module that makes them
+imports nothing of `jax` or `amgx_tpu` (selfcheck holds it to that).
+`operator` is the configuration's block as it stands, `seed` the run's
+`--seed`; whether a field is drawn from that or from a seed in the file
+is the configuration's to state under `assumed`.
 """
 from __future__ import annotations
 
@@ -53,3 +66,10 @@ def poisson_csr(stencil: str, grid, dtype=np.float64):
     vals[row_offsets[:-1]
          + mask[:, :centre].sum(axis=1, dtype=np.int32)] = float(k - 1)
     return row_offsets, cols, vals
+
+
+def stencil(operator: dict, seed: int):
+    """The generator of a configuration that gives `"stencil"`: the
+    constant-coefficient operator, the same for every seed."""
+    return poisson_csr(operator["stencil"], operator["grid"],
+                       np.dtype(operator["dtype"]))

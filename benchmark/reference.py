@@ -14,6 +14,8 @@ own recurrence residual or an iteration limit. Its status is always
 """
 from __future__ import annotations
 
+import sys
+
 import numpy as np
 
 from .entries import Solved
@@ -37,11 +39,15 @@ def decide(records, log, host_op, inputs, op_dtype, vector_dtype,
            guarantees, out=print):
     """Holds every sampled operation to the configuration's guarantees
     and prints each number compared beside its limit. Returns
-    (checked, failed): an operation whose status is not success, or
-    whose true residual is over the limit, has failed."""
+    (checked, failed, compared): an operation whose status is not
+    success, or whose true residual is over the limit, has failed;
+    `compared` is each number the check held to a limit, by a short
+    name, with that limit (the largest residual of the sample; the
+    operations of the whole window whose status was not success)."""
     ro, ci, base = host_op
     limit = float(guarantees["true_relative_residual"])
     failed = {r["op"] for r in log if not r["ok"]}
+    not_success = len(failed)
     for r in log:
         if not r["ok"]:
             out(f"check op={r['op']} status not success FAILED")
@@ -66,7 +72,13 @@ def decide(records, log, host_op, inputs, op_dtype, vector_dtype,
             f"{'ok' if good else 'FAILED'}")
     out(f"checked {len(records)} of {len(log)} operations; largest "
         f"true_relres {worst:.6e} against {limit:.1e}")
-    return len(records), len(failed)
+    compared = {
+        # a residual that is not finite is written as the largest float:
+        # the result line stays JSON
+        "true_relres_max": {"value": min(worst, sys.float_info.max),
+                            "limit": limit},
+        "status_not_success": {"value": not_success, "limit": 0}}
+    return len(records), len(failed), compared
 
 
 class ReferenceCG:

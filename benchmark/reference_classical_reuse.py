@@ -26,23 +26,14 @@ HIERARCHY to: a solve preconditioned by a stale coarse level still
 converges, so the residual alone would not see a resetup that skipped
 a level.
 
-The cell's control lives here too. `benchmark.control` finds a control
-in a fixed table, and its `ReferenceCG` has no `replace` / `resetup`, so
-it cannot run a time-step cell:
-
-    python3 -m benchmark.reference_classical_reuse --control \\
-        --workload classical-reuse-p7-128.time-step --seed <n> --seconds <s>
-
-runs the cell through the same harness with `ReferenceCGSteps` (the
-plain CG, matrix and vectors in the control's dtype, taking each step's
-new values) in the program's place, and exits 0 when it came out NOT
-correct, as it must.
+The cell's control lives here too: `ReferenceCGSteps`, the plain CG
+with matrix and vectors in the control's dtype, taking each step's new
+values. The configuration names it (`control.entry` with `control.module`
+= this module), and `python3 -m benchmark.control --workload
+classical-reuse-p7-128.time-step --seed <n> --seconds <s>` runs the cell
+with it in the program's place, exit 0 when it came out NOT correct.
 """
 from __future__ import annotations
-
-import argparse
-import json
-import sys
 
 import numpy as np
 import scipy.sparse as sp
@@ -192,33 +183,3 @@ class ReferenceCGSteps(ReferenceCG):
         import jax
         self.res = jax.block_until_ready(
             self._cg(self._diags, self.rhs[i]))
-
-
-def control_entry(config: dict):
-    ctl = config["control"]
-    assert ctl["entry"] == "reference_cg_steps", ctl["entry"]
-    operator = dict(config["operator"], **ctl.get("operator", {}))
-    return ReferenceCGSteps(ctl["solver"], operator)
-
-
-def main(argv=None):
-    ap = argparse.ArgumentParser(
-        description="the control of a classical structure-reuse "
-                    "time-step cell: exit 0 when it is NOT correct")
-    ap.add_argument("--control", action="store_true", required=True)
-    ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, required=True)
-    ap.add_argument("--seconds", type=float, required=True)
-    a = ap.parse_args(argv)
-    from . import run as harness
-    _cell, config, _traffic, _bench = harness.find_cell(a.workload)
-    print(f"CONTROL: {config['control']['what']}")
-    result = harness.run(a.workload, a.seed, a.seconds, False,
-                         make_entry=control_entry)
-    sys.stdout.flush()
-    print(json.dumps(result), flush=True)
-    return 1 if result["correct"] else 0
-
-
-if __name__ == "__main__":
-    raise SystemExit(main())

@@ -8,11 +8,16 @@ Set-up (everything up to the opening of the window) is reported as
 the check runs after the window has closed. The last line of standard
 output is the result object; the lines before it say where the set-up
 time went, each number the check compared beside its limit, and in a
-traced run the probe.
+traced run the probe. What the check compared, in sum, is also the
+result's last key, `compared`, and the last lines of standard error.
 
 Everything that belongs to one cell is data found by name from
 BENCHMARK.json: `configs/<config>.json`, `traffic/<traffic>.json`,
-`end_to_end/<metric>.json`, `layer_metrics/<metric>.json`.
+`end_to_end/<metric>.json`, `layer_metrics/<metric>.json`. The code a
+configuration or a traffic file names (its entry, its operator's
+generator, its traffic kind, its control) is found by `named`: in the
+table that is there, or in the module of the benchmark package that the
+file gives beside the name.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ T0 = time.perf_counter()        # process start, as near as Python gets
 
 import argparse                 # noqa: E402
 import gc                       # noqa: E402
+import importlib                # noqa: E402
 import json                     # noqa: E402
 import math                     # noqa: E402
 import os                       # noqa: E402
@@ -51,6 +57,70 @@ def find_cell(workload: str):
     cell = cells[workload]
     return (cell, load_json("configs", cell["config"] + ".json"),
             load_json("traffic", cell["traffic"] + ".json"), bench)
+
+
+def named(what: str, name: str, module, table: dict):
+    """What a data file names as its `what`: `table[name]`, or, where
+    the file gives `module` beside the name, that attribute of the
+    module. The module lies in the benchmark package, so that the
+    yardstick stays under BENCHMARK.json's `paths`. Anything else is a
+    SystemExit that says what was looked for and what is known."""
+    if module is None:
+        if name not in table:
+            raise SystemExit(
+                f"benchmark: no {what} {name!r}; known: {sorted(table)} "
+                f"(one of its own comes with its module named beside it)")
+        return table[name]
+    if not str(module).startswith("benchmark."):
+        raise SystemExit(
+            f"benchmark: {what} {name!r} names module {module!r}, which "
+            f"is outside the benchmark package (benchmark.<module>)")
+    try:
+        mod = importlib.import_module(module)
+    except ImportError as e:
+        raise SystemExit(f"benchmark: {what} {name!r} names module "
+                         f"{module!r}, which does not import: {e!r}")
+    if not hasattr(mod, name):
+        known = sorted(k for k in vars(mod) if not k.startswith("_"))
+        raise SystemExit(f"benchmark: module {module!r} has no {what} "
+                         f"{name!r}; it has: {known}")
+    return getattr(mod, name)
+
+
+def entry_of(config: dict, cell: dict = None):
+    """The class a configuration names as its entry. One that runs on
+    as many chips as its own configuration says names that key of
+    `solver` as `chips_key`, and a cell has to ask for as many."""
+    from .entries import ENTRIES
+    entry = named("entry", config["entry"], config.get("entry_module"),
+                  ENTRIES)
+    key = getattr(entry, "chips_key", None)
+    if cell is not None and key is not None \
+            and int(config["solver"][key]) != int(cell["chips"]):
+        raise SystemExit(
+            f"benchmark: cell {cell['name']!r} asks for {cell['chips']} "
+            f"chip(s), entry {config['entry']!r} runs on solver.{key} = "
+            f"{config['solver'][key]} of configuration {cell['config']!r}")
+    return entry
+
+
+def generator_of(operator: dict):
+    """`fn(operator, seed) -> CSR arrays` of a configuration's operator
+    block: `operator_host.stencil` where it gives a `stencil`, else the
+    `generator` of the `module` it names."""
+    from . import operator_host
+    if "generator" in operator:
+        return named("operator generator", operator["generator"],
+                     operator.get("module"), {})
+    named("stencil", operator["stencil"], None, operator_host.STENCILS)
+    return operator_host.stencil
+
+
+def kind_of(traffic_spec: dict):
+    """(loop, the span that is one operation of it) of a traffic file."""
+    from . import traffic
+    return named("traffic kind", traffic_spec["kind"],
+                 traffic_spec.get("module"), traffic.KINDS)
 
 
 def reported_here(metric: dict, cell: str, e2e_here=None):
@@ -122,6 +192,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     puts another entry in the program's place (control.py, the tests)
     and `devs` skips the look for a chip (the tests)."""
     cell, config, traffic_spec, bench = find_cell(workload)
+    # what the files name, or exit: before any device call
+    entry_class = entry_of(config, cell)
+    generator = generator_of(config["operator"])
+    loop, op_span = kind_of(traffic_spec)
     if devs is None:
         devs = require_chips(int(cell["chips"]))
     import jax
@@ -137,20 +211,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     mark("start to devices")
 
     from . import layer_metrics, reference, trace_reduce, traffic
-    from .entries import ENTRIES
-    from .operator_host import poisson_csr
     from .probe import fine_spmv_probe
 
     op = config["operator"]
-    host_op = poisson_csr(op["stencil"], op["grid"], np.dtype(op["dtype"]))
+    host_op = generator(op, seed)
     mark("host operator")
     inputs = traffic.Inputs(seed, traffic_spec, host_op[0].shape[0] - 1)
     mark("right-hand sides")
     if make_entry is None:
-        entry = ENTRIES[config["entry"]](config["solver"], op)
+        entry = entry_class(config["solver"], op)
     else:
         entry = make_entry(config)
-    loop, op_span = traffic.KINDS[traffic_spec["kind"]]
 
     # the program's own instruments, where the entry is the program
     try:
@@ -238,7 +309,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                     f"mean={statistics.fmean(walls):.6f} "
                     f"min={min(walls):.6f} max={max(walls):.6f} s")
 
-        checked, failed = reference.decide(
+        checked, failed, compared = reference.decide(
             sample.records(), log, host_op, inputs, np.dtype(op["dtype"]),
             entry.vector_dtype, config["guarantees"], out=out)
     finally:
@@ -278,7 +349,19 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             result["breakdown"] = trace_reduce.breakdown(obs.trace)
     result["metrics"] = metrics
     result["device"] = device
+    result["compared"] = compared       # last, as the contract has it
     return result
+
+
+def say(result: dict):
+    """The result line, last on standard output; each number the check
+    compared beside its limit, last on standard error."""
+    sys.stdout.flush()
+    for name, c in result["compared"].items():
+        print(f"compared {name} {c['value']:.6e} limit {c['limit']:.1e}",
+              file=sys.stderr)
+    sys.stderr.flush()
+    print(json.dumps(result), flush=True)
 
 
 def _peaks(kind: str) -> dict:
@@ -304,9 +387,7 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     a = ap.parse_args(argv)
-    result = run(a.workload, a.seed, a.seconds, bool(a.trace))
-    sys.stdout.flush()
-    print(json.dumps(result), flush=True)
+    say(run(a.workload, a.seed, a.seconds, bool(a.trace)))
 
 
 if __name__ == "__main__":

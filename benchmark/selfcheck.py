@@ -6,6 +6,12 @@ CPU, with no chip.
   reduction that exists and moves what BENCHMARK.json says, every
   per-layer metric moves an end-to-end metric that each of its cells
   reports.
+- Every name and module that every file under `configs/` and
+  `traffic/` gives resolves (entry, operator generator, traffic kind,
+  control), each cell's chips are its entry's, and no module that makes
+  an operator or stands in as a control holds `jax` or `amgx_tpu` once
+  a fresh interpreter has imported it: the reference imports nothing of
+  the program.
 - The trace reduction gives known numbers on a small recorded trace
   (data/fine_spmv_probe.xplane.pb: 20 calls of the 128^3 fine-level
   DIA SpMV on a TPU v5e, recorded by PR 28's exploration run).
@@ -13,14 +19,16 @@ CPU, with no chip.
 """
 from __future__ import annotations
 
+import glob
 import importlib
 import json
 import os
+import subprocess
 import sys
 
 import numpy as np
 
-from . import layer_metrics, probe, run, trace_reduce, traffic
+from . import layer_metrics, probe, run, trace_reduce
 from .operator_host import poisson_csr
 
 
@@ -35,8 +43,8 @@ def check_files():
         assert cfg["reduced"] == c["reduced"] and cfg["source"] == c["source"]
         assert cfg["guarantees"]["true_relative_residual"] > 0
     for w in cells.values():
-        spec = run.load_json("traffic", w["traffic"] + ".json")
-        assert spec["kind"] in traffic.KINDS, spec["kind"]
+        run.kind_of(run.load_json("traffic", w["traffic"] + ".json"))
+        run.entry_of(run.load_json("configs", w["config"] + ".json"), w)
     for m in e2e.values():
         spec = run.load_json("end_to_end", m["name"] + ".json")
         assert run.statistic(spec["statistic"], [1.0, 2.0], 3.0) > 0
@@ -57,6 +65,43 @@ def check_files():
         assert any(run.reported_here(m, cell, here)
                    for m in bench["per_layer"]), cell
     return len(cells), len(e2e), len(bench["per_layer"])
+
+
+def imports_of(module: str) -> list:
+    """Which of jax, jaxlib and amgx_tpu a fresh interpreter holds
+    after importing `module`."""
+    code = ("import importlib, sys; importlib.import_module(sys.argv[1]); "
+            "print(*sorted({m.split('.')[0] for m in sys.modules} "
+            "& {'jax', 'jaxlib', 'amgx_tpu'}))")
+    done = subprocess.run([sys.executable, "-c", code, module],
+                          cwd=run.ROOT, capture_output=True, text=True)
+    assert done.returncode == 0, done.stderr[-2000:]
+    return done.stdout.split()
+
+
+def check_names():
+    """Resolves what every configuration and traffic file names, held
+    by a cell or not (`check_files` holds each cell's chips to its
+    entry's), and holds the modules of operators and controls to the
+    reference's rule."""
+    from . import control
+    clean = {"benchmark.operator_host", "benchmark.reference"}
+    configs = sorted(glob.glob(os.path.join(run.HERE, "configs", "*.json")))
+    for path in configs:
+        config = run.load_json(path)
+        run.entry_of(config)
+        run.generator_of(config["operator"])
+        control.control_class(config)
+        for block in (config["operator"], config["control"]):
+            clean.add(block.get("module", "benchmark.reference"))
+    specs = sorted(glob.glob(os.path.join(run.HERE, "traffic", "*.json")))
+    for path in specs:
+        run.kind_of(run.load_json(path))
+    for module in sorted(clean):
+        held = imports_of(module)
+        assert not held, f"{module} imports {held}"
+    return {"configs": len(configs), "traffic": len(specs),
+            "clean": sorted(clean)}
 
 
 def check_trace():
@@ -114,6 +159,11 @@ def main():
     cells, e2e, layer = check_files()
     print(f"files: {cells} cells, {e2e} end-to-end and {layer} per-layer "
           f"metrics agree with their data files")
+    named = check_names()
+    print(f"names: {named['configs']} configurations and "
+          f"{named['traffic']} traffic files name parts that resolve; "
+          f"{len(named['clean'])} reference modules import neither jax "
+          f"nor the program")
     n_ops, share = check_trace()
     print(f"trace: recorded probe reduces to {n_ops} device ops and "
           f"{share:.2f}% of HBM peak")

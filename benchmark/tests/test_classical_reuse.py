@@ -5,9 +5,9 @@ the root of a checkout; the harness is driven as `test_correct.py`
 drives it (its fixtures, by import), at 16^3:
 
 - `classical-reuse-p7-128.time-step` comes out correct; its control (the
-  plain CG in bfloat16, taking each step's new values) does not, and
-  `python3 -m benchmark.reference_classical_reuse --control` says so
-  by its exit code;
+  plain CG in bfloat16, taking each step's new values), which its
+  configuration names by module, does not, and `python3 -m
+  benchmark.control` says so by its exit code;
 - every step of it, the warm one too, takes the structure route
   (`amg.resetup.structure` +1 a step, every level reused) and none
   coarsens again (`amg.setup.full` stands at the one setup) or traces
@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from benchmark import control
 from benchmark import reference_classical_reuse as reference
 from benchmark.operator_host import poisson_csr
 from benchmark.tests.test_correct import drive, small  # noqa: F401
@@ -52,7 +53,7 @@ def test_cell_is_correct_and_every_step_takes_the_structure_route(small):
 
 
 def test_control_is_not_correct(small):
-    result, lines = drive(CELL, make_entry=reference.control_entry)
+    result, lines = drive(CELL, make_entry=control.control_entry)
     assert not result["correct"] and result["failed"] >= 1, lines
     assert any(ln.endswith(" FAILED") for ln in lines)
 
@@ -78,13 +79,14 @@ def test_control_command_exits_0_when_not_correct(small, monkeypatch):
                     devs=jax.devices(), out=lambda line: None)
 
     monkeypatch.setattr(run, "run", harness)
-    argv = ["--control", "--workload", CELL, "--seed", "5",
-            "--seconds", "0.5"]
-    assert reference.main(argv) == 0
+    argv = ["--workload", CELL, "--seed", "5", "--seconds", "0.5"]
+    assert control.control_class(run.find_cell(CELL)[1]) \
+        is reference.ReferenceCGSteps
+    assert control.main(argv) == 0
     assert calls == [(CELL, 5, 0.5, False)]
     # and 1 when what ran in the control's place is correct
-    monkeypatch.setattr(reference, "control_entry", None)   # the program
-    assert reference.main(argv) == 1
+    monkeypatch.setattr(control, "control_entry", None)     # the program
+    assert control.main(argv) == 1
 
 
 def test_reference_equals_a_dense_product_written_out():

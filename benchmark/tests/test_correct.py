@@ -13,11 +13,14 @@ mode, skipping only its look for a chip:
   limit in float32, so what fails it in bfloat16 is the precision;
 - with the timed path broken underneath (an answer altered where it is
   produced; a step whose resetup leaves the solver's state unchanged)
-  `correct` comes out false.
+  `correct` comes out false;
+- every result carries what the check compared, each number beside its
+  limit, as its last key, and `run.say` ends standard error with them.
 """
 from __future__ import annotations
 
 import copy
+import json
 
 import jax
 import pytest
@@ -116,3 +119,26 @@ def test_a_failed_status_counts_even_unsampled(small):
         lambda cfg: NeverConverges(cfg["solver"], cfg["operator"]))
     assert not result["correct"]
     assert result["failed"] == result["attempted"], lines
+    status = result["compared"]["status_not_success"]
+    assert status == {"value": result["attempted"], "limit": 0}
+
+
+@pytest.mark.parametrize("broken,holds", [(None, True),
+                                          (AlteredAnswer, False)])
+def test_the_numbers_compared_end_the_result_and_standard_error(
+        small, capsys, broken, holds):
+    result, lines = drive(
+        "flagship-p7-128.solve-stream", broken and (
+            lambda cfg: broken(cfg["solver"], cfg["operator"])))
+    assert list(result)[-1] == "compared"
+    worst = result["compared"]["true_relres_max"]
+    assert worst["limit"] == 1e-8
+    assert (worst["value"] <= worst["limit"]) is holds is result["correct"]
+    assert any(f"largest true_relres {worst['value']:.6e}" in ln
+               for ln in lines)
+    run.say(result)
+    said = capsys.readouterr()
+    assert json.loads(said.out.splitlines()[-1]) == result
+    assert said.err.splitlines()[-2:] == [
+        f"compared true_relres_max {worst['value']:.6e} limit 1.0e-08",
+        "compared status_not_success 0.000000e+00 limit 0.0e+00"]
