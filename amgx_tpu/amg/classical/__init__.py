@@ -83,6 +83,7 @@ class ClassicalAMGLevel(AMGLevel):
         if not self.interpolator_registry.has(interp_name):
             interp_name = self.interpolator_fallback
         interp = self.interpolator_registry.create(interp_name, cfg, scope)
+        interp.level_index = self.level_index   # names its truncate leaf
         # host path: ell='auto' gives P and R the windowed-ELL (SWELL)
         # layout, the Pallas gather kernel's storage — transfer operators
         # are the other half of the unstructured cycle's SpMV traffic.

@@ -13,7 +13,10 @@ distance2.cu 2274 LoC, multipass.cu). Round-1 surface:
   Coarse points interpolate by injection (P row = e_c). All assembled
   with COO masks + segment sums (no per-row loops).
 - Truncation (interp_truncation_factor / interp_max_elements) trims P
-  and rescales rows to preserve the row sum (truncate analog).
+  and rescales rows to preserve the row sum (truncate analog); the rows
+  that lost an entry are counted in amg.interp.truncated_rows, and where
+  it is a pass of its own (every road but the native D2 sweep, which
+  fuses it) it runs under the leaf amg.L<k>.truncate.
 - MULTIPASS: real Stuben multipass interpolation (multipass.cu analog)
   via filtered SpGEMM passes — F-points acquire weights pass by pass
   through already-interpolated neighbors (see MultipassInterpolator).
@@ -26,6 +29,8 @@ import numpy as np
 
 from ... import registry
 from ...matrix import CsrMatrix
+from ...profiling import trace_region
+from ...telemetry import metrics as _tm
 
 
 def _coarse_index(cf_map):
@@ -77,9 +82,17 @@ class Interpolator:
         self.scope = scope
         self.trunc_factor = float(cfg.get("interp_truncation_factor", scope))
         self.max_elements = int(cfg.get("interp_max_elements", scope))
+        self.level_index = None     # the level that made it says which
 
     def generate(self, A: CsrMatrix, cf_map, strong) -> CsrMatrix:
         raise NotImplementedError
+
+    def _cut(self, P: CsrMatrix) -> CsrMatrix:
+        """P cut to the truncation keys, as a pass of its own."""
+        if self.trunc_factor > 1.0 and self.max_elements <= 0:
+            return P
+        with trace_region(f"amg.L{self.level_index}.truncate"):
+            return _truncate(P, self.trunc_factor, self.max_elements)
 
 
 @registry.interpolators.register("D2")
@@ -127,7 +140,8 @@ class Distance2Interpolator(Interpolator):
                 # truncation is fused into the native sweep; numpy-backed
                 # on purpose: the host hierarchy build stays off the
                 # XLA:CPU array path end to end
-                p_ptr, p_col, p_val = out
+                p_ptr, p_col, p_val, lost = out
+                _tm.inc("amg.interp.truncated_rows", lost)
                 nc = int(np.sum(np.asarray(cf_map) == 1))
                 return CsrMatrix(
                     row_offsets=p_ptr.astype(np.int32), col_indices=p_col,
@@ -249,7 +263,7 @@ class Distance2Interpolator(Interpolator):
         np.cumsum(counts, out=pp[1:])
         P = CsrMatrix.from_scipy_like(pp, pc.astype(np.int32),
                                       jnp.asarray(vsum), n, nc)
-        return _truncate(P, self.trunc_factor, self.max_elements)
+        return self._cut(P)
 
     def _generate_jnp(self, A: CsrMatrix, cf_map, strong) -> CsrMatrix:
         from ...ops.spgemm import _expand, csr_multiply
@@ -333,7 +347,7 @@ class Distance2Interpolator(Interpolator):
         p_vals = jnp.concatenate([w[f_row],
                                   jnp.ones((nc,), vals.dtype)])
         P = CsrMatrix.from_coo(p_rows, p_cols, p_vals, n, nc)
-        return _truncate(P, self.trunc_factor, self.max_elements)
+        return self._cut(P)
 
 
 @registry.interpolators.register("D1")
@@ -372,7 +386,7 @@ class Distance1Interpolator(Interpolator):
         p_vals = jnp.concatenate([w[mask],
                                   jnp.ones((nc,), vals.dtype)])
         P = CsrMatrix.from_coo(p_rows, p_cols, p_vals, n, nc)
-        return _truncate(P, self.trunc_factor, self.max_elements)
+        return self._cut(P)
 
 
 @registry.interpolators.register("MULTIPASS")
@@ -394,6 +408,28 @@ class MultipassInterpolator(Interpolator):
     """
 
     def generate(self, A: CsrMatrix, cf_map, strong) -> CsrMatrix:
+        from ...ops.spgemm import _on_host
+        if _on_host(A) and not A.has_external_diag:
+            # host-setup path: one native sweep a pass. The jnp form
+            # below runs there as hundreds of eager device programs
+            # whose shapes are the data's own counts (731 of them, 13
+            # minutes of compiling, on SPE10's first level: PR 47)
+            from ... import native
+            out = native.multipass_native(
+                A.num_rows, np.asarray(A.row_offsets),
+                np.asarray(A.col_indices), np.asarray(A.values),
+                np.asarray(strong, np.uint8), np.asarray(cf_map, np.int32))
+            if out is not None:
+                p_ptr, p_col, p_val = out
+                return self._cut(CsrMatrix(
+                    row_offsets=p_ptr.astype(np.int32), col_indices=p_col,
+                    values=p_val.astype(np.asarray(A.values).dtype,
+                                        copy=False),
+                    num_rows=A.num_rows,
+                    num_cols=int(np.sum(np.asarray(cf_map) == 1))))
+        return self._generate_jnp(A, cf_map, strong)
+
+    def _generate_jnp(self, A: CsrMatrix, cf_map, strong) -> CsrMatrix:
         from ...ops.spgemm import csr_multiply
         n = A.num_rows
         rows, cols, vals = A.coo()
@@ -459,7 +495,7 @@ class MultipassInterpolator(Interpolator):
         P = CsrMatrix.from_coo(
             jnp.concatenate(p_rows), jnp.concatenate(p_cols),
             jnp.concatenate(p_vals), n, nc)
-        return _truncate(P, self.trunc_factor, self.max_elements)
+        return self._cut(P)
 
 
 def _truncate(P: CsrMatrix, factor: float, max_elements: int) -> CsrMatrix:
@@ -497,6 +533,9 @@ def _truncate(P: CsrMatrix, factor: float, max_elements: int) -> CsrMatrix:
                                   num_segments=n, indices_are_sorted=True)
     scale = rowsum / jnp.where(keptsum == 0, 1.0, keptsum)
     scale = jnp.where(keptsum == 0, 1.0, scale)
+    lost = jax.ops.segment_sum((~keep).astype(jnp.int32), rows,
+                               num_segments=n, indices_are_sorted=True)
+    _tm.inc("amg.interp.truncated_rows", int(jnp.sum(lost > 0)))
     return _compact_coo(rows, cols, vals * scale[rows], keep, P.num_rows,
                         num_cols=P.num_cols)
 
@@ -535,6 +574,8 @@ def _truncate_host(P: CsrMatrix, factor: float, max_elements: int
     new_vals = (vals * scale[rows])[keep]
     new_cols = cols[keep]
     counts = np.bincount(rows[keep], minlength=n)
+    _tm.inc("amg.interp.truncated_rows",
+            int(np.count_nonzero(counts < np.diff(ro))))
     new_ro = np.zeros(n + 1, np.int32)
     np.cumsum(counts, out=new_ro[1:])
     return CsrMatrix(row_offsets=new_ro, col_indices=new_cols,

@@ -6,12 +6,22 @@ cr.cu 663 LoC, aggressive_*.cu, selector.cu).
 - PMIS (parallel modified independent set) is a natural TPU fit — it is
   already a data-parallel fixed point:
 
-    weight w_i = strong-degree(i) + hash(i)      (deterministic "random")
-    repeat: undecided i with w_i greater than every undecided strong
-            neighbor's weight becomes COARSE; undecided neighbors of new
-            COARSE points become FINE.
+    weight w_i = |S^T_i| + hash(i)               (deterministic "random";
+                 S^T_i = the points that strongly DEPEND on i)
+    start:  a point that depends on nothing, or that nothing depends
+            on, is FINE (the first kind keeps an empty row of P: the
+            reference's STRONG_FINE)
+    repeat: undecided i with w_i greater than every undecided neighbor's
+            weight over S | S^T becomes COARSE; an undecided point that
+            DEPENDS on a new COARSE point (row i of S) becomes FINE.
 
-  expressed as segment-max sweeps over the symmetrized strength graph.
+  The two directions of a strength edge are kept apart as hypre's
+  par_coarsen.c does (the reference's pmis.cu is modelled on it; its
+  source is not in this tree): only a point that depends on a C point
+  has one to interpolate from. On a symmetric mask (a constant stencil)
+  the direction changes nothing; on SPE10's operator the symmetrized
+  form left F points with no C point within two steps and FGMRES took
+  138 iterations where it takes 9 (PR 47).
 - RS is the classical serial first pass. The reference itself refuses to
   run it on the GPU ("it's a sequential algorithm", rs.cu:269-277) and
   runs it on the HOST; here it is a native C++ bucket-queue component
@@ -56,12 +66,25 @@ def _symmetrize(rows, cols, mask, n):
     return r[order], c[order]
 
 
+def _no_dependency(A: CsrMatrix, strong):
+    """(n,) bool: the rows without a strong entry (numpy)."""
+    ro = np.asarray(A.row_offsets)
+    st = np.asarray(strong, bool)
+    has = np.zeros(A.num_rows, bool)
+    has[np.repeat(np.arange(A.num_rows), np.diff(ro))[st]] = True
+    return ~has
+
+
 def pmis_split(A: CsrMatrix, strong, max_iters: int = 30, init=None):
     """Returns cf_map (n,) in {FINE, COARSE}. `init` (optional) seeds the
     fixed point with already-decided assignments (cf_map_init=1 analog,
     pmis.cu:508): entries in {FINE, COARSE} are kept, UNDECIDED entries
-    are resolved by the PMIS sweeps."""
+    are resolved by the PMIS sweeps. Without it, the rows that depend
+    on nothing start FINE."""
     n = A.num_rows
+    if init is None:
+        init = np.where(_no_dependency(A, strong), FINE,
+                        UNDECIDED).astype(np.int32)
     from ...ops.spgemm import _on_host
     if _on_host(A):
         # host-setup path: the synchronous fixed point as a native C++
@@ -69,25 +92,21 @@ def pmis_split(A: CsrMatrix, strong, max_iters: int = 30, init=None):
         from ...native import pmis_native
         cf = pmis_native(
             n, np.asarray(A.row_offsets), np.asarray(A.col_indices),
-            np.asarray(strong, np.uint8),
-            None if init is None else np.asarray(init, np.int32),
+            np.asarray(strong, np.uint8), np.asarray(init, np.int32),
             max_iters)
         if cf is not None:
             # numpy on purpose: the host hierarchy build stays off jax
             # CPU arrays (jnp consumers accept numpy transparently)
             return cf
     rows, cols, _ = A.coo()
+    strong = jnp.asarray(strong, bool) & (cols >= 0) & (cols < n)
     sr, sc = _symmetrize(rows, cols, strong, n)
-    deg = jnp.zeros((n,), jnp.float64).at[sr].add(1.0) * 0.5
-    w = deg + _hash01(n)
-    if init is None:
-        state = jnp.full((n,), UNDECIDED, jnp.int32)
-    else:
-        state = jnp.asarray(init, jnp.int32)
-    # isolated points (no strong connections): they cannot interpolate —
-    # make them COARSE (kept exactly, matches Dirichlet-row handling)
-    has_nbr = jnp.zeros((n,), bool).at[sr].set(True)
-    state = jnp.where((state == UNDECIDED) & ~has_nbr, COARSE, state)
+    dr, dc = rows[strong], cols[strong]      # dr DEPENDS on dc
+    indeg = jnp.zeros((n,), jnp.float64).at[dc].add(1.0)
+    w = indeg + _hash01(n)
+    state = jnp.asarray(init, jnp.int32)
+    # nothing depends on it: it is nobody's C point
+    state = jnp.where((state == UNDECIDED) & (indeg == 0), FINE, state)
 
     for _ in range(max_iters):
         und = state == UNDECIDED
@@ -99,9 +118,9 @@ def pmis_split(A: CsrMatrix, strong, max_iters: int = 30, init=None):
             indices_are_sorted=True)
         new_c = und & (w > nbr_max)
         state = jnp.where(new_c, COARSE, state)
-        # undecided points strongly connected to any C point become FINE
-        c_nbr = jnp.zeros((n,), bool).at[sr].max(state[sc] == COARSE)
-        state = jnp.where((state == UNDECIDED) & c_nbr, FINE, state)
+        # undecided points that depend on any C point become FINE
+        c_dep = jnp.zeros((n,), bool).at[dr].max(state[dc] == COARSE)
+        state = jnp.where((state == UNDECIDED) & c_dep, FINE, state)
     state = jnp.where(state == UNDECIDED, FINE, state)
     return state.astype(jnp.int32)
 
@@ -315,15 +334,32 @@ def _rs_first_pass(cfg, scope, A: CsrMatrix, strong):
 
 
 def _two_hop_strength(A: CsrMatrix, strong):
-    """Boolean S@S (distance-2 strength) as a COO edge list, built with
-    the sort-based expand machinery (aggressive coarsening graph)."""
-    from ...ops.spgemm import csr_multiply
-    rows, cols, vals = A.coo()
+    """(S2, mask): the graph of two-step dependence, a CsrMatrix whose
+    entries under `mask` are the pairs (i, j != i) with a path i -> k
+    -> j over `strong`. On the host-setup path the native stamp sweep
+    gives the pattern alone; elsewhere S@S through the sort-based
+    expand machinery, path counts and all."""
+    from ...ops.spgemm import _on_host, csr_multiply
+    if _on_host(A):
+        from ...native import two_step_pattern_native
+        out = two_step_pattern_native(
+            A.num_rows, np.asarray(A.row_offsets),
+            np.asarray(A.col_indices), np.asarray(strong, np.uint8))
+        if out is not None and out[0][-1] < 2 ** 31:
+            ptr, col = out
+            mask = np.ones(col.shape[0], np.uint8)
+            # the mask stands in for values: a pattern has none, and
+            # numpy values are what keeps the split on the host's road
+            S2 = CsrMatrix(row_offsets=ptr.astype(np.int32),
+                           col_indices=col, values=mask,
+                           num_rows=A.num_rows, num_cols=A.num_cols)
+            return S2, mask
     sv = jnp.where(strong, 1.0, 0.0)
     S = CsrMatrix(row_offsets=A.row_offsets, col_indices=A.col_indices,
                   values=sv, num_rows=A.num_rows, num_cols=A.num_cols)
     S2 = csr_multiply(S, S)
-    return S2
+    r2, c2, v2 = S2.coo()
+    return S2, (v2 > 0) & (r2 != c2)
 
 
 class ClassicalSelector:
@@ -372,10 +408,13 @@ class AggressivePMISSelector(ClassicalSelector):
     (aggressive_pmis.cu behavior)."""
 
     def mark_coarse_fine_points(self, A, strong):
-        S2 = _two_hop_strength(A, strong)
-        r2, c2, v2 = S2.coo()
-        strong2 = (v2 > 0) & (r2 != c2)
-        return pmis_split(S2, strong2)
+        S2, strong2 = _two_hop_strength(A, strong)
+        # FINE from the start is a row that depends on nothing in ONE
+        # step; a row whose neighbours depend on nothing has an empty
+        # row of S@S and still has to find a C point or become one
+        init = np.where(_no_dependency(A, strong), FINE,
+                        UNDECIDED).astype(np.int32)
+        return pmis_split(S2, strong2, init=init)
 
 
 @registry.classical_selectors.register("CR")

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from ... import registry
 from ...matrix import CsrMatrix
+from ...telemetry import metrics as _tm
 
 
 class Strength:
@@ -56,6 +57,8 @@ class AhatStrength(Strength):
             if A.has_external_diag:
                 rowsum = rowsum + A.diag
             weak_row = jnp.abs(rowsum) > self.max_row_sum * jnp.abs(diag)
+            # one scalar fetch a level, and only where the rule is on
+            _tm.inc("amg.strength.weakened_rows", int(jnp.sum(weak_row)))
             strong = strong & ~weak_row[rows]
         return strong
 
@@ -70,11 +73,12 @@ class AhatStrength(Strength):
         if not A.has_external_diag and \
                 np.asarray(A.values).dtype.kind == "f":
             from ... import native
-            strong = native.strength_ahat_native(
+            out = native.strength_ahat_native(
                 n, np.asarray(A.row_offsets), np.asarray(A.col_indices),
                 np.asarray(A.values), self.theta, self.max_row_sum)
-            if strong is not None:
-                return strong
+            if out is not None:
+                _tm.inc("amg.strength.weakened_rows", out[1])
+                return out[0]
         ro = np.asarray(A.row_offsets)
         cols = np.asarray(A.col_indices)
         vals = np.asarray(A.values)
@@ -99,6 +103,8 @@ class AhatStrength(Strength):
             if A.has_external_diag:
                 rowsum = rowsum + diag
             weak_row = np.abs(rowsum) > self.max_row_sum * np.abs(diag)
+            _tm.inc("amg.strength.weakened_rows",
+                    int(np.count_nonzero(weak_row)))
             strong = strong & ~weak_row[rows]
         return strong
 

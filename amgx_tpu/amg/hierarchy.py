@@ -190,7 +190,17 @@ class AMG(SolveDataOwner):
         self.postsweeps = int(cfg.get("postsweeps", scope))
         self.finest_sweeps = int(cfg.get("finest_sweeps", scope))
         self.coarsest_sweeps = int(cfg.get("coarsest_sweeps", scope))
-        self.dense_lu_num_rows = int(cfg.get("dense_lu_num_rows", scope))
+        # the reference's constructor (src/amg.cu, AMG::AMG) reads the
+        # key only inside `if (solverName.compare("DENSE_LU_SOLVER") ==
+        # 0)`, beside dense_lu_max_rows, and leaves m_dense_lu_num_rows
+        # 0 otherwise: under any other coarse solver (NOSOLVER, a
+        # smoother) nothing stops the coarsening at that size, and
+        # min_coarse_rows, max_levels or a stall end the hierarchy (as
+        # remembered: the reference's source is not in this tree)
+        self.dense_lu_num_rows = (
+            int(cfg.get("dense_lu_num_rows", scope))
+            if str(cfg.get("coarse_solver", scope)).upper()
+            == "DENSE_LU_SOLVER" else 0)
         self.cycle_name = str(cfg.get("cycle", scope)).upper()
         self.cycle_iters = int(cfg.get("cycle_iters", scope))
         self.cycle_fusion = bool(int(cfg.get("cycle_fusion", scope)))
@@ -846,9 +856,13 @@ class AMG(SolveDataOwner):
             return
         from ..ops.stencil import detect_stencil
         from ..profiling import trace_region
-        with trace_region(f"amg.L{level.level_index}.mf_detect"):
+        why = {}
+        with trace_region(f"amg.L{level.level_index}.mf_detect", args=why):
             sm._mf_stencil = detect_stencil(
-                level.A, dinv_mode=sm.matrix_free_dinv)
+                level.A, dinv_mode=sm.matrix_free_dinv, why=why)
+        if sm._mf_stencil is None:
+            from ..telemetry import metrics as _tm
+            _tm.inc("amg.stencil.declined")
 
     def _finalize_setup(self, t0: float):
         from ..solvers.base import make_solver

@@ -309,7 +309,7 @@ class CsrMatrix:
     swell_cols: Optional[Array] = None   # (nb, kpad, 128) local columns
     swell_vals: Optional[Array] = None   # (nb, kpad, 128)
     swell_c0row: Optional[Array] = None  # (nb,) window start, 128-rows
-    swell_nchunk: Optional[Array] = None  # (nb,) populated chunk count
+    swell_nchunk: Optional[Array] = None  # (nb, 1 + words): span in chunks, slab mask
     swell_w128: int = 0                  # static window width, 128-chunks
     num_rows: int = 0
     num_cols: int = 0

@@ -188,14 +188,15 @@ def pmis_native(n, row_offsets, col_indices, strong, init=None,
 
 def strength_ahat_native(n, row_offsets, col_indices, values, theta,
                          max_row_sum):
-    """Native AHAT strength mask; returns strong (nnz,) bool or None
-    when the native library is unavailable."""
+    """Native AHAT strength mask; returns (strong (nnz,) bool, rows the
+    row-sum rule weakened) or None when the native library is
+    unavailable."""
     import numpy as np
     L = lib()
     if L is None:
         return None
     fn = L.amgx_strength_ahat
-    fn.restype = None
+    fn.restype = ctypes.c_int64
     i32p = ctypes.POINTER(ctypes.c_int32)
     f64p = ctypes.POINTER(ctypes.c_double)
     u8p = ctypes.POINTER(ctypes.c_uint8)
@@ -203,11 +204,12 @@ def strength_ahat_native(n, row_offsets, col_indices, values, theta,
     ci = np.ascontiguousarray(col_indices, np.int32)
     va = np.ascontiguousarray(values, np.float64)
     strong = np.empty(ci.shape[0], np.uint8)
-    fn(ctypes.c_int32(int(n)), ro.ctypes.data_as(i32p),
-       ci.ctypes.data_as(i32p), va.ctypes.data_as(f64p),
-       ctypes.c_double(float(theta)), ctypes.c_double(float(max_row_sum)),
-       strong.ctypes.data_as(u8p))
-    return strong.view(np.bool_)
+    weakened = fn(
+        ctypes.c_int32(int(n)), ro.ctypes.data_as(i32p),
+        ci.ctypes.data_as(i32p), va.ctypes.data_as(f64p),
+        ctypes.c_double(float(theta)), ctypes.c_double(float(max_row_sum)),
+        strong.ctypes.data_as(u8p))
+    return strong.view(np.bool_), int(weakened)
 
 
 def l1_diag_native(n, row_offsets, col_indices, values):
@@ -235,7 +237,8 @@ def d2_interp_native(n, row_offsets, col_indices, values, strong, cf,
                      trunc_factor=1.1, max_elements=-1):
     """Native distance-two ext+i interpolation (the host analog of
     src/classical/interpolators/distance2.cu) with fused truncation.
-    Returns (p_ptr int64 (n+1,), p_col int32, p_val float64) or None."""
+    Returns (p_ptr int64 (n+1,), p_col int32, p_val float64, rows that
+    lost an entry to the truncation) or None."""
     import numpy as np
     L = lib()
     if L is None:
@@ -254,12 +257,75 @@ def d2_interp_native(n, row_offsets, col_indices, values, strong, cf,
     st = np.ascontiguousarray(strong, np.uint8)
     cfm = np.ascontiguousarray(cf, np.int32)
     handle = ctypes.c_void_p()
+    lost = ctypes.c_int64(0)
     nnz = build(ctypes.c_int32(int(n)),
                 ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
                 va.ctypes.data_as(f64p), st.ctypes.data_as(u8p),
                 cfm.ctypes.data_as(i32p),
                 ctypes.c_double(float(trunc_factor)),
-                ctypes.c_int32(int(max_elements)), ctypes.byref(handle))
+                ctypes.c_int32(int(max_elements)), ctypes.byref(lost),
+                ctypes.byref(handle))
+    if nnz < 0 or not handle:
+        return None
+    p_ptr = np.empty(int(n) + 1, np.int64)
+    p_col = np.empty(int(nnz), np.int32)
+    p_val = np.empty(int(nnz), np.float64)
+    fetch(handle, p_ptr.ctypes.data_as(i64p),
+          p_col.ctypes.data_as(i32p), p_val.ctypes.data_as(f64p))
+    return p_ptr, p_col, p_val, int(lost.value)
+
+
+def two_step_pattern_native(n, row_offsets, col_indices, strong):
+    """Pattern of S@S less its diagonal (the aggressive selector's
+    graph) as (ptr int64 (n+1,), col int32), or None."""
+    import numpy as np
+    L = lib()
+    if L is None:
+        return None
+    build, fetch = L.amgx_two_step_pattern, L.amgx_d2_fetch
+    build.restype = ctypes.c_longlong
+    fetch.restype = None
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    ro = np.ascontiguousarray(row_offsets, np.int32)
+    ci = np.ascontiguousarray(col_indices, np.int32)
+    st = np.ascontiguousarray(strong, np.uint8)
+    handle = ctypes.c_void_p()
+    nnz = build(ctypes.c_int32(int(n)), ro.ctypes.data_as(i32p),
+                ci.ctypes.data_as(i32p),
+                st.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+                ctypes.byref(handle))
+    ptr = np.empty(int(n) + 1, np.int64)
+    col = np.empty(int(nnz), np.int32)
+    fetch(handle, ptr.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+          col.ctypes.data_as(i32p), None)
+    return ptr, col
+
+
+def multipass_native(n, row_offsets, col_indices, values, strong, cf):
+    """Native multipass interpolation, rows whole (the host analog of
+    src/classical/interpolators/multipass.cu). Returns (p_ptr int64
+    (n+1,), p_col int32, p_val float64) or None."""
+    import numpy as np
+    L = lib()
+    if L is None:
+        return None
+    build, fetch = L.amgx_multipass_build, L.amgx_d2_fetch
+    build.restype = ctypes.c_longlong
+    fetch.restype = None
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    f64p = ctypes.POINTER(ctypes.c_double)
+    u8p = ctypes.POINTER(ctypes.c_uint8)
+    ro = np.ascontiguousarray(row_offsets, np.int32)
+    ci = np.ascontiguousarray(col_indices, np.int32)
+    va = np.ascontiguousarray(values, np.float64)
+    st = np.ascontiguousarray(strong, np.uint8)
+    cfm = np.ascontiguousarray(cf, np.int32)
+    handle = ctypes.c_void_p()
+    nnz = build(ctypes.c_int32(int(n)), ro.ctypes.data_as(i32p),
+                ci.ctypes.data_as(i32p), va.ctypes.data_as(f64p),
+                st.ctypes.data_as(u8p), cfm.ctypes.data_as(i32p),
+                ctypes.byref(handle))
     if nnz < 0 or not handle:
         return None
     p_ptr = np.empty(int(n) + 1, np.int64)
@@ -377,11 +443,14 @@ def rap_plan_values_native(stage1, sr, st, starts2, n_u, a_val, p_val,
 def swell_build_native(ro, ci, vals, num_rows):
     """Native SWELL layout build (ops/pallas_swell.py layout contract).
     Returns (cols4, vals4, c0row, nchunk, w128) with cols4/vals4 shaped
-    (nb, 8, kpad, 128), None when the layout does not pay (budget
+    (nb, 8, kpad, 128) and nchunk (nb, 1 + words) as
+    `pallas_swell.with_slab_mask` lays it out, None when the layout
+    does not pay (budget
     decisions delegated to ops/pallas_swell.swell_budget), or False
     when the native library is unavailable."""
     import numpy as np
-    from ..ops.pallas_swell import BLOCK_ROWS, LANES, SUBS, swell_budget
+    from ..ops.pallas_swell import (BLOCK_ROWS, LANES, SUBS, mask_words,
+                                    swell_budget)
     L = lib()
     vals = np.asarray(vals)
     if L is None or vals.dtype not in (np.float32, np.float64):
@@ -418,8 +487,17 @@ def swell_build_native(ro, ci, vals, num_rows):
          ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
          vals.ctypes.data_as(fp), c0row.ctypes.data_as(i32p),
          cols4.ctypes.data_as(i32p), vals4.ctypes.data_as(fp))
+    nwords = mask_words(w128)
+    mask = np.zeros((nb, nwords), np.uint32)
+    L.amgx_swell_slabmask.restype = None
+    L.amgx_swell_slabmask(
+        ctypes.c_int32(n), ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
+        c0row.ctypes.data_as(i32p), ctypes.c_int32(nwords),
+        mask.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)))
     return (cols4.reshape(nb, SUBS, kpad, LANES),
-            vals4.reshape(nb, SUBS, kpad, LANES), c0row, nchunk, w128)
+            vals4.reshape(nb, SUBS, kpad, LANES), c0row,
+            np.concatenate([nchunk[:, None], mask.view(np.int32)], axis=1),
+            w128)
 
 
 def swell_refill_native(ro, vals, num_rows, kpad):

@@ -37,25 +37,39 @@ double hash01(uint32_t i) {
 
 extern "C" {
 
-// PMIS fixed point over the symmetrized strength graph. `init` may be
-// null (all points start UNDECIDED) or hold {-1,0,1} seeds (HMIS).
-// Writes cf[n] in {0,1}. Returns 0 on success.
+// PMIS fixed point. `init` may be null (all points start UNDECIDED) or
+// hold {-1,0,1} seeds (HMIS). Writes cf[n] in {0,1}. Returns 0 on
+// success. The two directions of a strength edge do different work, as
+// in hypre's par_coarsen.c, which pmis.cu follows: row i's strong
+// entries are what i DEPENDS on, column i's are what i INFLUENCES.
+//   weight   w_i = |S^T_i| (the points that depend on i) + hash
+//   start    a point that nothing depends on is FINE; the caller's
+//            `init` makes FINE the points that depend on nothing (they
+//            keep an empty row of P and are left to the smoother: the
+//            reference's STRONG_FINE)
+//   C        an undecided local maximum of w over its undecided
+//            neighbours in S | S^T
+//   F        an undecided point that DEPENDS on a C point: only then
+//            has it a C point to interpolate from (on a symmetric mask
+//            the direction does not matter; on a variable-coefficient
+//            operator a point that merely influences a C point was
+//            left without any, PR 47)
 int amgx_pmis(
     int32_t n, const int32_t* ro, const int32_t* ci,
     const uint8_t* strong, const int32_t* init, int32_t max_iters,
     int32_t* cf) {
-    // symmetrized adjacency S | S^T with duplicates kept (duplicates are
-    // harmless for max/any reductions and keep deg identical to the
-    // segment-sum formulation: deg = 0.5 * (outdeg + indeg))
-    // strong edges only, cols within [0, n) — strength masks can mark
-    // edges to halo/rectangular columns (same guard as rs.cpp)
+    // symmetrized adjacency S | S^T with duplicates kept (harmless for
+    // a max); strong edges only, cols within [0, n): strength masks can
+    // mark edges to halo/rectangular columns (same guard as rs.cpp)
     std::vector<int64_t> off(static_cast<size_t>(n) + 2, 0);
+    std::vector<int32_t> indeg(static_cast<size_t>(n), 0);
     for (int32_t i = 0; i < n; ++i)
         for (int32_t e = ro[i]; e < ro[i + 1]; ++e) {
             const int32_t j = ci[e];
             if (strong[e] && j >= 0 && j < n) {
                 ++off[static_cast<size_t>(i) + 2];
                 ++off[static_cast<size_t>(j) + 2];
+                ++indeg[static_cast<size_t>(j)];
             }
         }
     for (size_t i = 2; i < off.size(); ++i) off[i] += off[i - 1];
@@ -72,12 +86,12 @@ int amgx_pmis(
     std::vector<double> w(static_cast<size_t>(n));
     std::vector<int32_t> state(static_cast<size_t>(n));
     for (int32_t i = 0; i < n; ++i) {
-        const int64_t d = off[static_cast<size_t>(i) + 1] -
-                          off[static_cast<size_t>(i)];
         w[static_cast<size_t>(i)] =
-            0.5 * static_cast<double>(d) + hash01(static_cast<uint32_t>(i));
+            static_cast<double>(indeg[static_cast<size_t>(i)]) +
+            hash01(static_cast<uint32_t>(i));
         int32_t s = init ? init[i] : UNDECIDED;
-        if (s == UNDECIDED && d == 0) s = COARSE;  // isolated point
+        if (s == UNDECIDED && indeg[static_cast<size_t>(i)] == 0)
+            s = FINE;
         state[static_cast<size_t>(i)] = s;
     }
 
@@ -106,17 +120,18 @@ int amgx_pmis(
         for (int32_t i = 0; i < n; ++i)
             if (new_c[static_cast<size_t>(i)])
                 state[static_cast<size_t>(i)] = COARSE;
-        // phase 2: undecided neighbours of (any, including new) COARSE
-        // points become FINE
+        // phase 2: undecided points that depend on (any, including
+        // new) COARSE points become FINE
         for (int32_t i = 0; i < n; ++i) {
             if (state[static_cast<size_t>(i)] != UNDECIDED) continue;
-            for (int64_t t = off[static_cast<size_t>(i)];
-                 t < off[static_cast<size_t>(i) + 1]; ++t)
-                if (state[static_cast<size_t>(adj[static_cast<size_t>(t)])] ==
-                    COARSE) {
+            for (int32_t e = ro[i]; e < ro[i + 1]; ++e) {
+                const int32_t j = ci[e];
+                if (strong[e] && j >= 0 && j < n &&
+                    state[static_cast<size_t>(j)] == COARSE) {
                     state[static_cast<size_t>(i)] = FINE;
                     break;
                 }
+            }
         }
     }
     for (int32_t i = 0; i < n; ++i)
@@ -129,10 +144,12 @@ int amgx_pmis(
 //   strong_ij = offdiag & (-a_ij * sgn_i >= theta * rowmax_i) & (> 0)
 // with max_row_sum weakening (rows with |rowsum| > mrs*|diag| lose all
 // connections). Diagonal = FIRST in-row occurrence (padded-duplicate
-// CSR convention). Writes strong[nnz] (uint8).
-void amgx_strength_ahat(
+// CSR convention). Writes strong[nnz] (uint8); returns the rows the
+// row-sum rule weakened.
+int64_t amgx_strength_ahat(
     int32_t n, const int32_t* ro, const int32_t* ci, const double* vals,
     double theta, double max_row_sum, uint8_t* strong) {
+    int64_t weakened = 0;
     for (int32_t i = 0; i < n; ++i) {
         double diag = 0.0;
         bool have_diag = false;
@@ -150,12 +167,14 @@ void amgx_strength_ahat(
         }
         const bool weak_row = max_row_sum < 1.0 &&
             std::abs(rowsum) > max_row_sum * std::abs(diag);
+        weakened += weak_row;
         for (int32_t e = ro[i]; e < ro[i + 1]; ++e) {
             if (ci[e] == i || weak_row) { strong[e] = 0; continue; }
             const double c = -vals[e] * sgn;
             strong[e] = (c > 0.0 && c >= theta * rowmax) ? 1 : 0;
         }
     }
+    return weakened;
 }
 
 // L1-strengthened Jacobi diagonal (jacobi_l1_solver.cu semantics;
@@ -191,13 +210,15 @@ struct D2Result {
 // (trunc_factor <= 1.0 and/or max_elements > 0; truncate.cu semantics —
 // keep the max_elements largest |w| per row, drop entries below
 // trunc_factor * rowmax, rescale survivors to preserve the row sum) is
-// fused into the per-row emit so the untruncated P never materializes.
+// fused into the per-row emit so the untruncated P never materializes;
+// *truncated_rows gets the rows that lost an entry to it.
 // Returns P's nnz and a handle for amgx_d2_fetch; -1 on failure.
 long long amgx_d2_build(
     int32_t n, const int32_t* ro, const int32_t* ci, const double* vals,
     const uint8_t* strong, const int32_t* cf, double trunc_factor,
-    int32_t max_elements, void** out_handle) {
+    int32_t max_elements, int64_t* truncated_rows, void** out_handle) {
     *out_handle = nullptr;
+    int64_t lost = 0;
     std::vector<double> diag(static_cast<size_t>(n), 0.0);
     std::vector<double> sgn(static_cast<size_t>(n), 1.0);
     std::vector<int32_t> cidx(static_cast<size_t>(n));
@@ -386,8 +407,10 @@ long long amgx_d2_build(
                 row_keep[row_rank[r]] = 0;
         }
         double keptsum = 0.0;
+        size_t kept = 0;
         for (size_t t = 0; t < m; ++t)
-            if (row_keep[t]) keptsum += row_w[t];
+            if (row_keep[t]) { keptsum += row_w[t]; ++kept; }
+        lost += kept < m;
         const double scale = keptsum == 0.0 ? 1.0 : rowsum / keptsum;
         for (size_t t = 0; t < m; ++t) {
             if (!row_keep[t]) continue;
@@ -396,6 +419,7 @@ long long amgx_d2_build(
         }
     }
     res->ptr[static_cast<size_t>(n)] = static_cast<int64_t>(res->col.size());
+    *truncated_rows = lost;
     *out_handle = res;
     return static_cast<long long>(res->col.size());
 }
@@ -411,3 +435,136 @@ void amgx_d2_fetch(void* handle, int64_t* ptr, int32_t* col, double* val) {
 void amgx_d2_free(void* handle) { delete static_cast<D2Result*>(handle); }
 
 }  // extern "C"
+
+// Stueben's multipass interpolation (amg/classical/interpolators.py
+// MultipassInterpolator has the formula; multipass.cu analog), rows
+// whole: an F point's pass is its distance to the C set over strong
+// negative couplings; pass 1 interpolates from its C points, pass p
+// through the rows of the points of earlier passes it depends on,
+//   w_i = -(alpha_i / ~a_ii) sum_{j in J_i} a_ij P_j,
+//   alpha_i = sum_{k != i, a_ik < 0} a_ik / sum_{j in J_i} a_ij,
+// ~a_ii the diagonal plus the positive couplings. The numpy form over
+// the native SpGEMM took 12 s more of a 128^3 set-up than the device
+// programs it replaced (PR 47); this is one sweep a pass. Returns P's
+// nnz and a handle for amgx_d2_fetch / amgx_d2_free; -1 on failure.
+extern "C" long long amgx_multipass_build(
+    int32_t n, const int32_t* ro, const int32_t* ci, const double* vals,
+    const uint8_t* strong, const int32_t* cf, void** out_handle) {
+    *out_handle = nullptr;
+    const size_t N = static_cast<size_t>(n);
+    const int32_t FAR = 1 << 30;
+    std::vector<int32_t> cidx(N, -1), pass(N, FAR);
+    int32_t nc = 0;
+    for (int32_t i = 0; i < n; ++i)
+        if (cf[i] == COARSE) { cidx[i] = nc++; pass[i] = 0; }
+    auto through = [&](int32_t i, int32_t e) {   // a strong negative coupling
+        return strong[e] && ci[e] != i && vals[e] < 0.0 &&
+               ci[e] >= 0 && ci[e] < n;
+    };
+    int32_t max_pass = 0;
+    for (int sweep = 0; sweep < 64; ++sweep) {
+        bool moved = false;
+        for (int32_t i = 0; i < n; ++i) {
+            if (cf[i] == COARSE) continue;
+            int32_t best = pass[i];
+            for (int32_t e = ro[i]; e < ro[i + 1]; ++e)
+                if (through(i, e) && pass[ci[e]] + 1 < best)
+                    best = pass[ci[e]] + 1;
+            if (best < pass[i]) { pass[i] = best; moved = true; }
+        }
+        if (!moved) break;
+    }
+    std::vector<std::vector<int32_t>> by_pass;
+    for (int32_t i = 0; i < n; ++i) {
+        if (pass[i] == 0 || pass[i] >= FAR) continue;
+        if (pass[i] > max_pass) { max_pass = pass[i]; by_pass.resize(max_pass); }
+        by_pass[static_cast<size_t>(pass[i]) - 1].push_back(i);
+    }
+    // rows are made pass by pass, out of row order: a pool, then CSR
+    std::vector<int64_t> start(N, 0);
+    std::vector<int32_t> len(N, 0), pool_col;
+    std::vector<double> pool_val, acc(static_cast<size_t>(nc), 0.0);
+    std::vector<int32_t> touched;
+    for (int32_t i = 0; i < n; ++i)
+        if (cf[i] == COARSE) {
+            start[i] = static_cast<int64_t>(pool_col.size());
+            len[i] = 1;
+            pool_col.push_back(cidx[i]);
+            pool_val.push_back(1.0);
+        }
+    for (int32_t p = 1; p <= max_pass; ++p)
+        for (const int32_t i : by_pass[static_cast<size_t>(p) - 1]) {
+            double dmod = 0.0, sum_neg = 0.0, denom = 0.0;
+            touched.clear();
+            for (int32_t e = ro[i]; e < ro[i + 1]; ++e) {
+                const int32_t j = ci[e];
+                if (j != i && vals[e] < 0.0) sum_neg += vals[e];
+                else dmod += vals[e];
+                if (!through(i, e) || pass[j] >= p) continue;
+                denom += vals[e];
+                for (int64_t t = start[j]; t < start[j] + len[j]; ++t) {
+                    const int32_t c = pool_col[static_cast<size_t>(t)];
+                    if (acc[c] == 0.0) touched.push_back(c);
+                    acc[c] += vals[e] * pool_val[static_cast<size_t>(t)];
+                }
+            }
+            const double scale = denom == 0.0 ? 0.0
+                : -(sum_neg / denom) / (dmod == 0.0 ? 1.0 : dmod);
+            std::sort(touched.begin(), touched.end());
+            start[i] = static_cast<int64_t>(pool_col.size());
+            for (const int32_t c : touched) {
+                if (acc[c] * scale != 0.0) {
+                    pool_col.push_back(c);
+                    pool_val.push_back(acc[c] * scale);
+                }
+                acc[c] = 0.0;
+            }
+            len[i] = static_cast<int32_t>(
+                static_cast<int64_t>(pool_col.size()) - start[i]);
+        }
+    auto* res = new D2Result();
+    res->ptr.assign(N + 1, 0);
+    for (int32_t i = 0; i < n; ++i) res->ptr[i + 1] = res->ptr[i] + len[i];
+    res->col.resize(static_cast<size_t>(res->ptr[N]));
+    res->val.resize(static_cast<size_t>(res->ptr[N]));
+    for (int32_t i = 0; i < n; ++i)
+        for (int32_t t = 0; t < len[i]; ++t) {
+            res->col[static_cast<size_t>(res->ptr[i] + t)] =
+                pool_col[static_cast<size_t>(start[i] + t)];
+            res->val[static_cast<size_t>(res->ptr[i] + t)] =
+                pool_val[static_cast<size_t>(start[i] + t)];
+        }
+    *out_handle = res;
+    return static_cast<long long>(res->col.size());
+}
+
+// The pattern of S@S without its diagonal: row i holds every j != i
+// that i depends on in exactly two steps (the aggressive selector's
+// graph). A stamp a row, no values, columns in the order met: the
+// SpGEMM with float64 path counts and sorted rows, and the COO views
+// round it, were 7 of the 10 s a 128^3 level's split took on the host
+// (PR 47). Returns the nnz and a handle for amgx_d2_fetch (no values).
+extern "C" long long amgx_two_step_pattern(
+    int32_t n, const int32_t* ro, const int32_t* ci, const uint8_t* strong,
+    void** out_handle) {
+    auto* res = new D2Result();
+    res->ptr.assign(static_cast<size_t>(n) + 1, 0);
+    std::vector<int32_t> stamp(static_cast<size_t>(n), -1);
+    for (int32_t i = 0; i < n; ++i) {
+        stamp[i] = i;                        // never the diagonal
+        for (int32_t e = ro[i]; e < ro[i + 1]; ++e) {
+            const int32_t k = ci[e];
+            if (!strong[e] || k < 0 || k >= n) continue;
+            for (int32_t f = ro[k]; f < ro[k + 1]; ++f) {
+                const int32_t j = ci[f];
+                if (!strong[f] || j < 0 || j >= n || stamp[j] == i) continue;
+                stamp[j] = i;
+                res->col.push_back(j);
+            }
+        }
+        res->ptr[static_cast<size_t>(i) + 1] =
+            static_cast<int64_t>(res->col.size());
+    }
+    *out_handle = res;
+    return static_cast<long long>(res->col.size());
+}

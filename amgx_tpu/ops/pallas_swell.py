@@ -21,8 +21,16 @@ kernel builds an SpMV out of that primitive:
   (8*kpad, 128), the gather decomposes per 128-wide window chunk c:
   take_along_axis(chunk broadcast, lo, axis=1) selected where the local
   column's hi bits == c;
-- a fori_loop runs only the block's populated chunk count (nchunk_b,
-  from SMEM), then y = sum over slots of acc * vals.
+- a fori_loop runs over the block's own column span (nchunk_b chunks,
+  from SMEM), 8 chunks to a slab, and skips the slabs in which the
+  block has no entry (a bit a slab, beside nchunk_b in SMEM: a block of
+  a coarse operator on a 3-D grid touches a few bands of its span, its
+  own z-plane's and its neighbours', each some ten chunks wide, so the
+  skip keeps about half of the span where a chunk-by-chunk one would
+  keep a third; but a bit a chunk, tested inside the slab, read 18%
+  SLOWER on the chip than the slab form, the eight branches costing
+  more than the chunks they save: PR 47), then y = sum over slots of
+  acc * vals.
 
 Traffic per block: 8*kpad*128 values + cols (the ELL-padded minimum)
 plus a W-element window of x. Compute is ~3 VPU ops per (8*kpad, 128)
@@ -47,6 +55,26 @@ SUBS = 8                      # sublane groups per super-block
 BLOCK_ROWS = SUBS * LANES     # rows per super-block
 SWELL_MAX_W = 512 * 1024      # max window elements (2 MB f32 a buffer)
 SWELL_MAX_K = 256             # max padded slots per row
+
+
+def mask_words(w128: int) -> int:
+    """int32 words of a block's slab mask: a bit an 8-chunk slab."""
+    return -(-int(w128) // (8 * 32))
+
+
+def with_slab_mask(nchunk, ci, row_block, c0, w128):
+    """(nb, 1 + words) int32: column 0 the block's chunk count, then
+    its slab mask, bit (s % 32) of word s // 32 set where slab s of the
+    block's window holds a column of the block (numpy form of
+    native amgx_swell_slabmask). `row_block` is each entry's block,
+    `c0` each block's first window column."""
+    nb = nchunk.shape[0]
+    slab = (ci.astype(np.int64) - c0[row_block]) // (8 * LANES)
+    mask = np.zeros((nb, mask_words(w128)), np.uint32)
+    np.bitwise_or.at(mask, (row_block, slab // 32),
+                     np.uint32(1) << (slab % 32).astype(np.uint32))
+    return np.concatenate([nchunk[:, None].astype(np.int32),
+                           mask.view(np.int32)], axis=1)
 
 
 def swell_budget(kmax, w128_raw, nb, nnz):
@@ -83,8 +111,8 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     Returns (cols4, vals4, c0row, nchunk, w128) or None when the layout
     does not pay (window or slot budget exceeded). cols4/vals4 are
     (nb, 8, kpad, 128) slot-major super-blocks; c0row is each block's
-    window start in 128-rows of the padded x; nchunk its populated
-    chunk count.
+    window start in 128-rows of the padded x; nchunk (nb, 1 + words)
+    its span in chunks and its slab mask (`with_slab_mask`).
     """
     n = int(num_rows)
     if n == 0 or ci.shape[0] == 0:
@@ -137,7 +165,8 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     vals4[flat] = vals
     return (cols4.reshape(nb, SUBS, kpad, LANES),
             vals4.reshape(nb, SUBS, kpad, LANES),
-            (c0 // LANES).astype(np.int32), nchunk, w // LANES)
+            (c0 // LANES).astype(np.int32),
+            with_slab_mask(nchunk, ci, b, c0, _w128), w // LANES)
 
 
 def swell_vals_host(ro, vals, num_rows, kpad):
@@ -194,7 +223,50 @@ def swell_spmv_supported(A, x_dtype) -> bool:
     return _swell_budget_ok(A, 4, 1)
 
 
-def _swell_kernel(w128, kpad, n_blocks):
+def _gather_window(nch_ref, stride, b, xbuf, slot, hi, lo, rows):
+    """acc[r, l] = the window's value at the local column (hi, lo) of
+    entry (r, l): the loop over the block's chunks, shared by the SpMV
+    and the fused sweep. `nch_ref` holds, per block, `stride` words:
+    the chunk count, then (stride > 1) the slab mask."""
+
+    def slab(s, acc):
+        # 8 window chunks per loop iteration: the fori overhead was
+        # a measured ~40% of kernel time on wide-window operators
+        # (AMG restriction matrices reach nchunk ~500); w128 is
+        # 8-aligned by the builders so the last slab stays in range
+        base = s * jnp.int32(8)
+        for j in range(8):
+            c = base + jnp.int32(j)
+            chunk = xbuf[slot, pl.ds(c, 1)]   # (1, 128)
+            src = jnp.broadcast_to(chunk, (rows, LANES))
+            g = jnp.take_along_axis(src, lo, axis=1)
+            acc = jnp.where(hi == c, g, acc)
+        return acc
+
+    def slab_step(s, acc):
+        if stride == 1:
+            return slab(s, acc)
+        word = nch_ref[b * jnp.int32(stride) + jnp.int32(1)
+                       + jax.lax.shift_right_logical(s, jnp.int32(5))]
+        bit = jax.lax.bitwise_and(
+            jax.lax.shift_right_logical(
+                word, jax.lax.bitwise_and(s, jnp.int32(31))), jnp.int32(1))
+        return jax.lax.cond(bit == jnp.int32(1), lambda a: slab(s, a),
+                            lambda a: a, acc)
+
+    nslab = jax.lax.div(nch_ref[b * jnp.int32(stride)] + jnp.int32(7),
+                        jnp.int32(8))
+    return jax.lax.fori_loop(jnp.int32(0), nslab, slab_step,
+                             jnp.zeros((rows, LANES), jnp.float32))
+
+
+def _nchunk_words(nchunk):
+    """(the per-block words flat for SMEM, words a block)."""
+    stride = 1 if nchunk.ndim == 1 else nchunk.shape[1]
+    return nchunk.reshape(-1), stride
+
+
+def _swell_kernel(w128, kpad, n_blocks, stride):
     rows = SUBS * kpad
 
     def kernel(c0_ref, nch_ref, xp_ref, cols_ref, vals_ref, y_ref,
@@ -222,23 +294,7 @@ def _swell_kernel(w128, kpad, n_blocks):
         hi = jax.lax.shift_right_logical(cols, jnp.int32(7))
         lo = jax.lax.bitwise_and(cols, jnp.int32(LANES - 1))
 
-        def slab_step(s, acc):
-            # 8 window chunks per loop iteration: the fori overhead was
-            # a measured ~40% of kernel time on wide-window operators
-            # (AMG restriction matrices reach nchunk ~500); w128 is
-            # 8-aligned by the builders so the last slab stays in range
-            base = s * jnp.int32(8)
-            for j in range(8):
-                c = base + jnp.int32(j)
-                chunk = xbuf[slot, pl.ds(c, 1)]   # (1, 128)
-                src = jnp.broadcast_to(chunk, (rows, LANES))
-                g = jnp.take_along_axis(src, lo, axis=1)
-                acc = jnp.where(hi == c, g, acc)
-            return acc
-
-        nslab = jax.lax.div(nch_ref[b] + jnp.int32(7), jnp.int32(8))
-        acc = jax.lax.fori_loop(jnp.int32(0), nslab, slab_step,
-                                jnp.zeros((rows, LANES), jnp.float32))
+        acc = _gather_window(nch_ref, stride, b, xbuf, slot, hi, lo, rows)
         y_ref[...] = jnp.sum(
             (acc * vals).reshape(SUBS, kpad, LANES), axis=1)
 
@@ -258,7 +314,8 @@ def _swell_spmv_call(cols4, vals4, c0row, nchunk, x, w128, num_rows,
     xp = jax.lax.dynamic_update_slice(xp, x.astype(jnp.float32), (0,))
     xp = xp.reshape(xp_rows, LANES)
 
-    kernel = _swell_kernel(w128, kpad, nb)
+    nchunk, stride = _nchunk_words(nchunk)
+    kernel = _swell_kernel(w128, kpad, nb, stride)
     y2 = kernel_call(
         kernel,
         grid=(nb,),
@@ -268,7 +325,7 @@ def _swell_spmv_call(cols4, vals4, c0row, nchunk, x, w128, num_rows,
             # x64 default, which Mosaic cannot legalize
             pl.BlockSpec((nb,), lambda b: (jnp.int32(0),),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((nb,), lambda b: (jnp.int32(0),),
+            pl.BlockSpec((nb * stride,), lambda b: (jnp.int32(0),),
                          memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, SUBS, kpad, LANES),
@@ -344,7 +401,7 @@ def swell_smooth_supported(A, x_dtype) -> bool:
     return _swell_budget_ok(A, dt.itemsize, 4)
 
 
-def _swell_smooth_kernel(w128, kpad, n_blocks, has_dinv):
+def _swell_smooth_kernel(w128, kpad, n_blocks, has_dinv, stride):
     rows = SUBS * kpad
 
     def kernel(*refs):
@@ -380,19 +437,7 @@ def _swell_smooth_kernel(w128, kpad, n_blocks, has_dinv):
         hi = jax.lax.shift_right_logical(cols, jnp.int32(7))
         lo = jax.lax.bitwise_and(cols, jnp.int32(LANES - 1))
 
-        def slab_step(s, acc):
-            base = s * jnp.int32(8)
-            for j in range(8):
-                c = base + jnp.int32(j)
-                chunk = xbuf[slot, pl.ds(c, 1)]
-                src = jnp.broadcast_to(chunk, (rows, LANES))
-                g = jnp.take_along_axis(src, lo, axis=1)
-                acc = jnp.where(hi == c, g, acc)
-            return acc
-
-        nslab = jax.lax.div(nch_ref[b] + jnp.int32(7), jnp.int32(8))
-        acc = jax.lax.fori_loop(jnp.int32(0), nslab, slab_step,
-                                jnp.zeros((rows, LANES), jnp.float32))
+        acc = _gather_window(nch_ref, stride, b, xbuf, slot, hi, lo, rows)
         y = jnp.sum((acc * vals).reshape(SUBS, kpad, LANES), axis=1)
         corr = tau_ref[0] * (bb_ref[...] - y)
         if has_dinv:
@@ -422,10 +467,11 @@ def _swell_smooth_call(cols4, vals4, c0row, nchunk, x, b, dinv, tau,
 
     blk = pl.BlockSpec((SUBS, LANES), lambda i: (i, jnp.int32(0)),
                        memory_space=pltpu.VMEM)
+    nchunk, stride = _nchunk_words(nchunk)
     in_specs = [
         pl.BlockSpec((nb,), lambda i: (jnp.int32(0),),
                      memory_space=pltpu.SMEM),
-        pl.BlockSpec((nb,), lambda i: (jnp.int32(0),),
+        pl.BlockSpec((nb * stride,), lambda i: (jnp.int32(0),),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec((1,), lambda i: (jnp.int32(0),),
                      memory_space=pltpu.SMEM),
@@ -446,7 +492,7 @@ def _swell_smooth_call(cols4, vals4, c0row, nchunk, x, b, dinv, tau,
     if has_dinv:
         in_specs.append(blk)
         operands.append(rowpad(dinv))
-    kernel = _swell_smooth_kernel(w128, kpad, nb, has_dinv)
+    kernel = _swell_smooth_kernel(w128, kpad, nb, has_dinv, stride)
     y2 = kernel_call(
         kernel,
         grid=(nb,),

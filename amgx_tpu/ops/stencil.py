@@ -141,25 +141,32 @@ def stencil_shifts(offsets, shape):
 
 
 def detect_stencil(A, dinv_mode: Optional[str] = None,
-                   coeffs_hint=None):
+                   coeffs_hint=None, why: Optional[dict] = None):
     """StencilOperator for a constant-coefficient DIA grid operator,
     or None (variable coefficients, no DIA/grid annotation, blocks,
     external diagonals, non-stencil offsets). One jitted compare +
     one tiny transfer per level. `coeffs_hint` (a (k,) device array,
     e.g. from GeoRapPlan.coarse_coeffs) skips the extraction and only
-    runs the constancy compare against it."""
-    if getattr(A, "dia_offsets", None) is None \
-            or getattr(A, "dia_vals", None) is None \
-            or getattr(A, "grid_shape", None) is None \
-            or A.is_block or A.has_external_diag \
-            or A.num_rows != A.num_cols:
+    runs the constancy compare against it. A caller that hands in a
+    dict `why` finds the reason of a None under its `declined` key."""
+    def declined(reason):
+        if why is not None:
+            why["declined"] = reason
         return None
+
+    if A.is_block or A.has_external_diag or A.num_rows != A.num_cols:
+        return declined("not a square scalar matrix with its diagonal")
+    if getattr(A, "dia_offsets", None) is None \
+            or getattr(A, "dia_vals", None) is None:
+        return declined("no DIA layout")
+    if getattr(A, "grid_shape", None) is None:
+        return declined("no grid annotation")
     shape = tuple(int(s) for s in A.grid_shape)
     if len(shape) != 3 or int(np.prod(shape)) != A.num_rows:
-        return None
+        return declined("grid annotation is not the rows'")
     shifts = stencil_shifts(A.dia_offsets, shape)
     if shifts is None:
-        return None
+        return declined("offsets are no stencil's")
     k = len(A.dia_offsets)
     vals2d = A.dia_vals.reshape(k, -1)[:, :A.num_rows]
     ok, coeffs = stencil_candidate(vals2d, shifts, shape)
@@ -169,7 +176,7 @@ def detect_stencil(A, dinv_mode: Optional[str] = None,
     with trace_region("mf_detect.sync"):
         ok = bool(ok)
     if not ok:
-        return None
+        return declined("values vary")
     offsets = tuple(int(d) for d in A.dia_offsets)
     return StencilOperator(
         coeffs=coeffs, offsets=offsets, shifts=shifts, shape=shape,

@@ -35,9 +35,9 @@ preconditioning with a fixed linear M: x = x0 + M (V^T y)); FGMRES stores
 the preconditioned vectors Z (flexible: M may vary per step).
 
 The state counts the basis rows its steps read and wrote
-(`basis_rows`); with the step count it rides the packed stats vector
-(`_extra_stats_spec`) to the counters `krylov.arnoldi_steps` and
-`krylov.basis_rows`.
+(`basis_rows`) and its restarts; with the step count they ride the
+packed stats vector (`_extra_stats_spec`) to the counters
+`krylov.arnoldi_steps`, `krylov.basis_rows` and `krylov.restarts`.
 """
 from __future__ import annotations
 
@@ -97,7 +97,8 @@ class _GmresBase(Solver):
         # the one whole-slab write of a solve: a step writes one row
         V = jnp.zeros((m + 1, rows128, 128), dt).at[0].set(
             blas.to_slab(r / jnp.where(beta == 0, 1.0, beta), rows128))
-        st = {"V": V, "basis_rows": jnp.zeros((), jnp.float32)}
+        st = {"V": V, "basis_rows": jnp.zeros((), jnp.float32),
+              "restarts": jnp.zeros((), jnp.float32)}
         st.update(self._cycle_start(beta))
         st.update(self._guard_init())
         if self.flexible:
@@ -213,14 +214,16 @@ class _GmresBase(Solver):
         new["basis_rows"] = st["basis_rows"] + (
             blas.CGS2_BASIS_READS * (i + 1) + (2 if self.flexible else 1)
             + jnp.where(restart, m, 0)).astype(jnp.float32)
+        new["restarts"] = st["restarts"] + restart.astype(jnp.float32)
         return new
 
     # -- the counters' source -------------------------------------------
     def _extra_stats_spec(self):
-        return ("arnoldi_steps", "basis_rows")
+        return ("arnoldi_steps", "basis_rows", "restarts")
 
     def _extra_stats(self, final_state):
-        return (final_state["iters"], final_state["basis_rows"])
+        return (final_state["iters"], final_state["basis_rows"],
+                final_state["restarts"])
 
     def finalize(self, data, b, state):
         # mid-cycle exit: reconstruct from the live Krylov data; exactly at

@@ -576,6 +576,24 @@ declare_counter("amg.setup.restored",
                 "setups served from a persisted structure snapshot "
                 "(serving/hstore.py: load + structure-reuse rebuild — "
                 "the crash-recovery path that replaces a full setup)")
+# what a classical set-up's keys decided (amg/classical/): set-up
+# counters, which no reader of a solve window sees; the operator's
+declare_counter("amg.strength.weakened_rows",
+                "rows whose connections the max_row_sum rule weakened "
+                "to nothing (|row sum| > max_row_sum x |diagonal|; "
+                "AHAT strength, every level of every set-up); stands "
+                "still where max_row_sum >= 1")
+declare_counter("amg.interp.truncated_rows",
+                "rows of P that lost an entry to "
+                "interp_truncation_factor / interp_max_elements "
+                "(every level of every set-up)")
+declare_counter("amg.stencil.declined",
+                "levels whose smoother could run matrix-free and whose "
+                "operator the constant-stencil detector declined (no "
+                "grid annotation, no DIA layout, offsets that are no "
+                "stencil's, or values that vary): the level keeps its "
+                "value slab; the reason is the `declined` arg of the "
+                "amg.L<k>.mf_detect span")
 declare_counter("amg.selector.device_sweep",
                 "RS/HMIS first passes taken by the device-parallel "
                 "independent-set sweep instead of the host-serial "
@@ -612,6 +630,12 @@ declare_counter("krylov.arnoldi_steps",
                 "Arnoldi steps of GMRES / FGMRES, raised after each "
                 "solve (under REFINEMENT: the inner steps of all its "
                 "outer steps); rides the packed stats vector")
+declare_counter("krylov.restarts",
+                "restarts of GMRES / FGMRES (a cycle of "
+                "gmres_n_restart steps that ran to its end: x "
+                "rebuilt, the true residual taken, the basis started "
+                "again), raised after each solve like "
+                "krylov.arnoldi_steps")
 declare_counter("krylov.basis_rows",
                 "rows of the Krylov basis (V, and Z when flexible) "
                 "those steps read and wrote: three readings of the "

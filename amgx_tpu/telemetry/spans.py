@@ -76,6 +76,10 @@ DECLARED_SPANS: Tuple[str, ...] = (
     "amg.L*.strength",
     "amg.L*.cfsplit",
     "amg.L*.interp",
+    # the cut of P to interp_truncation_factor / interp_max_elements
+    # where it is a pass of its own (not the native D2 sweep, which
+    # fuses it): a leaf of interp, opened only where the keys truncate
+    "amg.L*.truncate",
     "amg.L*.layoutP",
     "amg.L*.transposeR",
     "amg.L*.xfer_slabs",

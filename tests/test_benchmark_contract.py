@@ -1,11 +1,18 @@
 """What the benchmark and the documents name must exist in the tree.
 
-Two contracts, both read-only over their subjects:
+Three contracts, all read-only over their subjects:
 
 - every per-layer metric of `benchmark/layer_metrics/*.json` that reads
   the program's counters or span timers names ones the program
   declares. A renamed counter then fails here and not as a `null`
   under `per_layer` in the ledger, which nobody is asked to look at;
+- every module that a configuration, a traffic file or a reader file
+  of the benchmark names (`module`, `entry_module`, `control.module`)
+  imports, lies in the benchmark package and has the attribute the
+  file names beside it: an operator's generator, an entry, a control,
+  a traffic kind, a reduction. `python3 -m benchmark.selfcheck` holds
+  the files of the cells to that on the chip's side; this holds every
+  file, in tier-1;
 - every repo-relative file or directory that `README.md` and
   `.claude/skills/verify/SKILL.md` name in code spans exists, but for
   the few a run of the program writes.
@@ -50,6 +57,51 @@ def test_layer_metric_timers_are_declared(patterns):
                   if not spans.is_declared(p.replace("*", "0"))]
     assert not undeclared, (
         f"telemetry.spans declares no span for {undeclared}")
+
+
+def _named_by_module():
+    """(file, what, module, attribute) of every name a benchmark data
+    file gives with a module beside it."""
+    out = []
+
+    def add(path, what, module, attribute):
+        if module is not None:
+            out.append(pytest.param(
+                module, attribute,
+                id=f"{os.path.basename(path)[:-5]}:{what}"))
+
+    def files(*parts):
+        return sorted(glob.glob(os.path.join(REPO, "benchmark", *parts)))
+
+    for path in files("configs", "*.json") \
+            + files("tests", "local", "config.json"):
+        with open(path) as f:
+            cfg = json.load(f)
+        op, ctl = cfg["operator"], cfg["control"]
+        add(path, "generator", op.get("module"), op.get("generator"))
+        add(path, "entry", cfg.get("entry_module"), cfg["entry"])
+        add(path, "control", ctl.get("module"), ctl["entry"])
+    for path in files("traffic", "*.json") \
+            + files("tests", "local", "traffic.json"):
+        with open(path) as f:
+            spec = json.load(f)
+        add(path, "kind", spec.get("module"), spec["kind"])
+    for path in files("layer_metrics", "*.json"):
+        with open(path) as f:
+            spec = json.load(f)
+        add(path, "reduction", spec.get("module"), spec["reduction"])
+    return out
+
+
+@pytest.mark.parametrize("module,attribute", _named_by_module())
+def test_named_module_has_the_attribute(module, attribute):
+    import importlib
+    assert module.startswith("benchmark."), (
+        f"{module} lies outside the benchmark package")
+    mod = importlib.import_module(module)
+    assert hasattr(mod, attribute), (
+        f"module {module} has no {attribute!r}; it has "
+        f"{sorted(k for k in vars(mod) if not k.startswith('_'))}")
 
 
 # Named in the documents and absent from a fresh checkout, each because

@@ -409,23 +409,31 @@ def test_geo_cycle_on_the_chips_branch_has_no_xla_transfer(grid, onepass,
         assert moved and set(moved) == {"gather"}
 
 
+@pytest.mark.parametrize("words", [0, 1, 6])
 @pytest.mark.parametrize("kernel", ["spmv", "smooth"])
-def test_swell_kernels_compile(kernel, one_chip, on_tpu,
+def test_swell_kernels_compile(kernel, words, one_chip, on_tpu,
                                no_persistent_cache):
-    nb, kpad, w128 = 32, 24, 96
+    """With the slab mask (PR 47: `words` int32 words a block beside
+    its chunk count, a bit a slab, the empty slabs skipped under a
+    `lax.cond` in the chunk loop) and without (a layout from before
+    it: the count alone)."""
+    nb, kpad = 32, 24
+    w128 = 96 if words < 2 else 1480 + 8
+    assert words == 0 or words == sw.mask_words(w128)
     n = nb * sw.BLOCK_ROWS
     ent = ((nb, sw.SUBS, kpad, 128), jnp.int32)
     val = ((nb, sw.SUBS, kpad, 128), F32)
     blk = ((nb,), jnp.int32)
+    nch = ((nb, 1 + words), jnp.int32) if words else blk
     vec = ((n,), F32)
     if kernel == "spmv":
         _compile(lambda c, v, c0, nc, x: sw._swell_spmv_call(
             c, v, c0, nc, x, w128, n),
-            one_chip, ent, val, blk, blk, vec)
+            one_chip, ent, val, blk, nch, vec)
     else:
         _compile(lambda c, v, c0, nc, x, b, d, t: sw._swell_smooth_call(
             c, v, c0, nc, x, b, d, t, w128, n, True),
-            one_chip, ent, val, blk, blk, vec, vec, vec, ((1,), F32))
+            one_chip, ent, val, blk, nch, vec, vec, vec, ((1,), F32))
 
 
 def test_declined_families_decline_on_chip_only(on_tpu):

@@ -207,3 +207,63 @@ def test_d2_host_and_device_paths_agree():
     d1 = np.asarray(P1.to_dense())
     d2 = np.asarray(P2.to_dense())
     np.testing.assert_allclose(d1, d2, rtol=1e-13, atol=1e-14)
+
+
+# -- a variable-coefficient operator (PR 47): the direction of a strength
+# -- edge matters there, and on the constant stencils above it cannot show
+
+@pytest.fixture(scope="module")
+def spe10_small():
+    """SPE10's pressure operator at one small tile, float64, on the
+    host, with its strength mask under the large-problem preset's keys."""
+    from amgx_tpu.matrix import CsrMatrix
+    from benchmark import operator_spe10
+    from benchmark import run as harness
+    op = dict(harness.load_json(
+        "configs", "spe10-classical-l1trunc.json")["operator"],
+        tile=[12, 22, 17], tiles=[1, 1, 1], dtype="float64")
+    ro, ci, vals = operator_spe10.tpfa_spe10(op, 0)
+    n = ro.shape[0] - 1
+    A = CsrMatrix.from_scipy_like(ro, ci, vals, n, n).init()
+    cfg = Config.from_string("strength_threshold=0.25, max_row_sum=0.9, "
+                             "interp_max_elements=4")
+    strong = np.asarray(registry.strength.create(
+        "AHAT", cfg, "default").strong_mask(A), bool)
+    return A, strong, cfg
+
+
+@pytest.mark.parametrize("aggressive", [False, True])
+def test_pmis_keeps_the_directions_of_a_strength_edge_apart(spe10_small,
+                                                            aggressive):
+    """Every F point that depends on something reaches a C point
+    through what it depends on (so the interpolator has a row for it),
+    no C point depends on nothing, the mask is NOT symmetric here, and
+    the native sweep and the jnp fixed point give the same split."""
+    import scipy.sparse as sp
+    from benchmark import reference_spe10 as reference
+    A, strong, cfg = spe10_small
+    name = "AGGRESSIVE_PMIS" if aggressive else "PMIS"
+    sel = registry.classical_selectors.create(name, cfg, "default")
+    cf = np.asarray(sel.mark_coarse_fine_points(A, strong))
+    S = sp.csr_matrix((strong.astype(np.int8), np.array(A.col_indices),
+                       np.array(A.row_offsets)), shape=(A.num_rows,) * 2)
+    S.eliminate_zeros()
+    assert (S != S.T).nnz > 0
+    interp = registry.interpolators.create(
+        "MULTIPASS" if aggressive else "D2", cfg, "default")
+    P = interp.generate(A, cf, strong)
+    Pm = reference.csr(np.asarray(P.row_offsets), np.asarray(P.col_indices),
+                       np.asarray(P.values), cols=P.num_cols)
+    Am = sp.csr_matrix((np.asarray(A.values), np.asarray(A.col_indices),
+                        np.asarray(A.row_offsets)), shape=S.shape)
+    faults = reference.split_faults(Am, strong, cf, Pm)
+    assert not any(faults.values()), faults
+    assert np.diff(Pm.indptr).max() <= 4
+    # the jnp fixed point: the device set-up's form of the same rounds
+    from amgx_tpu.matrix import forced_device_setup
+    with forced_device_setup():
+        cf_dev = np.asarray(sel.mark_coarse_fine_points(A, strong))
+        P_dev = interp.generate(A, cf, strong)
+    assert np.array_equal(cf, cf_dev)
+    np.testing.assert_allclose(np.asarray(P_dev.to_dense()),
+                               Pm.toarray(), rtol=1e-12, atol=1e-14)

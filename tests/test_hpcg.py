@@ -68,11 +68,11 @@ def _valid(M, colors):
 
 # -- the program against the reference --------------------------------
 
-# At 16^3 the third level has 64 rows, and the hierarchy stops there
-# whatever max_levels says (dense_lu_num_rows): the reference is given
-# the depth the program built.
+# The coarse solver is a sweep and no dense LU, so dense_lu_num_rows
+# stops nothing: at 16^3 a fourth level of 2^3 = 8 rows is built where
+# max_levels allows it.
 @pytest.mark.parametrize("n,max_levels,depth", [
-    (16, 3, 3), (16, 4, 3), (32, 3, 3), (32, 4, 4)])
+    (16, 3, 3), (16, 4, 4), (32, 3, 3), (32, 4, 4)])
 def test_program_matches_reference(n, max_levels, depth):
     """Same PCG iteration count, residual history and answer as the
     reference. Tolerances: both sides are the same arithmetic in
@@ -347,8 +347,10 @@ def test_cut_and_join_are_inverse():
 
 def test_coloring_has_a_span_of_its_own():
     """amg.L<k>.coloring for every level's smoother and for the swept
-    coarsest level, beside smoother_setup and not inside it."""
-    A, _M = _operator("27pt", (16, 16, 16))
+    coarsest level, beside smoother_setup and not inside it. 32^3: at
+    16^3 the fourth level is 2^3, whose GEO wrap check fails and has
+    the levels built (and colored) a second time."""
+    A, _M = _operator("27pt", (32, 32, 32))
     spans.reset()
     slv = amgx.create_solver(Config.from_string(PCG_OPTIONS))
     slv.setup(A)

@@ -395,12 +395,13 @@ def test_solve_precision_unset_bitwise_off():
     assert str(j0) == str(j1)
     # flagship driver: no accounting machinery when unset
     slv = amgx.create_solver(Config.from_string(FLAGSHIP))
-    assert slv._extra_stats_spec() == ("arnoldi_steps", "basis_rows")
+    assert slv._extra_stats_spec() == (
+        "arnoldi_steps", "basis_rows", "restarts")
     assert not slv._precision_policy.active
     on = amgx.create_solver(Config.from_string(
         FLAGSHIP + ", solve_precision=bfloat16"))
     assert on._extra_stats_spec() == (
-        "inner_iters", "arnoldi_steps", "basis_rows")
+        "inner_iters", "arnoldi_steps", "basis_rows", "restarts")
 
 
 # ---------------------------------------------------------------------------

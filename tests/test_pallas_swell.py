@@ -123,3 +123,75 @@ def test_swell_empty_rows_and_tail():
     x = jnp.asarray(rng.standard_normal(n))
     assert np.allclose(np.asarray(swell_spmv_xla(A, x)), S @ np.asarray(x),
                        atol=1e-12)
+
+
+def _banded(rng, n, bands, half, per_band=3):
+    """Rows that reach a few narrow bands of columns far apart: a coarse
+    operator on a 3-D grid (its own z-plane's band, its neighbours')."""
+    rows = np.repeat(np.arange(n), per_band * len(bands))
+    centre = np.tile(np.repeat(np.asarray(bands), per_band), n) + rows
+    cols = np.clip(centre + rng.integers(-half, half, rows.shape[0]),
+                   0, n - 1)
+    S = sp.csr_matrix((rng.standard_normal(rows.shape[0]), (rows, cols)),
+                      shape=(n, n))
+    S.sum_duplicates()
+    return S
+
+
+def test_slab_mask_marks_the_slabs_a_block_touches():
+    """PR 47: beside each block's chunk count the layout keeps a bit an
+    8-chunk slab of its window, set where the block has a column; the
+    native sweep and the numpy form agree, and the mask is exactly the
+    set of slabs the block's entries fall in."""
+    from amgx_tpu.ops import pallas_swell as psw
+    rng = np.random.default_rng(17)
+    n = 90 * psw.BLOCK_ROWS + 77
+    S = _banded(rng, n, bands=(-40000, 0, 40000), half=300)
+    sw = build_swell_host(S.indptr, S.indices, S.data.astype(np.float32),
+                          n, n)
+    _c, _v, c0row, nchunk, w128 = sw
+    nb = -(-n // psw.BLOCK_ROWS)
+    assert nchunk.shape == (nb, 1 + psw.mask_words(w128))
+    rows = np.repeat(np.arange(n), np.diff(S.indptr))
+    block = rows // psw.BLOCK_ROWS
+    c0 = c0row.astype(np.int64) * psw.LANES
+    again = psw.with_slab_mask(nchunk[:, 0], S.indices, block, c0, w128)
+    assert np.array_equal(again, nchunk)
+    slab = (S.indices - c0[block]) // (8 * psw.LANES)
+    mask = nchunk[:, 1:].view(np.uint32)
+    touched = 0
+    for b in range(nb):
+        want = set(np.unique(slab[block == b]).tolist())
+        got = {s for s in range(32 * mask.shape[1])
+               if (mask[b, s // 32] >> np.uint32(s % 32)) & np.uint32(1)}
+        assert got == want
+        touched += len(got)
+    # the point of it: most of the spans' slabs are empty
+    assert touched < 0.3 * int((-(-nchunk[:, 0] // 8)).sum())
+
+
+@pytest.mark.parametrize("masked", [True, False])
+def test_swell_kernels_skip_empty_slabs_and_agree(masked):
+    """The SpMV and the fused sweep over a layout whose blocks touch
+    three far bands, with the slab mask and with the chunk count alone
+    (a layout from before the mask): the same numbers as scipy."""
+    from amgx_tpu.ops import pallas_swell as psw
+    rng = np.random.default_rng(19)
+    n = 24 * psw.BLOCK_ROWS + 300
+    S = _banded(rng, n, bands=(-9000, 0, 9000), half=200)
+    S = (S + sp.diags(np.full(n, 50.0))).tocsr()
+    A = _swell_matrix(S, np.float32)
+    if not masked:
+        import dataclasses
+        A = dataclasses.replace(A, swell_nchunk=A.swell_nchunk[:, 0])
+    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    b = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    y = np.asarray(swell_spmv(A, x, interpret=True))
+    y_ref = S @ np.asarray(x, np.float64)
+    assert np.allclose(y, y_ref, rtol=2e-5, atol=2e-4)
+    dinv = jnp.asarray(1.0 / S.diagonal(), jnp.float32)
+    out = np.asarray(psw.swell_smooth_step(A, b, x, jnp.float32(0.8),
+                                           dinv, interpret=True))
+    want = np.asarray(x, np.float64) + 0.8 * np.asarray(dinv, np.float64) \
+        * (np.asarray(b, np.float64) - y_ref)
+    assert np.allclose(out, want, rtol=2e-5, atol=2e-4)

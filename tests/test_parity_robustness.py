@@ -40,7 +40,10 @@ _PARITY = [
     # reference presets (MULTICOLOR_DILU smoother, aggressive levels,
     # reference tolerances)
     ("FGMRES_AGGREGATION.json", ("7pt", (16, 16, 16)), 7),
-    ("AMG_CLASSICAL_PMIS.json", ("7pt", (16, 16, 16)), 13),
+    # 13 until PR 47: under NOSOLVER the hierarchy no longer stops at
+    # dense_lu_num_rows (the reference reads that key only where the
+    # coarse solver is the dense LU) but runs down to min_coarse_rows
+    ("AMG_CLASSICAL_PMIS.json", ("7pt", (16, 16, 16)), 12),
     ("PCG_CLASSICAL_V_JACOBI.json", ("7pt", (16, 16, 16)), 14),
     ("PBICGSTAB_AGGREGATION_W_JACOBI.json", ("7pt", (16, 16, 16)), 6),
 ]
