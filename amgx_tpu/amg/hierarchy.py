@@ -888,6 +888,7 @@ class AMG(SolveDataOwner):
                               if self._ship_counted else None):
                 self._resolve_put_cache()
         self.num_levels = len(self.levels) + 1
+        self._swell_steps = self._count_swell_vreg_steps()
         self.setup_time = time.perf_counter() - t0
         if self.print_grid_stats:
             from ..output import amgx_printf
@@ -1112,6 +1113,40 @@ class AMG(SolveDataOwner):
                 and cs.name != "DENSE_LU_SOLVER":
             total += self.coarsest_sweeps * steps(cs)
         return total
+
+    _swell_steps = 0      # _count_swell_vreg_steps() of the last set-up
+
+    def _count_swell_vreg_steps(self) -> int:
+        """Vreg-steps of the SWELL gather one cycle is made of
+        (ops/pallas_swell.vreg_steps: over an operator's row groups,
+        the window chunks listed x the vregs of a group's tile): over
+        the levels, A's x (sweeps + the residual), P's and R's once, and
+        the coarsest operator's where a smoother stands in for a solve.
+        Read from the layouts' host copies as a set-up ends, so a solve
+        fetches nothing for it. (A V cycle's count, as
+        color_steps_per_cycle's.)"""
+        from ..matrix import host_mirror_asarray
+        from ..ops.pallas_swell import vreg_steps
+
+        def steps(M):
+            if getattr(M, "swell_nchunk", None) is None:
+                return 0
+            return vreg_steps(host_mirror_asarray(M.swell_nchunk),
+                              M.swell_cols.shape[2])
+        total = 0
+        for k, lv in enumerate(self.levels):
+            total += steps(lv.A) * (self._sweeps(k, True)
+                                    + self._sweeps(k, False) + 1)
+            total += steps(getattr(lv, "P", None))
+            total += steps(getattr(lv, "R", None))
+        cs = getattr(self, "coarse_solver", None)
+        if cs is not None and cs.is_smoother \
+                and cs.name != "DENSE_LU_SOLVER":
+            total += self.coarsest_sweeps * steps(self.coarsest_A)
+        return total
+
+    def swell_vreg_steps_per_cycle(self) -> int:
+        return self._swell_steps
 
     def geo_transfers_per_cycle(self):
         """(levels on the one-pass road, levels on the XLA road) of the

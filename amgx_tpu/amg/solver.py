@@ -63,6 +63,9 @@ class AlgebraicMultigridSolver(Solver):
     def geo_transfers_per_iteration(self):
         return self.amg.geo_transfers_per_cycle()
 
+    def swell_vreg_steps_per_iteration(self):
+        return self.amg.swell_vreg_steps_per_cycle()
+
     def solve_init(self, data, b, x, r):
         return self._guard_init()
 

@@ -305,11 +305,12 @@ class CsrMatrix:
     dia_vals: Optional[Array] = None   # (k, rows_pad, 128) tiled diagonals
     # windowed-ELL (SWELL) layout for unstructured matrices (the Pallas
     # gather kernel's storage, ops/pallas_swell.py): slot-major
-    # (nb, kpad, 128) blocks + per-block x-window starts/chunk counts
-    swell_cols: Optional[Array] = None   # (nb, kpad, 128) local columns
-    swell_vals: Optional[Array] = None   # (nb, kpad, 128)
+    # (nb, 8, kpad, 128) blocks + per-block x-window starts and each
+    # row group's list of the window chunks it has a column in
+    swell_cols: Optional[Array] = None   # (nb, 8, kpad, 128) local columns
+    swell_vals: Optional[Array] = None   # (nb, 8, kpad, 128)
     swell_c0row: Optional[Array] = None  # (nb,) window start, 128-rows
-    swell_nchunk: Optional[Array] = None  # (nb, 1 + words): span in chunks, slab mask
+    swell_nchunk: Optional[Array] = None  # (nb, 8, 1 + L): a group's count, its chunks
     swell_w128: int = 0                  # static window width, 128-chunks
     num_rows: int = 0
     num_cols: int = 0

@@ -443,14 +443,14 @@ def rap_plan_values_native(stage1, sr, st, starts2, n_u, a_val, p_val,
 def swell_build_native(ro, ci, vals, num_rows):
     """Native SWELL layout build (ops/pallas_swell.py layout contract).
     Returns (cols4, vals4, c0row, nchunk, w128) with cols4/vals4 shaped
-    (nb, 8, kpad, 128) and nchunk (nb, 1 + words) as
-    `pallas_swell.with_slab_mask` lays it out, None when the layout
+    (nb, 8, kpad, 128) and nchunk (nb, 8, 1 + L) as
+    `pallas_swell.pad_chunk_lists` lays it out, None when the layout
     does not pay (budget
     decisions delegated to ops/pallas_swell.swell_budget), or False
     when the native library is unavailable."""
     import numpy as np
-    from ..ops.pallas_swell import (BLOCK_ROWS, LANES, SUBS, mask_words,
-                                    swell_budget)
+    from ..ops.pallas_swell import (BLOCK_ROWS, LANES, SUBS,
+                                    pad_chunk_lists, swell_budget)
     L = lib()
     vals = np.asarray(vals)
     if L is None or vals.dtype not in (np.float32, np.float64):
@@ -463,11 +463,10 @@ def swell_build_native(ro, ci, vals, num_rows):
     ro = np.ascontiguousarray(ro, np.int32)
     ci = np.ascontiguousarray(ci, np.int32)
     c0row = np.empty(nb, np.int32)
-    nchunk = np.empty(nb, np.int32)
     kmax = ctypes.c_int32()
     w128_raw = win(ctypes.c_int32(n), ro.ctypes.data_as(i32p),
                    ci.ctypes.data_as(i32p), c0row.ctypes.data_as(i32p),
-                   nchunk.ctypes.data_as(i32p), ctypes.byref(kmax))
+                   ctypes.byref(kmax))
     # budget decisions live in ONE place (ops/pallas_swell.swell_budget)
     budget = swell_budget(int(kmax.value), w128_raw, nb, ci.shape[0])
     if budget is None:
@@ -487,17 +486,16 @@ def swell_build_native(ro, ci, vals, num_rows):
          ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
          vals.ctypes.data_as(fp), c0row.ctypes.data_as(i32p),
          cols4.ctypes.data_as(i32p), vals4.ctypes.data_as(fp))
-    nwords = mask_words(w128)
-    mask = np.zeros((nb, nwords), np.uint32)
-    L.amgx_swell_slabmask.restype = None
-    L.amgx_swell_slabmask(
+    counts = np.zeros(nb * SUBS, np.int32)
+    flat = np.empty(min(ci.shape[0], nb * SUBS * w128), np.int32)
+    L.amgx_swell_chunklists.restype = ctypes.c_int64
+    listed = L.amgx_swell_chunklists(
         ctypes.c_int32(n), ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
-        c0row.ctypes.data_as(i32p), ctypes.c_int32(nwords),
-        mask.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)))
+        c0row.ctypes.data_as(i32p), ctypes.c_int32(w128),
+        counts.ctypes.data_as(i32p), flat.ctypes.data_as(i32p))
     return (cols4.reshape(nb, SUBS, kpad, LANES),
             vals4.reshape(nb, SUBS, kpad, LANES), c0row,
-            np.concatenate([nchunk[:, None], mask.view(np.int32)], axis=1),
-            w128)
+            pad_chunk_lists(counts, flat[:listed], nb), w128)
 
 
 def swell_refill_native(ro, vals, num_rows, kpad):

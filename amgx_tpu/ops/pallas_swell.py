@@ -17,25 +17,36 @@ kernel builds an SpMV out of that primitive:
 - per block, the x window is DMA'd HBM->VMEM (double-buffered, like the
   DIA kernel) as (W/128, 128) chunks;
 - entry slots are stored slot-major as (8, kpad, 128): sublane group =
-  row-group, sublane = ELL slot, lane = row-in-group. Viewed as
-  (8*kpad, 128), the gather decomposes per 128-wide window chunk c:
+  row-group, sublane = ELL slot, lane = row-in-group. On a group's
+  (kpad, 128) tile the gather decomposes per 128-wide window chunk c:
   take_along_axis(chunk broadcast, lo, axis=1) selected where the local
   column's hi bits == c;
-- a fori_loop runs over the block's own column span (nchunk_b chunks,
-  from SMEM), 8 chunks to a slab, and skips the slabs in which the
-  block has no entry (a bit a slab, beside nchunk_b in SMEM: a block of
-  a coarse operator on a 3-D grid touches a few bands of its span, its
-  own z-plane's and its neighbours', each some ten chunks wide, so the
-  skip keeps about half of the span where a chunk-by-chunk one would
-  keep a third; but a bit a chunk, tested inside the slab, read 18%
-  SLOWER on the chip than the slab form, the eight branches costing
-  more than the chunks they save: PR 47), then y = sum over slots of
-  acc * vals.
+- the gather runs ROW GROUP by row group, over that group's own list
+  of window chunks: beside each block the layout keeps, for each of
+  its 8 row groups, a count and the ascending list of the chunks
+  (local to the block's c0) in which the group has a column
+  (`swell_nchunk`, (nb, 8, 1 + L); `group_chunk_lists`). A block of a
+  coarse operator on a 3-D grid touches a few bands of its span, its
+  own z-plane's and its neighbours', and a group of 128 rows a sixth to
+  a quarter of the chunks its block does, so the lists hold under a
+  fifth of what a loop over the block's chunks against the whole
+  (8*kpad, 128) tile visited (PR 48; the forms before it, a loop over
+  the span and a bit an 8-chunk slab, are PR 47's). A block's lists
+  ride the pipeline into SMEM with its entry slabs; the loop reads a
+  chunk index from there, UNROLL list entries an iteration and no
+  branch (a list is padded to L by repeats of its last chunk: the
+  select is idempotent), then y[g] = sum over slots of acc * vals.
+  GROUPS row groups share a loop, as long as the longest of their
+  lists: one group's chain of scalar read, row load, gather and select
+  left some 80 ns of latency an iteration bare, two fill each other's
+  (on the chip 6.2-6.6 ns a vreg-step for 7.5-8.1 at kpad 32: PR 48).
 
 Traffic per block: 8*kpad*128 values + cols (the ELL-padded minimum)
-plus a W-element window of x. Compute is ~3 VPU ops per (8*kpad, 128)
-tile per chunk — compute-bound relative to HBM, but 50-500x faster than
-the XLA gather form it replaces. float32 only (like the DIA kernel);
+plus a W-element window of x and the block's lists. Compute is ~3 VPU
+ops per (kpad, 128) group tile per listed chunk: ceil(kpad / 8) vregs,
+a "vreg-step" each (`vreg_steps` counts them: the kernel's clock) —
+compute-bound relative to HBM, but 50-500x faster than the XLA gather
+form it replaces. float32 only (like the DIA kernel);
 the XLA gather form below covers f64/CPU/batched callers.
 """
 from __future__ import annotations
@@ -57,38 +68,63 @@ SWELL_MAX_W = 512 * 1024      # max window elements (2 MB f32 a buffer)
 SWELL_MAX_K = 256             # max padded slots per row
 
 
-def mask_words(w128: int) -> int:
-    """int32 words of a block's slab mask: a bit an 8-chunk slab."""
-    return -(-int(w128) // (8 * 32))
+UNROLL = 8                    # list entries of a group a loop iteration
+GROUPS = 2                    # row groups that share a loop
 
 
-def with_slab_mask(nchunk, ci, row_block, c0, w128):
-    """(nb, 1 + words) int32: column 0 the block's chunk count, then
-    its slab mask, bit (s % 32) of word s // 32 set where slab s of the
-    block's window holds a column of the block (numpy form of
-    native amgx_swell_slabmask). `row_block` is each entry's block,
-    `c0` each block's first window column."""
-    nb = nchunk.shape[0]
-    slab = (ci.astype(np.int64) - c0[row_block]) // (8 * LANES)
-    mask = np.zeros((nb, mask_words(w128)), np.uint32)
-    np.bitwise_or.at(mask, (row_block, slab // 32),
-                     np.uint32(1) << (slab % 32).astype(np.uint32))
-    return np.concatenate([nchunk[:, None].astype(np.int32),
-                           mask.view(np.int32)], axis=1)
+def pad_chunk_lists(counts, flat, nb):
+    """(nb, 8, 1 + L) int32 from the groups' distinct-chunk counts
+    (nb * 8,) and their chunks back to back, ascending a group: column
+    0 the count, then the list, padded to L (the longest, rounded up to
+    whole iterations of UNROLL) by repeats of the group's last chunk;
+    an empty group's row is zeros."""
+    counts = counts.astype(np.int64)
+    L = max(UNROLL, -(-int(counts.max(initial=0)) // UNROLL) * UNROLL)
+    start = np.cumsum(counts) - counts
+    gid = np.repeat(np.arange(nb * SUBS), counts)
+    out = np.zeros((nb * SUBS, 1 + L), np.int32)
+    out[gid, 1 + np.arange(flat.shape[0]) - start[gid]] = flat
+    # a list ascends from >= 0, so its running maximum repeats its last
+    # chunk through the padding
+    np.maximum.accumulate(out[:, 1:], axis=1, out=out[:, 1:])
+    out[:, 0] = counts
+    return out.reshape(nb, SUBS, 1 + L)
+
+
+def group_chunk_lists(ci, row_ids, c0, nb, w128):
+    """`swell_nchunk` from the pattern (numpy form of native
+    amgx_swell_chunklists): for each row group of 128 rows the distinct
+    128-column chunks of its block's window its entries fall in.
+    `row_ids` is each entry's row, `c0` each block's first window
+    column."""
+    gid = row_ids // LANES
+    chunk = (ci.astype(np.int64) - c0[row_ids // BLOCK_ROWS]) // LANES
+    key = np.unique(gid * np.int64(w128) + chunk)
+    return pad_chunk_lists(
+        np.bincount(key // w128, minlength=nb * SUBS),
+        (key % w128).astype(np.int32), nb)
+
+
+def vreg_steps(nchunk, kpad) -> int:
+    """Vreg-steps one application of a layout costs: over its row
+    groups, the distinct chunks listed x the (8, 128) vregs of a
+    group's (kpad, 128) tile. The padding repeats are not counted: the
+    number is the pattern's, not the unroll's."""
+    return int(np.asarray(nchunk)[:, :, 0].sum(dtype=np.int64)) \
+        * -(-int(kpad) // SUBS)
 
 
 def swell_budget(kmax, w128_raw, nb, nnz):
     """Single source of the SWELL layout-budget decisions, shared by the
     numpy builder below and the native-wrapper path
-    (native/__init__.py swell_build_native) — the two drifted once and
-    an un-rounded w128 lets the kernel's slab loop read past the VMEM
-    window. Returns (kpad, w128) or None when the layout does not pay:
+    (native/__init__.py swell_build_native) — the two drifted once.
+    Returns (kpad, w128) or None when the layout does not pay:
     - kpad: exact for short rows (interpolation operators, kmax 4-5,
       where round-to-8 inflated HBM and wire bytes ~2x), 8-aligned
       above (Mosaic relayouts large unaligned slot dims through
       scoped-VMEM copies);
-    - w128: rounded to whole 8-chunk slabs (kernel slab loop + aligned
-      VMEM scratch);
+    - w128: rounded to 8 chunks (the window's VMEM scratch and its
+      DMA stay on whole (8, 128) tiles);
     - fill guard: one long row would otherwise inflate the padded
       layout to n*kpad slots; small layouts are exempt (round-to-8
       alone inflates tiny matrices past any ratio, and a <1M-slot
@@ -111,8 +147,9 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     Returns (cols4, vals4, c0row, nchunk, w128) or None when the layout
     does not pay (window or slot budget exceeded). cols4/vals4 are
     (nb, 8, kpad, 128) slot-major super-blocks; c0row is each block's
-    window start in 128-rows of the padded x; nchunk (nb, 1 + words)
-    its span in chunks and its slab mask (`with_slab_mask`).
+    window start in 128-rows of the padded x; nchunk (nb, 8, 1 + L)
+    each row group's count and list of window chunks
+    (`group_chunk_lists`).
     """
     n = int(num_rows)
     if n == 0 or ci.shape[0] == 0:
@@ -149,8 +186,6 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     if budget is None:
         return None
     kpad, _w128 = budget
-    w = _w128 * LANES
-    nchunk = (-(-span // LANES)).astype(np.int32)
     # scatter entries into (nb, 8, kpad, 128) slot-major blocks
     row_ids = np.repeat(np.arange(n, dtype=np.int64), row_nnz)
     slot = np.arange(ci.shape[0], dtype=np.int64) - \
@@ -166,7 +201,7 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     return (cols4.reshape(nb, SUBS, kpad, LANES),
             vals4.reshape(nb, SUBS, kpad, LANES),
             (c0 // LANES).astype(np.int32),
-            with_slab_mask(nchunk, ci, b, c0, _w128), w // LANES)
+            group_chunk_lists(ci, row_ids, c0, nb, _w128), _w128)
 
 
 def swell_vals_host(ro, vals, num_rows, kpad):
@@ -204,7 +239,10 @@ def _swell_budget_ok(A, val_itemsize: int, out_blocks: int) -> bool:
     the double-buffered cols(int32)+vals entry slabs (`val_itemsize`
     narrows for bf16 values), and `out_blocks` double-buffered
     (SUBS, 128) pipeline blocks (1 = SpMV's y; 4 = the fused sweep's
-    x/b/dinv/out)."""
+    x/b/dinv/out). The row groups' chunk lists are SMEM, not VMEM: a
+    list is at most the window, so a block's are 8 x (1 + w128) words,
+    double-buffered 256 KB at SWELL_MAX_W, which the chip's compiler
+    takes (tests/test_chip_compile.py) and nothing here counts."""
     w128 = A.swell_w128
     kpad = A.swell_vals.shape[2]
     win_bytes = 2 * w128 * LANES * 4
@@ -223,80 +261,105 @@ def swell_spmv_supported(A, x_dtype) -> bool:
     return _swell_budget_ok(A, 4, 1)
 
 
-def _gather_window(nch_ref, stride, b, xbuf, slot, hi, lo, rows):
-    """acc[r, l] = the window's value at the local column (hi, lo) of
-    entry (r, l): the loop over the block's chunks, shared by the SpMV
-    and the fused sweep. `nch_ref` holds, per block, `stride` words:
-    the chunk count, then (stride > 1) the slab mask."""
+def _window(c0_ref, xp_ref, xbuf, sems, w128, n_blocks):
+    """This block's x window, double-buffered: start the next block's
+    DMA, wait for this one's; returns the buffer slot it landed in."""
+    b = pl.program_id(0)
+    slot = jax.lax.rem(b, jnp.int32(2))
 
-    def slab(s, acc):
-        # 8 window chunks per loop iteration: the fori overhead was
-        # a measured ~40% of kernel time on wide-window operators
-        # (AMG restriction matrices reach nchunk ~500); w128 is
-        # 8-aligned by the builders so the last slab stays in range
-        base = s * jnp.int32(8)
-        for j in range(8):
-            c = base + jnp.int32(j)
-            chunk = xbuf[slot, pl.ds(c, 1)]   # (1, 128)
-            src = jnp.broadcast_to(chunk, (rows, LANES))
-            g = jnp.take_along_axis(src, lo, axis=1)
-            acc = jnp.where(hi == c, g, acc)
-        return acc
+    def dma(s, blk):
+        return pltpu.make_async_copy(
+            xp_ref.at[pl.ds(c0_ref[blk], w128)],
+            xbuf.at[jnp.int32(s)], sems.at[jnp.int32(s)])
 
-    def slab_step(s, acc):
-        if stride == 1:
-            return slab(s, acc)
-        word = nch_ref[b * jnp.int32(stride) + jnp.int32(1)
-                       + jax.lax.shift_right_logical(s, jnp.int32(5))]
-        bit = jax.lax.bitwise_and(
-            jax.lax.shift_right_logical(
-                word, jax.lax.bitwise_and(s, jnp.int32(31))), jnp.int32(1))
-        return jax.lax.cond(bit == jnp.int32(1), lambda a: slab(s, a),
-                            lambda a: a, acc)
+    @pl.when(b == 0)
+    def _():
+        dma(0, 0).start()
 
-    nslab = jax.lax.div(nch_ref[b * jnp.int32(stride)] + jnp.int32(7),
-                        jnp.int32(8))
-    return jax.lax.fori_loop(jnp.int32(0), nslab, slab_step,
-                             jnp.zeros((rows, LANES), jnp.float32))
+    @pl.when(b + 1 < n_blocks)
+    def _():
+        dma(jax.lax.rem(b + 1, jnp.int32(2)), b + 1).start()
+
+    dma(slot, b).wait()
+    return slot
 
 
-def _nchunk_words(nchunk):
-    """(the per-block words flat for SMEM, words a block)."""
-    stride = 1 if nchunk.ndim == 1 else nchunk.shape[1]
-    return nchunk.reshape(-1), stride
+def _gather_window(lst_ref, groups, xbuf, slot, cols):
+    """acc[k, l] = the window's value at the local column cols[k, l],
+    for the (kpad, 128) tile of each row group of `groups`: the loop
+    over THOSE groups' chunk lists (`lst_ref[0, g]`: a count, then the
+    chunks), shared by the SpMV and the fused sweep. The groups share
+    one loop, as long as the longest of their lists, so that one's
+    chain of scalar read, row load, gather and select fills the waits
+    of the other's; UNROLL list entries of each an iteration. The
+    builders pad every list to L by repeats of its last chunk, and a
+    chunk selected twice selects the same values, so the shorter list
+    and the last iteration need no test."""
+    hi = [jax.lax.shift_right_logical(c, jnp.int32(7)) for c in cols]
+    lo = [jax.lax.bitwise_and(c, jnp.int32(LANES - 1)) for c in cols]
+
+    def step(i, accs):
+        accs = list(accs)
+        base = i * jnp.int32(UNROLL) + jnp.int32(1)
+        for j in range(UNROLL):
+            for k, g in enumerate(groups):
+                c = lst_ref[0, g, base + jnp.int32(j)]
+                chunk = xbuf[slot, pl.ds(c, 1)]   # (1, 128)
+                src = jnp.broadcast_to(chunk, cols[k].shape)
+                accs[k] = jnp.where(
+                    hi[k] == c, jnp.take_along_axis(src, lo[k], axis=1),
+                    accs[k])
+        return tuple(accs)
+
+    longest = functools.reduce(
+        jnp.maximum, [lst_ref[0, g, 0] for g in groups])
+    steps = jax.lax.div(longest + jnp.int32(UNROLL - 1),
+                        jnp.int32(UNROLL))
+    return jax.lax.fori_loop(
+        jnp.int32(0), steps, step,
+        tuple(jnp.zeros(c.shape, jnp.float32) for c in cols))
 
 
-def _swell_kernel(w128, kpad, n_blocks, stride):
-    rows = SUBS * kpad
+def _block_product(lst_ref, xbuf, slot, cols_ref, vals_ref):
+    """The block's (8, 128) product, GROUPS row groups at a time. The
+    groups are walked by a loop and not unrolled: the body is traced
+    and lowered once (unrolled over all 8 it cost every process 0.2 s
+    a kernel of Python lowering, 11 s of a classical cell's set-up on
+    the chip's host: PR 48)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (SUBS, LANES), 0)
 
-    def kernel(c0_ref, nch_ref, xp_ref, cols_ref, vals_ref, y_ref,
+    def some(p, y):
+        groups = [p * jnp.int32(GROUPS) + jnp.int32(k)
+                  for k in range(GROUPS)]
+        accs = _gather_window(lst_ref, groups, xbuf, slot,
+                              [cols_ref[0, g] for g in groups])
+        for g, acc in zip(groups, accs):
+            y = jnp.where(row == g, jnp.sum(acc * vals_ref[0, g], axis=0,
+                                            keepdims=True), y)
+        return y
+
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(SUBS // GROUPS), some,
+                             jnp.zeros((SUBS, LANES), jnp.float32))
+
+
+def _entry_specs(kpad, lists_shape):
+    """in_specs of a block's chunk lists (SMEM) and its two entry slabs
+    (VMEM), all three riding the pipeline block by block."""
+    def blocked(shape, space):
+        return pl.BlockSpec(
+            (1,) + tuple(shape[1:]),
+            lambda b: (b,) + (jnp.int32(0),) * (len(shape) - 1),
+            memory_space=space)
+    slab = blocked((1, SUBS, kpad, LANES), pltpu.VMEM)
+    return [blocked(lists_shape, pltpu.SMEM), slab, slab]
+
+
+def _swell_kernel(w128, n_blocks):
+    def kernel(c0_ref, xp_ref, lst_ref, cols_ref, vals_ref, y_ref,
                xbuf, sems):
-        b = pl.program_id(0)
-        slot = jax.lax.rem(b, jnp.int32(2))
-
-        def dma(s, blk):
-            return pltpu.make_async_copy(
-                xp_ref.at[pl.ds(c0_ref[blk], w128)],
-                xbuf.at[jnp.int32(s)], sems.at[jnp.int32(s)])
-
-        @pl.when(b == 0)
-        def _():
-            dma(0, 0).start()
-
-        @pl.when(b + 1 < n_blocks)
-        def _():
-            dma(jax.lax.rem(b + 1, jnp.int32(2)), b + 1).start()
-
-        dma(slot, b).wait()
-
-        cols = cols_ref[0].reshape(rows, LANES)   # slot-major local cols
-        vals = vals_ref[0].reshape(rows, LANES)
-        hi = jax.lax.shift_right_logical(cols, jnp.int32(7))
-        lo = jax.lax.bitwise_and(cols, jnp.int32(LANES - 1))
-
-        acc = _gather_window(nch_ref, stride, b, xbuf, slot, hi, lo, rows)
-        y_ref[...] = jnp.sum(
-            (acc * vals).reshape(SUBS, kpad, LANES), axis=1)
+        slot = _window(c0_ref, xp_ref, xbuf, sems, w128, n_blocks)
+        y_ref[...] = _block_product(lst_ref, xbuf, slot, cols_ref,
+                                    vals_ref)
 
     return kernel
 
@@ -314,8 +377,7 @@ def _swell_spmv_call(cols4, vals4, c0row, nchunk, x, w128, num_rows,
     xp = jax.lax.dynamic_update_slice(xp, x.astype(jnp.float32), (0,))
     xp = xp.reshape(xp_rows, LANES)
 
-    nchunk, stride = _nchunk_words(nchunk)
-    kernel = _swell_kernel(w128, kpad, nb, stride)
+    kernel = _swell_kernel(w128, nb)
     y2 = kernel_call(
         kernel,
         grid=(nb,),
@@ -325,18 +387,8 @@ def _swell_spmv_call(cols4, vals4, c0row, nchunk, x, w128, num_rows,
             # x64 default, which Mosaic cannot legalize
             pl.BlockSpec((nb,), lambda b: (jnp.int32(0),),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((nb * stride,), lambda b: (jnp.int32(0),),
-                         memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, SUBS, kpad, LANES),
-                         lambda b: (b, jnp.int32(0), jnp.int32(0),
-                                    jnp.int32(0)),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, SUBS, kpad, LANES),
-                         lambda b: (b, jnp.int32(0), jnp.int32(0),
-                                    jnp.int32(0)),
-                         memory_space=pltpu.VMEM),
-        ],
+        ] + _entry_specs(kpad, nchunk.shape),
         out_specs=pl.BlockSpec((SUBS, LANES),
                                lambda b: (b, jnp.int32(0)),
                                memory_space=pltpu.VMEM),
@@ -351,7 +403,7 @@ def _swell_spmv_call(cols4, vals4, c0row, nchunk, x, w128, num_rows,
             transcendentals=0,
         ),
         interpret=interpret,
-    )(c0row, nchunk, xp, cols4, vals4)
+    )(c0row, xp, nchunk, cols4, vals4)
     y = y2.reshape(-1)
     if y.shape[0] != n:
         y = y[:n]
@@ -401,44 +453,17 @@ def swell_smooth_supported(A, x_dtype) -> bool:
     return _swell_budget_ok(A, dt.itemsize, 4)
 
 
-def _swell_smooth_kernel(w128, kpad, n_blocks, has_dinv, stride):
-    rows = SUBS * kpad
-
+def _swell_smooth_kernel(w128, n_blocks, has_dinv):
     def kernel(*refs):
-        # refs: c0, nch, tau, xp, cols, vals, xblk, bblk, [dinvblk],
+        # refs: c0, tau, xp, lists, cols, vals, xblk, bblk, [dinvblk],
         #       out, xbuf, sems
-        (c0_ref, nch_ref, tau_ref, xp_ref, cols_ref, vals_ref,
+        (c0_ref, tau_ref, xp_ref, lst_ref, cols_ref, vals_ref,
          xb_ref, bb_ref) = refs[:8]
         db_ref = refs[8] if has_dinv else None
-        out_ref = refs[8 + (1 if has_dinv else 0)]
-        xbuf = refs[9 + (1 if has_dinv else 0)]
-        sems = refs[10 + (1 if has_dinv else 0)]
+        out_ref, xbuf, sems = refs[8 + (1 if has_dinv else 0):]
 
-        b = pl.program_id(0)
-        slot = jax.lax.rem(b, jnp.int32(2))
-
-        def dma(s, blk):
-            return pltpu.make_async_copy(
-                xp_ref.at[pl.ds(c0_ref[blk], w128)],
-                xbuf.at[jnp.int32(s)], sems.at[jnp.int32(s)])
-
-        @pl.when(b == 0)
-        def _():
-            dma(0, 0).start()
-
-        @pl.when(b + 1 < n_blocks)
-        def _():
-            dma(jax.lax.rem(b + 1, jnp.int32(2)), b + 1).start()
-
-        dma(slot, b).wait()
-
-        cols = cols_ref[0].reshape(rows, LANES)
-        vals = vals_ref[0].reshape(rows, LANES)
-        hi = jax.lax.shift_right_logical(cols, jnp.int32(7))
-        lo = jax.lax.bitwise_and(cols, jnp.int32(LANES - 1))
-
-        acc = _gather_window(nch_ref, stride, b, xbuf, slot, hi, lo, rows)
-        y = jnp.sum((acc * vals).reshape(SUBS, kpad, LANES), axis=1)
+        slot = _window(c0_ref, xp_ref, xbuf, sems, w128, n_blocks)
+        y = _block_product(lst_ref, xbuf, slot, cols_ref, vals_ref)
         corr = tau_ref[0] * (bb_ref[...] - y)
         if has_dinv:
             corr = corr * db_ref[...]
@@ -467,32 +492,22 @@ def _swell_smooth_call(cols4, vals4, c0row, nchunk, x, b, dinv, tau,
 
     blk = pl.BlockSpec((SUBS, LANES), lambda i: (i, jnp.int32(0)),
                        memory_space=pltpu.VMEM)
-    nchunk, stride = _nchunk_words(nchunk)
     in_specs = [
         pl.BlockSpec((nb,), lambda i: (jnp.int32(0),),
-                     memory_space=pltpu.SMEM),
-        pl.BlockSpec((nb * stride,), lambda i: (jnp.int32(0),),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec((1,), lambda i: (jnp.int32(0),),
                      memory_space=pltpu.SMEM),
         pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec((1, SUBS, kpad, LANES),
-                     lambda i: (i, jnp.int32(0), jnp.int32(0),
-                                jnp.int32(0)),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, SUBS, kpad, LANES),
-                     lambda i: (i, jnp.int32(0), jnp.int32(0),
-                                jnp.int32(0)),
-                     memory_space=pltpu.VMEM),
+    ] + _entry_specs(kpad, nchunk.shape) + [
         blk,            # x block
         blk,            # b block
     ]
-    operands = [c0row, nchunk, jnp.reshape(tau, (1,)).astype(jnp.float32),
-                xp, cols4, vals4, rowpad(x), rowpad(b)]
+    operands = [c0row, jnp.reshape(tau, (1,)).astype(jnp.float32), xp,
+                nchunk, cols4, vals4, rowpad(x), rowpad(b)]
     if has_dinv:
         in_specs.append(blk)
         operands.append(rowpad(dinv))
-    kernel = _swell_smooth_kernel(w128, kpad, nb, has_dinv, stride)
+    kernel = _swell_smooth_kernel(w128, nb, has_dinv)
     y2 = kernel_call(
         kernel,
         grid=(nb,),

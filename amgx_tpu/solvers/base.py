@@ -552,6 +552,14 @@ class Solver(SolveDataOwner):
         pc = self.preconditioner
         return 0 if pc is None else pc.color_steps_per_iteration()
 
+    def swell_vreg_steps_per_iteration(self) -> int:
+        """Vreg-steps of the SWELL gather one iteration's cycle is made
+        of (the preconditioner's: AMG.swell_vreg_steps_per_cycle); 0
+        where the tree has no multigrid cycle. Kept by the hierarchy as
+        its set-up ends, so it follows a resetup whose program is kept."""
+        pc = self.preconditioner
+        return 0 if pc is None else pc.swell_vreg_steps_per_iteration()
+
     def geo_transfers_per_iteration(self):
         """(one-pass, XLA) GEO levels one iteration's cycle runs its
         transfers through (the preconditioner's), or None where the
@@ -1076,16 +1084,19 @@ class Solver(SolveDataOwner):
                 # shell's inner count where it keeps one, else its own
                 _tm.inc("smoother.color_steps", self._color_steps * int(
                     round((extras or {}).get("inner_iters", iters_i))))
+            # the cycles a solve ran: FGMRES's Arnoldi steps (its
+            # own or summed by the shell round it), else the
+            # inner count where one is kept, else this solver's
+            ex = extras or {}
+            cycles = int(round(ex.get(
+                "arnoldi_steps", ex.get("inner_iters", iters_i))))
             if self._geo_transfers is not None:
-                # the cycles a solve ran: FGMRES's Arnoldi steps (its
-                # own or summed by the shell round it), else the
-                # inner count where one is kept, else this solver's
-                ex = extras or {}
-                cycles = int(round(ex.get(
-                    "arnoldi_steps", ex.get("inner_iters", iters_i))))
                 for road, levels in zip(("onepass", "xla"),
                                         self._geo_transfers):
                     _tm.inc(f"amg.geo_transfer.{road}", cycles * levels)
+            swell_steps = self.swell_vreg_steps_per_iteration()
+            if swell_steps:
+                _tm.inc("swell.vreg_steps", cycles * swell_steps)
             for name, value in (extras or {}).items():
                 # an extra stat that a counter is named after (GMRES /
                 # FGMRES's account of a solve, its own or summed by

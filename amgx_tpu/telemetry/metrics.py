@@ -723,6 +723,17 @@ declare_counter("amg.geo_transfer.xla",
                 "extents off the kernels' grid, a CPU), raised "
                 "likewise")
 
+declare_counter("swell.vreg_steps",
+                "vreg-steps of the SWELL gather kernels "
+                "(ops/pallas_swell.py: one (8, 128) vreg through "
+                "gather-select once), raised after each solve by the "
+                "cycles that ran x the hierarchy's static steps a cycle "
+                "(over the SWELL operators a cycle applies: applications "
+                "x row groups' listed chunks x ceil(kpad / 8); the "
+                "lists' padding repeats are not counted), kept from the "
+                "host layouts as a set-up ends; 0 where no operator has "
+                "the layout")
+
 # jit retraces per solver entry point: a retrace in steady-state serving
 # is a latency cliff (first-request trace cost paid again)
 declare_counter("solver.retrace.solve",

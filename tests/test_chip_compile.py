@@ -409,22 +409,25 @@ def test_geo_cycle_on_the_chips_branch_has_no_xla_transfer(grid, onepass,
         assert moved and set(moved) == {"gather"}
 
 
-@pytest.mark.parametrize("words", [0, 1, 6])
+@pytest.mark.parametrize("w128,kpad,longest", [(96, 21, 40),
+                                               (1488, 112, 120),
+                                               (4096, 256, 4096)])
 @pytest.mark.parametrize("kernel", ["spmv", "smooth"])
-def test_swell_kernels_compile(kernel, words, one_chip, on_tpu,
-                               no_persistent_cache):
-    """With the slab mask (PR 47: `words` int32 words a block beside
-    its chunk count, a bit a slab, the empty slabs skipped under a
-    `lax.cond` in the chunk loop) and without (a layout from before
-    it: the count alone)."""
-    nb, kpad = 32, 24
-    w128 = 96 if words < 2 else 1480 + 8
-    assert words == 0 or words == sw.mask_words(w128)
+def test_swell_kernels_compile(kernel, w128, kpad, longest, one_chip,
+                               on_tpu, no_persistent_cache):
+    """With the row groups' chunk lists (PR 48: a blocked SMEM operand,
+    (nb, 8, 1 + L) int32, a block's 8 lists a grid step) at a slot
+    count off the tiling under a narrow window (cell 9's L0.R: `kpad`
+    21), 14 vregs deep under a window of 1,488 chunks (its L2.A:
+    `kpad` 112, lists up to 114 chunks), and at the largest window,
+    slot count and list `swell_budget` lets through (a list is at most
+    the window: 2 x 8 x 4,097 words of SMEM, which no gate counts)."""
+    nb = 32
     n = nb * sw.BLOCK_ROWS
     ent = ((nb, sw.SUBS, kpad, 128), jnp.int32)
     val = ((nb, sw.SUBS, kpad, 128), F32)
     blk = ((nb,), jnp.int32)
-    nch = ((nb, 1 + words), jnp.int32) if words else blk
+    nch = ((nb, sw.SUBS, 1 + longest), jnp.int32)
     vec = ((n,), F32)
     if kernel == "spmv":
         _compile(lambda c, v, c0, nc, x: sw._swell_spmv_call(

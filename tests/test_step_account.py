@@ -156,9 +156,12 @@ PARENT_SOLVE_COUNTERS = {
         "amg.geo_transfer.xla", "krylov.arnoldi_steps", "krylov.basis_rows",
         "solve.stage_s.prepare", "solve.stage_s.readback",
         "solve.stage_s.report", "solve.stage_s.run", "solve_data.reuse"},
+    # PR 48: a cycle over SWELL operators counts its vreg-steps, a
+    # product of two numbers the hierarchy and the solve already hold
     "classical": {
         "solve.stage_s.prepare", "solve.stage_s.readback",
-        "solve.stage_s.report", "solve.stage_s.run", "solve_data.reuse"},
+        "solve.stage_s.report", "solve.stage_s.run", "solve_data.reuse",
+        "swell.vreg_steps"},
 }
 PARENT_SOLVE_COUNTERS["flagship-reuse-p7-256"] = \
     PARENT_SOLVE_COUNTERS["flagship-p7-128"]
