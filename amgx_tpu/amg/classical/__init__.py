@@ -97,10 +97,12 @@ class ClassicalAMGLevel(AMGLevel):
             P = interp.generate(self.A, self.cf_map, self.strong)
         ell = "auto" if device_setup_forced() or host_resident(
             P.row_offsets, P.col_indices, P.values) else "never"
-        with trace_region(f"amg.L{k}.layoutP"):
-            self.P = P.init(ell=ell)
-        with trace_region(f"amg.L{k}.transposeR"):
-            self.R = transpose(self.P).init(ell=ell)
+        from ..hierarchy import laid_out
+        with trace_region(f"amg.L{k}.layoutP", args=(why := {})):
+            self.P = laid_out(P, why, lambda M: M.init(ell=ell))
+        with trace_region(f"amg.L{k}.transposeR", args=(why := {})):
+            self.R = laid_out(transpose(self.P), why,
+                              lambda M: M.init(ell=ell))
         # weighted transfer slabs for the fused cycle kernels: built at
         # SETUP (inside the accounted span) so the first solve pays no
         # slab assembly and the host-ship pipeline can prefetch them

@@ -41,6 +41,22 @@ def _record_route(route: str, A, **why):
     spans.mark("resetup.route", args={"route": route})
 
 
+def laid_out(M, why: dict, build=None):
+    """`M` with its SpMV layout (`build(M)`; a coarse operator's
+    `build_spmv_layout()` / `init()` by default); where the SWELL
+    budget said no on the way (ops/pallas_swell.swell_budget), the
+    reason goes into `why["declined"]`, the layout span's arg."""
+    from ..ops.pallas_swell import collect_declines
+    if build is None:
+        def build(M):
+            return M.build_spmv_layout() if M.initialized else M.init()
+    with collect_declines() as said:
+        out = build(M)
+    if said:
+        why["declined"] = ",".join(said)
+    return out
+
+
 class AMGLevel:
     """One hierarchy level: fine matrix + transfer operators + smoother.
 
@@ -667,9 +683,9 @@ class AMG(SolveDataOwner):
                         self._attach_level_smoother(level)
                     self._prefetch_level(level)
                     with trace_region(f"amg.L{lvl}.layout",
-                                      counter="amg.resetup.layout_s"):
-                        Af = (Ac.build_spmv_layout() if Ac.initialized
-                              else Ac.init())
+                                      counter="amg.resetup.layout_s",
+                                      args=(why := {})):
+                        Af = laid_out(Ac, why)
                 lvl += 1
             return Af, lvl
 
@@ -749,9 +765,9 @@ class AMG(SolveDataOwner):
                 if not self._defer_smoothers:
                     self._attach_level_smoother(level)
                 self._prefetch_level(level)
-                with trace_region(f"amg.L{lvl}.layout"):
-                    Af = (Ac.build_spmv_layout() if Ac.initialized
-                          else Ac.init())
+                with trace_region(f"amg.L{lvl}.layout",
+                                  args=(why := {})):
+                    Af = laid_out(Ac, why)
             lvl += 1
         self.coarsest_A = Af
 
@@ -889,6 +905,7 @@ class AMG(SolveDataOwner):
                 self._resolve_put_cache()
         self.num_levels = len(self.levels) + 1
         self._swell_steps = self._count_swell_vreg_steps()
+        self._csr_road_nnz = self._count_csr_road_nnz()
         self.setup_time = time.perf_counter() - t0
         if self.print_grid_stats:
             from ..output import amgx_printf
@@ -1129,24 +1146,51 @@ class AMG(SolveDataOwner):
         from ..ops.pallas_swell import vreg_steps
 
         def steps(M):
+            if getattr(M, "split", None) is not None:
+                return sum(steps(part) for part in M.split)
             if getattr(M, "swell_nchunk", None) is None:
                 return 0
             return vreg_steps(host_mirror_asarray(M.swell_nchunk),
                               M.swell_cols.shape[2])
-        total = 0
-        for k, lv in enumerate(self.levels):
-            total += steps(lv.A) * (self._sweeps(k, True)
-                                    + self._sweeps(k, False) + 1)
-            total += steps(getattr(lv, "P", None))
-            total += steps(getattr(lv, "R", None))
-        cs = getattr(self, "coarse_solver", None)
-        if cs is not None and cs.is_smoother \
-                and cs.name != "DENSE_LU_SOLVER":
-            total += self.coarsest_sweeps * steps(self.coarsest_A)
-        return total
+        return self._sum_over_cycle_operators(steps)
 
     def swell_vreg_steps_per_cycle(self) -> int:
         return self._swell_steps
+
+    _csr_road_nnz = 0     # _count_csr_road_nnz() of the last set-up
+
+    def _count_csr_road_nnz(self) -> int:
+        """Non-zeros one cycle sends down the XLA gather + segment-sum
+        road: over the levels, the non-zeros of every operator
+        application (A's x (sweeps + the residual), P's and R's once,
+        the coarsest operator's where a smoother stands in for a solve)
+        whose operator has no DIA / ELL / SWELL layout (`_layout_of`:
+        "csr"). Host metadata alone. (A V cycle's count, as
+        color_steps_per_cycle's.)"""
+        def nnz(M):
+            return int(M.nnz) if M is not None \
+                and self._layout_of(M) == "csr" else 0
+        return self._sum_over_cycle_operators(nnz)
+
+    def _sum_over_cycle_operators(self, cost) -> int:
+        """`cost(M)` summed over the operator applications one V cycle
+        makes: a level's A x (sweeps + the residual), its P and R once,
+        and the coarsest operator x `coarsest_sweeps` where a smoother
+        stands in for a solve."""
+        total = 0
+        for k, lv in enumerate(self.levels):
+            total += cost(lv.A) * (self._sweeps(k, True)
+                                   + self._sweeps(k, False) + 1)
+            total += cost(getattr(lv, "P", None))
+            total += cost(getattr(lv, "R", None))
+        cs = getattr(self, "coarse_solver", None)
+        if cs is not None and cs.is_smoother \
+                and cs.name != "DENSE_LU_SOLVER":
+            total += self.coarsest_sweeps * cost(self.coarsest_A)
+        return total
+
+    def csr_road_nnz_per_cycle(self) -> int:
+        return self._csr_road_nnz
 
     def geo_transfers_per_cycle(self):
         """(levels on the one-pass road, levels on the XLA road) of the
@@ -1189,6 +1233,8 @@ class AMG(SolveDataOwner):
             return "dia"
         if getattr(M, "swell_vals", None) is not None:
             return "swell"
+        if getattr(M, "split", None) is not None:
+            return "split"
         if getattr(M, "ell_vals", None) is not None:
             return "ell"
         return "csr"

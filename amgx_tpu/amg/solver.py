@@ -66,6 +66,9 @@ class AlgebraicMultigridSolver(Solver):
     def swell_vreg_steps_per_iteration(self):
         return self.amg.swell_vreg_steps_per_cycle()
 
+    def csr_road_nnz_per_iteration(self):
+        return self.amg.csr_road_nnz_per_cycle()
+
     def solve_init(self, data, b, x, r):
         return self._guard_init()
 

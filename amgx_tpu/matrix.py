@@ -280,7 +280,7 @@ def _np_row_reduce(op, data, ro, n, empty_val):
     data_fields=["row_offsets", "col_indices", "values", "diag",
                  "row_ids", "diag_idx", "ell_cols", "ell_vals", "dia_vals",
                  "swell_cols", "swell_vals", "swell_c0row", "swell_nchunk",
-                 "user_colors"],
+                 "split", "user_colors"],
     meta_fields=["num_rows", "num_cols", "block_dimx", "block_dimy",
                  "initialized", "dia_offsets", "swell_w128", "grid_shape",
                  "user_num_colors"],
@@ -312,6 +312,11 @@ class CsrMatrix:
     swell_c0row: Optional[Array] = None  # (nb,) window start, 128-rows
     swell_nchunk: Optional[Array] = None  # (nb, 8, 1 + L): a group's count, its chunks
     swell_w128: int = 0                  # static window width, 128-chunks
+    # row-split SWELL form of an operator whose rows are too uneven for
+    # one SWELL layout (ops/pallas_swell.split_rows_host): (A', S) with
+    # A = S A', each an spmv-only CsrMatrix in the SWELL layout; A'
+    # keeps its row offsets (a refill's map), S has no values to refill
+    split: Optional[tuple] = None
     num_rows: int = 0
     num_cols: int = 0
     block_dimx: int = 1
@@ -432,14 +437,22 @@ class CsrMatrix:
             _register_host_mirror(d, x)
             return d
 
+        def swell_up(m):
+            return dict(swell_cols=up(m.swell_cols),
+                        swell_vals=up(m.swell_vals),
+                        swell_c0row=up(m.swell_c0row),
+                        swell_nchunk=up(m.swell_nchunk))
+
         return dataclasses.replace(
             self, row_ids=up(host.row_ids), diag_idx=up(host.diag_idx),
             ell_cols=up(host.ell_cols), ell_vals=up(host.ell_vals),
             dia_offsets=host.dia_offsets, dia_vals=up(host.dia_vals),
-            swell_cols=up(host.swell_cols), swell_vals=up(host.swell_vals),
-            swell_c0row=up(host.swell_c0row),
-            swell_nchunk=up(host.swell_nchunk),
-            swell_w128=host.swell_w128, initialized=True)
+            swell_w128=host.swell_w128, **swell_up(host),
+            split=None if host.split is None else tuple(
+                dataclasses.replace(part, **swell_up(part),
+                                    row_offsets=up(part.row_offsets))
+                for part in host.split),
+            initialized=True)
 
     def _init_host(self, ell: str, ell_max_ratio: float) -> "CsrMatrix":
         """Numpy form of init() for host-resident scalar matrices — same
@@ -474,7 +487,8 @@ class CsrMatrix:
         n = self.num_rows
         out = dict(ell_cols=None, ell_vals=None, dia_offsets=None,
                    dia_vals=None, swell_cols=None, swell_vals=None,
-                   swell_c0row=None, swell_nchunk=None, swell_w128=0)
+                   swell_c0row=None, swell_nchunk=None, swell_w128=0,
+                   split=None)
         if n > 0 and self.nnz > 0 and not self.has_external_diag \
                 and ell == "auto":
             diffs = ci.astype(np.int64) - row_ids
@@ -515,6 +529,10 @@ class CsrMatrix:
                 (out["swell_cols"], out["swell_vals"], out["swell_c0row"],
                  out["swell_nchunk"], out["swell_w128"]) = sw
                 return out
+            if not self.has_external_diag:
+                out["split"] = self._split_host(ro, ci, vals)
+                if out["split"] is not None:
+                    return out
         if n > 0 and ell != "never" and self.nnz > 0:
             max_k = int(row_nnz.max()) if row_nnz.size else 0
             mean = max(float(self.nnz) / max(n, 1), 1e-30)
@@ -531,6 +549,27 @@ class CsrMatrix:
                 out["ell_cols"], out["ell_vals"] = \
                     ec.reshape(n, max_k), ev.reshape(n, max_k)
         return out
+
+    def _split_host(self, ro, ci, vals) -> "Optional[tuple]":
+        """(A', S) of the row-split SWELL form, as spmv-only matrices
+        (placeholders where the CSR payloads were: A' shares this
+        matrix's columns and values), or None where it does not fit."""
+        from .ops.pallas_swell import split_rows_host
+        parts = split_rows_host(ro, ci, vals, self.num_rows, self.num_cols)
+        if parts is None:
+            return None
+        (ro_p, lay_a), (ro_s, lay_s) = parts
+        dummy_i, dummy_v = _placeholder(jnp.int32), _placeholder(vals.dtype)
+
+        def part(row_offsets, lay, rows, cols):
+            return CsrMatrix(
+                row_offsets=row_offsets, col_indices=dummy_i,
+                values=dummy_v, swell_cols=lay[0], swell_vals=lay[1],
+                swell_c0row=lay[2], swell_nchunk=lay[3], swell_w128=lay[4],
+                num_rows=rows, num_cols=cols, initialized=True)
+        n_p = int(ro_p.shape[0]) - 1
+        return (part(ro_p, lay_a, n_p, self.num_cols),
+                part(ro_s, lay_s, self.num_rows, n_p))
 
     def _choose_layout(self, row_ids, row_nnz, ell: str,
                        ell_max_ratio: float):
@@ -560,7 +599,7 @@ class CsrMatrix:
         if not self.initialized:
             return self.init(ell=ell, ell_max_ratio=ell_max_ratio)
         if self.dia_vals is not None or self.ell_cols is not None \
-                or self.swell_cols is not None:
+                or self.swell_cols is not None or self.split is not None:
             return self
         if not self.is_block and host_resident(
                 self.row_offsets, self.col_indices, self.values,
@@ -709,14 +748,8 @@ class CsrMatrix:
             out = out._refill_dia(values)
         if self.initialized and self.swell_cols is not None:
             if host_resident(self.row_offsets, values):
-                from .ops.pallas_swell import swell_vals_host
-                with span("matrix.refill_host",
-                          counter="matrix.refill_host_s"):
-                    out = dataclasses.replace(
-                        out, swell_vals=swell_vals_host(
-                            np.asarray(self.row_offsets),
-                            np.asarray(values),
-                            self.num_rows, self.swell_cols.shape[2]))
+                out = dataclasses.replace(
+                    out, swell_vals=self._swell_vals_of(values))
             else:
                 # structure kept but values not re-scatterable off-host;
                 # drop the fast-path layout rather than serve stale data
@@ -725,7 +758,26 @@ class CsrMatrix:
                 out = dataclasses.replace(
                     out, swell_cols=None, swell_vals=None,
                     swell_c0row=None, swell_nchunk=None, swell_w128=0)
+        if self.initialized and self.split is not None:
+            Ap, S = self.split
+            if host_resident(Ap.row_offsets, values):
+                # A' holds the operator's values under its own offsets
+                out = dataclasses.replace(out, split=(dataclasses.replace(
+                    Ap, swell_vals=Ap._swell_vals_of(values)), S))
+            else:
+                _tm.inc("matrix.swell_layout_dropped")
+                _warn_swell_dropped()
+                out = dataclasses.replace(out, split=None)
         return out
+
+    def _swell_vals_of(self, values):
+        """New coefficients (host) packed into this SWELL layout's
+        slots, by this matrix's row offsets."""
+        from .ops.pallas_swell import swell_vals_host
+        with span("matrix.refill_host", counter="matrix.refill_host_s"):
+            return swell_vals_host(
+                np.asarray(self.row_offsets), np.asarray(values),
+                self.num_rows, self.swell_cols.shape[2])
 
     def _dia_shape(self):
         from .ops.pallas_spmv import LANES, dia_padded_rows
@@ -911,11 +963,14 @@ class CsrMatrix:
                 row_offsets=dummy_i, ell_cols=None, ell_vals=None,
                 swell_cols=None, swell_vals=None, swell_c0row=None,
                 swell_nchunk=None, swell_w128=0)
-        if self.swell_cols is not None:
+        if self.swell_cols is not None or self.split is not None:
             return dataclasses.replace(
                 self, values=dummy_v,
                 col_indices=dummy_i, row_ids=None, diag_idx=None,
-                row_offsets=dummy_i, ell_cols=None, ell_vals=None)
+                row_offsets=dummy_i, ell_cols=None, ell_vals=None,
+                split=None if self.split is None else tuple(
+                    dataclasses.replace(part, row_offsets=dummy_i)
+                    for part in self.split))
         if self.ell_cols is not None:
             return dataclasses.replace(
                 self, values=dummy_v,
@@ -935,7 +990,9 @@ class CsrMatrix:
         return dataclasses.replace(
             self, values=cast(self.values), diag=cast(self.diag),
             ell_vals=cast(self.ell_vals), dia_vals=cast(self.dia_vals),
-            swell_vals=cast(self.swell_vals))
+            swell_vals=cast(self.swell_vals),
+            split=None if self.split is None else tuple(
+                part.astype(dtype) for part in self.split))
 
     def coo(self):
         """Return (row_ids, col_indices, values) COO triplets. Computes

@@ -440,6 +440,43 @@ def rap_plan_values_native(stage1, sr, st, starts2, n_u, a_val, p_val,
     return out
 
 
+def rap_plan_stage_native(a_ro, a_ci, b_ro, b_ci, limit):
+    """One stage of a RapPlan's structure phase (src/rap_plan.cpp): the
+    candidates of A @ B from the two patterns, coalesced in the stable
+    (row, column) order, row by row. Returns (sa, sb, seg, urow):
+    int32 arrays of the A and B entry and the segment of every
+    candidate in coalesce order, and the output entries of each row;
+    None when the native library is unavailable, False when the
+    candidates number `limit` or more (the plan's int32 guard)."""
+    import numpy as np
+    L = lib()
+    if L is None:
+        return None
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    i64p = ctypes.POINTER(ctypes.c_int64)
+    count = L.amgx_rap_plan_stage_count
+    count.restype = ctypes.c_longlong
+    fill = L.amgx_rap_plan_stage_fill
+    fill.restype = ctypes.c_longlong
+    a_ro = np.ascontiguousarray(a_ro, np.int64)
+    a_ci = np.ascontiguousarray(a_ci, np.int32)
+    b_ro = np.ascontiguousarray(b_ro, np.int64)
+    b_ci = np.ascontiguousarray(b_ci, np.int32)
+    n = int(a_ro.shape[0]) - 1
+    cum = np.empty(n + 1, np.int64)
+    head = (ctypes.c_int32(n), a_ro.ctypes.data_as(i64p),
+            a_ci.ctypes.data_as(i32p), b_ro.ctypes.data_as(i64p))
+    total = int(count(*head, cum.ctypes.data_as(i64p)))
+    if total >= limit:
+        return False
+    sa, sb, seg = (np.empty(total, np.int32) for _ in range(3))
+    urow = np.empty(n, np.int32)
+    fill(*head, b_ci.ctypes.data_as(i32p), cum.ctypes.data_as(i64p),
+         sa.ctypes.data_as(i32p), sb.ctypes.data_as(i32p),
+         seg.ctypes.data_as(i32p), urow.ctypes.data_as(i32p))
+    return sa, sb, seg, urow
+
+
 def swell_build_native(ro, ci, vals, num_rows):
     """Native SWELL layout build (ops/pallas_swell.py layout contract).
     Returns (cols4, vals4, c0row, nchunk, w128) with cols4/vals4 shaped

@@ -51,7 +51,9 @@ the XLA gather form below covers f64/CPU/batched callers.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -114,31 +116,70 @@ def vreg_steps(nchunk, kpad) -> int:
         * -(-int(kpad) // SUBS)
 
 
+_declines = threading.local()
+
+
+@contextlib.contextmanager
+def collect_declines():
+    """The reasons `swell_budget` said no while the block ran, in
+    order, as a list (a set-up's layout spans carry them as their
+    `declined` arg)."""
+    outer = getattr(_declines, "log", None)
+    log = _declines.log = []
+    try:
+        yield log
+    finally:
+        _declines.log = outer
+
+
+def _declined(reason: str):
+    """Counts a decline (`amg.layout.declined.<reason>`) and hands the
+    reason to whoever collects them."""
+    from ..telemetry import metrics as _tm
+    _tm.inc(f"amg.layout.declined.{reason}")
+    log = getattr(_declines, "log", None)
+    if log is not None:
+        log.append(reason)
+
+
 def swell_budget(kmax, w128_raw, nb, nnz):
     """Single source of the SWELL layout-budget decisions, shared by the
     numpy builder below and the native-wrapper path
     (native/__init__.py swell_build_native) — the two drifted once.
-    Returns (kpad, w128) or None when the layout does not pay:
+    Returns (kpad, w128) or None when the layout does not pay (each
+    None is counted and named: `kmax`, `window`, `fill`):
+    - kmax: a row longer than SWELL_MAX_K slots (all or nothing: one
+      long row declines the operator);
     - kpad: exact for short rows (interpolation operators, kmax 4-5,
       where round-to-8 inflated HBM and wire bytes ~2x), 8-aligned
       above (Mosaic relayouts large unaligned slot dims through
       scoped-VMEM copies);
     - w128: rounded to 8 chunks (the window's VMEM scratch and its
-      DMA stay on whole (8, 128) tiles);
+      DMA stay on whole (8, 128) tiles); window: a block's column span
+      over SWELL_MAX_W elements;
     - fill guard: one long row would otherwise inflate the padded
       layout to n*kpad slots; small layouts are exempt (round-to-8
       alone inflates tiny matrices past any ratio, and a <1M-slot
       layout cannot blow memory)."""
-    if kmax == 0 or kmax > SWELL_MAX_K:
-        return None
+    if kmax == 0:
+        return None                        # nothing to lay out
+    if kmax > SWELL_MAX_K:
+        return _declined("kmax")
     w128 = -(-int(w128_raw) // 8) * 8
     if w128 * LANES > SWELL_MAX_W:
-        return None
-    kpad = kmax if kmax <= 24 else -(-kmax // 8) * 8
-    slots = nb * SUBS * kpad * LANES
-    if slots > 6 * max(nnz, 1) and slots > (1 << 20):
-        return None
+        return _declined("window")
+    kpad = _kpad(kmax)
+    if not _fill_ok(nb * SUBS * kpad * LANES, nnz):
+        return _declined("fill")
     return kpad, w128
+
+
+def _kpad(kmax: int) -> int:
+    return kmax if kmax <= 24 else -(-kmax // 8) * 8
+
+
+def _fill_ok(slots: int, nnz: int) -> bool:
+    return slots <= 6 * max(nnz, 1) or slots <= (1 << 20)
 
 
 def build_swell_host(ro, ci, vals, num_rows, num_cols):
@@ -162,7 +203,8 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     row_nnz = np.diff(ro)
     kmax = int(row_nnz.max())
     if kmax == 0 or kmax > SWELL_MAX_K:
-        return None                        # cheap reject before the scan
+        # cheap reject before the scan
+        return None if kmax == 0 else _declined("kmax")
     # per-row col extents -> per-super-block window
     starts = ro[:-1].astype(np.int64)
     nonempty = ro[1:] > ro[:-1]
@@ -202,6 +244,69 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
             vals4.reshape(nb, SUBS, kpad, LANES),
             (c0 // LANES).astype(np.int32),
             group_chunk_lists(ci, row_ids, c0, nb, _w128), _w128)
+
+
+SPLIT_PIECES = (4, 8, 16, 32, 64, 128)   # entries a piece, candidates
+
+
+def split_rows_host(ro, ci, vals, num_rows, num_cols):
+    """The row-split form of a CSR operator whose rows are too uneven
+    for one SWELL layout (`swell_budget` said `kmax` or `fill`: a mean
+    of 43 entries under a longest row of 295 pads five slots an entry,
+    or has no slot count at all): every row is cut into pieces of at
+    most K consecutive entries, each piece a row of A' (n' rows, the
+    SAME column and value arrays under other row offsets), and the
+    pieces of a row are summed by S (n x n', ones, a row's pieces
+    adjacent): A = S A'. Both are ordinary SWELL operators, A' with
+    rows of at most K entries and the window of the rows it came from,
+    S with perfectly local columns, so the product runs through the
+    SWELL kernels that are there. K is the candidate of SPLIT_PIECES
+    that pads the fewest slots in A' and S together among those whose
+    S keeps to exact short rows and that pass the fill guard twice.
+
+    Returns ((ro', layout of A'), (ro_S, layout of S)) with each
+    layout as `build_swell_host` gives it, or None where no candidate
+    fits (counted by the declines that said so)."""
+    n = int(num_rows)
+    ro = np.asarray(ro).astype(np.int64)
+    lengths = np.diff(ro)
+    nnz = int(ci.shape[0])
+    if n == 0 or nnz == 0:
+        return None
+    best = None
+    for K in SPLIT_PIECES:
+        pieces = -(-lengths // K)
+        n_p = int(pieces.sum())
+        kmax_s = int(pieces.max())
+        if kmax_s > 24:              # S stays on exact short rows
+            continue
+        slots_a = -(-n_p // BLOCK_ROWS) * BLOCK_ROWS \
+            * _kpad(min(K, int(lengths.max())))
+        slots_s = -(-n // BLOCK_ROWS) * BLOCK_ROWS * kmax_s
+        if not (_fill_ok(slots_a, nnz) and _fill_ok(slots_s, n_p)):
+            continue                 # the fill guard would say no
+        if best is None or slots_a + slots_s < best[0]:
+            best = (slots_a + slots_s, K, pieces, n_p)
+    if best is None:
+        return None
+    _slots, K, pieces, n_p = best
+    # A': piece j of row i holds entries [ro_i + j K, ro_i + (j + 1) K)
+    first = np.cumsum(pieces) - pieces           # row i's first piece
+    row_of = np.repeat(np.arange(n, dtype=np.int64), pieces)
+    j = np.arange(n_p, dtype=np.int64) - first[row_of]
+    ro_p = np.empty(n_p + 1, np.int32)
+    ro_p[:-1] = ro[row_of] + j * K
+    ro_p[-1] = nnz
+    lay_a = build_swell_host(ro_p, ci, vals, n_p, num_cols)
+    if lay_a is None:
+        return None
+    ro_s = np.zeros(n + 1, np.int32)
+    np.cumsum(pieces, out=ro_s[1:])
+    lay_s = build_swell_host(ro_s, np.arange(n_p, dtype=np.int32),
+                             np.ones(n_p, vals.dtype), n, n_p)
+    if lay_s is None:
+        return None
+    return (ro_p, lay_a), (ro_s, lay_s)
 
 
 def swell_vals_host(ro, vals, num_rows, kpad):

@@ -340,6 +340,41 @@ def _host_pattern(*arrays):
     return [None if a is None else np.asarray(a) for a in arrays]
 
 
+def _plan_stage(a_ro, a_ci, b_ro, b_ci):
+    """One stage of the general plan's structure phase: the candidates
+    of A @ B from the two patterns, coalesced in the stable (row,
+    column) order. Returns (sa, sb, seg, starts, rows_u, cols_u): int32
+    arrays of each candidate's A and B entry and segment in coalesce
+    order, the (nU + 1,) segment boundaries, and the output entries'
+    coordinates; None where the candidates do not fit int32. The native
+    sweep (native/src/rap_plan.cpp) sorts row by row; the numpy form
+    expands five int64 arrays of candidate length and lexsorts them
+    (most of a classical set-up's wall where coarse rows are long).
+    Both give the same arrays to the last tie."""
+    from .. import native
+    limit = int(np.iinfo(np.int32).max)
+    out = native.rap_plan_stage_native(a_ro, a_ci, b_ro, b_ci, limit)
+    if out is False:
+        return None
+    if out is not None:
+        sa, sb, seg, urow = out
+        if seg.shape[0]:
+            starts = np.flatnonzero(np.concatenate(
+                [np.ones(1, bool), seg[1:] != seg[:-1],
+                 np.ones(1, bool)])).astype(np.int32)
+        else:
+            starts = np.zeros(1, np.int32)
+        rows_u = np.repeat(np.arange(urow.shape[0], dtype=np.int64), urow)
+        cols_u = np.asarray(b_ci)[sb[starts[:-1]]].astype(np.int64)
+        return sa, sb, seg, starts, rows_u, cols_u
+    rows_c, cols_c, src_a, src_b = _np_expand_pattern(a_ro, a_ci, b_ro, b_ci)
+    if rows_c.shape[0] >= limit:
+        return None
+    order, seg, starts, rows_u, cols_u = _np_coalesce(rows_c, cols_c)
+    return (src_a[order].astype(np.int32), src_b[order].astype(np.int32),
+            seg, starts.astype(np.int32), rows_u, cols_u)
+
+
 def build_agg_plan(A: CsrMatrix, agg, nc: int):
     """Structure phase of the aggregation relabel Galerkin: candidates
     are A's (diag-folded) entries relabeled by aggregate id, in the
@@ -399,30 +434,22 @@ def build_rap_plan(R: CsrMatrix, A: CsrMatrix, P: CsrMatrix):
     else:
         fold_src = None
     # stage 1: T = A @ P
-    t_rows_c, t_cols_c, s1a, s1p = _np_expand_pattern(
-        a_ro, a_ci, p_ro, p_ci)
-    if t_rows_c.shape[0] >= np.iinfo(np.int32).max:
+    one = _plan_stage(a_ro, a_ci, p_ro, p_ci)
+    if one is None:
         return None
-    order1, seg1, starts1, t_rows, t_cols = _np_coalesce(
-        t_rows_c, t_cols_c)
-    sa = s1a[order1]
+    sa, sp, seg1, starts1, t_rows, t_cols = one
     if fold_src is not None:
         sa = fold_src[sa]
-    sp = s1p[order1]
     nT = t_rows.shape[0]
     t_counts = np.bincount(t_rows, minlength=A.num_rows)
     t_ro = np.zeros(A.num_rows + 1, np.int64)
     t_ro[1:] = np.cumsum(t_counts)
     # stage 2: C = R @ T
-    c_rows_c, c_cols_c, s2r, s2t = _np_expand_pattern(
-        r_ro, r_ci, t_ro, t_cols)
-    if c_rows_c.shape[0] >= np.iinfo(np.int32).max:
+    two = _plan_stage(r_ro, r_ci, t_ro, t_cols)
+    if two is None:
         return None
-    order2, seg2, starts2, c_rows, c_cols = _np_coalesce(
-        c_rows_c, c_cols_c)
-    sr = s2r[order2].astype(np.int32)
-    st = s2t[order2].astype(np.int32)
-    stage1 = {"sa": sa.astype(np.int32), "sp": sp.astype(np.int32),
+    sr, st, seg2, starts2, c_rows, c_cols = two
+    stage1 = {"sa": sa.astype(np.int32, copy=False), "sp": sp,
               "seg1": seg1, "starts1": starts1, "nT": int(nT)}
     structure = _np_csr_structure(c_rows, c_cols, R.num_rows)
     return RapPlan("rap", stage1, sr, st, seg2, starts2,

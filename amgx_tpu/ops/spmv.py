@@ -138,6 +138,17 @@ def spmv_swell(A: CsrMatrix, x: jax.Array) -> jax.Array:
     return y
 
 
+def spmv_split(A: CsrMatrix, x: jax.Array) -> jax.Array:
+    """y = A @ x in the row-split SWELL form, A = S A': the pieces'
+    products through the SWELL kernel, then each row's pieces summed by
+    the same kernel (ops/pallas_swell.split_rows_host)."""
+    Ap, S = A.split
+    y = spmv_swell(S, spmv_swell(Ap, x))
+    if A.has_external_diag:
+        y = y + A.diag * x[: A.num_rows]
+    return y
+
+
 def spmv_dia(A: CsrMatrix, x: jax.Array) -> jax.Array:
     """y = A @ x in DIA (diagonal) storage: for each stored diagonal with
     offset d, y += vals_d * shift(x, d). Pure dense vector multiply-adds
@@ -167,6 +178,8 @@ def spmv(A, x: jax.Array) -> jax.Array:
         return _fault.corrupt_spmv(spmv_dia(A, x))
     if A.swell_cols is not None:
         return _fault.corrupt_spmv(spmv_swell(A, x))
+    if A.split is not None:
+        return _fault.corrupt_spmv(spmv_split(A, x))
     if A.ell_cols is not None:
         return _fault.corrupt_spmv(spmv_ell(A, x))
     return _fault.corrupt_spmv(spmv_csr_segsum(A, x))

@@ -560,6 +560,19 @@ class Solver(SolveDataOwner):
         pc = self.preconditioner
         return 0 if pc is None else pc.swell_vreg_steps_per_iteration()
 
+    def csr_road_nnz_per_iteration(self) -> int:
+        """Non-zeros one iteration's cycle sends down the XLA gather +
+        segment-sum road (the preconditioner's:
+        AMG.csr_road_nnz_per_cycle); 0 where the tree has no multigrid
+        cycle or every operator of it has a layout."""
+        pc = self.preconditioner
+        return 0 if pc is None else pc.csr_road_nnz_per_iteration()
+
+    # preconditioner applications one iteration makes (PBICGSTAB: two)
+    precond_applications_per_iteration = 1
+    _fused_sites = 0     # fused shell call sites one iteration's trace
+    #                      routed to the Pallas kernels (cached programs)
+
     def geo_transfers_per_iteration(self):
         """(one-pass, XLA) GEO levels one iteration's cycle runs its
         transfers through (the preconditioner's), or None where the
@@ -679,6 +692,7 @@ class Solver(SolveDataOwner):
         # and the way back to x are the shell's device time, and would
         # read as unscoped without a name
         scope = f"krylov.{self.name}"
+        from ..telemetry import metrics as _tm
 
         def solve_fn(data, b, x0):
             A = data["A"]
@@ -712,9 +726,13 @@ class Solver(SolveDataOwner):
                 core = {k: v for k, v in st.items()
                         if k not in ("iters", "done", "converged",
                                      "res_norm", "res_hist", "status")}
+                routed = _tm.get("krylov.fused_dispatch")
                 with _fi.iteration_scope(iters), \
                         jax.named_scope(f"{scope}.iter"):
                     core = self.solve_iteration(data, b, core)
+                # trace time: static like the program it is kept with
+                self._fused_sites = \
+                    _tm.get("krylov.fused_dispatch") - routed
                 new = dict(st)
                 new.update(core)
                 new["iters"] = iters + 1
@@ -1089,7 +1107,8 @@ class Solver(SolveDataOwner):
             # inner count where one is kept, else this solver's
             ex = extras or {}
             cycles = int(round(ex.get(
-                "arnoldi_steps", ex.get("inner_iters", iters_i))))
+                "arnoldi_steps", ex.get("inner_iters", iters_i)))) \
+                * self.precond_applications_per_iteration
             if self._geo_transfers is not None:
                 for road, levels in zip(("onepass", "xla"),
                                         self._geo_transfers):
@@ -1097,6 +1116,11 @@ class Solver(SolveDataOwner):
             swell_steps = self.swell_vreg_steps_per_iteration()
             if swell_steps:
                 _tm.inc("swell.vreg_steps", cycles * swell_steps)
+            csr_nnz = self.csr_road_nnz_per_iteration()
+            if csr_nnz:
+                _tm.inc("cycle.csr_road_nnz", cycles * csr_nnz)
+            if self._fused_sites:
+                _tm.inc("krylov.fused_calls", iters_i * self._fused_sites)
             for name, value in (extras or {}).items():
                 # an extra stat that a counter is named after (GMRES /
                 # FGMRES's account of a solve, its own or summed by

@@ -332,6 +332,7 @@ class PBiCGStabSolver(_KrylovBase):
     """Preconditioned BiCGStab (pbicgstab_solver.cu)."""
 
     uses_preconditioner = True
+    precond_applications_per_iteration = 2     # p_hat and s_hat
 
     def solve_init(self, data, b, x, r):
         if self.krylov_fusion:

@@ -734,6 +734,33 @@ declare_counter("swell.vreg_steps",
                 "host layouts as a set-up ends; 0 where no operator has "
                 "the layout")
 
+declare_counter("cycle.csr_road_nnz",
+                "non-zeros a solve's cycles sent down the XLA gather + "
+                "segment-sum road: raised after each solve by the "
+                "cycles that ran x the non-zeros of every operator "
+                "application of a cycle (A in sweeps and residuals, P, "
+                "R) whose operator has no DIA / ELL / SWELL layout; "
+                "static per hierarchy, kept as a set-up ends; 0 where "
+                "every operator of the cycle has a layout")
+for _reason, _what in (
+        ("kmax", "a row longer than SWELL_MAX_K slots"),
+        ("window", "a 1,024-row block whose column span is over "
+                   "SWELL_MAX_W elements"),
+        ("fill", "padded slots over 6 x the non-zeros (and over 2^20)")):
+    declare_counter(f"amg.layout.declined.{_reason}",
+                    "operators (A, P or R of a level) that "
+                    "ops/pallas_swell.swell_budget declined at set-up: "
+                    + _what + "; the reason is also the `declined` arg "
+                    "of the level's amg.L<k>.layout / .layoutP / "
+                    ".transposeR span")
+declare_counter("krylov.fused_calls",
+                "calls of the shell's single-pass Pallas kernels "
+                "(SpMV+dot, CG update), raised after each solve by the "
+                "iterations that ran x the fused call sites that one "
+                "iteration's trace routed to the kernels "
+                "(krylov.fused_dispatch's growth over the trace of the "
+                "iteration body); 0 where the shell fell to XLA")
+
 # jit retraces per solver entry point: a retrace in steady-state serving
 # is a latency cliff (first-request trace cost paid again)
 declare_counter("solver.retrace.solve",

@@ -131,7 +131,12 @@ def _named_paths(text):
 
 
 def _exists(word):
-    if word in WRITTEN_AT_RUN_TIME:
+    # a run-time product is excused under either base: the skill file
+    # writes `native/_build/`, which a tree whose native library has
+    # not been built yet (the driver's fresh checkout, this file's
+    # turn coming before the first native call of the run) lacks
+    if word in WRITTEN_AT_RUN_TIME \
+            or os.path.join("amgx_tpu", word) in WRITTEN_AT_RUN_TIME:
         return True
     return any(glob.glob(os.path.join(base, word))
                for base in (REPO, os.path.join(REPO, "amgx_tpu")))
@@ -140,6 +145,11 @@ def _exists(word):
 @pytest.mark.parametrize("doc", ["README.md",
                                  ".claude/skills/verify/SKILL.md"])
 def test_documents_name_only_paths_that_exist(doc):
+    if not os.path.exists(os.path.join(REPO, doc)):
+        # a tree handed over without its .claude/ directory: nothing
+        # of this document to hold; README.md has to be there
+        assert doc.startswith(".claude/"), f"{doc} is missing"
+        return
     with open(os.path.join(REPO, doc)) as f:
         named = sorted(set(_named_paths(f.read())))
     assert len(named) >= 10, f"the extraction found {named} in {doc}"

@@ -439,6 +439,29 @@ def test_swell_kernels_compile(kernel, w128, kpad, longest, one_chip,
             one_chip, ent, val, blk, nch, vec, vec, vec, ((1,), F32))
 
 
+@pytest.mark.parametrize("rows,cols,w128,kpad,longest", [
+    (4, 2, 16, 4, 8),          # A' of a P: pieces of 4 entries
+    (2, 4, 8, 5, 8),           # S of it: a row's pieces, adjacent
+    (6, 4, 160, 16, 80),       # A' of a long-row level: pieces of 16
+    (4, 6, 8, 18, 8),          # S of it
+    (5, 1, 112, 64, 112)])     # pieces of 64 under a window of the level
+def test_row_split_operators_compile(rows, cols, w128, kpad, longest,
+                                     one_chip, on_tpu, no_persistent_cache):
+    """The two factors of the row-split SWELL form A = S A'
+    (ops/pallas_swell.split_rows_host) are NOT square and their slot
+    counts are small and off the tiling: A' has the operator's columns
+    under more rows, S has A's rows over A''s. Shapes of the
+    convection-diffusion cell's hierarchy (PR 49), in 1,024-row blocks."""
+    nb = 8 * rows
+    n = nb * sw.BLOCK_ROWS
+    n_cols = 8 * cols * sw.BLOCK_ROWS
+    _compile(lambda c, v, c0, nc, x: sw._swell_spmv_call(
+        c, v, c0, nc, x, w128, n),
+        one_chip, ((nb, sw.SUBS, kpad, 128), jnp.int32),
+        ((nb, sw.SUBS, kpad, 128), F32), ((nb,), jnp.int32),
+        ((nb, sw.SUBS, 1 + longest), jnp.int32), ((n_cols,), F32))
+
+
 def test_declined_families_decline_on_chip_only(on_tpu):
     """Every gate of the family Mosaic refuses ("Only 2D gather is
     supported": ops.pallas_spmv.flat_gather_ok) says no on the

@@ -256,7 +256,7 @@ def test_pytree_has_the_leaves_it_had():
         == ["row_offsets", "col_indices", "values", "diag", "row_ids",
             "diag_idx", "ell_cols", "ell_vals", "dia_offsets", "dia_vals",
             "swell_cols", "swell_vals", "swell_c0row", "swell_nchunk",
-            "swell_w128", "num_rows", "num_cols", "block_dimx",
+            "swell_w128", "split", "num_rows", "num_cols", "block_dimx",
             "block_dimy", "initialized", "grid_shape", "user_colors",
             "user_num_colors"]
 

@@ -585,3 +585,33 @@ def test_plan_counters_declared():
     counter names."""
     _tm.get("amg.spgemm.plan_build")
     _tm.get("amg.spgemm.plan_hit")
+
+
+@pytest.mark.parametrize("shape", [(500, 400, 300, 0.02, 0.03),
+                                   (2000, 2000, 1500, 0.01, 0.01),
+                                   (50, 50, 50, 0.0, 0.1)])
+def test_native_plan_stage_is_the_numpy_stage(shape, monkeypatch):
+    """One stage of a plan's structure phase, the native row-by-row
+    sweep against the numpy expand + lexsort: the same arrays to the
+    last tie (the same dtypes too), and a candidate count at the int32
+    guard declines on both roads."""
+    import scipy.sparse as sp
+    from amgx_tpu import native
+    from amgx_tpu.ops import spgemm
+    if native.lib() is None:
+        pytest.skip("no native library")
+    n, m, k, da, db = shape
+    A = sp.random(n, m, density=da, random_state=1, format="csr")
+    B = sp.random(m, k, density=db, random_state=2, format="csr")
+    A.sort_indices()
+    B.sort_indices()
+    args = (A.indptr, A.indices, B.indptr, B.indices)
+    fast = spgemm._plan_stage(*args)
+    guard = native.rap_plan_stage_native(*args, limit=1)
+    monkeypatch.setattr(native, "rap_plan_stage_native",
+                        lambda *a, **kw: None)
+    slow = spgemm._plan_stage(*args)
+    assert len(fast) == len(slow) == 6
+    for x, y in zip(fast, slow):
+        assert x.dtype == y.dtype and np.array_equal(x, y)
+    assert guard is (False if fast[0].shape[0] >= 1 else guard)
