@@ -45,15 +45,17 @@ def laid_out(M, why: dict, build=None):
     """`M` with its SpMV layout (`build(M)`; a coarse operator's
     `build_spmv_layout()` / `init()` by default); where the SWELL
     budget said no on the way (ops/pallas_swell.swell_budget), the
-    reason goes into `why["declined"]`, the layout span's arg."""
-    from ..ops.pallas_swell import collect_declines
+    reason goes into `why["declined"]`, and where the row-split form
+    was taken over a layout the budget admits (`split_pays`), its K
+    and the model's two costs into `why["chosen"]`: the layout span's
+    args."""
+    from ..ops.pallas_swell import collect_layout_notes
     if build is None:
         def build(M):
             return M.build_spmv_layout() if M.initialized else M.init()
-    with collect_declines() as said:
+    with collect_layout_notes() as said:
         out = build(M)
-    if said:
-        why["declined"] = ",".join(said)
+    why.update({k: ",".join(v) for k, v in said.items() if v})
     return out
 
 
@@ -904,7 +906,7 @@ class AMG(SolveDataOwner):
                               if self._ship_counted else None):
                 self._resolve_put_cache()
         self.num_levels = len(self.levels) + 1
-        self._swell_steps = self._count_swell_vreg_steps()
+        self._swell_steps, self._swell_model_s = self._count_swell_costs()
         self._csr_road_nnz = self._count_csr_road_nnz()
         self.setup_time = time.perf_counter() - t0
         if self.print_grid_stats:
@@ -1131,31 +1133,48 @@ class AMG(SolveDataOwner):
             total += self.coarsest_sweeps * steps(cs)
         return total
 
-    _swell_steps = 0      # _count_swell_vreg_steps() of the last set-up
+    _swell_steps = 0      # _count_swell_costs() of the last set-up:
+    _swell_model_s = 0.0  # vreg-steps and the model's seconds a cycle
 
-    def _count_swell_vreg_steps(self) -> int:
-        """Vreg-steps of the SWELL gather one cycle is made of
-        (ops/pallas_swell.vreg_steps: over an operator's row groups,
-        the window chunks listed x the vregs of a group's tile): over
-        the levels, A's x (sweeps + the residual), P's and R's once, and
-        the coarsest operator's where a smoother stands in for a solve.
-        Read from the layouts' host copies as a set-up ends, so a solve
-        fetches nothing for it. (A V cycle's count, as
-        color_steps_per_cycle's.)"""
+    @staticmethod
+    def swell_account(M) -> list:
+        """(listed chunks, kpad, blocks) of each SWELL layout one
+        application of `M` runs through: its own, or the two of its
+        row-split form (A' first); none where it has neither. Read from
+        the layouts' host copies."""
         from ..matrix import host_mirror_asarray
-        from ..ops.pallas_swell import vreg_steps
+        from ..ops.pallas_swell import listed_chunks
+        if getattr(M, "split", None) is not None:
+            return [row for part in M.split
+                    for row in AMG.swell_account(part)]
+        if getattr(M, "swell_nchunk", None) is None:
+            return []
+        return [(listed_chunks(host_mirror_asarray(M.swell_nchunk)),
+                 int(M.swell_cols.shape[2]), int(M.swell_cols.shape[0]))]
 
-        def steps(M):
-            if getattr(M, "split", None) is not None:
-                return sum(steps(part) for part in M.split)
-            if getattr(M, "swell_nchunk", None) is None:
-                return 0
-            return vreg_steps(host_mirror_asarray(M.swell_nchunk),
-                              M.swell_cols.shape[2])
-        return self._sum_over_cycle_operators(steps)
+    def _count_swell_costs(self):
+        """What the SWELL gather of one cycle costs, by two counts
+        (ops/pallas_swell): its vreg-steps (`vreg_steps`: over an
+        operator's row groups, the window chunks listed x the vregs of
+        a group's tile) and the seconds the layout choice's model puts
+        on it (`model_seconds`); over the levels, A's x (sweeps + the
+        residual), P's and R's once, and the coarsest operator's where
+        a smoother stands in for a solve. Read as a set-up ends, so a
+        solve fetches nothing for it. (A V cycle's count, as
+        color_steps_per_cycle's.)"""
+        from ..ops.pallas_swell import model_seconds, tile_vregs
+        return (
+            self._sum_over_cycle_operators(lambda M: sum(
+                listed * tile_vregs(kpad)
+                for listed, kpad, _blocks in self.swell_account(M))),
+            self._sum_over_cycle_operators(lambda M: sum(
+                model_seconds(*row) for row in self.swell_account(M))))
 
     def swell_vreg_steps_per_cycle(self) -> int:
         return self._swell_steps
+
+    def swell_model_s_per_cycle(self) -> float:
+        return self._swell_model_s
 
     _csr_road_nnz = 0     # _count_csr_road_nnz() of the last set-up
 

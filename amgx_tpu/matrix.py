@@ -481,9 +481,11 @@ class CsrMatrix:
     def _choose_layout_host(self, ro, ci, vals, row_ids, row_nnz, ell: str,
                             ell_max_ratio: float) -> dict:
         """Host layout choice: DIA if banded, else the windowed-ELL
-        (SWELL) Pallas layout if the block windows fit, else padded ELL
-        if the row lengths are tight. Returns the layout fields as a
-        dict for dataclasses.replace."""
+        (SWELL) Pallas layout if the block windows fit, as one layout
+        or in the row-split form (ops/pallas_swell.split_pays: whichever
+        the kernels' clock puts lower, from the pattern alone), else
+        padded ELL if the row lengths are tight. Returns the layout
+        fields as a dict for dataclasses.replace."""
         n = self.num_rows
         out = dict(ell_cols=None, ell_vals=None, dia_offsets=None,
                    dia_vals=None, swell_cols=None, swell_vals=None,
@@ -523,13 +525,24 @@ class CsrMatrix:
                     k, rows_pad, LANES)
                 return out
         if n > 0 and self.nnz > 0 and ell == "auto":
-            from .ops.pallas_swell import build_swell_host
+            from .ops.pallas_swell import (build_swell_host, note_chosen,
+                                           split_pays)
+            # the row-split form where the pattern's count says it is
+            # clearly the cheaper, the one SWELL layout where the budget
+            # admits it, the row-split form again where it does not
+            choice = None if self.has_external_diag \
+                else split_pays(ro, ci, n)
+            if choice is not None:
+                out["split"] = self._split_host(ro, ci, vals, choice[0])
+                if out["split"] is not None:
+                    note_chosen(choice[1])
+                    return out
             sw = build_swell_host(ro, ci, vals, n, self.num_cols)
             if sw is not None:
                 (out["swell_cols"], out["swell_vals"], out["swell_c0row"],
                  out["swell_nchunk"], out["swell_w128"]) = sw
                 return out
-            if not self.has_external_diag:
+            if choice is None and not self.has_external_diag:
                 out["split"] = self._split_host(ro, ci, vals)
                 if out["split"] is not None:
                     return out
@@ -550,12 +563,14 @@ class CsrMatrix:
                     ec.reshape(n, max_k), ev.reshape(n, max_k)
         return out
 
-    def _split_host(self, ro, ci, vals) -> "Optional[tuple]":
+    def _split_host(self, ro, ci, vals, K=None) -> "Optional[tuple]":
         """(A', S) of the row-split SWELL form, as spmv-only matrices
         (placeholders where the CSR payloads were: A' shares this
-        matrix's columns and values), or None where it does not fit."""
+        matrix's columns and values), or None where it does not fit;
+        `K` is `split_pays`'s where it chose."""
         from .ops.pallas_swell import split_rows_host
-        parts = split_rows_host(ro, ci, vals, self.num_rows, self.num_cols)
+        parts = split_rows_host(ro, ci, vals, self.num_rows, self.num_cols,
+                                K)
         if parts is None:
             return None
         (ro_p, lay_a), (ro_s, lay_s) = parts

@@ -477,6 +477,81 @@ def rap_plan_stage_native(a_ro, a_ci, b_ro, b_ci, limit):
     return sa, sb, seg, urow
 
 
+def _swell_windows(L, ro, ci, n):
+    """amgx_swell_windows over contiguous int32 arrays: (c0row, kmax,
+    w128_raw)."""
+    import numpy as np
+    from ..ops.pallas_swell import BLOCK_ROWS
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    L.amgx_swell_windows.restype = ctypes.c_int32
+    c0row = np.empty(-(-n // BLOCK_ROWS), np.int32)
+    kmax = ctypes.c_int32()
+    w128_raw = L.amgx_swell_windows(
+        ctypes.c_int32(n), ro.ctypes.data_as(i32p),
+        ci.ctypes.data_as(i32p), c0row.ctypes.data_as(i32p),
+        ctypes.byref(kmax))
+    return c0row, int(kmax.value), int(w128_raw)
+
+
+def _swell_chunklists(L, ro, ci, n, c0row, w128):
+    """amgx_swell_chunklists: (counts (nb * 8,), the lists back to
+    back)."""
+    import numpy as np
+    from ..ops.pallas_swell import SUBS
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    groups = SUBS * c0row.shape[0]
+    counts = np.zeros(groups, np.int32)
+    flat = np.empty(min(ci.shape[0], groups * w128), np.int32)
+    L.amgx_swell_chunklists.restype = ctypes.c_int64
+    listed = L.amgx_swell_chunklists(
+        ctypes.c_int32(n), ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
+        c0row.ctypes.data_as(i32p), ctypes.c_int32(w128),
+        counts.ctypes.data_as(i32p), flat.ctypes.data_as(i32p))
+    return counts, flat[:listed]
+
+
+def swell_count_native(ro, ci, num_rows):
+    """(kmax, w128_raw, listed chunks) of the SWELL layout a pattern
+    would take (ops/pallas_swell.count_listed): the window and
+    chunk-list sweeps alone, nothing scattered; None when the native
+    library is unavailable."""
+    import numpy as np
+    L = lib()
+    if L is None:
+        return None
+    n = int(num_rows)
+    ro = np.ascontiguousarray(ro, np.int32)
+    ci = np.ascontiguousarray(ci, np.int32)
+    c0row, kmax, w128_raw = _swell_windows(L, ro, ci, n)
+    if kmax == 0:
+        return 0, w128_raw, 0
+    _counts, flat = _swell_chunklists(L, ro, ci, n, c0row,
+                                      -(-w128_raw // 8) * 8)
+    return kmax, w128_raw, int(flat.shape[0])
+
+
+def swell_split_count_native(ro, ci, num_rows, K):
+    """(rows of A', its longest row, its widest window in chunks, its
+    listed chunks, S's longest row, S's listed chunks) of the row-split
+    form at piece length K (ops/pallas_swell._split_counts), in one
+    sweep of the pattern; None when the native library is
+    unavailable."""
+    import numpy as np
+    L = lib()
+    if L is None:
+        return None
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    ro = np.ascontiguousarray(ro, np.int32)
+    ci = np.ascontiguousarray(ci, np.int32)
+    out = np.zeros(6, np.int64)
+    L.amgx_swell_split_count.restype = None
+    L.amgx_swell_split_count(
+        ctypes.c_int32(int(num_rows)), ro.ctypes.data_as(i32p),
+        ci.ctypes.data_as(i32p), ctypes.c_int32(int(K)),
+        out.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+    return tuple(int(v) for v in out)
+
+
 def swell_build_native(ro, ci, vals, num_rows):
     """Native SWELL layout build (ops/pallas_swell.py layout contract).
     Returns (cols4, vals4, c0row, nchunk, w128) with cols4/vals4 shaped
@@ -495,17 +570,11 @@ def swell_build_native(ro, ci, vals, num_rows):
     n = int(num_rows)
     nb = -(-n // BLOCK_ROWS)
     i32p = ctypes.POINTER(ctypes.c_int32)
-    win = L.amgx_swell_windows
-    win.restype = ctypes.c_int32
     ro = np.ascontiguousarray(ro, np.int32)
     ci = np.ascontiguousarray(ci, np.int32)
-    c0row = np.empty(nb, np.int32)
-    kmax = ctypes.c_int32()
-    w128_raw = win(ctypes.c_int32(n), ro.ctypes.data_as(i32p),
-                   ci.ctypes.data_as(i32p), c0row.ctypes.data_as(i32p),
-                   ctypes.byref(kmax))
+    c0row, kmax, w128_raw = _swell_windows(L, ro, ci, n)
     # budget decisions live in ONE place (ops/pallas_swell.swell_budget)
-    budget = swell_budget(int(kmax.value), w128_raw, nb, ci.shape[0])
+    budget = swell_budget(kmax, w128_raw, nb, ci.shape[0])
     if budget is None:
         return None
     kpad, w128 = budget
@@ -523,16 +592,10 @@ def swell_build_native(ro, ci, vals, num_rows):
          ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
          vals.ctypes.data_as(fp), c0row.ctypes.data_as(i32p),
          cols4.ctypes.data_as(i32p), vals4.ctypes.data_as(fp))
-    counts = np.zeros(nb * SUBS, np.int32)
-    flat = np.empty(min(ci.shape[0], nb * SUBS * w128), np.int32)
-    L.amgx_swell_chunklists.restype = ctypes.c_int64
-    listed = L.amgx_swell_chunklists(
-        ctypes.c_int32(n), ro.ctypes.data_as(i32p), ci.ctypes.data_as(i32p),
-        c0row.ctypes.data_as(i32p), ctypes.c_int32(w128),
-        counts.ctypes.data_as(i32p), flat.ctypes.data_as(i32p))
+    counts, flat = _swell_chunklists(L, ro, ci, n, c0row, w128)
     return (cols4.reshape(nb, SUBS, kpad, LANES),
             vals4.reshape(nb, SUBS, kpad, LANES), c0row,
-            pad_chunk_lists(counts, flat[:listed], nb), w128)
+            pad_chunk_lists(counts, flat, nb), w128)
 
 
 def swell_refill_native(ro, vals, num_rows, kpad):

@@ -90,6 +90,59 @@ int64_t amgx_swell_chunklists(
     return out;
 }
 
+// What the row-split form A = S A' at piece length K would list,
+// from the pattern alone and in one pass, with no array of A' built
+// (pallas_swell._split_counts; the layout choice counts a few K an
+// operator, PR 51): piece j of row i is the entries
+// [ro_i + j K, ro_i + (j + 1) K), a row of A'; 128 consecutive pieces
+// are a row group of A', 1,024 a block. A block's window starts on a
+// multiple of 128, so the distinct chunks a group lists are the
+// distinct ci / 128 of its entries, whatever the window's start. S has
+// row i's pieces as adjacent columns, so a group of 128 rows lists the
+// chunks from its first piece's to its last's. out[6]: rows of A', its
+// longest row, its widest block window in chunks, its listed chunks,
+// the longest row of S, S's listed chunks.
+void amgx_swell_split_count(
+    int32_t n, const int32_t* ro, const int32_t* ci, int32_t K,
+    int64_t* out) {
+    std::vector<int64_t> seen;               // the group that last hit
+    int64_t p = 0, listed_a = 0, listed_s = 0, group_first = 0;
+    int32_t kmax_a = 0, kmax_s = 0, w128 = 0;
+    int32_t bmin = INT32_MAX, bmax = -1;
+    auto close_block = [&]() {
+        if (bmax < 0) { bmin = 0; bmax = 0; }
+        const int32_t c0 = (bmin / LANES) * LANES;
+        w128 = std::max(w128, (bmax - c0 + 1 + LANES - 1) / LANES);
+        bmin = INT32_MAX; bmax = -1;
+    };
+    for (int32_t i = 0; i < n; ++i) {
+        if (i % LANES == 0) group_first = p;
+        const int32_t len = ro[i + 1] - ro[i];
+        const int32_t pieces = (len + K - 1) / K;
+        kmax_s = std::max(kmax_s, pieces);
+        for (int32_t j = 0; j < pieces; ++j, ++p) {
+            if (p % BLOCK_ROWS == 0 && p > 0) close_block();
+            const int64_t g = p / LANES;
+            const int32_t e0 = ro[i] + j * K;
+            const int32_t e1 = std::min(e0 + K, ro[i + 1]);
+            kmax_a = std::max(kmax_a, e1 - e0);
+            for (int32_t e = e0; e < e1; ++e) {
+                const int32_t c = ci[e];
+                bmin = std::min(bmin, c);
+                bmax = std::max(bmax, c);
+                const size_t ch = static_cast<size_t>(c / LANES);
+                if (ch >= seen.size()) seen.resize(ch + 1, -1);
+                if (seen[ch] != g) { seen[ch] = g; ++listed_a; }
+            }
+        }
+        if ((i % LANES == LANES - 1 || i == n - 1) && p > group_first)
+            listed_s += (p - 1) / LANES - group_first / LANES + 1;
+    }
+    if (p > 0) close_block();
+    out[0] = p; out[1] = kmax_a; out[2] = w128; out[3] = listed_a;
+    out[4] = kmax_s; out[5] = listed_s;
+}
+
 // Scatter entries into caller-zeroed (nb, 8, kpad, 128) slot-major
 // buffers. Local column = ci - c0row[block] * 128.
 #define SWELL_FILL(name, T)                                              \

@@ -107,29 +107,90 @@ def group_chunk_lists(ci, row_ids, c0, nb, w128):
         (key % w128).astype(np.int32), nb)
 
 
+def listed_chunks(nchunk) -> int:
+    """The distinct chunks a layout's row groups list, all together.
+    The padding repeats are not counted: the number is the pattern's,
+    not the unroll's."""
+    return int(np.asarray(nchunk)[:, :, 0].sum(dtype=np.int64))
+
+
+def tile_vregs(kpad) -> int:
+    """The (8, 128) vregs of a row group's (kpad, 128) tile."""
+    return -(-int(kpad) // SUBS)
+
+
 def vreg_steps(nchunk, kpad) -> int:
     """Vreg-steps one application of a layout costs: over its row
-    groups, the distinct chunks listed x the (8, 128) vregs of a
-    group's (kpad, 128) tile. The padding repeats are not counted: the
-    number is the pattern's, not the unroll's."""
-    return int(np.asarray(nchunk)[:, :, 0].sum(dtype=np.int64)) \
-        * -(-int(kpad) // SUBS)
+    groups, the distinct chunks listed x the vregs of a group's tile."""
+    return listed_chunks(nchunk) * tile_vregs(kpad)
 
 
-_declines = threading.local()
+# The SWELL kernels' clock, as the layout choice reads it (PR 51): one
+# application costs, per chunk a row group lists, a fixed part (the
+# scalar read of the list, the row load, the loop's share) and a part
+# per (8, 128) vreg of the group's (kpad, 128) tile that goes through
+# gather-select, and per 1,024-row block a grid step with its window's
+# DMA; a tile of ONE vreg reads under that line. Fitted to 53 timed
+# forms of cells 2, 9 and 10's own operators on the chip (one layout
+# and row-split at K 4 ... 64; rms error 3.9%, PERF.md section 3).
+SWELL_ENTRY_NS = 6.0          # a listed chunk, tiles of two vregs up
+SWELL_VREG_NS = 4.65          # a vreg-step of such a tile
+SWELL_ONE_VREG_NS = 9.2       # a listed chunk of a one-vreg tile
+SWELL_BLOCK_NS = 510.0        # a block
+
+# The row-split form as a CHOICE (`split_pays`): counted only for an
+# operator of SPLIT_MIN_NNZ non-zeros whose one layout would pad
+# SPLIT_SCREEN slots a non-zero (even rows never pay for the count),
+# taken only where the model puts it SPLIT_MARGIN under the one layout
+# (2.5 times the fit's rms error) and SPLIT_MIN_SAVING_S an application
+# (on a small operator a second launch is more than any share).
+SPLIT_PIECES = (4, 8, 16, 32, 64, 128)   # entries a piece, candidates
+SPLIT_MIN_NNZ = 20_000
+SPLIT_SCREEN = 2.25
+SPLIT_MARGIN = 0.10
+SPLIT_MIN_SAVING_S = 20e-6
+
+
+def model_seconds(listed, kpad, blocks) -> float:
+    """What the clock above predicts for one application of a layout
+    of `blocks` blocks whose row groups list `listed` chunks over tiles
+    of `kpad` slots."""
+    v = tile_vregs(kpad)
+    chunk = SWELL_ONE_VREG_NS if v == 1 \
+        else SWELL_ENTRY_NS + SWELL_VREG_NS * v
+    return 1e-9 * (int(listed) * chunk + int(blocks) * SWELL_BLOCK_NS)
+
+
+_notes = threading.local()
 
 
 @contextlib.contextmanager
-def collect_declines():
-    """The reasons `swell_budget` said no while the block ran, in
-    order, as a list (a set-up's layout spans carry them as their
-    `declined` arg)."""
-    outer = getattr(_declines, "log", None)
-    log = _declines.log = []
+def collect_layout_notes():
+    """What the layout choice said while the block ran, as a dict of
+    lists in order: `declined`, the reasons `swell_budget` said no, and
+    `chosen`, the row-split forms `split_pays` took over a layout the
+    budget admits (a set-up's layout spans carry both as args)."""
+    outer = getattr(_notes, "log", None)
+    log = _notes.log = {"declined": [], "chosen": []}
     try:
         yield log
     finally:
-        _declines.log = outer
+        _notes.log = outer
+
+
+def _note(kind: str, what: str):
+    log = getattr(_notes, "log", None)
+    if log is not None:
+        log[kind].append(what)
+
+
+def note_chosen(words: str):
+    """Counts a row-split form taken by `split_pays` and built
+    (`amg.layout.split.chosen`) and hands the choice in words to
+    whoever collects them."""
+    from ..telemetry import metrics as _tm
+    _tm.inc("amg.layout.split.chosen")
+    _note("chosen", words)
 
 
 def _declined(reason: str):
@@ -137,9 +198,23 @@ def _declined(reason: str):
     reason to whoever collects them."""
     from ..telemetry import metrics as _tm
     _tm.inc(f"amg.layout.declined.{reason}")
-    log = getattr(_declines, "log", None)
-    if log is not None:
-        log.append(reason)
+    _note("declined", reason)
+
+
+def _budget(kmax, w128_raw, nb, nnz):
+    """(kpad, w128) of a layout that pays, the reason (a str) why it
+    does not, or None for no entries at all; `swell_budget`'s rules."""
+    if kmax == 0:
+        return None                        # nothing to lay out
+    if kmax > SWELL_MAX_K:
+        return "kmax"
+    w128 = -(-int(w128_raw) // 8) * 8
+    if w128 * LANES > SWELL_MAX_W:
+        return "window"
+    kpad = _kpad(kmax)
+    if not _fill_ok(nb * SUBS * kpad * LANES, nnz):
+        return "fill"
+    return kpad, w128
 
 
 def swell_budget(kmax, w128_raw, nb, nnz):
@@ -161,17 +236,8 @@ def swell_budget(kmax, w128_raw, nb, nnz):
       layout to n*kpad slots; small layouts are exempt (round-to-8
       alone inflates tiny matrices past any ratio, and a <1M-slot
       layout cannot blow memory)."""
-    if kmax == 0:
-        return None                        # nothing to lay out
-    if kmax > SWELL_MAX_K:
-        return _declined("kmax")
-    w128 = -(-int(w128_raw) // 8) * 8
-    if w128 * LANES > SWELL_MAX_W:
-        return _declined("window")
-    kpad = _kpad(kmax)
-    if not _fill_ok(nb * SUBS * kpad * LANES, nnz):
-        return _declined("fill")
-    return kpad, w128
+    said = _budget(kmax, w128_raw, nb, nnz)
+    return _declined(said) if isinstance(said, str) else said
 
 
 def _kpad(kmax: int) -> int:
@@ -180,6 +246,31 @@ def _kpad(kmax: int) -> int:
 
 def _fill_ok(slots: int, nnz: int) -> bool:
     return slots <= 6 * max(nnz, 1) or slots <= (1 << 20)
+
+
+def _windows_host(ro, ci, n):
+    """(kmax, c0, w128_raw): the longest row, each 1,024-row block's
+    first window column and the widest window in 128-column chunks
+    (numpy form of native amgx_swell_windows)."""
+    nb = -(-n // BLOCK_ROWS)
+    starts = ro[:-1].astype(np.int64)
+    nonempty = ro[1:] > ro[:-1]
+    idx = np.clip(starts, 0, ci.shape[0] - 1)
+    big = np.iinfo(np.int32).max
+    rmin = np.where(nonempty, np.minimum.reduceat(ci, idx), big)
+    rmax = np.where(nonempty, np.maximum.reduceat(ci, idx), -1)
+    pad = nb * BLOCK_ROWS - n
+    if pad:
+        rmin = np.concatenate([rmin, np.full(pad, big)])
+        rmax = np.concatenate([rmax, np.full(pad, -1)])
+    bmin = rmin.reshape(nb, BLOCK_ROWS).min(axis=1)
+    bmax = rmax.reshape(nb, BLOCK_ROWS).max(axis=1)
+    empty_b = bmax < 0
+    bmin = np.where(empty_b, 0, bmin)
+    bmax = np.where(empty_b, 0, bmax)
+    c0 = (bmin // LANES) * LANES
+    span = bmax - c0 + 1
+    return int(np.diff(ro).max()), c0, -(-int(span.max()) // LANES)
 
 
 def build_swell_host(ro, ci, vals, num_rows, num_cols):
@@ -205,26 +296,8 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
     if kmax == 0 or kmax > SWELL_MAX_K:
         # cheap reject before the scan
         return None if kmax == 0 else _declined("kmax")
-    # per-row col extents -> per-super-block window
-    starts = ro[:-1].astype(np.int64)
-    nonempty = ro[1:] > ro[:-1]
-    idx = np.clip(starts, 0, ci.shape[0] - 1)
-    big = np.iinfo(np.int32).max
-    rmin = np.where(nonempty, np.minimum.reduceat(ci, idx), big)
-    rmax = np.where(nonempty, np.maximum.reduceat(ci, idx), -1)
-    pad = nb * BLOCK_ROWS - n
-    if pad:
-        rmin = np.concatenate([rmin, np.full(pad, big)])
-        rmax = np.concatenate([rmax, np.full(pad, -1)])
-    bmin = rmin.reshape(nb, BLOCK_ROWS).min(axis=1)
-    bmax = rmax.reshape(nb, BLOCK_ROWS).max(axis=1)
-    empty_b = bmax < 0
-    bmin = np.where(empty_b, 0, bmin)
-    bmax = np.where(empty_b, 0, bmax)
-    c0 = (bmin // LANES) * LANES
-    span = bmax - c0 + 1
-    budget = swell_budget(kmax, -(-int(span.max()) // LANES), nb,
-                          ci.shape[0])
+    kmax, c0, w128_raw = _windows_host(ro, ci, n)
+    budget = swell_budget(kmax, w128_raw, nb, ci.shape[0])
     if budget is None:
         return None
     kpad, _w128 = budget
@@ -246,62 +319,172 @@ def build_swell_host(ro, ci, vals, num_rows, num_cols):
             group_chunk_lists(ci, row_ids, c0, nb, _w128), _w128)
 
 
-SPLIT_PIECES = (4, 8, 16, 32, 64, 128)   # entries a piece, candidates
+def count_listed(ro, ci, num_rows):
+    """(kmax, w128_raw, listed) of the SWELL layout these row offsets
+    would give over this column array: the longest row, the widest
+    block window in chunks and the chunks its row groups would list,
+    from the pattern alone (the native window and chunk-list sweeps, or
+    their numpy forms); nothing is scattered, nothing counted as a
+    decline."""
+    n = int(num_rows)
+    from .. import native
+    out = native.swell_count_native(ro, ci, n)
+    if out is not None:
+        return out
+    kmax, c0, w128_raw = _windows_host(ro, ci, n)
+    row_ids = np.repeat(np.arange(n, dtype=np.int64), np.diff(ro))
+    nb = -(-n // BLOCK_ROWS)
+    lists = group_chunk_lists(ci, row_ids, c0, nb,
+                              -(-int(w128_raw) // 8) * 8)
+    return kmax, w128_raw, listed_chunks(lists)
 
 
-def split_rows_host(ro, ci, vals, num_rows, num_cols):
+def _piece_offsets(ro, pieces, K):
+    """Row offsets of A': piece j of row i holds the entries
+    [ro_i + j K, ro_i + (j + 1) K) of the operator's arrays."""
+    n_p = int(pieces.sum())
+    first = np.cumsum(pieces) - pieces           # row i's first piece
+    row_of = np.repeat(np.arange(pieces.shape[0], dtype=np.int64), pieces)
+    j = np.arange(n_p, dtype=np.int64) - first[row_of]
+    ro_p = np.empty(n_p + 1, np.int32)
+    ro_p[:-1] = ro[row_of] + j * K
+    ro_p[-1] = ro[-1]
+    return ro_p
+
+
+def _split_counts(ro, ci, lengths, K):
+    """(rows of A', its longest row, its widest block window in chunks,
+    its listed chunks, S's longest row, S's listed chunks) of the
+    row-split form at piece length K, from the pattern alone: one
+    native sweep (amgx_swell_split_count), or `count_listed` over the
+    row offsets of A' and, for S, whose row's pieces are adjacent
+    columns, the chunks from a row group's first piece to its last."""
+    n = lengths.shape[0]
+    from .. import native
+    out = native.swell_split_count_native(ro, ci, n, K)
+    if out is not None:
+        return out
+    pieces = -(-lengths // K)
+    ends = np.cumsum(pieces)
+    n_p = int(ends[-1])
+    kmax_a, w128_raw, listed_a = count_listed(
+        _piece_offsets(ro, pieces, K), ci, n_p)
+    g0 = np.arange(0, n, LANES)              # a row group's first row
+    last = ends[np.minimum(g0 + LANES, n) - 1] - 1
+    first = ends[g0] - pieces[g0]
+    listed_s = int(np.where(last >= first, last // LANES
+                            - first // LANES + 1, 0).sum())
+    return n_p, kmax_a, w128_raw, listed_a, int(pieces.max()), listed_s
+
+
+def _split_candidates(ro, ci, lengths):
+    """[(model seconds, K)] of the row-split forms that fit: the K of
+    SPLIT_PIECES under the longest row (from there on A' is the
+    operator itself; the smallest K stays, the last resort of an
+    operator the budget declines, and goes where the next one fits:
+    both tiles are one vreg and the smaller lists more chunks over
+    more blocks), whose S keeps
+    to exact short rows and that pass the budget, each with the
+    model's cost of A' plus S by `_split_counts`."""
+    n = lengths.shape[0]
+    nnz = int(ro[-1])
+    longest = int(lengths.max())
+    nb = -(-n // BLOCK_ROWS)
+    fits = [K for K in SPLIT_PIECES[1:] if K < longest] \
+        or list(SPLIT_PIECES[:1])
+    out = []
+    for K in fits:
+        if -(-longest // K) > 24:
+            continue                 # S stays on exact short rows
+        n_p, kmax_a, w128_raw, listed_a, kmax_s, listed_s = \
+            _split_counts(ro, ci, lengths, K)
+        said = _budget(kmax_a, w128_raw, -(-n_p // BLOCK_ROWS), nnz)
+        if not isinstance(said, tuple) \
+                or not _fill_ok(nb * BLOCK_ROWS * kmax_s, n_p):
+            continue                 # a part the budget would decline
+        out.append((model_seconds(listed_a, said[0], -(-n_p // BLOCK_ROWS))
+                    + model_seconds(listed_s, kmax_s, nb), K))
+    return out
+
+
+def _cheapest(candidates):
+    """The candidate the model puts lowest, ties to the larger K."""
+    return min(candidates, key=lambda c: (c[0], -c[1]), default=None)
+
+
+def split_pays(ro, ci, num_rows):
+    """(K, the choice in words) of the row-split form where the model
+    puts it clearly under the one SWELL layout `swell_budget` admits
+    (the caller builds it and says so: `note_chosen`), else None: a
+    function of the pattern alone (row lengths and chunk lists), so a
+    pattern takes the same form at every re-setup. Nothing is counted
+    for an operator under SPLIT_MIN_NNZ non-zeros, for one whose tile
+    is one vreg already (every P of a truncated interpolation), or for
+    one whose one layout pads under SPLIT_SCREEN slots a non-zero. The
+    margin is 2.5 times the rms error of the clock against the timed
+    operators; what the count does not see of a split (its second
+    launch; the Jacobi update, which leaves the fused sweep for XLA
+    ops and read the same to the third digit on the chip) matters on
+    a small operator alone: hence the least saving."""
+    n = int(num_rows)
+    nnz = int(ci.shape[0])
+    if n == 0 or nnz < SPLIT_MIN_NNZ:
+        return None
+    ro = np.asarray(ro).astype(np.int64)
+    lengths = np.diff(ro)
+    nb = -(-n // BLOCK_ROWS)
+    kpad = _kpad(int(lengths.max()))
+    if kpad <= SUBS or nb * BLOCK_ROWS * kpad < SPLIT_SCREEN * nnz:
+        return None
+    kmax, w128_raw, listed = count_listed(ro, ci, n)
+    if not isinstance(_budget(kmax, w128_raw, nb, nnz), tuple):
+        return None                  # declined: the caller's road
+    plain = model_seconds(listed, kpad, nb)
+    best = _cheapest(_split_candidates(ro, ci, lengths))
+    if best is None or plain - best[0] < max(SPLIT_MARGIN * plain,
+                                             SPLIT_MIN_SAVING_S):
+        return None
+    cost, K = best
+    return K, f"K={K} model {1e3 * cost:.3f} of {1e3 * plain:.3f} ms"
+
+
+def split_rows_host(ro, ci, vals, num_rows, num_cols, K=None):
     """The row-split form of a CSR operator whose rows are too uneven
     for one SWELL layout (`swell_budget` said `kmax` or `fill`: a mean
     of 43 entries under a longest row of 295 pads five slots an entry,
-    or has no slot count at all): every row is cut into pieces of at
-    most K consecutive entries, each piece a row of A' (n' rows, the
-    SAME column and value arrays under other row offsets), and the
-    pieces of a row are summed by S (n x n', ones, a row's pieces
-    adjacent): A = S A'. Both are ordinary SWELL operators, A' with
-    rows of at most K entries and the window of the rows it came from,
-    S with perfectly local columns, so the product runs through the
-    SWELL kernels that are there. K is the candidate of SPLIT_PIECES
-    that pads the fewest slots in A' and S together among those whose
-    S keeps to exact short rows and that pass the fill guard twice.
+    or has no slot count at all; or `split_pays` said the one layout
+    costs clearly more): every row is cut into pieces of at most K
+    consecutive entries, each piece a row of A' (n' rows, the SAME
+    column and value arrays under other row offsets), and the pieces of
+    a row are summed by S (n x n', ones, a row's pieces adjacent):
+    A = S A'. Both are ordinary SWELL operators, A' with rows of at
+    most K entries and the window of the rows it came from, S with
+    perfectly local columns, so the product runs through the SWELL
+    kernels that are there. `K` is `split_pays`'s where it chose;
+    without it, the candidate the model puts lowest
+    (`_split_candidates`).
 
     Returns ((ro', layout of A'), (ro_S, layout of S)) with each
     layout as `build_swell_host` gives it, or None where no candidate
     fits (counted by the declines that said so)."""
     n = int(num_rows)
     ro = np.asarray(ro).astype(np.int64)
-    lengths = np.diff(ro)
     nnz = int(ci.shape[0])
     if n == 0 or nnz == 0:
         return None
-    best = None
-    for K in SPLIT_PIECES:
-        pieces = -(-lengths // K)
-        n_p = int(pieces.sum())
-        kmax_s = int(pieces.max())
-        if kmax_s > 24:              # S stays on exact short rows
-            continue
-        slots_a = -(-n_p // BLOCK_ROWS) * BLOCK_ROWS \
-            * _kpad(min(K, int(lengths.max())))
-        slots_s = -(-n // BLOCK_ROWS) * BLOCK_ROWS * kmax_s
-        if not (_fill_ok(slots_a, nnz) and _fill_ok(slots_s, n_p)):
-            continue                 # the fill guard would say no
-        if best is None or slots_a + slots_s < best[0]:
-            best = (slots_a + slots_s, K, pieces, n_p)
-    if best is None:
-        return None
-    _slots, K, pieces, n_p = best
-    # A': piece j of row i holds entries [ro_i + j K, ro_i + (j + 1) K)
-    first = np.cumsum(pieces) - pieces           # row i's first piece
-    row_of = np.repeat(np.arange(n, dtype=np.int64), pieces)
-    j = np.arange(n_p, dtype=np.int64) - first[row_of]
-    ro_p = np.empty(n_p + 1, np.int32)
-    ro_p[:-1] = ro[row_of] + j * K
-    ro_p[-1] = nnz
+    if K is None:
+        best = _cheapest(_split_candidates(ro, ci, np.diff(ro)))
+        if best is None:
+            return None
+        K = best[1]
+    per_row = -(-np.diff(ro) // K)
+    ro_p = _piece_offsets(ro, per_row, K)
+    n_p = int(ro_p.shape[0]) - 1
     lay_a = build_swell_host(ro_p, ci, vals, n_p, num_cols)
     if lay_a is None:
         return None
     ro_s = np.zeros(n + 1, np.int32)
-    np.cumsum(pieces, out=ro_s[1:])
+    np.cumsum(per_row, out=ro_s[1:])
     lay_s = build_swell_host(ro_s, np.arange(n_p, dtype=np.int32),
                              np.ones(n_p, vals.dtype), n, n_p)
     if lay_s is None:

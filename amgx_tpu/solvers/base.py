@@ -560,6 +560,13 @@ class Solver(SolveDataOwner):
         pc = self.preconditioner
         return 0 if pc is None else pc.swell_vreg_steps_per_iteration()
 
+    def swell_model_s_per_iteration(self) -> float:
+        """Seconds the layout choice's model puts on the SWELL gather
+        of one iteration's cycle (the preconditioner's:
+        AMG.swell_model_s_per_cycle), kept as the vreg-steps are."""
+        pc = self.preconditioner
+        return 0.0 if pc is None else pc.swell_model_s_per_iteration()
+
     def csr_road_nnz_per_iteration(self) -> int:
         """Non-zeros one iteration's cycle sends down the XLA gather +
         segment-sum road (the preconditioner's:
@@ -1116,6 +1123,8 @@ class Solver(SolveDataOwner):
             swell_steps = self.swell_vreg_steps_per_iteration()
             if swell_steps:
                 _tm.inc("swell.vreg_steps", cycles * swell_steps)
+                _tm.add("swell.model_s",
+                        cycles * self.swell_model_s_per_iteration())
             csr_nnz = self.csr_road_nnz_per_iteration()
             if csr_nnz:
                 _tm.inc("cycle.csr_road_nnz", cycles * csr_nnz)

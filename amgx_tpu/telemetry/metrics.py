@@ -734,6 +734,18 @@ declare_counter("swell.vreg_steps",
                 "host layouts as a set-up ends; 0 where no operator has "
                 "the layout")
 
+declare_counter("swell.model_s",
+                "seconds the layout choice's model of the SWELL kernels "
+                "(ops/pallas_swell.model_seconds: a fixed part a listed "
+                "chunk + a part a vreg-step + a part a 1,024-row block, "
+                "the constants beside SPLIT_PIECES) puts on a solve's "
+                "cycles: raised after "
+                "each solve, as swell.vreg_steps is, by the cycles that "
+                "ran x the model's seconds over every SWELL application "
+                "of a cycle, both parts of a row-split operator "
+                "included; beside the traced `_swell_*` seconds it says "
+                "whether the model the choice trusts still holds")
+
 declare_counter("cycle.csr_road_nnz",
                 "non-zeros a solve's cycles sent down the XLA gather + "
                 "segment-sum road: raised after each solve by the "
@@ -753,6 +765,13 @@ for _reason, _what in (
                     + _what + "; the reason is also the `declined` arg "
                     "of the level's amg.L<k>.layout / .layoutP / "
                     ".transposeR span")
+declare_counter("amg.layout.split.chosen",
+                "operators (A, P or R of a level) whose one SWELL "
+                "layout the budget admits and that took the row-split "
+                "form because the model of their pattern's count puts "
+                "it clearly lower (ops/pallas_swell.split_pays); its K "
+                "and the two costs are the `chosen` arg of the level's "
+                "amg.L<k>.layout / .layoutP / .transposeR span")
 declare_counter("krylov.fused_calls",
                 "calls of the shell's single-pass Pallas kernels "
                 "(SpMV+dot, CG update), raised after each solve by the "

@@ -133,6 +133,8 @@ def _layout_kind(A) -> str:
         return "dia"
     if getattr(A, "swell_vals", None) is not None:
         return "swell"
+    if getattr(A, "split", None) is not None:
+        return "split"
     if getattr(A, "ell_vals", None) is not None:
         return "ell"
     return "csr"

@@ -444,7 +444,11 @@ def test_swell_kernels_compile(kernel, w128, kpad, longest, one_chip,
     (2, 4, 8, 5, 8),           # S of it: a row's pieces, adjacent
     (6, 4, 160, 16, 80),       # A' of a long-row level: pieces of 16
     (4, 6, 8, 18, 8),          # S of it
-    (5, 1, 112, 64, 112)])     # pieces of 64 under a window of the level
+    (5, 1, 112, 64, 112),      # pieces of 64 under a window of the level
+    # forms `split_pays` takes over a layout the budget admits (PR 51):
+    (64, 57, 1480, 8, 48),     # A' of cell 9's L0.R at K 8: one vreg a tile
+    (56, 64, 16, 2, 8),        # S of its L1.A: two pieces a row at most
+    (100, 50, 344, 16, 40)])   # A' of cell 10's L1.A at K 16: 798 blocks
 def test_row_split_operators_compile(rows, cols, w128, kpad, longest,
                                      one_chip, on_tpu, no_persistent_cache):
     """The two factors of the row-split SWELL form A = S A'

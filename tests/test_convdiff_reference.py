@@ -291,14 +291,14 @@ def test_swell_budget_names_why_it_says_no():
     from amgx_tpu.telemetry import metrics
     names = [f"amg.layout.declined.{r}" for r in ("kmax", "window", "fill")]
     before = [metrics.get(k) for k in names]
-    with ps.collect_declines() as said:
+    with ps.collect_layout_notes() as said:
         assert ps.swell_budget(8, 16, 4, 20000) == (8, 16)
         assert ps.swell_budget(ps.SWELL_MAX_K + 1, 16, 4, 10 ** 6) is None
         assert ps.swell_budget(8, ps.SWELL_MAX_W // 128 + 1, 4, 10 ** 6) \
             is None
         assert ps.swell_budget(200, 16, 2000, 10 ** 6) is None
         assert ps.swell_budget(0, 16, 4, 0) is None       # empty: no reason
-    assert said == ["kmax", "window", "fill"]
+    assert said == {"declined": ["kmax", "window", "fill"], "chosen": []}
     assert [metrics.get(k) - b for k, b in zip(names, before)] == [1, 1, 1]
 
 

@@ -264,7 +264,7 @@ def test_grid_stats_dict_and_text_render(poisson16):
     assert d["operator_complexity"] >= 1.0
     assert sum(r["rows"] for r in d["levels"]) == d["total_rows"]
     for row in d["levels"]:
-        assert row["layout"] in ("dia", "ell", "swell", "csr")
+        assert row["layout"] in ("dia", "ell", "swell", "split", "csr")
     # the text report renders FROM the dict (same numbers, same count)
     text = amg.grid_stats()
     assert f"Number of Levels: {d['num_levels']}" in text

@@ -157,11 +157,12 @@ PARENT_SOLVE_COUNTERS = {
         "solve.stage_s.prepare", "solve.stage_s.readback",
         "solve.stage_s.report", "solve.stage_s.run", "solve_data.reuse"},
     # PR 48: a cycle over SWELL operators counts its vreg-steps, a
-    # product of two numbers the hierarchy and the solve already hold
+    # product of two numbers the hierarchy and the solve already hold;
+    # PR 51: and the seconds the layout choice's model puts on them
     "classical": {
         "solve.stage_s.prepare", "solve.stage_s.readback",
         "solve.stage_s.report", "solve.stage_s.run", "solve_data.reuse",
-        "swell.vreg_steps"},
+        "swell.vreg_steps", "swell.model_s"},
 }
 PARENT_SOLVE_COUNTERS["flagship-reuse-p7-256"] = \
     PARENT_SOLVE_COUNTERS["flagship-p7-128"]

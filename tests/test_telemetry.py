@@ -260,7 +260,7 @@ def test_solve_report_contents(poisson12_3d):
     assert len(rep.levels) >= 2
     assert rep.levels[0]["rows"] == poisson12_3d.num_rows
     for row in rep.levels:
-        assert row["layout"] in ("dia", "ell", "swell", "csr")
+        assert row["layout"] in ("dia", "ell", "swell", "split", "csr")
     assert rep.levels[-1].get("coarse_solver") == "DENSE_LU_SOLVER"
     assert rep.solve_time_s > 0
 

@@ -109,6 +109,51 @@ def find_amg(solver):
     raise ValueError("no AMG preconditioner in this solver tree")
 
 
+def layout_rows(amg, records) -> list:
+    """The operator's log of the SWELL layouts, one row an operator a
+    cycle applies (a level's A, P, R; the coarsest A) that has one:
+    its layout; `how` it came by it, from the args of the set-up's
+    layout spans in `records` (`spans.records()`: `amg.L<k>.layout`
+    lays out level k + 1's operator, `.layoutP` / `.transposeR` level
+    k's transfers): `chosen` with the K and the model's two costs where
+    the row-split form was taken over a layout the budget admits,
+    `declined` with the budget's reason where it had to be; the slots
+    of each part's tile (a split's A' then S: A''s is its K), the
+    chunks its row groups list, its blocks, its vreg-steps and the
+    milliseconds
+    the choice's model puts on one application. What the next
+    `perf_opt` sizes from."""
+    from amgx_tpu.ops.pallas_swell import model_seconds, tile_vregs
+    said = {r["name"]: r["args"] for r in records if r.get("args")}
+    rows = []
+    for k, lv in enumerate(list(amg.levels) + [None]):
+        ops = (("A", amg.coarsest_A, f"amg.L{k - 1}.layout"),) if lv is None \
+            else (("A", lv.A, f"amg.L{k - 1}.layout"),
+                  ("P", lv.P, f"amg.L{k}.layoutP"),
+                  ("R", lv.R, f"amg.L{k}.transposeR"))
+        for name, M, span in ops:
+            parts = amg.swell_account(M)
+            if not parts:
+                continue
+            args = said.get(span, {})
+            how = " ".join(f"{key} {args[key]}" for key in
+                           ("chosen", "declined") if key in args)
+            lengths = np.diff(np.asarray(M.row_offsets))
+            rows.append({
+                "op": f"L{k}.{name}", "rows": int(M.num_rows),
+                "mean_row": round(float(lengths.mean()), 1),
+                "longest_row": int(lengths.max()),
+                "layout": amg._layout_of(M), "how": how,
+                "kpad": [part[1] for part in parts],
+                "listed": [part[0] for part in parts],
+                "blocks": [part[2] for part in parts],
+                "vreg_steps": sum(listed * tile_vregs(kpad)
+                                  for listed, kpad, _blocks in parts),
+                "model_ms": round(1e3 * sum(
+                    model_seconds(*part) for part in parts), 4)})
+    return rows
+
+
 def _csr(A) -> sp.csr_matrix:
     return sp.csr_matrix((np.asarray(A.values, dtype=np.float64),
                           np.asarray(A.col_indices),

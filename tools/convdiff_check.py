@@ -20,7 +20,11 @@ stops after its own. `setup` ends after `Solver.setup` and two solves
 and prints the operator's log: every level's rows, non-zeros, mean and
 longest row and the layout its operator, its P and its R took
 (`AMGX_solver_get_grid_stats`' rows with the transfers beside them),
-why `swell_budget` said no where it did, the non-zeros a cycle sends
+why `swell_budget` said no where it did, a `layout` line for every
+operator in a SWELL layout (`classical_reuse_check.layout_rows`:
+`chosen` by the model or forced by a `declined` budget, the slots of
+its tiles, listed chunks, vreg-steps, the model's ms an application),
+the non-zeros a cycle sends
 down the XLA gather road counted by hand from those rows beside the
 program's `cycle.csr_road_nnz`, the Galerkin plans' bytes, the set-up's
 timers and counters, and every `resilience.*` counter (all 0, or the
@@ -84,7 +88,7 @@ if ROOT not in sys.path:
 
 from benchmark import reference_convdiff as reference  # noqa: E402
 from tools.classical_reuse_check import (  # noqa: E402
-    HALF_ULP, find_amg, limits as galerkin_limits)
+    HALF_ULP, find_amg, layout_rows, limits as galerkin_limits)
 from tools.spe10_check import (  # noqa: E402
     _arrays, _difference, _finish, _unsummed)
 
@@ -94,6 +98,7 @@ HISTORY = 4             # residual-history entries held to the reference
 COUNTERS = (
     "amg.setup.full", "amg.layout.declined.kmax",
     "amg.layout.declined.window", "amg.layout.declined.fill",
+    "amg.layout.split.chosen", "swell.model_s",
     "amg.spgemm.plan_build", "amg.spgemm.plan_hit",
     "cycle.csr_road_nnz", "swell.vreg_steps", "krylov.fused_dispatch",
     "krylov.fused_declined", "krylov.fused_calls",
@@ -357,6 +362,7 @@ def main(argv=None):
     out["declined"] = [
         {"span": r["name"], "reason": r["args"]["declined"]}
         for r in spans.records() if "declined" in r.get("args", {})]
+    out["layouts"] = layout_rows(amg, spans.records())
     out["counters"] = {k: counters().get(k, 0) for k in COUNTERS}
     cycles = 2 * sum(s["iterations"] for s in solves)
     out["csr_road_nnz"] = {
@@ -374,6 +380,8 @@ def main(argv=None):
         spans.flat_timers().items()) if k.startswith("amg.") and tot >= 0.05}
     for row in out["levels"]:
         print("level", json.dumps(row))
+    for row in out["layouts"]:
+        print("layout", json.dumps(row))
     for key in ("complexity", "declined", "counters", "csr_road_nnz",
                 "rap_plans", "resilience", "setup_timers"):
         print(key, json.dumps(out[key]), flush=True)

@@ -21,7 +21,12 @@ call gives both where chip time is short). `setup` ends after
 `Solver.setup` and two solves
 and prints the operator's log: every level's rows, non-zeros and the
 layout its operator and its P took, the levels that declined the
-constant-stencil form with the reason, the set-up's counters, and every
+constant-stencil form with the reason, a `layout` line for every
+operator in a SWELL layout (`classical_reuse_check.layout_rows`: one
+layout or the row-split form, `chosen` by the model or forced by a
+`declined` budget, the slots of its tiles, the chunks its row groups
+list, its vreg-steps and the model's ms an application: what the next
+`perf_opt` on the kernels sizes from), the set-up's counters, and every
 `resilience.*` counter, which all have to stand at 0 (no
 `config_fallback`, no guard swap). `compare` adds the comparison;
 `solves` adds, in its place, the program's iteration counts beside the
@@ -82,13 +87,16 @@ if ROOT not in sys.path:
 
 from benchmark import reference_spe10 as reference  # noqa: E402
 from tools.classical_reuse_check import (  # noqa: E402
-    HALF_ULP, find_amg, limits as galerkin_limits)
+    HALF_ULP, find_amg, layout_rows, limits as galerkin_limits)
 
 STAGES = ("setup", "compare", "solves")
 CONFIG = "spe10-classical-l1trunc"
 COUNTERS = (
     "amg.setup.full", "amg.strength.weakened_rows",
     "amg.interp.truncated_rows", "amg.stencil.declined",
+    "amg.layout.split.chosen", "amg.layout.declined.kmax",
+    "amg.layout.declined.window", "amg.layout.declined.fill",
+    "swell.vreg_steps", "swell.model_s",
     "krylov.arnoldi_steps", "krylov.restarts", "solver.retrace.solve",
     "compile.programs")
 
@@ -338,6 +346,7 @@ def main(argv=None):
     out["declined"] = [
         {"span": r["name"], "reason": r["args"]["declined"]}
         for r in spans.records() if "declined" in r.get("args", {})]
+    out["layouts"] = layout_rows(amg, spans.records())
     out["counters"] = {k: counters().get(k, 0) for k in COUNTERS}
     out["resilience"] = counters("resilience.")
     out["setup_timers"] = {k: round(tot, 3) for k, (_c, tot) in sorted(
@@ -347,6 +356,8 @@ def main(argv=None):
     print("coarsest", json.dumps(out["coarsest"]), "complexity",
           json.dumps(out["complexity"]))
     print("declined", json.dumps(out["declined"]))
+    for row in out["layouts"]:
+        print("layout", json.dumps(row))
     print("counters", json.dumps(out["counters"]))
     print("resilience", json.dumps(out["resilience"]))
     print("setup timers", json.dumps(out["setup_timers"]), flush=True)
