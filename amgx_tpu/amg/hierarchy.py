@@ -13,6 +13,7 @@ loop, include/amg_level.h:51). Redesign for XLA:
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
@@ -1227,11 +1228,11 @@ class AMG(SolveDataOwner):
         precision and the correction is returned in the caller's dtype."""
         from .cycles import run_cycle
         dt = self._PRECISIONS[self.precision]
-        if dt is None:
-            return run_cycle(self, self.cycle_name, data, b, x)
         out_dtype = x.dtype
-        x = run_cycle(self, self.cycle_name, data,
-                      b.astype(dt), x.astype(dt))
+        if dt is not None:
+            b, x = b.astype(dt), x.astype(dt)
+        with self._note_dia_smooth():
+            x = run_cycle(self, self.cycle_name, data, b, x)
         return x.astype(out_dtype)
 
     def cycle_dot(self, data, b, x):
@@ -1243,7 +1244,24 @@ class AMG(SolveDataOwner):
         from .cycles import run_cycle_dot
         if self._PRECISIONS[self.precision] is not None:
             return self.cycle(data, b, x), None
-        return run_cycle_dot(self, self.cycle_name, data, b, x)
+        with self._note_dia_smooth():
+            return run_cycle_dot(self, self.cycle_name, data, b, x)
+
+    _dia_smooth = (0, 0)    # what the last traced cycle launched
+
+    @contextlib.contextmanager
+    def _note_dia_smooth(self):
+        """Keep what ONE cycle launches of the fused DIA smoother
+        (`_dia_smooth_call`s, and the lane-rows x applications their
+        plans compute, a halo's or a drain's included) while it is
+        TRACED: static per program, and no set-up clears it, so a
+        rebuild that keeps the program keeps its count."""
+        with _ps.count_smooth_launches() as tally:
+            yield
+        self._dia_smooth = tuple(tally)
+
+    def dia_smooth_per_cycle(self):
+        return self._dia_smooth
 
     # -- observability ----------------------------------------------------
     @staticmethod

@@ -57,7 +57,7 @@ _AMG_ATTRS = ("algorithm", "cycle_name", "cycle_iters", "cycle_fusion",
 _NOT_TRACED = frozenset({
     "A", "cfg", "setup_time", "_jit_cache", "_batched",
     "_batched_wrappers", "_color_steps", "_geo_transfers", "_reused",
-    "_data_cache", "_swell_steps"})
+    "_data_cache", "_swell_steps", "_dia_smooth"})
 
 
 def _aval(x):

@@ -66,6 +66,9 @@ class AlgebraicMultigridSolver(Solver):
     def swell_vreg_steps_per_iteration(self):
         return self.amg.swell_vreg_steps_per_cycle()
 
+    def dia_smooth_per_iteration(self):
+        return self.amg.dia_smooth_per_cycle()
+
     def swell_model_s_per_iteration(self):
         return self.amg.swell_model_s_per_cycle()
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import typing
 
 import jax
 import jax.numpy as jnp
@@ -289,30 +290,52 @@ def dia_spmv(A, x, interpret=False):
 # (Jacobi/Jacobi-L1: tau_s = relaxation_factor, dinv = D^{-1};
 #  CHEBYSHEV_POLY: tau_s = magic-damping taus, dinv absent) followed by
 # r = b - A x_S. Unfused, that is S+1 HBM passes over A's diagonal slab
-# plus an elementwise pass per sweep. This kernel runs all S sweeps AND
-# the residual epilogue in ONE pallas_call via temporal blocking: each
-# grid block loads a row window wide enough to compute all applications
-# locally (redundant halo compute), so A's values stream from HBM once.
+# plus an elementwise pass per sweep. One pallas_call runs all S sweeps
+# AND the residual epilogue, in one of two forms (dia_smooth_plan):
 #
-# Window math (rows of 128 lanes). Per application the data dependence
-# grows mr0 rows downward and Mr0 rows upward (mr0 = ceil(max(0,-min d)
-# / 128), Mr0 = max(0, max d)//128 + 1). With n_app applications
-# (n_app = sweeps + 1 when the residual is fused):
+# ONE BLOCK (the level fits a block: rows <= _BR_CAP). The block loads
+# a row window wide enough to compute all applications locally. Per
+# application the data dependence grows mr0 rows downward and Mr0 rows
+# upward (mr0 = ceil(max(0,-min d) / 128), Mr0 = max(0, max d)//128 + 1).
+# With n_app applications (n_app = sweeps + 1 when the residual rides):
 #   win_v = br + (n_app-1)*(mr0+Mr0)    # vals/b/dinv window (compute rows)
 #   win_x = win_v + mr0 + Mr0           # x window (read halo on top)
-# The x state buffer lives in "window coordinates" (row j = x row
-# i*br - n_app*mr0 + j); each application computes rows [mr0, mr0+win_v)
+# The x state lives in "window coordinates" (row j = x row
+# -n_app*mr0 + j); each application computes rows [mr0, mr0+win_v)
 # of the next state and zero-fills the shrinking edges — the zeros land
 # exactly on rows already invalidated by the dependence cone, so the
-# final block rows [n_app*mr0, n_app*mr0+br) are exact.
+# final rows [n_app*mr0, n_app*mr0+br) are exact. b and x are padded
+# in-trace to that window (a level of at most _BR_CAP rows: 1 MiB).
 #
-# The values/b/dinv operands need (n_app-1)*mr0 front-halo rows, which
-# the tile-aligned dia_vals store does not carry; callers pass PRE-PADDED
-# operand slabs (built once per setup/resetup by ops.smooth and carried
-# in the smoother's solve_data) so no per-cycle re-layout of A happens.
+# CARRY (more than one block). The grid runs its row blocks in order on
+# one core, so nothing is recomputed: level t (x after t sweeps; the
+# residual is level n_app) trails level t-1 by a fixed SKEW of rows, at
+# least the operator's forward reach. At grid step i level t computes
+# rows [i*br - t*skew, +br) from the newest br + skew + back-reach rows
+# of level t-1, which stay in VMEM: every level keeps a ring of its
+# newest rows that persists across grid steps and moves down by br rows
+# a step (static slices only). x, b and the value/dinv slabs are
+# streams: block i+1 is DMA'd behind the ring while step i computes,
+# straight from the caller's arrays (no padded copy: rows outside
+# [0, n) are zero-filled in VMEM, and the masks / the quota slab's zero
+# rows keep every level zero there). Outputs leave block-aligned with a
+# LAG of ceil(n_app*skew / br) steps, which are also the extra grid
+# steps that drain the pipeline. Every row of every level is computed
+# once: x and b are read once and x' (and r) written once a stage.
+#
+# The values/dinv operands arrive as QUOTA-PADDED slabs (built once per
+# setup/resetup by ops.smooth and carried in the smoother's solve_data)
+# so no per-cycle re-layout of A happens.
 # ---------------------------------------------------------------------------
 
 _SMOOTH_VMEM_BUDGET = VMEM_LIMIT * 11 // 64   # 11 MiB, as above
+# the carry form counts everything it holds — rings, output blocks and
+# the body's planes — and keeps a quarter of the limit for what the
+# compiler adds (tests/test_chip_compile.py asks Mosaic)
+_CARRY_VMEM_BUDGET = VMEM_LIMIT * 3 // 4
+# a grid step's fixed cost in the carry plan's work model, in
+# row-applications (0.35 us a step against 1 ns a row-application)
+_CARRY_STEP_ROWS = 512
 SMOOTH_MAX_APPS = 8          # sweeps + residual cap for one fused call
 _BR_CAP = 2048               # largest candidate block size
 
@@ -390,12 +413,15 @@ def smooth_br_candidates(num_rows: int):
 
 def smooth_quota_rows(offsets, num_rows: int):
     """(front, content, back) rows of the quota-padded operand slabs
-    (values / dinv) the fused kernel DMAs windows from. The quota is
-    sized for ANY plan up to SMOOTH_MAX_APPS applications and _BR_CAP
-    block rows, so ONE padded slab per matrix (built at setup by
-    ops.smooth) serves every sweep count the cycle asks for — the
-    sweep count is only known at trace time, after the solve-data
-    pytree is already fixed."""
+    (values / dinv) the fused kernel DMAs row blocks from. ONE padded
+    slab per matrix (built at setup by ops.smooth) serves every sweep
+    count the cycle asks for — the sweep count is only known at trace
+    time, after the solve-data pytree is already fixed. The front quota
+    is sized for SMOOTH_MAX_APPS applications of the one-block form's
+    window; the carry form reads no row before the content, but the
+    layout is shared with distributed/fused.py, which FILLS both quotas
+    with the neighbour shards' rows and reads them in its boundary
+    strips."""
     mr0, Mr0 = smooth_halo_rows(offsets)
     rows128 = max(1, -(-num_rows // LANES))
     content = max(8, -(-rows128 // 8) * 8)
@@ -427,17 +453,19 @@ _MF_WORK_ROWS = 6
 
 
 def smooth_body_planes(k: int, coeffs: bool) -> int:
-    """(win_v, 128) f32 planes the fused smoother's BODY keeps live in
-    VMEM on top of its DMA windows: state, accumulator and shifted
-    views, and in the matrix-free form the k masked value planes the
-    compiler hoists out of the application loop. An upper bound from
-    compiling for v5e and searching the smallest vmem_limit_bytes that
-    is accepted: the slab form needed up to 8 planes (7-pt, 32^3 to
-    128^3), the matrix-free form up to 21 at k = 7 and 66 at k = 27
-    (19.5 MiB where its windows took 4.9 MiB at 7-pt 64^3, 5 sweeps +
-    residual — what XLA refused under the 16 MiB default with "Scoped
-    allocation with size 17.23M and limit 16.00M exceeded scoped vmem
-    limit" — and 48 MiB at 27-pt 128^3)."""
+    """(compute rows, 128) f32 planes the fused smoother's BODY keeps
+    live in VMEM on top of its DMA windows and rings (compute rows:
+    win_v in the one-block form, br in the carry form, whose levels run
+    one after another over the same planes): state, accumulator and
+    shifted views, and in the matrix-free form the k masked value
+    planes. An upper bound from compiling for v5e and searching the
+    smallest vmem_limit_bytes that is accepted: the slab form needed
+    up to 8 planes (7-pt, 32^3 to 128^3), the matrix-free form up to
+    21 at k = 7 and 66 at k = 27 (19.5 MiB where its windows took
+    4.9 MiB at 7-pt 64^3, 5 sweeps + residual — what XLA refused under
+    the 16 MiB default with "Scoped allocation with size 17.23M and
+    limit 16.00M exceeded scoped vmem limit" — and 48 MiB at 27-pt
+    128^3)."""
     return 3 * k if coeffs else 10
 
 
@@ -476,20 +504,14 @@ def _mf_ok(shape, coords, shift, base):
     return ok
 
 
-def _mf_vals_dinv(mf, cget, coords, valid, cdt):
-    """(val(t), dinv rows | None) synthesized from coefficient scalars.
-    `cget(t)` reads diagonal t's scalar at `cdt` (SMEM ref or array);
-    `valid` is the row-valid mask of the window. val(t) reproduces the
-    slab row (coefficient on in-grid rows, 0 on halo/off-grid rows);
-    the dinv rows reproduce safe_recip of the plain ("jacobi") or
-    L1-strengthened ("l1") diagonal the smoother would have shipped."""
-
-    def val(t):
-        ok = _mf_ok(mf.shape, coords, mf.shifts[t], valid)
-        return jnp.where(ok, cget(t), jnp.zeros((), cdt))
-
+def _mf_dinv(mf, cget, ok, valid, cdt):
+    """The dinv rows (or None) the smoother would have shipped,
+    synthesized from coefficient scalars: safe_recip of the plain
+    ("jacobi") or L1-strengthened ("l1") diagonal. `ok(t)` is diagonal
+    t's mask (its shift stays in the grid, on a real row), `valid` the
+    row-valid mask."""
     if mf.dinv is None:
-        return val, None
+        return None
     c0 = cget(mf.diag_rank)
     if mf.dinv == "jacobi":
         den = jnp.where(valid, c0, jnp.zeros((), cdt))
@@ -498,14 +520,29 @@ def _mf_vals_dinv(mf, cget, coords, valid, cdt):
         for t in range(len(mf.shifts)):
             if t == mf.diag_rank:
                 continue
-            ok = _mf_ok(mf.shape, coords, mf.shifts[t], valid)
-            l1 = l1 + jnp.where(ok, jnp.abs(cget(t)),
+            l1 = l1 + jnp.where(ok(t), jnp.abs(cget(t)),
                                 jnp.zeros((), cdt))
         den = jnp.where(valid, c0 + jnp.sign(c0) * l1,
                         jnp.zeros((), cdt))
-    dw = jnp.where(den == 0, jnp.zeros((), cdt),
-                   1 / jnp.where(den == 0, jnp.ones((), cdt), den))
-    return val, dw
+    return jnp.where(den == 0, jnp.zeros((), cdt),
+                     1 / jnp.where(den == 0, jnp.ones((), cdt), den))
+
+
+def _mf_vals_dinv(mf, cget, coords, valid, cdt):
+    """(val(t), dinv rows | None) synthesized from coefficient scalars.
+    `cget(t)` reads diagonal t's scalar at `cdt` (SMEM ref or array);
+    `valid` is the row-valid mask of the window. val(t) reproduces the
+    slab row (coefficient on in-grid rows, 0 on halo/off-grid rows);
+    the dinv rows reproduce what the smoother would have shipped
+    (_mf_dinv)."""
+
+    def ok(t):
+        return _mf_ok(mf.shape, coords, mf.shifts[t], valid)
+
+    def val(t):
+        return jnp.where(ok(t), cget(t), jnp.zeros((), cdt))
+
+    return val, _mf_dinv(mf, cget, ok, valid, cdt)
 
 
 def _mf_block_vals(mf, coeffs_ref, row0, win_v, col, cdt):
@@ -521,25 +558,77 @@ def _mf_block_vals(mf, coeffs_ref, row0, win_v, col, cdt):
                          coords, valid, cdt)
 
 
+def _r8(rows: int) -> int:
+    return -(-rows // 8) * 8
+
+
+class SmoothPlan(typing.NamedTuple):
+    """Block plan of one fused smoother call (dia_smooth_plan).
+    `lag` == 0 is the one-block form (win_x / win_v are its windows);
+    `lag` > 0 the carry form: `n_blocks` row blocks of `br` rows, level
+    t trailing level t-1 by `skew` rows, `lag` drain steps, win_v = br
+    rows computed a level a step and win_x the rows one level reads."""
+    br: int
+    n_app: int
+    mr0: int
+    Mr0: int
+    win_x: int
+    win_v: int
+    n_blocks: int
+    skew: int = 0
+    lag: int = 0
+
+    @property
+    def steps(self) -> int:
+        return self.n_blocks + self.lag
+
+    @property
+    def row_apps(self) -> int:
+        """Lane-rows x applications the call COMPUTES (halo rows of
+        the one-block window and the carry form's drain included)."""
+        return self.n_app * self.steps * self.win_v
+
+
+def _carry_rings(br, skew, lag, back, n_steps, with_residual,
+                 with_dot=True):
+    """Ring lengths (rows) of the carry form, shared by the plan's VMEM
+    arithmetic and the kernel so the two cannot diverge. After step i
+    a stream's ring ends at row (i+1)*br and level t's at
+    (i+1)*br - t*skew; a ring is as long as its farthest reader needs:
+    the next level's window (br + skew + back rows), the levels'
+    b / value rows (down to level n_app), the lagged output block."""
+    n_app = n_steps + (1 if with_residual else 0)
+    read = br + skew + back
+    out = (lag + 1) * br
+    return {
+        "x": read,
+        "mid": read,                          # levels 1 .. n_steps-1
+        "last": max(out - n_steps * skew,     # level n_steps: x'
+                    read if with_residual else br),
+        "res": out - n_app * skew,            # the residual's level
+        "b": out if with_dot else br + n_app * skew,
+        "vals": br + n_app * skew,
+    }
+
+
 def dia_smooth_plan(offsets, k: int, num_rows: int, n_steps: int,
                     with_residual: bool, itemsize: int = 4,
                     coeffs: bool = False):
-    """Block plan for the fused smoother or None when it does not pay.
+    """Block plan (SmoothPlan) for the fused smoother, or None when no
+    form fits VMEM or the schedule is longer than SMOOTH_MAX_APPS.
 
-    Returns (br, n_app, mr0, Mr0, win_x, win_v, n_blocks). The block
-    size is the largest that fits the double-buffered windows in the
-    VMEM budget; the plan is rejected when the halo recompute would
-    cost more HBM traffic than the unfused n_app passes it replaces
-    (callers then chain shorter fused calls instead). `itemsize` is
-    the operand-slab byte width: bf16 slabs (2) halve the DMA-window
-    footprint so larger blocks fit, at the cost of the f32 upcast
-    working set the budget accounts below. `coeffs` plans the
-    matrix-free form: the values/dinv slabs (the k-stream that
-    dominates both the HBM traffic and the VMEM budget) vanish — the
-    kernel synthesizes masked value rows in-register from k SMEM
-    scalars, paying only a coordinate/mask working set — so the
-    halved traffic model admits larger blocks and the guard almost
-    never rejects."""
+    A level of at most _BR_CAP rows is ONE block with the halo window
+    of all its applications. Anything larger takes the carry form:
+    the skew is the operator's forward reach rounded to the 8-row
+    tiling (for a 7-point stencil whose z-plane is whole 8-row tiles,
+    exactly one plane, so every level sees the same x/y coordinates),
+    and the block size is the candidate with the least modelled work —
+    rows computed over all steps, the drain's included, plus the rows
+    the rings move a step and a fixed charge a step — among those whose
+    rings, output blocks and body fit VMEM_LIMIT. `itemsize` is the
+    operand-slab byte width (bf16 streams narrow; levels stay f32).
+    `coeffs` plans the matrix-free form: no value/dinv streams, the
+    kernel synthesizes masked value rows from k SMEM scalars."""
     if not offsets:
         return None
     n_app = int(n_steps) + (1 if with_residual else 0)
@@ -549,10 +638,13 @@ def dia_smooth_plan(offsets, k: int, num_rows: int, n_steps: int,
     mr0, Mr0 = smooth_halo_rows(offsets)
     H = mr0 + Mr0
     rows128 = max(1, -(-num_rows // LANES))
-    for br in smooth_br_candidates(num_rows):
+    n_out = 2 if with_residual else 1
+    planes = smooth_body_planes(k, coeffs)
+    cands = smooth_br_candidates(num_rows)
+    if cands[0] >= rows128:         # the level is one block
+        br = cands.pop(0)
         win_v = br + (n_app - 1) * H
         win_x = win_v + H
-        n_out = 2 if with_residual else 1
         if coeffs:
             vmem = (2 * (win_v + win_x)  # b/x windows, 2 slots
                     + 2 * n_out * br     # pipelined output blocks
@@ -567,23 +659,34 @@ def dia_smooth_plan(offsets, k: int, num_rows: int, n_steps: int,
             # sub-f32 operands: the f32 state + per-application upcast
             # temporaries ride on top of the narrow DMA buffers
             vmem += (win_x + 3 * win_v) * LANES * 4
-        body = smooth_body_planes(k, coeffs) * win_v * LANES * 4
-        if vmem > _SMOOTH_VMEM_BUDGET or vmem + body > VMEM_LIMIT:
+        body = planes * win_v * LANES * 4
+        if vmem <= _SMOOTH_VMEM_BUDGET and vmem + body <= VMEM_LIMIT:
+            return SmoothPlan(br, n_app, mr0, Mr0, win_x, win_v, 1)
+    back = _r8(mr0)
+    fwd = max(0, max(o // LANES + (1 if o % LANES else 0)
+                     for o in offsets))
+    skew = max(8, _r8(fwd))
+    best = None
+    for br in cands:
+        lag = -(-n_app * skew // br)
+        ring = _carry_rings(br, skew, lag, back, n_steps, with_residual)
+        streams = ring["x"] + ring["b"] + 2 * br
+        if not coeffs:
+            streams += (k + 1) * (ring["vals"] + br)
+        levels = (n_steps - 1) * ring["mid"] + ring["last"] \
+            + (ring["res"] if with_residual else 0)
+        vmem = (streams + 2 * n_out * br) * LANES * ib \
+            + (levels + planes * br) * LANES * 4
+        if vmem > _CARRY_VMEM_BUDGET:
             continue
-        # traffic guard: the fused windows must undercut the n_app
-        # separate passes (matrix-free: A contributes no stream on
-        # either side, so only the b/x/y vectors count)
-        if coeffs:
-            fused = 2 * win_v + win_x
-            unfused = n_app * 4 * br
-        else:
-            fused = (k + 2) * win_v + win_x
-            unfused = n_app * (k + 3) * br
-        if n_app > 1 and fused >= 0.9 * unfused:
-            return None     # halo dominates; caller chains smaller calls
-        n_blocks = -(-rows128 // br)
-        return br, n_app, mr0, Mr0, win_x, win_v, n_blocks
-    return None
+        nb = -(-rows128 // br)
+        work = (nb + lag) * (n_app * br + (streams + levels) // 4
+                             + _CARRY_STEP_ROWS)
+        if best is None or work < best[0]:
+            best = (work, SmoothPlan(br, n_app, mr0, Mr0,
+                                     back + br + _r8(fwd), br, nb,
+                                     skew, lag))
+    return None if best is None else best[1]
 
 
 def dia_smooth_supported(A, x_dtype, n_steps: int,
@@ -600,6 +703,25 @@ def dia_smooth_supported(A, x_dtype, n_steps: int,
                            with_residual,
                            itemsize=jnp.dtype(x_dtype).itemsize) \
         is not None
+
+
+def _smooth_refs(refs, mf, has_dinv, with_residual, with_dot):
+    """The carry kernel's operand and output refs in call order — x,
+    [vals_q], b, [dinv_q] | [coeffs], taus, out_x, [out_r], [out_dot]
+    (None where absent) — and an iterator over the scratch refs behind
+    them."""
+    it = iter(refs)
+    x_ref = next(it)
+    vals_ref = next(it) if mf is None else None
+    b_ref = next(it)
+    dinv_ref = next(it) if has_dinv else None
+    coeffs_ref = next(it) if mf is not None else None
+    taus_ref = next(it)
+    y_ref = next(it)
+    r_ref = next(it) if with_residual else None
+    d_ref = next(it) if with_dot else None
+    return (x_ref, vals_ref, b_ref, dinv_ref, coeffs_ref, taus_ref,
+            y_ref, r_ref, d_ref), it
 
 
 def _dia_smooth_kernel(offsets, br, n_app, mr0, Mr0, win_x, win_v,
@@ -750,45 +872,361 @@ def _dia_smooth_kernel(offsets, br, n_app, mr0, Mr0, win_x, win_v,
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "offsets", "num_rows", "with_residual", "mf", "with_dot",
-    "interpret"))
-def _dia_smooth_call(vals_q, dinv_q, taus, b, x, offsets, num_rows,
-                     with_residual, mf=None, coeffs=None,
-                     with_dot=False, interpret=False):
-    """Run the fused smoother kernel. `vals_q` (k, Q, 128) and `dinv_q`
-    ((Q, 128) or None) are the QUOTA-PADDED operand slabs from
-    ops.smooth (built once per setup, smooth_quota_rows layout); b and
-    x are padded in-trace (the same cost the plain SpMV kernel already
-    pays for x). Caller must have checked dia_smooth_supported.
-    Matrix-free form (`mf` spec + `coeffs` (k,)): vals_q/dinv_q are
-    None — the A-operand stream vanishes and the k coefficients ride
-    SMEM next to the taus. `with_dot` (postsmoother-only, exclusive
-    with with_residual) appends the x'.b dot epilogue and returns
-    (x', dot) — the Krylov shell's cycle-borne r.z reduction."""
-    assert not (with_dot and with_residual)
+def _shifted_sum(s, val, ro, rl, rows, col, cdt):
+    """A @ state on `rows` compute rows: sum over the diagonals, in
+    their order, of val(t) * (the state window `s` shifted by diagonal
+    t: ro[t] window rows, rl[t] lanes). Both forms of the fused
+    smoother call this, so a row's update is the same f32 sum in both."""
+    acc = jnp.zeros((rows, LANES), cdt)
+    for t in range(len(ro)):
+        a = jax.lax.slice_in_dim(s, ro[t], ro[t] + rows, 1, 0)
+        if rl[t] == 0:
+            w = a
+        else:
+            b2 = jax.lax.slice_in_dim(s, ro[t] + 1, ro[t] + 1 + rows,
+                                      1, 0)
+            shift = LANES - rl[t]
+            wa = pltpu.roll(a, jnp.int32(shift), 1)
+            wb = pltpu.roll(b2, jnp.int32(shift), 1)
+            w = jnp.where(col < shift, wa, wb)
+        acc = acc + val(t) * w
+    return acc
+
+
+def _dia_carry_kernel(offsets, plan, n_steps, with_residual, has_dinv,
+                      rows, slab_front, dtype, mf=None, with_dot=False):
+    """Kernel body factory of the carry form (see the header above).
+    `rows` is the row count of the flat (rows, 128) x / b operands, a
+    multiple of 8; the last block may be short, and blocks past it are
+    zero-filled in VMEM. `slab_front` is the quota slab's front padding
+    (slab row slab_front == x row 0). Streams (x, b, values, dinv) keep
+    a ring of their newest rows with one more block behind it, where
+    the next block's DMA lands while this step computes; every step
+    starts by moving all rings down by br rows."""
+    br, n_app, nb = plan.br, plan.n_app, plan.n_blocks
+    skew, lag = plan.skew, plan.lag
+    back = _r8(plan.mr0)
+    win = plan.win_x
+    ring = _carry_rings(br, skew, lag, back, n_steps, with_residual,
+                        with_dot)
+    ro = [back + o // LANES for o in offsets]   # window row offset
+    rl = [o % LANES for o in offsets]           # lane shift
+    cdt = compute_dtype(dtype)
+    rem = rows - (nb - 1) * br      # rows of the last block of x and b
+    n_full = nb if rem == br else nb - 1
+    k = len(offsets)
+    # matrix-free: where the skew is a whole number of z-planes every
+    # level sees the x / y coordinates of the step's newest rows and a
+    # z coordinate a constant below them: one div/rem set a step
+    per = mf.shape[0] * mf.shape[1] if mf is not None else None
+    aligned = mf is not None and (skew * LANES) % per == 0
+
+    def kernel(*refs):
+        # scratch: x ring, b ring, [vals ring], [dinv ring],
+        #          level rings 1..n_steps, [residual ring], sems
+        (x_ref, vals_ref, b_ref, dinv_ref, coeffs_ref, taus_ref, y_ref,
+         r_ref, d_ref), it = _smooth_refs(refs, mf, has_dinv,
+                                          with_residual, with_dot)
+        xr, brg = next(it), next(it)
+        vr = next(it) if mf is None else None
+        dr = next(it) if has_dinv else None
+        lv = [next(it) for _ in range(n_steps)]
+        rr = next(it) if with_residual else None
+        sems = next(it)
+
+        # (source, ring, ring rows, first source row, leading axis)
+        streams = [(x_ref, xr, ring["x"], 0, False),
+                   (b_ref, brg, ring["b"], 0, False)]
+        if mf is None:
+            streams.append((vals_ref, vr, ring["vals"], slab_front,
+                            True))
+        if has_dinv:
+            streams.append((dinv_ref, dr, ring["vals"], slab_front,
+                            False))
+        level_rows = [ring["mid"]] * (n_steps - 1) + [ring["last"]]
+        # (ring, its rows, rows behind it where a DMA lands, leading axis)
+        carried = [(r_, ln, br, lead)
+                   for _, r_, ln, _, lead in streams] \
+            + [(r_, ln, 0, False) for r_, ln in zip(lv, level_rows)]
+        if with_residual:
+            carried.append((rr, ring["res"], 0, False))
+
+        i = pl.program_id(0)
+
+        def at(ref, lo, n, lead):
+            return ref.at[:, pl.ds(lo, n)] if lead \
+                else ref.at[pl.ds(lo, n)]
+
+        def copy(s_, blk, n):
+            src, dst, ln, base, lead = streams[s_]
+            lo = blk * jnp.int32(br) + jnp.int32(base)
+            return pltpu.make_async_copy(
+                at(src, lo, n, lead), at(dst, ln, n, lead),
+                sems.at[jnp.int32(s_)])
+
+        def fetch(blk, start):
+            """Start (or wait for) block `blk` of every stream landing
+            behind its ring; zero-fill what no DMA writes."""
+            def go(c):
+                c.start() if start else c.wait()
+
+            @pl.when(blk < n_full)
+            def _():
+                for s_ in range(len(streams)):
+                    go(copy(s_, blk, br))
+
+            if rem != br:
+                @pl.when(blk == nb - 1)
+                def _():
+                    for s_, (_, dst, ln, base, lead) in \
+                            enumerate(streams):
+                        if dst is not xr and dst is not brg:
+                            # a quota slab has the whole block
+                            go(copy(s_, blk, br))
+                            continue
+                        if start:
+                            dst[ln + rem:ln + br] = jnp.zeros(
+                                (br - rem, LANES), dst.dtype)
+                        go(copy(s_, blk, rem))
+
+            if start:
+                @pl.when(blk == nb)
+                def _():
+                    for _, dst, ln, _, lead in streams:
+                        if lead:
+                            dst[:, ln:ln + br] = jnp.zeros(
+                                (k, br, LANES), dst.dtype)
+                        else:
+                            dst[ln:ln + br] = jnp.zeros(
+                                (br, LANES), dst.dtype)
+
+        @pl.when(i == 0)
+        def _():
+            # rows before row 0 are zero on every level; the block
+            # behind a stream's ring is left to its DMA
+            for r_, ln, _, lead in carried:
+                if lead:
+                    r_[:, 0:ln] = jnp.zeros((k, ln, LANES), r_.dtype)
+                else:
+                    r_[0:ln] = jnp.zeros((ln, LANES), r_.dtype)
+            fetch(i, True)
+
+        fetch(i, False)
+        for r_, ln, landing, lead in carried:
+            for c in range(0, ln + landing - br, br):
+                m = min(br, ln + landing - br - c)
+                if lead:
+                    r_[:, c:c + m] = r_[:, c + br:c + br + m]
+                else:
+                    r_[c:c + m] = r_[c + br:c + br + m]
+        fetch(i + 1, True)
+
+        col = jax.lax.broadcasted_iota(jnp.int32, (br, LANES), 1)
+        zero = jnp.zeros((), cdt)
+        if aligned:
+            # the step's newest rows give every level its x / y masks:
+            # one masked value plane a diagonal a STEP; a level adds
+            # its z range, two compares of its own linear index
+            row = jax.lax.broadcasted_iota(jnp.int32, (br, LANES), 0)
+            idx0 = (i * jnp.int32(br) + row) * jnp.int32(LANES) + col
+            gx0, gy0, _ = _mf_coords(mf.shape, idx0)
+
+            def cget(d):
+                return coeffs_ref[d].astype(cdt)
+
+            xy_ok = [_mf_ok(mf.shape, (gx0, gy0, None), (dx, dy, 0), True)
+                     if dx or dy else None for dx, dy, _ in mf.shifts]
+            vxy = [cget(d) if m is None else jnp.where(m, cget(d), zero)
+                   for d, m in enumerate(xy_ok)]
+
+        def level_vals(t):
+            """(val, dinv rows | None, row-valid mask | None) on level
+            t's rows, which start at x row i*br - t*skew. Where the
+            mask is given, val(d) is exact on valid rows only and the
+            caller zeroes the others (as a zero slab row would)."""
+            if mf is None:
+                lo = ring["vals"] - br - t * skew
+
+                def val(d):
+                    return vr[d, lo:lo + br].astype(cdt)
+                dw = dr[lo:lo + br].astype(cdt) \
+                    if has_dinv and t <= n_steps else None
+                return val, dw, None
+            if not aligned:
+                row0 = i * jnp.int32(br) - jnp.int32(t * skew)
+                return _mf_block_vals(mf, coeffs_ref, row0, br, col,
+                                      cdt) + (None,)
+            idx = idx0 - jnp.int32(t * skew * LANES)
+            z_ok = {}
+            for dz in sorted({sh[2] for sh in mf.shifts} | {0}):
+                z_ok[dz] = (idx >= jnp.int32(max(0, -dz * per))) \
+                    & (idx < jnp.int32(min(mf.n, mf.n - dz * per)))
+
+            def val(d):
+                dz = mf.shifts[d][2]
+                return jnp.where(z_ok[dz], vxy[d], zero) if dz \
+                    else vxy[d]
+
+            def ok(d):
+                dz = mf.shifts[d][2]
+                return z_ok[dz] if xy_ok[d] is None \
+                    else xy_ok[d] & z_ok[dz]
+            dw = _mf_dinv(mf, cget, ok, z_ok[0], cdt) \
+                if t <= n_steps else None
+            return val, dw, z_ok[0]
+
+        def level(t, below, below_rows):
+            """b - A @ (level t-1) on level t's rows (zero on rows
+            outside [0, n)), level t-1 on the same rows, the dinv
+            rows."""
+            lo = below_rows - (br + skew + back)
+            s = below[lo:lo + win].astype(cdt)
+            val, dw, valid = level_vals(t)
+            lo_b = ring["b"] - br - t * skew
+            res = brg[lo_b:lo_b + br].astype(cdt) \
+                - _shifted_sum(s, val, ro, rl, br, col, cdt)
+            if valid is not None:
+                res = jnp.where(valid, res, zero)
+            return res, jax.lax.slice_in_dim(s, back, back + br, 1, 0), dw
+
+        below, below_rows = xr, ring["x"]
+        for t in range(1, n_steps + 1):
+            res, mid, dw = level(t, below, below_rows)
+            corr = taus_ref[t - 1] * res
+            if dw is not None:
+                corr = corr * dw
+            below, below_rows = lv[t - 1], level_rows[t - 1]
+            below[below_rows - br:below_rows] = mid + corr
+        lo_y = below_rows - (lag + 1) * br + n_steps * skew
+        y_ref[...] = below[lo_y:lo_y + br].astype(dtype)
+        if with_residual:
+            res, _, _ = level(n_app, below, below_rows)
+            rr[ring["res"] - br:ring["res"]] = res
+            r_ref[...] = rr[0:br].astype(dtype)
+        if with_dot:
+            lo_b = ring["b"] - (lag + 1) * br
+            _part_store(d_ref, below[lo_y:lo_y + br]
+                        * brg[lo_b:lo_b + br].astype(cdt))
+
+    return kernel
+
+
+def _smooth_operands(xv, bv, vals_q, dinv_q, taus, mf, coeffs, dtype):
+    """(operands, in_specs, streams) of the carry call in the order
+    _smooth_refs reads them: the `streams` operands the kernel
+    DMAs itself (x, [vals_q], b, [dinv_q]) stay in HBM; the k
+    coefficients of the matrix-free form and the taus ride SMEM at the
+    ACCUMULATION dtype — a bf16-rounded damping factor would throw
+    away Chebyshev coefficient precision the f32 arithmetic can keep
+    (identity for f32/f64 operands)."""
+    cdt = compute_dtype(dtype)
+    operands = [v for v in (xv, vals_q, bv, dinv_q) if v is not None]
+    streams = len(operands)
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * streams
+    for v in ([coeffs] if mf is not None else []) + [taus]:
+        operands.append(v.astype(cdt))
+        in_specs.append(pl.BlockSpec(v.shape, lambda i: (jnp.int32(0),),
+                                     memory_space=pltpu.SMEM))
+    return operands, in_specs, streams
+
+
+def _smooth_results(outs, n_out, n, with_dot):
+    """x' (, r) as n-vectors (, the summed dot partials) of a call's
+    (rows, 128) outputs."""
+    vecs = [o.reshape(-1) for o in outs[:n_out]]
+    vecs = [v if v.shape[0] == n else v[:n] for v in vecs]
+    if with_dot:
+        vecs.append(jnp.sum(outs[-1]))
+    return tuple(vecs) if len(vecs) > 1 else vecs[0]
+
+
+def _carry_call(plan, vals_q, dinv_q, taus, b, x, offsets, n,
+                with_residual, mf, coeffs, with_dot, dtype, interpret):
+    """The carry form's pallas_call: x and b go in as flat (rows, 128)
+    views of the caller's vectors and x' (and r) come out the same way
+    (a vector of no whole number of 8-row tiles is padded to one
+    first; the grids of the flagship cells are whole)."""
+    br, nb, lag = plan.br, plan.n_blocks, plan.lag
     n_steps = taus.shape[0]
     has_dinv = dinv_q is not None
+    k = len(offsets)
+    cdt = compute_dtype(dtype)
+    rows = _r8(max(1, -(-n // LANES)))
+
+    def flat(v):
+        v = v.astype(dtype)
+        if rows * LANES != n:
+            v = jnp.pad(v, (0, rows * LANES - n))
+        return v.reshape(rows, LANES)
+
+    qf = smooth_quota_rows(offsets, n)[0] if mf is None else 0
+    ring = _carry_rings(br, plan.skew, lag, _r8(plan.mr0), n_steps,
+                        with_residual, with_dot)
+    kernel = _dia_carry_kernel(offsets, plan, n_steps, with_residual,
+                               has_dinv, rows, qf, dtype, mf=mf,
+                               with_dot=with_dot)
+    operands, in_specs, streams = _smooth_operands(
+        flat(x), flat(b), vals_q, dinv_q, taus, mf, coeffs, dtype)
+    scratch = [pltpu.VMEM((ring["x"] + br, LANES), dtype),
+               pltpu.VMEM((ring["b"] + br, LANES), dtype)]
     if mf is None:
-        k = vals_q.shape[0]
-        dtype = vals_q.dtype
-    else:
-        k = len(offsets)
-        dtype = x.dtype
+        scratch.append(pltpu.VMEM((k, ring["vals"] + br, LANES), dtype))
+    if has_dinv:
+        scratch.append(pltpu.VMEM((ring["vals"] + br, LANES), dtype))
+    scratch += [pltpu.VMEM((ring["mid"], LANES), cdt)] * (n_steps - 1)
+    scratch.append(pltpu.VMEM((ring["last"], LANES), cdt))
+    if with_residual:
+        scratch.append(pltpu.VMEM((ring["res"], LANES), cdt))
+    scratch.append(pltpu.SemaphoreType.DMA((streams,)))
+
+    def lagged(i):      # the output block that step i completes
+        return (jnp.maximum(i - jnp.int32(lag), jnp.int32(0)),
+                jnp.int32(0))
+
+    n_out = 2 if with_residual else 1
+    out_specs = [pl.BlockSpec((br, LANES), lagged,
+                              memory_space=pltpu.VMEM)] * n_out
+    out_shape = [jax.ShapeDtypeStruct((rows, LANES), dtype)] * n_out
+    if with_dot:
+        out_specs.append(pl.BlockSpec((PART_ROWS, LANES), lagged,
+                                      memory_space=pltpu.VMEM))
+        out_shape.append(_part_shape(nb))
+    outs = kernel_call(
+        kernel,
+        grid=(plan.steps,),
+        in_specs=in_specs,
+        out_specs=tuple(out_specs),
+        out_shape=tuple(out_shape),
+        scratch_shapes=scratch,
+        cost_estimate=pl.CostEstimate(
+            flops=2 * k * plan.row_apps * LANES,
+            # every stream read once, every output written once
+            bytes_accessed=(streams + (k - 1 if mf is None else 0)
+                            + n_out)
+            * rows * LANES * jnp.dtype(dtype).itemsize,
+            transcendentals=0,
+        ),
+        interpret=interpret,
+    )(*operands)
+    return _smooth_results(outs, n_out, n, with_dot)
+
+
+def _one_block_call(plan, vals_q, dinv_q, taus, b, x, offsets,
+                    num_rows, with_residual, mf, coeffs, with_dot, dtype,
+                    interpret):
+    """The one-block form's pallas_call, as it ran before the carry
+    form came (PR 52 leaves it as it was): x and b are padded in-trace
+    to the halo window of all the applications (a level of at most
+    _BR_CAP rows)."""
+    br, n_app, mr0, Mr0, win_x, win_v, nb = plan[:7]
+    n_steps = taus.shape[0]
+    has_dinv = dinv_q is not None
+    k = len(offsets)
     ib = jnp.dtype(dtype).itemsize
-    plan = dia_smooth_plan(offsets, k, num_rows, n_steps, with_residual,
-                           itemsize=ib, coeffs=mf is not None)
-    br, n_app, mr0, Mr0, win_x, win_v, nb = plan
-    if mf is None:
-        qf, qc, qb = smooth_quota_rows(offsets, num_rows)
-        assert vals_q.shape[1] == qf + qc + qb, \
-            f"fused slab rows {vals_q.shape[1]} != quota {qf + qc + qb}"
-        # quota slab row qf == x row 0; this plan's window base (block
-        # i) is x row i*br - (n_app-1)*mr0, i.e. slab row i*br +
-        # slab_shift
-        slab_shift = qf - (n_app - 1) * mr0
-    else:
-        slab_shift = 0
+    # quota slab row qf == x row 0; the window base is x row
+    # -(n_app-1)*mr0, i.e. slab row slab_shift
+    slab_shift = smooth_quota_rows(offsets, num_rows)[0] \
+        - (n_app - 1) * mr0 if mf is None else 0
     n = num_rows
     # x window coordinates: front pad n_app*mr0 rows
     xp_rows = n_app * mr0 + nb * br + n_app * Mr0
@@ -880,6 +1318,96 @@ def _dia_smooth_call(vals_q, dinv_q, taus, b, x, offsets, num_rows,
     if with_dot:
         trimmed.append(jnp.sum(outs[-1]))
     return tuple(trimmed) if multi_out else trimmed[0]
+
+
+# Tallies [launches, row-applications] open while a cycle is traced
+# (count_smooth_launches): every _dia_smooth_call a trace makes adds
+# itself to each, so the hierarchy knows what ONE cycle launches
+# (amg/hierarchy.AMG.cycle -> the smoother.dia_calls /
+# smoother.dia_row_apps counters, raised after each solve).
+_LAUNCH_TALLIES = []
+
+
+@contextlib.contextmanager
+def count_smooth_launches():
+    tally = [0, 0]
+    _LAUNCH_TALLIES.append(tally)
+    try:
+        yield tally
+    finally:
+        _LAUNCH_TALLIES.remove(tally)
+
+
+def _dia_smooth_call(vals_q, dinv_q, taus, b, x, offsets, num_rows,
+                     with_residual, mf=None, coeffs=None,
+                     with_dot=False, interpret=False):
+    """The fused smoother's one entry (the jitted program below, whose
+    name the device trace shows), noted on the open launch tallies."""
+    if _LAUNCH_TALLIES:
+        plan = dia_smooth_plan(
+            offsets, len(offsets), num_rows, taus.shape[0],
+            with_residual, itemsize=jnp.dtype(
+                x.dtype if mf is not None else vals_q.dtype).itemsize,
+            coeffs=mf is not None)
+        for tally in _LAUNCH_TALLIES:
+            tally[0] += 1
+            tally[1] += plan.row_apps
+    return _dia_smooth_jit(vals_q, dinv_q, taus, b, x, offsets, num_rows,
+                           with_residual, mf=mf, coeffs=coeffs,
+                           with_dot=with_dot, interpret=interpret)
+
+
+def _dia_smooth_program(vals_q, dinv_q, taus, b, x, offsets, num_rows,
+                        with_residual, mf=None, coeffs=None,
+                        with_dot=False, interpret=False):
+    """Run the fused smoother kernel: x' (and r) after len(taus) damped
+    sweeps, x and b read as zero outside [0, num_rows). `vals_q`
+    (k, Q, 128) and `dinv_q` ((Q, 128) or None) are the QUOTA-PADDED
+    operand slabs from ops.smooth (built once per setup,
+    smooth_quota_rows layout). Caller must have checked
+    dia_smooth_supported. Matrix-free form (`mf` spec + `coeffs` (k,)):
+    vals_q/dinv_q are None — the A-operand stream vanishes and the k
+    coefficients ride SMEM next to the taus. `with_dot`
+    (postsmoother-only, exclusive with with_residual) appends the x'.b
+    dot epilogue and returns (x', dot) — the Krylov shell's cycle-borne
+    r.z reduction. A level of more than one block takes the carry form
+    (no copy of x or b, every row computed once); a one-block level
+    pads its two vectors to the halo window in-trace."""
+    # NOTE: `interpret` must be resolved by the (un-jitted) caller —
+    # reading the _FORCE_INTERPRET global here would bake it into a
+    # trace whose jit cache key does not carry it, so an interpret-mode
+    # trace could outlive the forcing context
+    assert not (with_dot and with_residual)
+    n_steps = taus.shape[0]
+    if mf is None:
+        k = vals_q.shape[0]
+        dtype = vals_q.dtype
+    else:
+        k = len(offsets)
+        dtype = x.dtype
+    ib = jnp.dtype(dtype).itemsize
+    plan = dia_smooth_plan(offsets, k, num_rows, n_steps, with_residual,
+                           itemsize=ib, coeffs=mf is not None)
+    if mf is None:
+        qf, qc, qb = smooth_quota_rows(offsets, num_rows)
+        assert vals_q.shape[1] == qf + qc + qb, \
+            f"fused slab rows {vals_q.shape[1]} != quota {qf + qc + qb}"
+    if plan.lag:
+        return _carry_call(plan, vals_q, dinv_q, taus, b, x, offsets,
+                           num_rows, with_residual, mf, coeffs,
+                           with_dot, dtype, interpret)
+    return _one_block_call(plan, vals_q, dinv_q, taus, b, x, offsets,
+                           num_rows, with_residual, mf, coeffs,
+                           with_dot, dtype, interpret)
+
+
+# the device trace names a jitted program after its function: the
+# readers (kernels.pallas_busy_share, cycle.glue_busy_share, the scope
+# table) match `_dia_*_call*`
+_dia_smooth_program.__name__ = "_dia_smooth_call"
+_dia_smooth_jit = jax.jit(_dia_smooth_program, static_argnames=(
+    "offsets", "num_rows", "with_residual", "mf", "with_dot",
+    "interpret"))
 
 
 def _dia_stencil_smooth_call(coeffs, taus, b, x, spec, with_residual,

@@ -12,11 +12,10 @@ CHEBYSHEV_POLY: tau_s = the magic-damping taus, no dinv) through the
 fused Pallas kernels:
 
 - DIA: all sweeps AND the trailing residual in ONE pallas_call
-  (ops/pallas_spmv.py temporal blocking) — A's diagonal slab streams
-  from HBM once instead of sweeps+1 times. When the full fusion misses
-  the VMEM/traffic budget (deep halos at very large grids), the
-  dispatcher chains the largest supported fused sub-calls, each still
-  one pass over A.
+  (ops/pallas_spmv.py: one block with its halo window, or row blocks
+  in order with each sweep's edge rows carried in VMEM) — A's diagonal
+  slab, x and b stream from HBM once instead of sweeps+1 times, at
+  any size.
 - SWELL: each sweep is one pallas_call with the Jacobi update in the
   kernel epilogue (ops/pallas_swell.py) — the lane-gather layout cannot
   temporally block (window reach is unbounded), but fusing the update
@@ -236,11 +235,10 @@ def _dia_call(A, fused, taus, b, x, dinv, with_residual):
 def dia_fused_smooth(A, fused, b, x, taus, dinv=None,
                      with_residual=True):
     """Fused DIA smoother dispatch: x' (and r when `with_residual`)
-    after len(taus) damped sweeps, or None when no fused plan applies
-    (caller falls back to its unfused compose). One pallas_call when
-    the whole schedule fits the plan budget; otherwise the largest
-    supported fused sub-calls are chained — each still a single HBM
-    pass over A's values."""
+    after len(taus) damped sweeps in ONE pallas_call, or None when no
+    fused plan applies (no slab, an off-whitelist dtype, a schedule
+    longer than SMOOTH_MAX_APPS, a body VMEM has no room for): the
+    caller falls back to its unfused compose."""
     if fused is None or getattr(A, "dia_vals", None) is None:
         return None
     if dinv is not None and "dinv_q" not in fused:
@@ -250,37 +248,9 @@ def dia_fused_smooth(A, fused, b, x, taus, dinv=None,
         return None
     if not _fused_dtype_ok(A, x.dtype):
         return None
-    sup = functools.partial(_ps.dia_smooth_supported, A, x.dtype)
-    if sup(n_steps, with_residual):
-        return _dia_call(A, fused, taus, b, x, dinv, with_residual)
-    if not sup(1, False):
+    if not _ps.dia_smooth_supported(A, x.dtype, n_steps, with_residual):
         return None
-    # supported fused sweep-chunk sizes (no residual), largest first
-    sizes = [c for c in range(min(n_steps, _ps.SMOOTH_MAX_APPS), 0, -1)
-             if sup(c, False)]
-    # largest tail segment that can fuse WITH the residual epilogue
-    tail = 0
-    if with_residual:
-        for c in range(min(n_steps, _ps.SMOOTH_MAX_APPS - 1), 0, -1):
-            if sup(c, True):
-                tail = c
-                break
-    done = 0
-    while n_steps - done - tail > 0:
-        rem = n_steps - done - tail
-        take = next((c for c in sizes if c <= rem), None)
-        if take is None:        # tail too greedy for the remainder
-            tail = 0
-            continue
-        x = _dia_call(A, fused, taus[done:done + take], b, x, dinv,
-                      False)
-        done += take
-    if not with_residual:
-        return x
-    if tail:
-        return _dia_call(A, fused, taus[done:], b, x, dinv, True)
-    from .spmv import spmv
-    return x, b - spmv(A, x)
+    return _dia_call(A, fused, taus, b, x, dinv, with_residual)
 
 
 # ---------------------------------------------------------------------------
@@ -816,7 +786,8 @@ def fused_smooth_restrict(data, b, x, taus, xfer, dinv=None):
     tail = next((c for c in range(
         min(n_steps - 1, _ps.SMOOTH_MAX_APPS - 1), 0, -1)
         if sup_r(c)), 0)
-    if not tail or not _ps.dia_smooth_supported(A, x.dtype, 1, False):
+    if not tail or not _ps.dia_smooth_supported(
+            A, x.dtype, n_steps - tail, False):
         return None
     head = dia_fused_smooth(A, fused, b, x, taus[:n_steps - tail],
                             dinv=dinv, with_residual=False)
@@ -854,7 +825,8 @@ def fused_corr_smooth(data, b, x, xc, taus, xfer, dinv=None,
                           with_dot=want_dot)
     head = next((c for c in range(
         min(n_steps - 1, _ps.SMOOTH_MAX_APPS), 0, -1) if sup_p(c)), 0)
-    if not head or not _ps.dia_smooth_supported(A, x.dtype, 1, False):
+    if not head or not _ps.dia_smooth_supported(
+            A, x.dtype, n_steps - head, False):
         return None
     x = _corr_call(A, fused, xfer, taus[:head], b, x, xc, dinv)
     x = dia_fused_smooth(A, fused, b, x, taus[head:], dinv=dinv,

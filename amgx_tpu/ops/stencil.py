@@ -467,53 +467,20 @@ def stencil_fused_smooth(st: StencilOperator, taus, b, x,
                          with_residual=True):
     """Matrix-free smoother dispatch: x' (and r) after len(taus)
     damped sweeps. ALWAYS produces a result — there is no slab to fall
-    back to. One fused coeffs-mode pallas_call when the schedule fits
-    the plan; oversized schedules chain the largest supported fused
-    sub-calls (each a single pass over b/x — A contributes no stream
-    at all); everything else takes the XLA masked compose."""
+    back to: one fused coeffs-mode pallas_call when the schedule has a
+    plan (ops/pallas_spmv.dia_smooth_plan), the XLA masked compose
+    otherwise (f64, CPU, a schedule longer than SMOOTH_MAX_APPS)."""
     spec = st.spec()
     coeffs = st.coeffs
     cdt = _ps.compute_dtype(x.dtype)
     taus = jnp.asarray(taus, cdt)
-    n_steps = int(taus.shape[0])
-    if n_steps < 1:
+    if int(taus.shape[0]) < 1:
         if with_residual:
             cc = coeffs.astype(cdt)
             r = b.astype(cdt) - _apply_vec(spec, cc, x.astype(cdt))
             return x, r.astype(x.dtype)
         return x
-
-    def sup(c, wr):
-        return stencil_smooth_supported(spec, x.dtype, c, wr)
-
-    if sup(n_steps, with_residual) or not sup(1, False):
-        # one fused call, or no fused plan at all (XLA primal)
-        return _smooth_fn(spec, with_residual)(coeffs, taus, b, x)
-    sizes = [c for c in range(min(n_steps, _ps.SMOOTH_MAX_APPS), 0, -1)
-             if sup(c, False)]
-    tail = 0
-    if with_residual:
-        for c in range(min(n_steps, _ps.SMOOTH_MAX_APPS - 1), 0, -1):
-            if sup(c, True):
-                tail = c
-                break
-    done = 0
-    while n_steps - done - tail > 0:
-        rem = n_steps - done - tail
-        take = next((c for c in sizes if c <= rem), None)
-        if take is None:
-            tail = 0
-            continue
-        x = _smooth_fn(spec, False)(coeffs, taus[done:done + take],
-                                    b, x)
-        done += take
-    if not with_residual:
-        return x
-    if tail:
-        return _smooth_fn(spec, True)(coeffs, taus[done:], b, x)
-    cc = coeffs.astype(cdt)
-    r = b.astype(cdt) - _apply_vec(spec, cc, x.astype(cdt))
-    return x, r.astype(x.dtype)
+    return _smooth_fn(spec, with_residual)(coeffs, taus, b, x)
 
 
 def stencil_smooth_restrict(st: StencilOperator, taus, b, x, xfer):

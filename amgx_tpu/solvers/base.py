@@ -560,6 +560,14 @@ class Solver(SolveDataOwner):
         pc = self.preconditioner
         return 0 if pc is None else pc.swell_vreg_steps_per_iteration()
 
+    def dia_smooth_per_iteration(self):
+        """(`_dia_smooth_call` launches, lane-rows x applications they
+        compute) of one iteration's cycle (the preconditioner's:
+        AMG.dia_smooth_per_cycle, kept while the cycle is traced);
+        (0, 0) where the tree has no multigrid cycle."""
+        pc = self.preconditioner
+        return (0, 0) if pc is None else pc.dia_smooth_per_iteration()
+
     def swell_model_s_per_iteration(self) -> float:
         """Seconds the layout choice's model puts on the SWELL gather
         of one iteration's cycle (the preconditioner's:
@@ -1125,6 +1133,10 @@ class Solver(SolveDataOwner):
                 _tm.inc("swell.vreg_steps", cycles * swell_steps)
                 _tm.add("swell.model_s",
                         cycles * self.swell_model_s_per_iteration())
+            dia_calls, dia_rows = self.dia_smooth_per_iteration()
+            if dia_calls:
+                _tm.inc("smoother.dia_calls", cycles * dia_calls)
+                _tm.inc("smoother.dia_row_apps", cycles * dia_rows)
             csr_nnz = self.csr_road_nnz_per_iteration()
             if csr_nnz:
                 _tm.inc("cycle.csr_road_nnz", cycles * csr_nnz)

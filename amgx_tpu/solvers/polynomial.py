@@ -281,8 +281,8 @@ class ChebyshevPolySolver(Solver):
     # -- fused smoothing (ops/smooth.py) --------------------------------
     # One smoother application is `order` damped-Richardson steps
     # x += tau_i (b - A x); `sweeps` applications are the tiled tau
-    # schedule, which the fused kernels run (with the trailing cycle
-    # residual) in as few HBM passes over A as the plan budget allows.
+    # schedule, which the fused kernel runs (with the trailing cycle
+    # residual) in one HBM pass over A, x and b.
     def _fused_taus(self, data, sweeps: int, dtype):
         taus = jnp.asarray(data["taus"], dtype)
         return jnp.tile(taus, sweeps) if sweeps > 1 else taus

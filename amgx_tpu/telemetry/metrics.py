@@ -734,6 +734,21 @@ declare_counter("swell.vreg_steps",
                 "host layouts as a set-up ends; 0 where no operator has "
                 "the layout")
 
+declare_counter("smoother.dia_calls",
+                "launches of the fused DIA smoother kernel "
+                "(ops/pallas_spmv._dia_smooth_call, both modes), raised "
+                "after each solve by the cycles that ran x the launches "
+                "one cycle makes over all levels, counted while the "
+                "cycle is traced; 0 where no level's smoother runs the "
+                "kernel (coloured sweeps, SWELL levels, a CPU)")
+declare_counter("smoother.dia_row_apps",
+                "lane-rows (128 elements) x applications those launches "
+                "COMPUTE, from their plans (ops/pallas_spmv.SmoothPlan."
+                "row_apps: the one-block form's halo rows and the carry "
+                "form's drain steps included), raised likewise: against "
+                "rows x applications it says what a stage computes more "
+                "than once")
+
 declare_counter("swell.model_s",
                 "seconds the layout choice's model of the SWELL kernels "
                 "(ops/pallas_swell.model_seconds: a fixed part a listed "

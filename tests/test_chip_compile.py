@@ -101,13 +101,18 @@ def test_dia_spmv_compiles(n, one_chip, on_tpu, no_persistent_cache):
 
 
 # (sweeps, with_residual): the flagship's Chebyshev pre-smoother is 5
-# damped applications + the residual, its post-smoother 5 without
-@pytest.mark.parametrize("n", [FINE, COARSE])
+# damped applications + the residual, its post-smoother 5 without.
+# 256 and FINE are levels of many blocks (the carry form: its rings,
+# the lagged outputs and, at 256, 64 blocks of 2,048 rows), COARSE one
+# block
+@pytest.mark.parametrize("n", [256, FINE, COARSE])
 @pytest.mark.parametrize("ns,wr", [(5, True), (5, False)])
 def test_dia_smooth_stencil_twin_compiles(n, ns, wr, one_chip, on_tpu,
                                           no_persistent_cache):
     sp = _spec7(n)
     assert stencil.stencil_smooth_supported(sp, F32, ns, wr)
+    plan = ps.dia_smooth_plan(sp.offsets, 7, sp.n, ns, wr, coeffs=True)
+    assert (plan.lag > 0) == (n > COARSE)
     _compile(lambda c, t, b, x: ps._dia_stencil_smooth_call(
         c, t, b, x, sp, wr),
         one_chip, ((7,), F32), ((ns,), F32), ((sp.n,), F32),
@@ -395,6 +400,12 @@ def test_geo_cycle_on_the_chips_branch_has_no_xla_transfer(grid, onepass,
     assert not any(c["interpret"] for c in _census.pallas_calls(jaxpr))
     counts = _census.kernel_counts(jaxpr)
     assert counts.get("_dia_smooth_call", 0) == 2
+    # what the trace left for the smoother.dia_* counters: the two
+    # launches, and the row-applications of their two plans
+    sp = amg.levels[0].level_data()["smoother"]["stencil"].spec()
+    assert amg.dia_smooth_per_cycle() == (2, sum(
+        ps.dia_smooth_plan(sp.offsets, 7, sp.n, 5, wr,
+                           coeffs=True).row_apps for wr in (True, False)))
     moved = _strided_or_padding(jaxpr)
     if onepass:
         assert amg.geo_transfers_per_cycle() == (1, 0)

@@ -97,8 +97,9 @@ def test_dia_fused_parity_f32(schedule, with_dinv, with_residual):
 
 
 def test_dia_fused_parity_multiblock_and_chained():
-    """Small VMEM budget forces both the multi-block double-buffered
-    DMA path and the chained (per-chunk) dispatch."""
+    """A VMEM budget the level's one block does not fit sends it to
+    the carry form (several blocks, the levels' edge rows carried from
+    block to block: tests/test_dia_carry.py), still one call."""
     A = gallery.poisson("7pt", 16, 16, 16, dtype=jnp.float32).init()
     n = A.num_rows
     rng = np.random.default_rng(1)
@@ -109,7 +110,7 @@ def test_dia_fused_parity_multiblock_and_chained():
     ref = _ref_sweeps(A, b, x, taus, dinv, True)
     old = ps._SMOOTH_VMEM_BUDGET
     try:
-        for budget in (300 * 1024, 120 * 1024):   # multi-block; chained
+        for budget in (300 * 1024, 120 * 1024):
             ps._SMOOTH_VMEM_BUDGET = budget
             with ps.force_pallas_interpret():
                 slabs = fused.build_fused_slabs(A, dinv)

@@ -616,7 +616,7 @@ def test_host_stage_readers_are_deltas_per_operation():
 def test_benchmark_selfcheck_passes_with_the_new_cell(capsys):
     selfcheck.main()
     out = capsys.readouterr().out
-    assert "files: 10 cells, 5 end-to-end and 60 per-layer" in out
+    assert "files: 10 cells, 5 end-to-end and 62 per-layer" in out
     assert out.rstrip().endswith("selfcheck ok")
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
