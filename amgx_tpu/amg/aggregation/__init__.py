@@ -185,74 +185,7 @@ class AggregationAMGLevel(AMGLevel):
             # n-sized host array per jitted call (the GEO selector keeps
             # it host-resident on purpose)
             d["aggregates"] = self.aggregates
-        xfer = self._transfer_slabs()
-        if xfer is not None:
-            d["xfer"] = xfer
         return d
-
-    def _transfer_slabs(self):
-        """Structure-only transfer payloads for the fused grid-transfer
-        and coarse-tail kernels (ops/smooth.py), memoized on the level
-        (the aggregates map is fixed for the level's lifetime; a
-        structure-reuse resetup builds NEW level objects and rebuilds).
-        None off-TPU, with cycle_fusion=0, or for ineligible layouts —
-        those rigs/configs build nothing and change nothing."""
-        memo = getattr(self, "_xfer_memo", None)
-        if memo is not None:
-            return memo[0]
-        from ...ops import smooth as fused
-        slabs = None
-        if bool(int(self.cfg.get("cycle_fusion", self.scope))) \
-                and fused.flat_gather_ok() \
-                and getattr(self, "aggregates", None) is not None \
-                and self.coarse_size:
-            slabs = fused.build_transfer_slabs(
-                self.A, self.aggregates, int(self.coarse_size))
-        self._xfer_memo = (slabs,)
-        return slabs
-
-    def supports_fusion(self, data):
-        """Single-device aggregation levels advertise the fused
-        grid-transfer kernels; distributed level-data (explicit sharded
-        R/P) declines — the cycle's plain compose already runs the
-        halo-folded per-shard smoother kernel through the smoother's
-        own dispatch (ops/smooth.fused_smooth). Matrix-free levels
-        (constant-coefficient stencil payload installed by the
-        hierarchy's `matrix_free` detector) additionally advertise
-        the "matrix_free" capability — the cycle's fused hooks then
-        route through the coefficient kernels of ops/stencil.py with
-        no A value-slab operand at all."""
-        if "R" in data or "P" in data:
-            return ()
-        if self.smoother is None:
-            return ()
-        if "stencil" in data:
-            return self.FUSION_CAPS | {"matrix_free"}
-        return self.FUSION_CAPS
-
-    def restrict_fused(self, data, b, x, sweeps: int):
-        """Presmooth + restriction in one kernel (ops/smooth.py), or
-        None (distributed levels with explicit R, unsupported layouts,
-        smoothers without a fused form)."""
-        if "R" in data or "P" in data or self.smoother is None:
-            return None
-        fn = getattr(self.smoother, "smooth_restrict", None)
-        if fn is None:
-            return None
-        return fn(data["smoother"], b, x, sweeps, data.get("xfer"))
-
-    def prolongate_smooth(self, data, b, x, xc, sweeps: int,
-                          want_dot: bool = False):
-        """Prolongation/correction folded into the postsmoother's first
-        kernel application, or None. want_dot additionally requests the
-        x'.b dot epilogue from the final kernel → (x', dot|None)."""
-        if "R" in data or "P" in data or self.smoother is None:
-            return None
-        fn = getattr(self.smoother, "smooth_corr", None)
-        if fn is None:
-            return None
-        return fn(data["smoother"], b, x, xc, sweeps, data.get("xfer"),
-                  want_dot=want_dot)
 
     def restrict(self, data, r):
         if "R" in data:       # distributed: explicit sharded R = P^T

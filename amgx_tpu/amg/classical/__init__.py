@@ -103,11 +103,6 @@ class ClassicalAMGLevel(AMGLevel):
         with trace_region(f"amg.L{k}.transposeR", args=(why := {})):
             self.R = laid_out(transpose(self.P), why,
                               lambda M: M.init(ell=ell))
-        # weighted transfer slabs for the fused cycle kernels: built at
-        # SETUP (inside the accounted span) so the first solve pays no
-        # slab assembly and the host-ship pipeline can prefetch them
-        with trace_region(f"amg.L{k}.xfer_slabs"):
-            self._transfer_slabs()
         return self._galerkin_rap()
 
     def _galerkin_rap(self) -> CsrMatrix:
@@ -115,8 +110,8 @@ class ClassicalAMGLevel(AMGLevel):
         phase is memoized on the level (structure resetups carry it —
         P/R survive with their values) and in the digest-keyed cache
         (warm full setups of the same pattern hit it), so only the
-        VALUE phase runs per setup — through the fused kernel / slab /
-        host-reduceat route regardless of backend forcing. The plan
+        VALUE phase runs per setup — through the slab or the host
+        route regardless of backend forcing. The plan
         lookup precedes the host-native dispatch on purpose: a warm
         host setup used to rebuild the whole product from numpy even
         when the pattern was already planned. spgemm_plan=0 (or
@@ -168,14 +163,6 @@ class ClassicalAMGLevel(AMGLevel):
         self._aggressive = old._aggressive
         self.P = old.P
         self.R = old.R
-        # the transfer slabs are a function of (A's DIA offsets, P, R)
-        # — all kept by structure reuse — so the memo carries over
-        # when the new coefficients kept the offset packing (a
-        # restored ghost has none; the lazy level_data path rebuilds)
-        memo = getattr(old, "_xfer_memo", None)
-        if memo is not None and getattr(self.A, "dia_offsets", None) \
-                == getattr(old.A, "dia_offsets", None):
-            self._xfer_memo = memo
         # the RAP plan is a function of (A pattern, P, R) — all kept by
         # structure reuse — so a resetup's Galerkin is value-phase only
         memo = getattr(old, "_rap_plan_memo", None)
@@ -225,73 +212,7 @@ class ClassicalAMGLevel(AMGLevel):
         # views keep their CSR payloads out of the solve program's HBM
         d["P"] = self.P.slim_for_spmv()
         d["R"] = self.R.slim_for_spmv()
-        xfer = self._transfer_slabs()
-        if xfer is not None:
-            d["xfer"] = xfer
         return d
-
-    def _transfer_slabs(self):
-        """Weighted row-segment transfer payloads for the fused cycle
-        kernels (ops/smooth.py build_csr_transfer_slabs), memoized on
-        the level. Built at setup inside amg.L*.xfer_slabs (and kept
-        across structure reuse — P/R survive value resetups on
-        classical levels, weights included, so the slabs are
-        structure-lifetime payloads). None off-TPU, with
-        cycle_fusion=0, for non-DIA fine operators, or when a P/R row
-        exceeds the kernel child caps — those configs build nothing
-        and the cycle composes the explicit R/P SpMVs unchanged."""
-        memo = getattr(self, "_xfer_memo", None)
-        if memo is not None:
-            return memo[0]
-        from ...ops import smooth as fused
-        slabs = None
-        if bool(int(self.cfg.get("cycle_fusion", self.scope))) \
-                and fused.flat_gather_ok() \
-                and getattr(self, "P", None) is not None \
-                and getattr(self, "R", None) is not None \
-                and self.coarse_size:
-            # weight slabs emit in the hierarchy's effective precision
-            # (precision.py) so the solve-data cast never materializes
-            # a full-precision twin of the cwt/pwt payloads
-            from ...precision import resolve_precision
-            dt = resolve_precision(self.cfg, self.scope).cast_dtype
-            slabs = fused.build_csr_transfer_slabs(self.A, self.P,
-                                                   self.R, dtype=dt)
-        self._xfer_memo = (slabs,)
-        return slabs
-
-    # -- cycle fusion (amg/cycles.py _fusion_caps dispatch) ------------
-    def supports_fusion(self, data):
-        """Classical levels advertise the fused grid-transfer kernels
-        when their weighted row-segment slabs built (DIA fine
-        operator, rows within the child caps); everything else — and
-        every smoother without a fused form — composes the explicit
-        R/P SpMVs exactly as before. Distributed classical levels are
-        a different class and advertise nothing (the capability is
-        resolved through the CLASS, see cycles._fusion_caps)."""
-        if data.get("xfer") is None or self.smoother is None:
-            return ()
-        return self.FUSION_CAPS
-
-    def restrict_fused(self, data, b, x, sweeps: int):
-        """Presmooth + weighted-restriction epilogue in one kernel
-        (bc = R(b - A x') summed in VMEM), or None (caller composes
-        smooth_residual -> spmv(R, r))."""
-        fn = getattr(self.smoother, "smooth_restrict", None)
-        if fn is None:
-            return None
-        return fn(data["smoother"], b, x, sweeps, data["xfer"])
-
-    def prolongate_smooth(self, data, b, x, xc, sweeps: int,
-                          want_dot: bool = False):
-        """Weighted prolongation/correction (x + P xc) folded into the
-        postsmoother's first kernel application, or None. want_dot
-        additionally requests the x'.b dot epilogue → (x', dot|None)."""
-        fn = getattr(self.smoother, "smooth_corr", None)
-        if fn is None:
-            return None
-        return fn(data["smoother"], b, x, xc, sweeps, data["xfer"],
-                  want_dot=want_dot)
 
     def restrict(self, data, r):
         return spmv(data["R"], r)

@@ -12,11 +12,9 @@ be put down to its level and stage (telemetry/programs.py reads the
 scopes back from the compiled program; benchmark/scope_metrics.py joins
 them with a trace): `amg.L<k>` round everything a level does,
 `amg.L<k>.presmooth` / `.restrict` / `.prolong` / `.postsmooth` round
-the stages (a fused kernel that spans two takes both names:
-`.presmooth_restrict`, `.prolong_postsmooth`), `amg.coarse` round the
-coarsest solve, `amg.tail.L<k>` round the VMEM-resident coarse tail
-entered at level k. A scope is metadata: it adds no op and renames no
-kernel.
+the four stages, `amg.coarse` round the coarsest solve. A scope is
+metadata: it adds no op and renames no kernel. The composition is the
+same on every backend: nothing chooses it.
 """
 from __future__ import annotations
 
@@ -49,91 +47,40 @@ def _smooth_residual(level, data, b, x, sweeps: int):
     return level.smoother.smooth_residual(data["smoother"], b, x, sweeps)
 
 
-def _fusion_caps(level, data):
-    """Fusion capabilities a level ADVERTISES for its solve-data — the
-    single gate the cycle consults before invoking any fused hook
-    (`restrict_fused` / `prolongate_smooth`). Levels declare support
-    via `supports_fusion(data)` returning a capability collection
-    ("restrict", "prolongate"). Resolved through the CLASS (MRO), not
-    instance getattr: a `__getattr__`-delegating wrapper must define
-    `supports_fusion` (and the hooks) EXPLICITLY to advertise anything
-    — its inner level answering through delegation would claim the
-    WRONG transfer space (the level's shard-local R/P instead of the
-    wrapper's gather/compact). A class that defines neither advertises
-    nothing and is never called, so new hooks cannot re-introduce the
-    AttributeError-on-distributed-levels class of bug PR 5 fixed."""
-    fn = getattr(type(level), "supports_fusion", None)
-    if fn is None:
-        return ()
-    return fn(level, data)
-
-
 def _prolongate_correct(level, data, x, xc):
     """x + P xc, the coarse-grid correction. A level class that defines
     `prolongate_correct` does the add inside its own transfer (GEO
     aggregation levels: one pass over x instead of a prolongation and
-    an add); resolved through the CLASS, as _fusion_caps is, so a
-    `__getattr__`-delegating wrapper keeps its own `prolongate`."""
+    an add); resolved through the CLASS, so a `__getattr__`-delegating
+    wrapper (distributed consolidation) keeps its own `prolongate`."""
     fn = getattr(type(level), "prolongate_correct", None)
     if fn is None:
         return x + level.prolongate(data, xc)
     return fn(level, data, x, xc)
 
 
-def _smooth_restrict(amg, level, data, b, x, sweeps: int, lvl: int):
-    """Presmooth + restriction: with cycle_fusion, aggregation/DIA
-    levels emit the segment-summed coarse rhs from the presmoother
-    kernel's epilogue (ops/smooth.py) — the residual never round-trips
-    HBM and `level.restrict` disappears from the trace — classical
-    DIA levels do the same through their WEIGHTED row-segment slabs
-    (bc = R r summed inside the kernel, general CSR interpolation),
-    and distributed DIA levels run the halo-folded per-shard kernel
-    (distributed/fused.py) before their explicit sharded restriction.
-    Everything else (cycle_fusion=0, non-DIA levels, unsupported
-    layouts) composes exactly the prior smooth_residual -> restrict
-    pair."""
-    if amg.cycle_fusion and sweeps > 0 and \
-            "restrict" in _fusion_caps(level, data):
-        with jax.named_scope(f"amg.L{lvl}.presmooth_restrict"):
-            out = level.restrict_fused(data, b, x, sweeps)
-        if out is not None:
-            return out
+def _smooth_restrict(level, data, b, x, sweeps: int, lvl: int):
+    """Presmooth + residual, then the restriction: (x', R r)."""
     with jax.named_scope(f"amg.L{lvl}.presmooth"):
         x, r = _smooth_residual(level, data, b, x, sweeps)
     with jax.named_scope(f"amg.L{lvl}.restrict"):
         return x, level.restrict(data, r)
 
 
-def _prolongate_smooth(amg, level, data, b, x, xc, sweeps: int, lvl: int,
-                       want_dot: bool = False):
-    """Prolongation + correction + postsmooth: with cycle_fusion,
-    aggregation AND classical DIA levels fold x + P xc into the
-    postsmoother kernel's first application (ops/smooth.py —
-    aggregate-id gather or the weighted multi-entry CSR-row gather),
-    removing the correction add's full-vector pass. Falls back to the
-    prior x + prolongate -> smooth compose bit-for-bit.
-
-    With want_dot (the cycle-borne reduction, Krylov shell fusion) the
-    return is (x', dot) where dot = x'.b from the postsmoother kernel's
-    epilogue — PCG reads it as r.z since the cycle's rhs is r and its
-    output is z — or (x', None) when no fused hook carries it; the
-    want_dot kwarg is only passed to level hooks when True, so hook
-    signatures that predate it keep working un-updated."""
-    if amg.cycle_fusion and sweeps > 0 and \
-            "prolongate" in _fusion_caps(level, data):
-        with jax.named_scope(f"amg.L{lvl}.prolong_postsmooth"):
-            if want_dot:
-                out = level.prolongate_smooth(data, b, x, xc, sweeps,
-                                              want_dot=True)
-            else:
-                out = level.prolongate_smooth(data, b, x, xc, sweeps)
-        if out is not None:
-            return out
+def _correct_smooth(level, data, b, x, xc, sweeps: int, lvl: int,
+                    rec=None):
+    """Coarse-grid correction, then the postsmooth. `rec` (a
+    diagnostics probe, telemetry/diagnostics.py) records the stage
+    residuals on either side of the smoother."""
     with jax.named_scope(f"amg.L{lvl}.prolong"):
         x = _prolongate_correct(level, data, x, xc)
+    if rec is not None:
+        rec.record(lvl, 2, _level_A(data), x, b)
     with jax.named_scope(f"amg.L{lvl}.postsmooth"):
         x = _smooth(level, data, b, x, sweeps)
-    return (x, None) if want_dot else x
+    if rec is not None:
+        rec.record(lvl, 3, _level_A(data), x, b)
+    return x
 
 
 def apply_coarse_solver(cs, data, bc, xc, coarsest_sweeps: int):
@@ -155,7 +102,7 @@ def apply_coarse_solver(cs, data, bc, xc, coarsest_sweeps: int):
 def _coarse_solve(amg, data, bc, xc):
     with jax.named_scope("amg.coarse"):
         if bc.dtype == jnp.bfloat16:
-            # the coarse tail stays f32+ (precision.py policy keeps the
+            # the coarse solve stays f32+ (precision.py policy keeps the
             # coarse-solver payload at f32): a bf16 cycle upcasts the
             # coarse rhs around the solve and rounds the correction back
             out = apply_coarse_solver(
@@ -167,39 +114,23 @@ def _coarse_solve(amg, data, bc, xc):
                                    xc, amg.coarsest_sweeps)
 
 
-def _cycle(amg, shape: str, data, lvl: int, b, x, want_dot: bool = False):
+def _cycle(amg, shape: str, data, lvl: int, b, x):
     """FixedCycle::cycle analog. `shape` in {V, W, F}; recursion count per
-    level: V=1, W=2, F=(F then V). want_dot asks the ENTRY level's final
-    kernel (postsmoother or whole-cycle VMEM tail) for the x'.b dot
-    epilogue; recursion below the entry level never requests it."""
+    level: V=1, W=2, F=(F then V)."""
     levels = amg.levels
     if lvl == len(levels):
-        out = _coarse_solve(amg, data, b, x)
-        return (out, None) if want_dot else out
+        return _coarse_solve(amg, data, b, x)
     # convergence diagnostics (telemetry/diagnostics.py): while a probe
-    # cycle is being traced, record the level's stage residual norms
-    # and compose the correction/postsmooth boundary explicitly so each
-    # stage exists to measure. `rec` is None for every normal cycle
-    # trace — the probe is a separate trace at the end of the solve
-    # program, so the solve iterations keep their fused kernels.
+    # cycle is being traced, record the level's stage residual norms.
+    # `rec` is None for every normal cycle trace — the probe is a
+    # separate trace at the end of the solve program.
     rec = _diag.current()
-    if amg.cycle_fusion and rec is None:
-        # VMEM-resident coarse tail: when every level from here down
-        # fits VMEM together, the whole sub-cycle (smooth -> restrict
-        # -> ... -> coarsest solve -> ... -> prolongate -> smooth) is
-        # ONE pallas_call instead of ~10 tiny dispatches per cycle
-        from ..ops.smooth import coarse_tail_cycle
-        with jax.named_scope(f"amg.tail.L{lvl}"):
-            out = coarse_tail_cycle(amg, shape, data, lvl, b, x,
-                                    want_dot=want_dot)
-        if out is not None:
-            return out
     with jax.named_scope(f"amg.L{lvl}"):
         level = levels[lvl]
         ldata = data["levels"][lvl]
         if rec is not None:
             rec.record(lvl, 0, _level_A(ldata), x, b)
-        x, bc = _smooth_restrict(amg, level, ldata, b, x,
+        x, bc = _smooth_restrict(level, ldata, b, x,
                                  amg._sweeps(lvl, pre=True), lvl)
         if rec is not None:
             rec.record(lvl, 1, _level_A(ldata), x, b)
@@ -216,15 +147,8 @@ def _cycle(amg, shape: str, data, lvl: int, b, x, want_dot: bool = False):
                 xc = _cycle(amg, "V", data, lvl + 1, bc, xc)
         else:
             raise ValueError(f"unknown fixed cycle {shape!r}")
-        if rec is not None:
-            x = _prolongate_correct(level, ldata, x, xc)
-            rec.record(lvl, 2, _level_A(ldata), x, b)
-            x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
-            rec.record(lvl, 3, _level_A(ldata), x, b)
-            return (x, None) if want_dot else x
-        return _prolongate_smooth(amg, level, ldata, b, x, xc,
-                                  amg._sweeps(lvl, pre=False), lvl,
-                                  want_dot=want_dot)
+        return _correct_smooth(level, ldata, b, x, xc,
+                               amg._sweeps(lvl, pre=False), lvl, rec)
 
 
 def _kcycle(amg, data, lvl: int, b, x, flex: bool):
@@ -240,7 +164,7 @@ def _kcycle(amg, data, lvl: int, b, x, flex: bool):
         rec = _diag.current()
         if rec is not None:
             rec.record(lvl, 0, _level_A(ldata), x, b)
-        x, bc = _smooth_restrict(amg, level, ldata, b, x,
+        x, bc = _smooth_restrict(level, ldata, b, x,
                                  amg._sweeps(lvl, pre=True), lvl)
         if rec is not None:
             rec.record(lvl, 1, _level_A(ldata), x, b)
@@ -290,14 +214,8 @@ def _kcycle(amg, data, lvl: int, b, x, flex: bool):
             beta = num / jnp.where(rz == 0, 1.0, rz) * (rz != 0)
             rz = rz_new
             p = z + beta * p
-        if rec is not None:
-            x = _prolongate_correct(level, ldata, x, xc)
-            rec.record(lvl, 2, _level_A(ldata), x, b)
-            x = _smooth(level, ldata, b, x, amg._sweeps(lvl, pre=False))
-            rec.record(lvl, 3, _level_A(ldata), x, b)
-            return x
-        return _prolongate_smooth(amg, level, ldata, b, x, xc,
-                                  amg._sweeps(lvl, pre=False), lvl)
+        return _correct_smooth(level, ldata, b, x, xc,
+                               amg._sweeps(lvl, pre=False), lvl, rec)
 
 
 def spmv_coarsest(amg, data, v):
@@ -323,15 +241,3 @@ def run_cycle(amg, name: str, data, b, x):
     if name == "CGF":
         return _kcycle(amg, data, 0, b, x, flex=True)
     raise ValueError(f"unknown cycle {name!r}")
-
-
-def run_cycle_dot(amg, name: str, data, b, x):
-    """Cycle application that ALSO asks for the x'.b dot epilogue from
-    the cycle's last kernel (the Krylov shell's cycle-borne r.z).
-    Returns (x', dot) with dot=None whenever the cycle cannot carry it
-    — K-cycles, diagnostics probes, unfused last levels — so callers
-    fall back to an explicit reduction."""
-    name = name.upper()
-    if name in ("V", "W", "F"):
-        return _cycle(amg, name, data, 0, b, x, want_dot=True)
-    return run_cycle(amg, name, data, b, x), None

@@ -149,47 +149,6 @@ class AMGLevel:
     def prolongate(self, data, xc):
         raise NotImplementedError
 
-    # -- cycle fusion hooks (amg/cycles.py) ------------------------------
-    # The cycle NEVER calls restrict_fused / prolongate_smooth blindly:
-    # it first consults `supports_fusion(data)` (cycles._fusion_caps,
-    # resolved through the CLASS so `__getattr__`-delegating wrappers
-    # advertise nothing unless they define the surface explicitly) and
-    # invokes a hook only when its capability is advertised — a level
-    # class that does not implement a future hook is simply skipped
-    # instead of raising. Aggregation levels override the hooks with
-    # the fused grid-transfer kernels (presmooth+restrict in one
-    # pallas_call, prolongate+correction folded into the postsmoother's
-    # first application); classical levels do the same through the
-    # WEIGHTED row-segment slabs of their general CSR interpolation
-    # (amg/classical). Distributed levels advertise NOTHING here on
-    # purpose: their fusion — the halo-folded per-shard smoother
-    # kernel (distributed/fused.py) — rides inside the smoother's own
-    # smooth/smooth_residual dispatch (ops/smooth.fused_smooth sees the
-    # "dist_fused" payload), so the plain compose the cycle falls back
-    # to IS the fused distributed path; transfer-space-changing
-    # wrappers (consolidation) need no overrides at all.
-    FUSION_CAPS = frozenset({"restrict", "prolongate"})
-
-    def supports_fusion(self, data):
-        """Capabilities of the fused cycle hooks for this level's
-        solve-data: a collection drawn from {"restrict", "prolongate"}
-        (empty = always compose unfused)."""
-        return ()
-
-    def restrict_fused(self, data, b, x, sweeps: int):
-        """(x', bc) with the presmooth+residual fused into one kernel,
-        or None when unsupported."""
-        return None
-
-    def prolongate_smooth(self, data, b, x, xc, sweeps: int,
-                          want_dot: bool = False):
-        """smooth(b, x + P xc) with the correction folded into the
-        postsmoother's kernel prologue, or None when unsupported. With
-        want_dot, (x', dot) where dot is the kernel's x'.b epilogue
-        (the Krylov shell's cycle-borne r.z) or None when the fused
-        form cannot carry it."""
-        return None
-
 
 _PENDING = object()    # _put_cache placeholder: (src, (_PENDING, fut, i))
 
@@ -222,9 +181,6 @@ class AMG(SolveDataOwner):
             == "DENSE_LU_SOLVER" else 0)
         self.cycle_name = str(cfg.get("cycle", scope)).upper()
         self.cycle_iters = int(cfg.get("cycle_iters", scope))
-        self.cycle_fusion = bool(int(cfg.get("cycle_fusion", scope)))
-        self.cycle_fusion_tail_rows = int(
-            cfg.get("cycle_fusion_tail_rows", scope))
         # matrix-free GEO levels (ops/stencil.py): auto = only on a
         # real TPU backend (CPU rigs stay bit-identical to the slab
         # build), 1 = force the detector everywhere, 0 = never
@@ -343,7 +299,6 @@ class AMG(SolveDataOwner):
         self._resetup_precast = None
         self._vr_plan = None     # value-resetup plan re-derives lazily
         self._last_resetup_value_only = False
-        self._tail_entry_level = None   # re-recorded at cycle trace time
         self._telemetry_level_cache = None
         host = self._host_setup_device(A)
         if host is not None:
@@ -394,7 +349,6 @@ class AMG(SolveDataOwner):
         self._resetup_precast = None
         self._vr_plan = None
         self._last_resetup_value_only = False
-        self._tail_entry_level = None
         self._telemetry_level_cache = None
         host = self._host_setup_device(A)
         if host is not None:
@@ -561,7 +515,7 @@ class AMG(SolveDataOwner):
         hierarchy as a side effect is carried over (no trace will
         record it again)."""
         before = self._static_sig
-        traced = (self._tail_entry_level, self._telemetry_level_cache)
+        table = self._telemetry_level_cache
         self._resetup_same_static = False
         # the old values' tree (its cast twins with it) goes before
         # whichever route makes the new leaves
@@ -572,20 +526,13 @@ class AMG(SolveDataOwner):
         self._static_sig = self._signature()
         if before is not None and before == self._static_sig:
             self._resetup_same_static = True
-            self._carry_trace_records(*traced)
+            # the report's level table is keyed on the level list:
+            # equal signatures make the old one true of the new list
+            # (rows, sizes, layouts, fused payloads and dtypes are all
+            # in the signature)
+            from ..telemetry.report import carry_level_table
+            carry_level_table(self, table)
         return self
-
-    def _carry_trace_records(self, tail, table):
-        """The VMEM tail's entry level is written by coarse_tail_cycle
-        while the cycle is TRACED (ops/smooth.py) and the report's
-        level table is keyed on it and on the level list: a rebuild
-        that keeps the program runs no trace, so both come over from
-        the hierarchy the program was traced against (equal signatures
-        make them equal: rows, sizes, layouts, fused payloads and
-        dtypes are all in it)."""
-        from ..telemetry.report import carry_level_table
-        self._tail_entry_level = tail
-        carry_level_table(self, table)
 
     def _resetup_route(self, A: CsrMatrix):
         reuse = int(self.cfg.get("structure_reuse_levels", self.scope))
@@ -609,11 +556,9 @@ class AMG(SolveDataOwner):
             _tm.inc("amg.resetup.value_declined")
         _tm.inc("amg.resetup.structure")
         _record_route("structure", A, **why)
-        # a structure resetup rebuilds levels and retraces the cycle:
-        # the recorded tail boundary and the memoized report level
-        # table are for the OLD hierarchy (the value-only path above
-        # keeps both valid — structure and traces survive)
-        self._tail_entry_level = None
+        # a structure resetup rebuilds levels: the memoized report
+        # level table is for the OLD hierarchy (the value-only path
+        # above keeps it valid)
         self._telemetry_level_cache = None
         if self._ship_device is not None:
             host = jax.devices("cpu")[0]
@@ -712,11 +657,8 @@ class AMG(SolveDataOwner):
         """The entries of a put cache whose host source is a leaf this
         level took over by `reuse_structure`: the transfer operators (P
         and R hold the coefficients of the first setup, layout slabs
-        included) and the transfer-slab memo where it was carried.
-        Matched by identity, as the cache is keyed."""
-        memo = getattr(level, "_xfer_memo", None)
-        carried = [getattr(level, "P", None), getattr(level, "R", None),
-                   memo[0] if memo else None]
+        included). Matched by identity, as the cache is keyed."""
+        carried = [getattr(level, "P", None), getattr(level, "R", None)]
         kept = {}
         for leaf in jax.tree.leaves(carried):
             entry = shipped.get(id(leaf))
@@ -1016,12 +958,6 @@ class AMG(SolveDataOwner):
                 op = getattr(level, name, None)
                 if op is not None and op.initialized:
                     pieces.append(op.slim_for_spmv())
-            # fused-cycle transfer slabs (built at setup by the level
-            # classes): ship with the level instead of as a first-solve
-            # straggler
-            memo = getattr(level, "_xfer_memo", None)
-            if memo is not None and memo[0] is not None:
-                pieces.append(memo[0])
             if level.smoother is not None:
                 pieces.append(level.smoother.solve_data_part())
             self._prefetch_leaves(pieces)
@@ -1234,18 +1170,6 @@ class AMG(SolveDataOwner):
         with self._note_dia_smooth():
             x = run_cycle(self, self.cycle_name, data, b, x)
         return x.astype(out_dtype)
-
-    def cycle_dot(self, data, b, x):
-        """One cycle PLUS the x'.b dot epilogue from its final kernel
-        ((x', dot), dot None when unavailable). A reduced-precision
-        cycle declines the dot: the epilogue would reduce the rounded
-        product while callers need the caller-dtype x'.b, so the cheap
-        explicit reduction stays correct there."""
-        from .cycles import run_cycle_dot
-        if self._PRECISIONS[self.precision] is not None:
-            return self.cycle(data, b, x), None
-        with self._note_dia_smooth():
-            return run_cycle_dot(self, self.cycle_name, data, b, x)
 
     _dia_smooth = (0, 0)    # what the last traced cycle launched
 

@@ -39,12 +39,10 @@ from ..config import Config
 from ..solvers.base import Solver
 from .hierarchy import AMG, AMGLevel
 
-# what the cycle, the coarse tail and the cast of solve_data read from
-# the hierarchy object itself (amg/cycles.py, ops/smooth.py
-# coarse_tail_cycle, AMG.solve_data / cycle / cycle_dot)
-_AMG_ATTRS = ("algorithm", "cycle_name", "cycle_iters", "cycle_fusion",
-              "cycle_fusion_tail_rows", "precision", "coarsest_sweeps",
-              "diagnostics")
+# what the cycle and the cast of solve_data read from the hierarchy
+# object itself (amg/cycles.py, AMG.solve_data / cycle)
+_AMG_ATTRS = ("algorithm", "cycle_name", "cycle_iters", "precision",
+              "coarsest_sweeps", "diagnostics")
 
 # instance attributes that are no input of a trace: the matrix and the
 # config object (the first is the observable half's, the second is read

@@ -78,19 +78,6 @@ class AlgebraicMultigridSolver(Solver):
     def solve_init(self, data, b, x, r):
         return self._guard_init()
 
-    def apply_dot(self, data, rhs):
-        """One cycle with the x'.rhs dot riding its last kernel's
-        epilogue (AMG.cycle_dot). Only the single-cycle shape
-        qualifies: apply() with max_iters > 1 loops cycles whose
-        intermediate outputs the epilogue cannot represent, so that
-        declines to (apply, None) and callers reduce explicitly.
-        (apply() never monitors and its breakdown flag is dead, so
-        max_iters is the whole gate.)"""
-        if self.max_iters != 1:
-            return self.apply(data, rhs), None
-        return self.amg.cycle_dot(data["amg"], rhs,
-                                  jnp.zeros_like(rhs))
-
     def solve_iteration(self, data, b, st):
         out = dict(st)
         x_new = self.amg.cycle(data["amg"], b, st["x"])

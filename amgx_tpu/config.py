@@ -285,9 +285,9 @@ def _register_default_parameters():
       "coalesce order, output CSR pattern) runs once per sparsity "
       "pattern and is memoized on the level + a digest-keyed cache, "
       "so warm setups and value resetups do ZERO symbolic work — the "
-      "value phase is one fused Pallas kernel per level on TPU "
-      "(ops/pallas_spgemm.py) and a sort-free gather/segment-sum (or "
-      "host reduceat) program elsewhere. auto/1 = plan split on; 0 = "
+      "value phase is a sort-free gather/segment-sum XLA program on "
+      "the device and the native sweep (or a numpy reduceat) on host "
+      "hierarchies. auto/1 = plan split on; 0 = "
       "the eager sort/expand composition, bit-for-bit (no plan "
       "machinery runs at all)", "auto", ("auto", "0", "1"))
     R("spmm_gmem_size", int, "deprecated", 1024)
@@ -361,29 +361,18 @@ def _register_default_parameters():
       "GEO levels (ops/stencil.py): a setup-time detector replaces the "
       "level's DIA value slab with a StencilOperator (k coefficients + "
       "static geometry, O(levels) operator memory) and every fused "
-      "smoother/transfer/tail kernel reads the coefficients from SMEM "
+      "smoother kernel reads the coefficients from SMEM "
       "instead of streaming the A value slab from HBM; "
       "variable-coefficient levels always keep the slab path. auto = "
       "on only on a real TPU backend (CPU rigs bit-identical to the "
       "slab build), 1 = force the detector on every backend (the XLA "
       "masked-coefficient compose off-TPU), 0 = never detect — the "
       "slab path bit-for-bit", "auto", ("auto", "0", "1"))
-    R("cycle_fusion", int, "fuse the cycle's grid transfers into the "
-      "smoother kernels on aggregation/DIA levels (restriction epilogue "
-      "in the presmoother, prolongation+correction prologue in the "
-      "postsmoother) and run the VMEM-resident coarse tail of the "
-      "hierarchy as one kernel (ops/smooth.py); 0 restores the "
-      "per-level smooth/restrict/prolongate composition bit-for-bit",
-      1, BOOL01)
-    R("cycle_fusion_tail_rows", int, "largest level row count admitted "
-      "into the fused coarse-tail kernel (the dispatch-latency-bound "
-      "tiny-level region; levels above it keep per-level kernels)",
-      65536, None, 0)
     R("krylov_fusion", int, "fuse the Krylov shell around the cycle on "
       "DIA operators (ops/pallas_spmv.py): the direction update, SpMV "
       "and p.Ap run as ONE kernel with the dot as a per-block epilogue, "
       "the x/r updates and the monitor's r.r share a second single-pass "
-      "kernel, PCG's r.z rides the cycle's last kernel, and distributed "
+      "kernel, and distributed "
       "runs pack the iteration's scalars into one psum bundle; 0 "
       "restores the unfused SpMV/BLAS-1 composition bit-for-bit",
       1, BOOL01)

@@ -205,15 +205,13 @@ class _ConsolidationBoundaryLevel:
                                     self._nc_local, self._nc_global)
         return self._level.prolongate(data, xc_local)
 
-    # Cycle-fusion hooks: none, and the wrapped level's must never be
-    # reached through __getattr__ delegation — they would
-    # restrict/prolongate in ITS (shard-local) space, skipping this
-    # wrapper's gather into the replicated-tail numbering. The cycle's
-    # class-resolved capability check (amg/cycles.py _fusion_caps)
-    # guarantees that: no class-level surface here means the plain
-    # compose runs, with the smoother's "dist_fused" payload fusing
-    # the sweeps and the gathered tail levels downstream qualifying
-    # for the single-chip VMEM coarse-tail megakernel unchanged.
+    # No `prolongate_correct` here, and the wrapped level's must never
+    # be reached through __getattr__ delegation — it would correct in
+    # ITS (shard-local) space, skipping this wrapper's gather into the
+    # replicated-tail numbering. The cycle resolves that one optional
+    # method through the CLASS (amg/cycles.py _prolongate_correct), so
+    # the plain x + prolongate runs, with the smoother's "dist_fused"
+    # payload fusing the sweeps.
 
 
 class DistributedCoarseSolver:

@@ -160,7 +160,7 @@ def fusion_gates(cfg, scope: str, smoother) -> bool:
         return False
     if smoother is None or not getattr(smoother, "fused_smoother", False):
         return False
-    if getattr(smoother, "fused_tail_spec", None) is None:
+    if getattr(smoother, "_fused_taus", None) is None:
         return False          # not a damped-relaxation-family smoother
     return True
 
@@ -172,7 +172,7 @@ def attach_shard_fused(smd: dict, A, smoother, n_ranks: int,
     solve-data dict (key "dist_fused"), or do nothing. Gated on
     `fusion_gates` (knob / runtime / smoother family — non-TPU rigs
     build no payloads and change nothing, same contract as
-    fused_smoother / cycle_fusion). Memoized on the identity of the
+    fused_smoother). Memoized on the identity of the
     value-carrying arrays, so a value resetup that swaps in new
     coefficients rebuilds the halo-extended slabs while repeated
     setups on the same values reuse them. A caller whose dinv is

@@ -713,18 +713,13 @@ class DistAMGLevel:
         from ..ops.spmv import spmv
         return spmv(data["P"], xc)
 
-    # Cycle-fusion hooks: none needed. The cycle consults
-    # `supports_fusion` through the CLASS (amg/cycles.py _fusion_caps)
-    # and this class defines no capability surface, so the plain
-    # smooth_residual -> restrict / prolongate -> smooth compose runs —
-    # which IS the fused distributed path: the halo-folded per-shard
-    # kernel (distributed/fused.py, attached as the smoother's
-    # "dist_fused" payload) dispatches inside smooth/smooth_residual
-    # (ops/smooth.fused_smooth), and the sharded R/P's owned-aggregate
-    # segment sums are shard-local by construction of the partition
-    # (remote members arrive through R's own halo map). The PR-5
-    # AttributeError class of bug is structurally impossible: an
-    # unimplemented hook is never invoked.
+    # The halo-folded per-shard smoother kernel (distributed/fused.py,
+    # attached as the smoother's "dist_fused" payload) dispatches
+    # inside smooth/smooth_residual (ops/smooth.fused_smooth), so the
+    # cycle's one composition IS the fused distributed path; the
+    # sharded R/P's owned-aggregate segment sums are shard-local by
+    # construction of the partition (remote members arrive through R's
+    # own halo map).
 
 
 class ShardedConsolidationLevel:
@@ -766,16 +761,11 @@ class ShardedConsolidationLevel:
             k < cnt, xp[jnp.clip(lo + k, 0, self._nc_g)], 0.0)
         return self._level.prolongate(data, xc_local)
 
-    # Cycle-fusion hooks: none — and none may be ADDED via __getattr__
-    # delegation: the wrapped level's hooks would finish with ITS
-    # transfers (the shard-local R/P), skipping this wrapper's
-    # gather/compact into the replicated tail's numbering. The cycle's
-    # class-resolved capability check (amg/cycles.py _fusion_caps)
-    # guarantees the delegation is never consulted; the plain compose
-    # runs, the smoother's "dist_fused" dispatch fuses the sweeps, and
-    # the replicated tail levels below the boundary feed the
-    # single-chip VMEM coarse-tail megakernel
-    # (ops/smooth.coarse_tail_cycle) unchanged.
+    # No `prolongate_correct` here: the wrapped level's would finish
+    # with ITS transfer (the shard-local P), skipping this wrapper's
+    # gather/compact into the replicated tail's numbering. The cycle
+    # resolves it through the CLASS (amg/cycles.py
+    # _prolongate_correct), so the delegation is never consulted.
 
 
 def _mk_shard(fields: dict, n_global: int, n_local: int,

@@ -221,132 +221,3 @@ def cg_update_multi(X: jax.Array, P: jax.Array, R: jax.Array,
     Rn = R.astype(cdt) - a * AP.astype(cdt)
     rr = jnp.sum(Rn * Rn, axis=1)
     return Xn.astype(dt), Rn.astype(dt), rr
-
-
-# ---------------------------------------------------------------------------
-# cycle fusion slab forms (the custom_vmap fallbacks of the fused
-# grid-transfer / coarse-tail kernels in ops/smooth.py — and the f64
-# reference the kernel parity tests compare against)
-# ---------------------------------------------------------------------------
-
-
-def restrict_multi(R: jax.Array, xfer) -> jax.Array:
-    """BC = R-restriction of the residual slab (B, n) via the
-    child-index slab (m gathers, no scatter): the aggregation
-    segment-sum, or — when the slab carries weights (general CSR
-    interpolation, classical levels) — the weighted row-segment sum
-    bc[c] = sum_j cwt[j][c] * r[ctab[j][c]]."""
-    ctab = xfer.ctab.reshape(xfer.m, -1)
-    valid = ctab >= 0
-    idx = jnp.where(valid, ctab, 0)
-    g = R[:, idx]                                   # (B, m, ncr*128)
-    if xfer.cwt is not None:
-        g = g * xfer.cwt.reshape(xfer.m, -1)[None]
-    bc = jnp.where(valid[None], g, 0.0).sum(axis=1)
-    return bc[:, : xfer.nc]
-
-
-def _agg_content(A: CsrMatrix, xfer) -> jax.Array:
-    """Aggregate id per fine row (n,) — the content slice of the
-    quota-padded atab slab."""
-    from .pallas_spmv import LANES, transfer_quota_rows
-    aqf = transfer_quota_rows(A.dia_offsets, A.num_rows)[0]
-    return xfer.atab.reshape(-1)[aqf * LANES: aqf * LANES + A.num_rows]
-
-
-def prolong_corr_multi(A: CsrMatrix, X: jax.Array, XC: jax.Array,
-                       xfer) -> jax.Array:
-    """X + P XC for (B, n) X and (B, nc) XC: gather by aggregate id
-    (piecewise-constant aggregation P), or the weighted row-segment
-    gather X += sum_j pwt[j] * XC[ptab[j]] (general CSR P)."""
-    if xfer.ptab is None:
-        return X + XC[:, _agg_content(A, xfer)]
-    from .pallas_spmv import LANES, transfer_quota_rows
-    aqf = transfer_quota_rows(A.dia_offsets, A.num_rows)[0]
-    n = A.num_rows
-    lo, hi = aqf * LANES, aqf * LANES + n
-    pt = xfer.ptab.reshape(xfer.mp, -1)[:, lo:hi]   # (mp, n)
-    pw = xfer.pwt.reshape(xfer.mp, -1)[:, lo:hi]
-    valid = pt >= 0
-    g = XC[:, jnp.where(valid, pt, 0)]              # (B, mp, n)
-    corr = (jnp.where(valid, pw, 0.0)[None] * g).sum(axis=1)
-    return X + corr
-
-
-def smooth_restrict_dia_multi(A: CsrMatrix, B: jax.Array, X: jax.Array,
-                              taus, dinv, xfer):
-    """Multi-RHS form of the fused presmooth + restriction epilogue:
-    (X', BC) with BC = R (B - A X'). bf16 inputs run the whole chain
-    at f32 (the kernel's restriction partial sums are f32 too) and
-    round the outputs back."""
-    dt = X.dtype
-    cdt = _cdt(dt)
-    X, R = smooth_dia_multi(A, B.astype(cdt), X.astype(cdt), taus,
-                            dinv, True)
-    return X.astype(dt), restrict_multi(R, xfer).astype(dt)
-
-
-def corr_smooth_dia_multi(A: CsrMatrix, B: jax.Array, X: jax.Array,
-                          XC: jax.Array, taus, dinv, xfer):
-    """Multi-RHS form of the fused prolongation prologue + postsmooth:
-    X' = smooth(B, X + P XC). bf16 inputs accumulate the correction
-    gather in f32 and round back (kernel-mirroring)."""
-    dt = X.dtype
-    cdt = _cdt(dt)
-    X = prolong_corr_multi(A, X.astype(cdt), XC.astype(cdt), xfer)
-    return smooth_dia_multi(A, B.astype(cdt), X, taus,
-                            dinv, False).astype(dt)
-
-
-def rap_values_multi(sarrs, AF: jax.Array, r_vals, p_vals, nT: int,
-                     nU: int, has1: bool, has_r: bool,
-                     r_batched: bool = False, p_batched: bool = False):
-    """Multi-coefficient form of the plan-split RAP value phase
-    (ops/spgemm.py RapPlan / ops/pallas_spgemm.py kernel): the batch
-    axis rides the candidate gathers and sorted segment-sums with the
-    plan's index slabs shared across systems. This is both the
-    `custom_vmap` route of the fused value kernel (a vmapped
-    coefficient stream over one pattern never re-streams the index
-    slabs per system) and the f64 parity reference the kernel tests
-    compare against — like `affine_window_sweeps` for the smoother
-    suite. Zero sort/argsort/unique primitives by construction."""
-    if has1:
-        PV = p_vals[:, sarrs["sp"]] if p_batched else \
-            p_vals[sarrs["sp"]][None]
-        cand1 = AF[:, sarrs["sa"]] * PV
-        base = jax.ops.segment_sum(
-            cand1.T, sarrs["seg1"], num_segments=nT,
-            indices_are_sorted=True).T
-    else:
-        base = AF
-    cand2 = base[:, sarrs["st"]]
-    if has_r:
-        RV = r_vals[:, sarrs["sr"]] if r_batched else \
-            r_vals[sarrs["sr"]][None]
-        cand2 = RV * cand2
-    return jax.ops.segment_sum(cand2.T, sarrs["seg2"],
-                               num_segments=nU,
-                               indices_are_sorted=True).T
-
-
-def tail_cycle_multi(arrs, B: jax.Array, X: jax.Array, spec):
-    """Multi-RHS form of the VMEM-resident coarse-tail sub-cycle: the
-    SAME _tail_compute the Pallas kernel body runs, vmapped over the
-    batch with the matrix slabs shared — XLA streams each level's
-    values once per slab pass."""
-    from .pallas_spmv import LANES, _tail_compute
-
-    l0 = spec.levels[0]
-
-    def single(b, x):
-        b2 = jnp.zeros((l0.qc * LANES,), b.dtype)
-        b2 = jax.lax.dynamic_update_slice(b2, b, (0,))
-        x2 = jnp.zeros((l0.qc * LANES,), x.dtype)
-        x2 = jax.lax.dynamic_update_slice(x2, x, (0,))
-        out = _tail_compute(arrs, b2.reshape(l0.qc, LANES),
-                            x2.reshape(l0.qc, LANES), spec)
-        # _tail_compute returns the f32+ accumulation dtype; round
-        # back so the vmapped cycle's state dtype is stable
-        return out.reshape(-1)[: l0.n].astype(b.dtype)
-
-    return jax.vmap(single)(B, X)

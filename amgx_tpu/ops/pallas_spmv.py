@@ -400,10 +400,7 @@ def smooth_halo_rows(offsets):
 
 
 def smooth_br_candidates(num_rows: int):
-    """Candidate block sizes shared by the plan functions AND the
-    transfer-slab builder (which precomputes per-block coarse window
-    bases for every br the plans could pick — the two lists must never
-    diverge or a planned br would have no window metadata)."""
+    """Candidate block sizes of the smoother's plan, largest first."""
     rows128 = max(1, -(-num_rows // LANES))
     single = max(8, -(-rows128 // 8) * 8)
     cands = [c for c in (_BR_CAP, 1536, 1024, 768, 512, 384, 256, 192,
@@ -1422,289 +1419,13 @@ def _dia_stencil_smooth_call(coeffs, taus, b, x, spec, with_residual,
                             interpret=interpret)
 
 
-# ---------------------------------------------------------------------------
-# Cycle fusion: grid-transfer epilogues + VMEM-resident coarse tail
-#
-# After the fused smoother removed the standalone residual pass (above),
-# the remaining solve-phase HBM traffic of an aggregation level is the
-# grid-transfer chain: restrict reads the residual the smoother just
-# wrote, and prolongate+correction makes one more full-vector pass
-# before the post-smoother reads x again. Both fold into the smoother
-# kernels:
-#
-# - RESTRICTION EPILOGUE (`_dia_smooth_restrict_call`): the presmooth
-#   kernel already holds r in VMEM — instead of writing it to HBM, each
-#   grid block emits the partial segment-sums of its OWN fine rows into
-#   the (static) coarse row window the block touches, gathered through a
-#   precomputed child-index slab (ctab[j][c] = fine slot of aggregate
-#   c's j-th child, -1 when absent). Aggregates straddling a block
-#   boundary complete in the cheap XLA combine that adds the per-block
-#   windows into the coarse rhs — each fine slot belongs to exactly one
-#   block, so the partials sum exactly. r never round-trips HBM and
-#   `level.restrict` disappears from the cycle.
-#
-# - PROLONGATION PROLOGUE (`_dia_prolong_smooth_call`): the postsmooth
-#   kernel's first application folds x + P xc in: each block DMAs the
-#   coarse window its x-window references (per-block base from an SMEM
-#   table) and gathers xc through the aggregate-id slab (atab[slot] =
-#   coarse id, -1 at padding) before the first sweep — the correction
-#   add's full-vector pass disappears.
-#
-# - COARSE TAIL (`_dia_coarse_tail_call`): when every level >= k fits
-#   the VMEM budget simultaneously (the dispatch-latency-bound tiny
-#   levels), the whole sub-cycle — smooth, restrict, ..., coarsest
-#   solve (dense inverse matmul), ..., prolongate, smooth — runs as ONE
-#   grid=(1,) kernel with every intermediate vector VMEM-resident.
-#   `_tail_compute` is the single source of truth: the Pallas kernel
-#   body and the XLA fallback (f64 / vmapped batches, ops/batched.py)
-#   both call it.
-#
-# The child/aggregate index slabs are STRUCTURE-only (built once per
-# (re)setup from the aggregates map by ops.smooth.build_transfer_slabs;
-# value-only resetups keep them). In-kernel gathers use precomputed
-# indices only — no data-dependent addressing.
-# ---------------------------------------------------------------------------
-
-TRANSFER_MAX_CHILD = 16     # largest aggregate the epilogue fuses
-# weighted (general-CSR) transfer slabs: classical interpolation rows
-# are short (interp_max_elements-truncated) but a coarse point's
-# R-row — the set of fine points it interpolates — runs longer than
-# any aggregate, so the weighted child table gets its own cap (the
-# plans still arbitrate the real VMEM/traffic cost per block size)
-CSR_TRANSFER_MAX_CHILD = 32
-
-
-def coarse_pad_rows(nc: int) -> int:
-    """Padded 128-lane row count of kernel-side coarse vectors."""
-    return max(1, -(-nc // LANES))
-
-
-def transfer_quota_rows(offsets, num_rows: int):
-    """(front, content, back) rows of the quota-padded aggregate-id
-    slab (atab): sized like smooth_quota_rows but one application
-    deeper in front (the prolongation prologue covers the x window,
-    which reaches n_app*mr0 rows before the block)."""
-    mr0, Mr0 = smooth_halo_rows(offsets)
-    rows128 = max(1, -(-num_rows // LANES))
-    content = max(8, -(-rows128 // 8) * 8)
-    front = SMOOTH_MAX_APPS * mr0
-    back = SMOOTH_MAX_APPS * Mr0 + min(content, _BR_CAP)
-    return front, content, back
-
-
-@jax.tree_util.register_pytree_node_class
-class TransferSlabs:
-    """Setup-built transfer payloads of one aggregation OR classical
-    level.
-
-    Children (device arrays): `ctab` (m, ncr, 128) int32 child-index
-    slab (restriction: fine slot of coarse row c's j-th source entry,
-    -1 absent); `atab` (quota rows, 128) int32 aggregate-id slab
-    (aggregation prolongation: ONE unit-weight coarse id per fine
-    slot); `bases` {br: (cb, pcb)} per-candidate-block-size int32
-    coarse window bases (restriction / prolongation). General-CSR
-    (classical interpolation) levels add the WEIGHTED row-segment
-    slabs: `cwt` (m, ncr, 128) restriction weights aligned with ctab,
-    and `ptab`/`pwt` (mp, quota rows, 128) — the j-th (coarse id,
-    weight) entry of P's row per fine slot, replacing atab. Static
-    aux: `nc` coarse rows, `ncr` padded coarse 128-lane rows, `m` max
-    restriction row length, `windows` ((br, cw, pcw), ...) — the
-    static coarse-window row counts the plan functions check VMEM
-    against — `mp` max prolongation row length, and `wavg`/`pavg`
-    (ceil average R/P row lengths: the plans' honest unfused-traffic
-    term for the weighted forms)."""
-
-    def __init__(self, ctab, atab, bases, nc, ncr, m, windows,
-                 cwt=None, ptab=None, pwt=None, mp=1, wavg=None,
-                 pavg=None):
-        self.ctab = ctab
-        self.atab = atab
-        self.bases = bases
-        self.nc = nc
-        self.ncr = ncr
-        self.m = m
-        self.windows = windows
-        self.cwt = cwt
-        self.ptab = ptab
-        self.pwt = pwt
-        self.mp = mp
-        self.wavg = m if wavg is None else wavg
-        self.pavg = mp if pavg is None else pavg
-
-    def tree_flatten(self):
-        return ((self.ctab, self.atab, self.bases, self.cwt,
-                 self.ptab, self.pwt),
-                (self.nc, self.ncr, self.m, self.windows, self.mp,
-                 self.wavg, self.pavg))
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        nc, ncr, m, windows, mp, wavg, pavg = aux
-        return cls(children[0], children[1], children[2], nc, ncr, m,
-                   windows, cwt=children[3], ptab=children[4],
-                   pwt=children[5], mp=mp, wavg=wavg, pavg=pavg)
-
-
-def dia_restrict_plan(offsets, k: int, num_rows: int, n_steps: int,
-                      m: int, windows, weighted: bool = False,
-                      wavg=None, itemsize: int = 4,
-                      coeffs: bool = False):
-    """Block plan for the smoother+restriction-epilogue kernel, or
-    None. Mirrors dia_smooth_plan(with_residual=True) plus the epilogue
-    buffers: m double-buffered child-index windows (and, `weighted`,
-    the matching weight windows of the general-CSR form) and the
-    pipelined partial-coarse output block. `wavg` (weighted only) is
-    the ceil-average R row length — the honest per-window cost of the
-    unfused SWELL restriction the fusion replaces. `itemsize` is the
-    operand byte width (value/vector/weight streams; the index tables
-    are always int32)."""
-    cap = CSR_TRANSFER_MAX_CHILD if weighted else TRANSFER_MAX_CHILD
-    if not offsets or m < 1 or m > cap:
-        return None
-    n_app = int(n_steps) + 1
-    if n_steps < 1 or n_app > SMOOTH_MAX_APPS:
-        return None
-    ib = int(itemsize)
-    wavg = m if wavg is None else wavg
-    tabs = 2 if weighted else 1          # index (+ weight) tables
-    wmap = {w[0]: w[1] for w in windows}
-    mr0, Mr0 = smooth_halo_rows(offsets)
-    H = mr0 + Mr0
-    rows128 = max(1, -(-num_rows // LANES))
-    for br in smooth_br_candidates(num_rows):
-        if br not in wmap:
-            continue
-        cw = wmap[br]
-        win_v = br + (n_app - 1) * H
-        win_x = win_v + H
-        if coeffs:
-            vmem = (2 * (win_v + win_x) + 2 * br + 2 * cw) * LANES \
-                * ib + 2 * m * cw * LANES * 4 \
-                + _MF_WORK_ROWS * win_v * LANES * 4
-        else:
-            vmem = (2 * k * win_v + 2 * (2 * win_v + win_x)
-                    + 2 * br             # x output pipeline
-                    + 2 * cw             # partial-coarse output pipeline
-                    ) * LANES * ib \
-                + 2 * m * cw * LANES * 4   # child-index windows (int32)
-        if weighted:
-            vmem += 2 * m * cw * LANES * ib   # weight windows
-        if ib < 4:
-            # f32 state + upcast temporaries + f32 partial sums
-            vmem += (win_x + 3 * win_v + cw) * LANES * 4
-        if vmem > _SMOOTH_VMEM_BUDGET:
-            continue
-        # traffic guard vs the unfused compose: n_app passes over A
-        # plus the standalone restrict pass (r write + r read + bc
-        # write ~ 3*br + cw; weighted: + the R vals/cols stream the
-        # unfused SWELL SpMV would read, ~ 2*wavg*cw)
-        if coeffs:
-            fused = 2 * win_v + win_x + (m + 1) * cw
-            unfused = n_app * 4 * br + 3 * br + cw
-        else:
-            fused = (k + 2) * win_v + win_x + (tabs * m + 1) * cw
-            unfused = n_app * (k + 3) * br + 3 * br + cw \
-                + (2 * wavg * cw if weighted else 0)
-        if n_app > 1 and fused >= 0.95 * unfused:
-            continue
-        n_blocks = -(-rows128 // br)
-        return br, n_app, mr0, Mr0, win_x, win_v, n_blocks, cw
-    return None
-
-
-def dia_prolong_plan(offsets, k: int, num_rows: int, n_steps: int,
-                     windows, mp: int = 1, weighted: bool = False,
-                     pavg=None, itemsize: int = 4,
-                     coeffs: bool = False):
-    """Block plan for the prolongation-prologue+smoother kernel, or
-    None. with_residual is never true here (the correction folds into
-    the POST-smoother); the prologue adds the aggregate-id window (or,
-    general CSR, mp index+weight window pairs) and the coarse-vector
-    window to the budget. `itemsize` is the operand byte width (the
-    id tables stay int32)."""
-    if not offsets or mp < 1 or mp > TRANSFER_MAX_CHILD:
-        return None
-    n_app = int(n_steps)
-    if n_app < 1 or n_app > SMOOTH_MAX_APPS:
-        return None
-    ib = int(itemsize)
-    pavg = mp if pavg is None else pavg
-    tabs = 2 if weighted else 1
-    wmap = {w[0]: w[2] for w in windows}
-    mr0, Mr0 = smooth_halo_rows(offsets)
-    H = mr0 + Mr0
-    rows128 = max(1, -(-num_rows // LANES))
-    for br in smooth_br_candidates(num_rows):
-        if br not in wmap:
-            continue
-        pcw = wmap[br]
-        win_v = br + (n_app - 1) * H
-        win_x = win_v + H
-        if coeffs:
-            vmem = (2 * (win_v + win_x) + 2 * br + 2 * pcw) * LANES \
-                * ib + 2 * win_x * LANES * 4 \
-                + _MF_WORK_ROWS * win_v * LANES * 4
-        else:
-            vmem = (2 * k * win_v + 2 * (2 * win_v + win_x)
-                    + 2 * br             # x output pipeline
-                    + 2 * pcw            # coarse-vector windows
-                    ) * LANES * ib \
-                + 2 * mp * win_x * LANES * 4      # id windows (int32)
-        if weighted:
-            vmem += 2 * mp * win_x * LANES * ib   # weight windows
-        if ib < 4:
-            vmem += (win_x + 3 * win_v + pcw) * LANES * 4
-        if vmem > _SMOOTH_VMEM_BUDGET:
-            continue
-        # guard vs unfused: n_app passes plus the correction pass
-        # (x read + xc read + x write ~ 2*br + pcw; weighted: + the P
-        # vals/cols stream of the unfused SWELL prolongation)
-        if coeffs:
-            fused = 2 * win_v + win_x + win_x + pcw
-            unfused = n_app * 4 * br + 2 * br + pcw
-        else:
-            fused = (k + 2) * win_v + win_x + tabs * mp * win_x + pcw
-            unfused = n_app * (k + 3) * br + 2 * br + pcw \
-                + (2 * pavg * br if weighted else 0)
-        if fused >= 0.95 * unfused and n_app > 1:
-            continue
-        n_blocks = -(-rows128 // br)
-        return br, n_app, mr0, Mr0, win_x, win_v, n_blocks, pcw
-    return None
-
-
-def flat_gather_ok() -> bool:
-    """Capability of every kernel that gathers from a flattened 1-D
-    vector with `jnp.take`: the cycle-fusion family (restriction
-    epilogue, prolongation prologue, coarse tail — slab and stencil
-    twins: child/aggregate index slabs into the residual or the coarse
-    correction) and the plan-split Galerkin value kernel
-    (ops/pallas_spgemm.py). The chip's compiler refuses that whatever
-    the shape (jax 0.9.0, Mosaic for v5e; flagship 7-pt 32^3..128^3
-    and an 8^3 RAP plan):
-
-        NotImplementedError: Only 2D gather is supported
-
-    so those families decline on the compiled-for-chip branch: the
-    cycle runs dia_smooth + the level's XLA restrict/prolongate (the
-    composition cycle_fusion=0 tests), the planned RAP values take the
-    XLA slab / native route. Interpret mode keeps the kernels (their
-    CPU tests guard the semantics until the gathers are redesigned for
-    Mosaic: ROADMAP queue A)."""
-    return pallas_backend() == "interpret"
-
-
 def declined_families():
     """Kernel families that decline on the compiled-for-chip branch,
     with the compiler's refusal: what chip_smoke.py prints beside the
     kernel census. Empty off the chip and in interpret mode."""
     if pallas_backend() != "mosaic":
         return {}
-    gather = "NotImplementedError: Only 2D gather is supported"
     return {
-        "cycle_fusion transfers (restrict epilogue, prolong prologue, "
-        "coarse tail; slab and stencil twins)": gather,
-        "pallas_spgemm plan-split RAP value kernel": gather,
         "bf16 operand windows of dia_smooth / dia_spmv_dot (slab and "
         "stencil twins)":
         "Mosaic failed to compile TPU kernel: Slice shape along "
@@ -1712,916 +1433,19 @@ def declined_families():
     }
 
 
-def _transfer_gate(A, x_dtype) -> bool:
-    if not flat_gather_ok():
-        return False
-    if not smooth_dtype_ok(A, x_dtype):
-        return False
-    return A.num_rows == A.num_cols and not A.has_external_diag
-
-
-def dia_restrict_supported(A, x_dtype, n_steps: int, xfer) -> bool:
-    if xfer is None or not _transfer_gate(A, x_dtype):
-        return False
-    k = A.dia_vals.shape[0]
-    return dia_restrict_plan(A.dia_offsets, k, A.num_rows, n_steps,
-                             xfer.m, xfer.windows,
-                             weighted=xfer.cwt is not None,
-                             wavg=xfer.wavg,
-                             itemsize=jnp.dtype(x_dtype).itemsize) \
-        is not None
-
-
-def dia_prolong_supported(A, x_dtype, n_steps: int, xfer) -> bool:
-    if xfer is None or not _transfer_gate(A, x_dtype):
-        return False
-    k = A.dia_vals.shape[0]
-    return dia_prolong_plan(A.dia_offsets, k, A.num_rows, n_steps,
-                            xfer.windows, mp=xfer.mp,
-                            weighted=xfer.ptab is not None,
-                            pavg=xfer.pavg,
-                            itemsize=jnp.dtype(x_dtype).itemsize) \
-        is not None
-
-
-def _dia_smooth_restrict_kernel(offsets, br, n_app, mr0, Mr0, win_x,
-                                win_v, n_steps, has_dinv, n_blocks,
-                                slab_shift, m, cw, has_w, dtype,
-                                mf=None):
-    """Kernel body factory: the dia_smooth body (window coordinates
-    documented on _dia_smooth_kernel) with the residual epilogue
-    replaced by per-block partial coarse segment-sums — r is gathered
-    through the child-index window into the block's coarse rows and
-    never written to HBM. `has_w` (general-CSR / classical form)
-    gathers a weight window next to each child-index window and the
-    partial sums become weighted: bc[c] = sum_j w[j][c] * r[ct[j][c]]
-    (the aggregation form is the unit-weight special case). Sub-f32
-    operands upcast per block and every partial sum accumulates in
-    `cdt` (f32+) — see _dia_smooth_kernel."""
-    ro = [mr0 + (o - (o % LANES)) // LANES for o in offsets]
-    rl = [o % LANES for o in offsets]
-    cdt = compute_dtype(dtype)
-
-    def kernel(*refs):
-        # refs: xp, vals_q, bp, [dinv_q], ctab, [cwt], cb, taus,
-        #       out_x, out_bc, xbuf, vbuf, bbuf, [dbuf], cbuf, [wbuf],
-        #       sems
-        # mf:   xp, bp, ctab, coeffs, cb, taus, out_x, out_bc,
-        #       xbuf, bbuf, cbuf, sems
-        if mf is None:
-            xp_ref, vals_ref, bp_ref = refs[0], refs[1], refs[2]
-            coeffs_ref = None
-            off = 3
-            dinv_ref = refs[off] if has_dinv else None
-            off += 1 if has_dinv else 0
-            ctab_ref = refs[off]
-            off += 1
-            cwt_ref = refs[off] if has_w else None
-            off += 1 if has_w else 0
-            cb_ref, taus_ref = refs[off], refs[off + 1]
-            off += 2
-            y_ref, bc_ref = refs[off], refs[off + 1]
-            off += 2
-            xbuf, vbuf, bbuf = refs[off], refs[off + 1], refs[off + 2]
-            off += 3
-            dbuf = refs[off] if has_dinv else None
-            off += 1 if has_dinv else 0
-            cbuf = refs[off]
-            off += 1
-            wbuf = refs[off] if has_w else None
-            off += 1 if has_w else 0
-            sems = refs[off]
-        else:
-            xp_ref, bp_ref, ctab_ref = refs[0], refs[1], refs[2]
-            vals_ref = dinv_ref = cwt_ref = None
-            coeffs_ref, cb_ref, taus_ref = refs[3], refs[4], refs[5]
-            y_ref, bc_ref = refs[6], refs[7]
-            xbuf, bbuf, cbuf = refs[8], refs[9], refs[10]
-            vbuf = dbuf = wbuf = None
-            sems = refs[11]
-
-        i = pl.program_id(0)
-        slot = jax.lax.rem(i, jnp.int32(2))
-
-        def dmas(s, blk):
-            base = jnp.int32(blk) * jnp.int32(br)
-            qbase = base + jnp.int32(slab_shift)
-            ops = [
-                pltpu.make_async_copy(xp_ref.at[pl.ds(base, win_x)],
-                                      xbuf.at[jnp.int32(s)],
-                                      sems.at[jnp.int32(s), 0]),
-            ]
-            if mf is None:
-                ops.append(pltpu.make_async_copy(
-                    vals_ref.at[:, pl.ds(qbase, win_v)],
-                    vbuf.at[jnp.int32(s)], sems.at[jnp.int32(s), 1]))
-            ops.append(pltpu.make_async_copy(
-                bp_ref.at[pl.ds(base, win_v)], bbuf.at[jnp.int32(s)],
-                sems.at[jnp.int32(s), 1 if mf is not None else 2]))
-            nsem = 2 if mf is not None else 3
-            if has_dinv:
-                ops.append(pltpu.make_async_copy(
-                    dinv_ref.at[pl.ds(qbase, win_v)],
-                    dbuf.at[jnp.int32(s)], sems.at[jnp.int32(s), nsem]))
-                nsem += 1
-            cbv = cb_ref[blk]
-            for j in range(m):
-                ops.append(pltpu.make_async_copy(
-                    ctab_ref.at[j, pl.ds(cbv, cw)],
-                    cbuf.at[jnp.int32(s), j],
-                    sems.at[jnp.int32(s), nsem + j]))
-            if has_w:
-                for j in range(m):
-                    ops.append(pltpu.make_async_copy(
-                        cwt_ref.at[j, pl.ds(cbv, cw)],
-                        wbuf.at[jnp.int32(s), j],
-                        sems.at[jnp.int32(s), nsem + m + j]))
-            return ops
-
-        @pl.when(i == 0)
-        def _():
-            for d in dmas(0, 0):
-                d.start()
-
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            for d in dmas(jax.lax.rem(i + 1, jnp.int32(2)), i + 1):
-                d.start()
-
-        for d in dmas(slot, i):
-            d.wait()
-
-        col = jax.lax.broadcasted_iota(jnp.int32, (win_v, LANES), 1)
-        bw = bbuf[slot].astype(cdt)
-        if mf is None:
-            vals = vbuf[slot]
-            def val(t):
-                return vals[t].astype(cdt)
-            dw = dbuf[slot].astype(cdt) if has_dinv else None
-        else:
-            row0 = i * jnp.int32(br) - jnp.int32((n_app - 1) * mr0)
-            val, dw = _mf_block_vals(mf, coeffs_ref, row0, win_v, col,
-                                     cdt)
-
-        def apply_A(s):
-            acc = jnp.zeros((win_v, LANES), cdt)
-            for t, _ in enumerate(offsets):
-                a = jax.lax.slice_in_dim(s, ro[t], ro[t] + win_v, 1, 0)
-                if rl[t] == 0:
-                    w = a
-                else:
-                    b2 = jax.lax.slice_in_dim(s, ro[t] + 1,
-                                              ro[t] + 1 + win_v, 1, 0)
-                    shift = LANES - rl[t]
-                    wa = pltpu.roll(a, jnp.int32(shift), 1)
-                    wb = pltpu.roll(b2, jnp.int32(shift), 1)
-                    w = jnp.where(col < shift, wa, wb)
-                acc = acc + val(t) * w
-            return acc
-
-        s = xbuf[slot].astype(cdt)
-        for t in range(n_steps):
-            tau = taus_ref[t]
-            mid = jax.lax.slice_in_dim(s, mr0, mr0 + win_v, 1, 0)
-            corr = tau * (bw - apply_A(s))
-            if dw is not None:
-                corr = corr * dw
-            pieces = [mid + corr, jnp.zeros((Mr0, LANES), cdt)]
-            if mr0:
-                pieces.insert(0, jnp.zeros((mr0, LANES), cdt))
-            s = jnp.concatenate(pieces, axis=0)
-        y_ref[...] = jax.lax.slice_in_dim(
-            s, n_app * mr0, n_app * mr0 + br, 1, 0).astype(dtype)
-        r = bw - apply_A(s)
-        rblk = jax.lax.slice_in_dim(
-            r, (n_app - 1) * mr0, (n_app - 1) * mr0 + br, 1, 0)
-        rflat = rblk.reshape(br * LANES)
-        base = i * jnp.int32(br * LANES)
-        part = jnp.zeros((cw, LANES), cdt)
-        for j in range(m):
-            idxj = cbuf[slot, j]                       # (cw, 128) int32
-            rel = idxj - base
-            valid = (idxj >= 0) & (rel >= 0) & (rel < br * LANES)
-            g = jnp.take(rflat, jnp.where(valid, rel, 0))
-            if has_w:
-                g = g * wbuf[slot, j].astype(cdt)
-            part = part + jnp.where(valid, g, jnp.zeros((), cdt))
-        bc_ref[...] = part.astype(dtype)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "offsets", "num_rows", "mf", "interpret"))
-def _dia_smooth_restrict_call(vals_q, dinv_q, taus, b, x, xfer,
-                              offsets, num_rows, mf=None, coeffs=None,
-                              interpret=False):
-    """Fused presmoother + restriction epilogue: (x', bc) after
-    len(taus) damped sweeps, with bc the segment-summed coarse rhs of
-    the trailing residual. Caller must have checked
-    dia_restrict_supported. Matrix-free form (`mf` + `coeffs`): no
-    vals/dinv slabs; the child-index windows (structure-only) stay."""
-    n_steps = taus.shape[0]
-    has_dinv = dinv_q is not None
-    has_w = xfer.cwt is not None
-    if mf is None:
-        k = vals_q.shape[0]
-        dtype = vals_q.dtype
-    else:
-        k = len(offsets)
-        dtype = x.dtype
-    ib = jnp.dtype(dtype).itemsize
-    plan = dia_restrict_plan(offsets, k, num_rows, n_steps, xfer.m,
-                             xfer.windows, weighted=has_w,
-                             wavg=xfer.wavg, itemsize=ib,
-                             coeffs=mf is not None)
-    br, n_app, mr0, Mr0, win_x, win_v, nb, cw = plan
-    if mf is None:
-        qf, qc, qb = smooth_quota_rows(offsets, num_rows)
-        assert vals_q.shape[1] == qf + qc + qb
-        slab_shift = qf - (n_app - 1) * mr0
-    else:
-        slab_shift = 0
-    n = num_rows
-    cb = xfer.bases[br][0]
-    xp_rows = n_app * mr0 + nb * br + n_app * Mr0
-    xp = jnp.zeros((xp_rows * LANES,), dtype)
-    xp = jax.lax.dynamic_update_slice(xp, x.astype(dtype),
-                                      (n_app * mr0 * LANES,))
-    xp = xp.reshape(xp_rows, LANES)
-    front_v = (n_app - 1) * mr0
-    rows_v = front_v + nb * br + (n_app - 1) * Mr0
-    bp = jnp.zeros((rows_v * LANES,), dtype)
-    bp = jax.lax.dynamic_update_slice(bp, b.astype(dtype),
-                                      (front_v * LANES,))
-    bp = bp.reshape(rows_v, LANES)
-
-    kernel = _dia_smooth_restrict_kernel(
-        offsets, br, n_app, mr0, Mr0, win_x, win_v, n_steps, has_dinv,
-        nb, slab_shift, xfer.m, cw, has_w, dtype, mf=mf)
-    if mf is None:
-        n_sem = (4 if has_dinv else 3) + xfer.m * (2 if has_w else 1)
-        in_specs = [
-            pl.BlockSpec(memory_space=pl.ANY),          # xp
-            pl.BlockSpec(memory_space=pl.ANY),          # vals_q
-            pl.BlockSpec(memory_space=pl.ANY),          # bp
-        ]
-        operands = [xp, vals_q, bp]
-        if has_dinv:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            operands.append(dinv_q)
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # ctab
-        operands.append(xfer.ctab)
-        if has_w:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # cwt
-            operands.append(xfer.cwt.astype(dtype))
-    else:
-        n_sem = 2 + xfer.m
-        in_specs = [
-            pl.BlockSpec(memory_space=pl.ANY),          # xp
-            pl.BlockSpec(memory_space=pl.ANY),          # bp
-            pl.BlockSpec(memory_space=pl.ANY),          # ctab
-            pl.BlockSpec((k,), lambda i: (jnp.int32(0),),
-                         memory_space=pltpu.SMEM),      # coeffs
-        ]
-        operands = [xp, bp, xfer.ctab,
-                    coeffs.astype(compute_dtype(dtype))]
-    in_specs.append(pl.BlockSpec((nb,), lambda i: (jnp.int32(0),),
-                                 memory_space=pltpu.SMEM))
-    operands.append(cb.astype(jnp.int32))
-    in_specs.append(pl.BlockSpec((n_steps,), lambda i: (jnp.int32(0),),
-                                 memory_space=pltpu.SMEM))
-    operands.append(taus.astype(compute_dtype(dtype)))
-    out_specs = (
-        pl.BlockSpec((br, LANES), lambda i: (i, jnp.int32(0)),
-                     memory_space=pltpu.VMEM),
-        pl.BlockSpec((cw, LANES), lambda i: (i, jnp.int32(0)),
-                     memory_space=pltpu.VMEM),
-    )
-    out_shape = (
-        jax.ShapeDtypeStruct((nb * br, LANES), dtype),
-        jax.ShapeDtypeStruct((nb * cw, LANES), dtype),
-    )
-    scratch = [pltpu.VMEM((2, win_x, LANES), dtype)]
-    if mf is None:
-        scratch.append(pltpu.VMEM((2, k, win_v, LANES), dtype))
-    scratch.append(pltpu.VMEM((2, win_v, LANES), dtype))
-    if has_dinv:
-        scratch.append(pltpu.VMEM((2, win_v, LANES), dtype))
-    scratch.append(pltpu.VMEM((2, xfer.m, cw, LANES), jnp.int32))
-    if has_w:
-        scratch.append(pltpu.VMEM((2, xfer.m, cw, LANES), dtype))
-    scratch.append(pltpu.SemaphoreType.DMA((2, n_sem)))
-    nbytes = ((k + 2) * win_v + win_x
-              + (xfer.m * (2 if has_w else 1) + 1) * cw + br) \
-        if mf is None else (2 * win_v + win_x + (xfer.m + 1) * cw + br)
-    y2, parts = kernel_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        cost_estimate=pl.CostEstimate(
-            flops=2 * n_app * k * nb * br * LANES,
-            bytes_accessed=nbytes * nb * LANES * ib,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(*operands)
-    y = y2.reshape(-1)
-    if y.shape[0] != n:
-        y = y[:n]
-    # combine: add each block's partial coarse window at its base row —
-    # every fine slot lives in exactly one block, so aggregates that
-    # straddle block windows complete here
-    if nb == 1 and cw == xfer.ncr:
-        bc = parts.reshape(-1)[:xfer.nc]
-        return y, bc
-    flat = parts.reshape(nb, cw * LANES)
-    bcp = jnp.zeros((xfer.ncr * LANES,), dtype)
-    for i in range(nb):
-        start = cb[i].astype(jnp.int32) * LANES
-        cur = jax.lax.dynamic_slice(bcp, (start,), (cw * LANES,))
-        bcp = jax.lax.dynamic_update_slice(bcp, cur + flat[i], (start,))
-    return y, bcp[:xfer.nc]
-
-
-def _dia_stencil_smooth_restrict_call(coeffs, taus, b, x, xfer, spec,
-                                      interpret=False):
-    """Matrix-free fused presmoother + restriction epilogue. Caller
-    must have checked stencil_restrict_supported."""
-    return _dia_smooth_restrict_call(None, None, taus, b, x, xfer,
-                                     spec.offsets, spec.n, mf=spec,
-                                     coeffs=coeffs, interpret=interpret)
-
-
-def _dia_prolong_smooth_kernel(offsets, br, n_app, mr0, Mr0, win_x,
-                               win_v, n_steps, has_dinv, n_blocks,
-                               slab_shift, ashift, pcw, mp, has_w,
-                               dtype, mf=None, with_dot=False):
-    """Kernel body factory: the dia_smooth body with a prologue that
-    folds the coarse correction in — the state window becomes
-    x + P xc (gather of the block's coarse window through the
-    aggregate-id window) BEFORE the first sweep, so the correction
-    add's full-vector HBM pass disappears. `ashift` is the static
-    offset of the x-window base inside the quota-padded atab/ptab
-    slab. The general-CSR (classical) form — `has_w` — gathers mp
-    (coarse id, weight) window pairs per fine slot and accumulates
-    x += sum_j w[j] * xc[id[j]]; the aggregation form (mp=1, no
-    weights, 2-D atab) is unchanged. Sub-f32 operands upcast per
-    block; state/accumulation in `cdt` (f32+) — see
-    _dia_smooth_kernel."""
-    ro = [mr0 + (o - (o % LANES)) // LANES for o in offsets]
-    rl = [o % LANES for o in offsets]
-    cdt = compute_dtype(dtype)
-
-    def kernel(*refs):
-        # refs: xp, vals_q, bp, [dinv_q], xcp, atab|ptab, [pwt], pcb,
-        #       taus, out_x, xbuf, vbuf, bbuf, [dbuf], xcbuf, abuf,
-        #       [wbuf], sems
-        # mf:   xp, bp, xcp, atab, coeffs, pcb, taus, out_x,
-        #       xbuf, bbuf, xcbuf, abuf, sems
-        if mf is None:
-            xp_ref, vals_ref, bp_ref = refs[0], refs[1], refs[2]
-            coeffs_ref = None
-            off = 3
-            dinv_ref = refs[off] if has_dinv else None
-            off += 1 if has_dinv else 0
-            xcp_ref, atab_ref = refs[off], refs[off + 1]
-            off += 2
-            pwt_ref = refs[off] if has_w else None
-            off += 1 if has_w else 0
-            pcb_ref, taus_ref = refs[off], refs[off + 1]
-            off += 2
-            y_ref = refs[off]
-            off += 1
-            d_ref = refs[off] if with_dot else None
-            off += 1 if with_dot else 0
-            xbuf, vbuf, bbuf = refs[off], refs[off + 1], refs[off + 2]
-            off += 3
-            dbuf = refs[off] if has_dinv else None
-            off += 1 if has_dinv else 0
-            xcbuf, abuf = refs[off], refs[off + 1]
-            off += 2
-            wbuf = refs[off] if has_w else None
-            off += 1 if has_w else 0
-            sems = refs[off]
-        else:
-            xp_ref, bp_ref = refs[0], refs[1]
-            vals_ref = dinv_ref = pwt_ref = None
-            xcp_ref, atab_ref = refs[2], refs[3]
-            coeffs_ref, pcb_ref, taus_ref = refs[4], refs[5], refs[6]
-            y_ref = refs[7]
-            off = 8
-            d_ref = refs[off] if with_dot else None
-            off += 1 if with_dot else 0
-            xbuf, bbuf = refs[off], refs[off + 1]
-            vbuf = dbuf = wbuf = None
-            xcbuf, abuf = refs[off + 2], refs[off + 3]
-            sems = refs[off + 4]
-
-        i = pl.program_id(0)
-        slot = jax.lax.rem(i, jnp.int32(2))
-
-        def dmas(s, blk):
-            base = jnp.int32(blk) * jnp.int32(br)
-            qbase = base + jnp.int32(slab_shift)
-            abase = base + jnp.int32(ashift)
-            ops = [
-                pltpu.make_async_copy(xp_ref.at[pl.ds(base, win_x)],
-                                      xbuf.at[jnp.int32(s)],
-                                      sems.at[jnp.int32(s), 0]),
-            ]
-            if mf is None:
-                ops.append(pltpu.make_async_copy(
-                    vals_ref.at[:, pl.ds(qbase, win_v)],
-                    vbuf.at[jnp.int32(s)], sems.at[jnp.int32(s), 1]))
-            ops.append(pltpu.make_async_copy(
-                bp_ref.at[pl.ds(base, win_v)], bbuf.at[jnp.int32(s)],
-                sems.at[jnp.int32(s), 1 if mf is not None else 2]))
-            nsem = 2 if mf is not None else 3
-            if has_dinv:
-                ops.append(pltpu.make_async_copy(
-                    dinv_ref.at[pl.ds(qbase, win_v)],
-                    dbuf.at[jnp.int32(s)], sems.at[jnp.int32(s), nsem]))
-                nsem += 1
-            ops.append(pltpu.make_async_copy(
-                xcp_ref.at[pl.ds(pcb_ref[blk], pcw)],
-                xcbuf.at[jnp.int32(s)], sems.at[jnp.int32(s), nsem]))
-            nsem += 1
-            if has_w:
-                for j in range(mp):
-                    ops.append(pltpu.make_async_copy(
-                        atab_ref.at[j, pl.ds(abase, win_x)],
-                        abuf.at[jnp.int32(s), j],
-                        sems.at[jnp.int32(s), nsem + j]))
-                    ops.append(pltpu.make_async_copy(
-                        pwt_ref.at[j, pl.ds(abase, win_x)],
-                        wbuf.at[jnp.int32(s), j],
-                        sems.at[jnp.int32(s), nsem + mp + j]))
-            else:
-                ops.append(pltpu.make_async_copy(
-                    atab_ref.at[pl.ds(abase, win_x)],
-                    abuf.at[jnp.int32(s)], sems.at[jnp.int32(s), nsem]))
-            return ops
-
-        @pl.when(i == 0)
-        def _():
-            for d in dmas(0, 0):
-                d.start()
-
-        @pl.when(i + 1 < n_blocks)
-        def _():
-            for d in dmas(jax.lax.rem(i + 1, jnp.int32(2)), i + 1):
-                d.start()
-
-        for d in dmas(slot, i):
-            d.wait()
-
-        col = jax.lax.broadcasted_iota(jnp.int32, (win_v, LANES), 1)
-        bw = bbuf[slot].astype(cdt)
-        if mf is None:
-            vals = vbuf[slot]
-            def val(t):
-                return vals[t].astype(cdt)
-            dw = dbuf[slot].astype(cdt) if has_dinv else None
-        else:
-            row0 = i * jnp.int32(br) - jnp.int32((n_app - 1) * mr0)
-            val, dw = _mf_block_vals(mf, coeffs_ref, row0, win_v, col,
-                                     cdt)
-
-        def apply_A(s):
-            acc = jnp.zeros((win_v, LANES), cdt)
-            for t, _ in enumerate(offsets):
-                a = jax.lax.slice_in_dim(s, ro[t], ro[t] + win_v, 1, 0)
-                if rl[t] == 0:
-                    w = a
-                else:
-                    b2 = jax.lax.slice_in_dim(s, ro[t] + 1,
-                                              ro[t] + 1 + win_v, 1, 0)
-                    shift = LANES - rl[t]
-                    wa = pltpu.roll(a, jnp.int32(shift), 1)
-                    wb = pltpu.roll(b2, jnp.int32(shift), 1)
-                    w = jnp.where(col < shift, wa, wb)
-                acc = acc + val(t) * w
-            return acc
-
-        # prologue: s = x + P xc over the WHOLE x window (the sweeps
-        # consume halo rows, which need the corrected state too)
-        s = xbuf[slot].astype(cdt)
-        xcw = xcbuf[slot].reshape(pcw * LANES).astype(cdt)
-        if has_w:
-            for j in range(mp):
-                aw = abuf[slot, j]                     # (win_x, 128)
-                rel = aw - pcb_ref[i] * jnp.int32(LANES)
-                valid = (aw >= 0) & (rel >= 0) & (rel < pcw * LANES)
-                g = jnp.take(xcw, jnp.where(valid, rel, 0))
-                g = g * wbuf[slot, j].astype(cdt)
-                s = s + jnp.where(valid, g, jnp.zeros((), cdt))
-        else:
-            aw = abuf[slot]                            # (win_x, 128)
-            rel = aw - pcb_ref[i] * jnp.int32(LANES)
-            valid = (aw >= 0) & (rel >= 0) & (rel < pcw * LANES)
-            corr0 = jnp.take(xcw, jnp.where(valid, rel, 0))
-            s = s + jnp.where(valid, corr0, jnp.zeros((), cdt))
-        for t in range(n_steps):
-            tau = taus_ref[t]
-            mid = jax.lax.slice_in_dim(s, mr0, mr0 + win_v, 1, 0)
-            corr = tau * (bw - apply_A(s))
-            if dw is not None:
-                corr = corr * dw
-            pieces = [mid + corr, jnp.zeros((Mr0, LANES), cdt)]
-            if mr0:
-                pieces.insert(0, jnp.zeros((mr0, LANES), cdt))
-            s = jnp.concatenate(pieces, axis=0)
-        y_ref[...] = jax.lax.slice_in_dim(
-            s, n_app * mr0, n_app * mr0 + br, 1, 0).astype(dtype)
-        if with_dot:
-            # cycle-borne reduction: the postsmoothed x' against the
-            # aligned b rows — per-block (1, 128) partials, lanes
-            # combined by the caller's XLA sum
-            xb = jax.lax.slice_in_dim(
-                s, n_app * mr0, n_app * mr0 + br, 1, 0)
-            bb = jax.lax.slice_in_dim(
-                bw, (n_app - 1) * mr0, (n_app - 1) * mr0 + br, 1, 0)
-            _part_store(d_ref, xb * bb)
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "offsets", "num_rows", "mf", "with_dot", "interpret"))
-def _dia_prolong_smooth_call(vals_q, dinv_q, taus, b, x, xc, xfer,
-                             offsets, num_rows, mf=None, coeffs=None,
-                             with_dot=False, interpret=False):
-    """Fused prolongation/correction prologue + postsmoother:
-    x' = smooth(b, x + P xc) after len(taus) damped sweeps. Caller
-    must have checked dia_prolong_supported. Matrix-free form (`mf` +
-    `coeffs`): no vals/dinv slabs; the aggregate-id windows
-    (structure-only) stay."""
-    n_steps = taus.shape[0]
-    has_dinv = dinv_q is not None
-    has_w = xfer.ptab is not None
-    if mf is None:
-        k = vals_q.shape[0]
-        dtype = vals_q.dtype
-    else:
-        k = len(offsets)
-        dtype = x.dtype
-    ib = jnp.dtype(dtype).itemsize
-    plan = dia_prolong_plan(offsets, k, num_rows, n_steps, xfer.windows,
-                            mp=xfer.mp, weighted=has_w, pavg=xfer.pavg,
-                            itemsize=ib, coeffs=mf is not None)
-    br, n_app, mr0, Mr0, win_x, win_v, nb, pcw = plan
-    if mf is None:
-        qf, qc, qb = smooth_quota_rows(offsets, num_rows)
-        assert vals_q.shape[1] == qf + qc + qb
-        slab_shift = qf - (n_app - 1) * mr0
-    else:
-        slab_shift = 0
-    aqf, aqc, aqb = transfer_quota_rows(offsets, num_rows)
-    id_slab = xfer.ptab if has_w else xfer.atab
-    assert id_slab.shape[1 if has_w else 0] == aqf + aqc + aqb
-    ashift = aqf - n_app * mr0
-    n = num_rows
-    pcb = xfer.bases[br][1]
-    xp_rows = n_app * mr0 + nb * br + n_app * Mr0
-    xp = jnp.zeros((xp_rows * LANES,), dtype)
-    xp = jax.lax.dynamic_update_slice(xp, x.astype(dtype),
-                                      (n_app * mr0 * LANES,))
-    xp = xp.reshape(xp_rows, LANES)
-    front_v = (n_app - 1) * mr0
-    rows_v = front_v + nb * br + (n_app - 1) * Mr0
-    bp = jnp.zeros((rows_v * LANES,), dtype)
-    bp = jax.lax.dynamic_update_slice(bp, b.astype(dtype),
-                                      (front_v * LANES,))
-    bp = bp.reshape(rows_v, LANES)
-    xcp = jnp.zeros((xfer.ncr * LANES,), dtype)
-    xcp = jax.lax.dynamic_update_slice(xcp, xc.astype(dtype), (0,))
-    xcp = xcp.reshape(xfer.ncr, LANES)
-
-    kernel = _dia_prolong_smooth_kernel(
-        offsets, br, n_app, mr0, Mr0, win_x, win_v, n_steps, has_dinv,
-        nb, slab_shift, ashift, pcw, xfer.mp, has_w, dtype, mf=mf,
-        with_dot=with_dot)
-    if mf is None:
-        n_sem = (4 if has_dinv else 3) + 1 \
-            + (2 * xfer.mp if has_w else 1)
-        in_specs = [
-            pl.BlockSpec(memory_space=pl.ANY),          # xp
-            pl.BlockSpec(memory_space=pl.ANY),          # vals_q
-            pl.BlockSpec(memory_space=pl.ANY),          # bp
-        ]
-        operands = [xp, vals_q, bp]
-        if has_dinv:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-            operands.append(dinv_q)
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # xcp
-        operands.append(xcp)
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # atab|ptab
-        operands.append(id_slab)
-        if has_w:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))   # pwt
-            operands.append(xfer.pwt.astype(dtype))
-    else:
-        n_sem = 4
-        in_specs = [
-            pl.BlockSpec(memory_space=pl.ANY),          # xp
-            pl.BlockSpec(memory_space=pl.ANY),          # bp
-            pl.BlockSpec(memory_space=pl.ANY),          # xcp
-            pl.BlockSpec(memory_space=pl.ANY),          # atab
-            pl.BlockSpec((k,), lambda i: (jnp.int32(0),),
-                         memory_space=pltpu.SMEM),      # coeffs
-        ]
-        operands = [xp, bp, xcp, id_slab,
-                    coeffs.astype(compute_dtype(dtype))]
-    in_specs.append(pl.BlockSpec((nb,), lambda i: (jnp.int32(0),),
-                                 memory_space=pltpu.SMEM))
-    operands.append(pcb.astype(jnp.int32))
-    in_specs.append(pl.BlockSpec((n_steps,), lambda i: (jnp.int32(0),),
-                                 memory_space=pltpu.SMEM))
-    operands.append(taus.astype(compute_dtype(dtype)))
-    out_specs = pl.BlockSpec((br, LANES), lambda i: (i, jnp.int32(0)),
-                             memory_space=pltpu.VMEM)
-    out_shape = jax.ShapeDtypeStruct((nb * br, LANES), dtype)
-    if with_dot:
-        out_specs = (out_specs, _part_spec())
-        out_shape = (out_shape, _part_shape(nb))
-    scratch = [pltpu.VMEM((2, win_x, LANES), dtype)]
-    if mf is None:
-        scratch.append(pltpu.VMEM((2, k, win_v, LANES), dtype))
-    scratch.append(pltpu.VMEM((2, win_v, LANES), dtype))
-    if has_dinv:
-        scratch.append(pltpu.VMEM((2, win_v, LANES), dtype))
-    scratch.append(pltpu.VMEM((2, pcw, LANES), dtype))
-    if has_w:
-        scratch.append(pltpu.VMEM((2, xfer.mp, win_x, LANES),
-                                  jnp.int32))
-        scratch.append(pltpu.VMEM((2, xfer.mp, win_x, LANES), dtype))
-    else:
-        scratch.append(pltpu.VMEM((2, win_x, LANES), jnp.int32))
-    scratch.append(pltpu.SemaphoreType.DMA((2, n_sem)))
-    nbytes = ((k + 2) * win_v + win_x + pcw + br
-              + (2 * xfer.mp if has_w else 1) * win_x) if mf is None \
-        else (2 * win_v + win_x + pcw + br + win_x)
-    y2 = kernel_call(
-        kernel,
-        grid=(nb,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        cost_estimate=pl.CostEstimate(
-            flops=2 * n_app * k * nb * br * LANES,
-            bytes_accessed=nbytes * nb * LANES * ib,
-            transcendentals=0,
-        ),
-        interpret=interpret,
-    )(*operands)
-    if with_dot:
-        y2, dparts = y2
-    y = y2.reshape(-1)
-    if y.shape[0] != n:
-        y = y[:n]
-    if with_dot:
-        return y, jnp.sum(dparts)
-    return y
-
-
-def _dia_stencil_prolong_smooth_call(coeffs, taus, b, x, xc, xfer,
-                                     spec, with_dot=False,
-                                     interpret=False):
-    """Matrix-free fused prolongation prologue + postsmoother. Caller
-    must have checked stencil_prolong_supported."""
-    return _dia_prolong_smooth_call(None, None, taus, b, x, xc, xfer,
-                                    spec.offsets, spec.n, mf=spec,
-                                    coeffs=coeffs, with_dot=with_dot,
-                                    interpret=interpret)
-
-
-# ---------------------------------------------------------------------------
-# VMEM-resident coarse-tail sub-cycle
-# ---------------------------------------------------------------------------
-
-import collections
-
-# `mf` (default None) marks a matrix-free level: its arrs dict carries
-# a (k,) "coeffs" leaf instead of the "vals"/"dinv" slab slices, and
-# the per-offset value/dinv rows synthesize from the StencilSpec in
-# _tail_compute — shared by the Pallas tail kernel and the XLA
-# fallback exactly like the slab form.
-TailLevelSpec = collections.namedtuple(
-    "TailLevelSpec",
-    "offsets n qc has_dinv n_pre n_post nc ncr m mf",
-    defaults=(None,))
-TailSpec = collections.namedtuple("TailSpec", "shape levels coarse")
-# coarse: ("inv", nz, ncrz) — dense inverse matmul; ("none", nz, ncrz)
-# — NOSOLVER (no coarse correction)
-
-
-def _rows_to(v, rows: int):
-    """Row-pad / row-trim a (r, 128) vector to `rows` 128-lane rows —
-    the lane packing (linear index, x fastest) is shared by every
-    level's vector layout, so converting between a level's coarse-rhs
-    rows and the next level's content rows is pure row arithmetic."""
-    r = v.shape[0]
-    if rows == r:
-        return v
-    if rows > r:
-        return jnp.pad(v, ((0, rows - r), (0, 0)))
-    return jax.lax.slice_in_dim(v, 0, rows, 1, 0)
-
-
-def _tail_compute(arrs, b, x, spec):
-    """The whole coarse-tail sub-cycle on (rows, 128) VMEM-resident
-    values: per level — presmooth sweeps, residual, child-gather
-    restriction, recursion (V/W/F shape), aggregate-gather prolongation
-    + correction, postsmooth sweeps; dense-inverse matmul (or nothing,
-    NOSOLVER) at the coarsest. SINGLE SOURCE OF TRUTH: the Pallas
-    kernel body runs this on loaded refs and the XLA fallback
-    (ops/batched.py tail_cycle_multi, the f64 / vmapped route) runs it
-    on plain arrays — they cannot drift apart. Sub-f32 vectors/slabs
-    (bf16) upcast at entry/use and the WHOLE sub-cycle accumulates in
-    f32 (the coarse inverse stays f32 by the precision policy); the
-    caller rounds the returned state back to its vector dtype."""
-    levels = spec.levels
-    cdt = compute_dtype(b.dtype)
-    b = b.astype(cdt)
-    x = x.astype(cdt)
-
-    def level_vals(ls, ar):
-        """(val(t), dinv | None): slab levels slice their VMEM-loaded
-        quota slabs; matrix-free levels synthesize both from the (k,)
-        coefficient leaf and ls.mf's static masks (tail vectors start
-        at element 0, so idx = row*128 + lane directly)."""
-        if ls.mf is None:
-            dw = ar["dinv"].astype(cdt) if ls.has_dinv else None
-            return (lambda t: ar["vals"][t].astype(cdt)), dw
-        col = jax.lax.broadcasted_iota(jnp.int32, (ls.qc, LANES), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (ls.qc, LANES), 0)
-        idx = row * jnp.int32(LANES) + col
-        coords = _mf_coords(ls.mf.shape, idx)
-        valid = idx < jnp.int32(ls.mf.n)
-        return _mf_vals_dinv(ls.mf,
-                             lambda t: ar["coeffs"][t].astype(cdt),
-                             coords, valid, cdt)
-
-    def apply_dia(ls, val, s):
-        mr0, Mr0 = smooth_halo_rows(ls.offsets)
-        sp = jnp.pad(s, ((mr0, Mr0), (0, 0)))
-        col = jax.lax.broadcasted_iota(jnp.int32, (ls.qc, LANES), 1)
-        acc = jnp.zeros((ls.qc, LANES), cdt)
-        for t, o in enumerate(ls.offsets):
-            ro = mr0 + (o - (o % LANES)) // LANES
-            a = jax.lax.slice_in_dim(sp, ro, ro + ls.qc, 1, 0)
-            rl = o % LANES
-            if rl == 0:
-                w = a
-            else:
-                b2 = jax.lax.slice_in_dim(sp, ro + 1, ro + 1 + ls.qc,
-                                          1, 0)
-                shift = LANES - rl
-                w = jnp.where(col < shift, jnp.roll(a, shift, 1),
-                              jnp.roll(b2, shift, 1))
-            acc = acc + val(t) * w
-        return acc
-
-    def sweeps(ls, val, dw, bc, s, taus, n_taus):
-        for t in range(n_taus):
-            corr = taus[t].astype(cdt) * (bc - apply_dia(ls, val, s))
-            if dw is not None:
-                corr = corr * dw
-            s = s + corr
-        return s
-
-    def run(shape, i, bc, s):
-        ls, ar = levels[i], arrs[i]
-        val, dw = level_vals(ls, ar)
-        s = sweeps(ls, val, dw, bc, s, ar["taus_pre"], ls.n_pre)
-        r = bc - apply_dia(ls, val, s)
-        rflat = r.reshape(-1)
-        coarse_b = jnp.zeros((ls.ncr, LANES), cdt)
-        for j in range(ls.m):
-            idxj = ar["ctab"][j]
-            valid = idxj >= 0
-            g = jnp.take(rflat, jnp.where(valid, idxj, 0))
-            coarse_b = coarse_b + jnp.where(valid, g,
-                                            jnp.zeros((), cdt))
-        if i + 1 < len(levels):
-            bq = _rows_to(coarse_b, levels[i + 1].qc)
-            xc = run(shape, i + 1, bq, jnp.zeros_like(bq))
-            if shape == "W":
-                xc = run("W", i + 1, bq, xc)
-            elif shape == "F":
-                xc = run("V", i + 1, bq, xc)
-            xc = _rows_to(xc, ls.ncr)
-        else:
-            kind, nz, ncrz = spec.coarse
-            bz = _rows_to(coarse_b, ncrz)
-            if kind == "inv":
-                F = ncrz * LANES
-                xcf = jnp.dot(bz.reshape(1, F),
-                              arrs[-1]["invT"].astype(cdt),
-                              preferred_element_type=cdt)
-                xc = _rows_to(xcf.reshape(ncrz, LANES), ls.ncr)
-            else:               # NOSOLVER: no coarse correction
-                xc = jnp.zeros((ls.ncr, LANES), cdt)
-        xcflat = xc.reshape(-1)
-        aw = ar["atab_c"]
-        valid = aw >= 0
-        corr = jnp.take(xcflat, jnp.where(valid, aw, 0))
-        s = s + jnp.where(valid, corr, jnp.zeros((), cdt))
-        s = sweeps(ls, val, dw, bc, s, ar["taus_post"], ls.n_post)
-        return s
-
-    return run(spec.shape, 0, b, x)
-
-
-def _dia_tail_kernel(spec, treedef, n_leaves, dtype, with_dot=False):
-    def kernel(*refs):
-        arrs = jax.tree_util.tree_unflatten(
-            treedef, [r[...] for r in refs[:n_leaves]])
-        b, x = refs[n_leaves][...], refs[n_leaves + 1][...]
-        out = _tail_compute(arrs, b, x, spec)
-        refs[n_leaves + 2][...] = out.astype(dtype)
-        if with_dot:
-            # everything is VMEM-resident, so the x'.b reduction over
-            # rows is free; lanes combine in the caller's XLA sum
-            refs[n_leaves + 3][...] = jnp.sum(
-                out * b.astype(out.dtype), axis=0,
-                keepdims=True).astype(jnp.float32)
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("spec", "with_dot",
-                                             "interpret"))
-def _dia_coarse_tail_call(arrs, b, x, spec, with_dot=False,
-                          interpret=False):
-    """One grid=(1,) pallas_call running the whole coarse-tail
-    sub-cycle with every intermediate vector VMEM-resident — ~10 tiny
-    kernel dispatches per cycle become one. Caller (ops.smooth
-    coarse_tail_plan) has checked eligibility and the VMEM budget."""
-    l0 = spec.levels[0]
-    dtype = b.dtype
-    b2 = jnp.zeros((l0.qc * LANES,), dtype)
-    b2 = jax.lax.dynamic_update_slice(b2, b, (0,)).reshape(l0.qc, LANES)
-    x2 = jnp.zeros((l0.qc * LANES,), dtype)
-    x2 = jax.lax.dynamic_update_slice(x2, x, (0,)).reshape(l0.qc, LANES)
-    leaves, treedef = jax.tree_util.tree_flatten(arrs)
-    kernel = _dia_tail_kernel(spec, treedef, len(leaves), dtype,
-                              with_dot=with_dot)
-
-    def _spec_of(v):
-        nd = len(v.shape)
-        return pl.BlockSpec(v.shape, lambda i, _nd=nd: (jnp.int32(0),)
-                            * _nd, memory_space=pltpu.VMEM)
-
-    flops = sum(2 * (ls.n_pre + ls.n_post + 1) * len(ls.offsets)
-                * ls.qc * LANES for ls in spec.levels)
-    byts = sum(int(v.size) * v.dtype.itemsize for v in leaves) \
-        + 3 * l0.qc * LANES * 4
-    out_specs = pl.BlockSpec((l0.qc, LANES),
-                             lambda i: (jnp.int32(0), jnp.int32(0)),
-                             memory_space=pltpu.VMEM)
-    out_shape = jax.ShapeDtypeStruct((l0.qc, LANES), dtype)
-    if with_dot:
-        out_specs = (out_specs, pl.BlockSpec(
-            (1, LANES), lambda i: (jnp.int32(0), jnp.int32(0)),
-            memory_space=pltpu.VMEM))
-        out_shape = (out_shape, jax.ShapeDtypeStruct((1, LANES),
-                                                     jnp.float32))
-    out = kernel_call(
-        kernel,
-        grid=(1,),
-        in_specs=[_spec_of(v) for v in leaves] + [_spec_of(b2),
-                                                  _spec_of(x2)],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        cost_estimate=pl.CostEstimate(flops=flops, bytes_accessed=byts,
-                                      transcendentals=0),
-        interpret=interpret,
-    )(*leaves, b2, x2)
-    if with_dot:
-        out, dparts = out
-        return out.reshape(-1)[:l0.n], jnp.sum(dparts)
-    return out.reshape(-1)[:l0.n]
-
-
 # ---------------------------------------------------------------------------
 # Krylov shell fusion: SpMV + dot epilogues and the single-pass CG
 # update
 #
-# The fused-cycle suite stops at the preconditioner boundary: a
+# The fused smoother stops at the preconditioner boundary: a
 # CG/PCG iteration still runs a standalone SpMV, three separate dot
 # reductions, and bare axpy updates — each a full n-vector HBM pass
 # outside the cycle. Two kernels close the shell:
 #
 # - SPMV + DOT (`_dia_spmv_dot_call`): A.p with a per-block d.Ap
 #   partial-sum epilogue ((nb, 128) partials, rows reduced in-kernel,
-#   lanes combined by a cheap XLA sum — the restriction-epilogue
-#   pattern), an optional PROLOGUE folding the direction update
-#   p = z + beta*p_prev (beta a scalar in SMEM; the halo rows
+#   lanes combined by a cheap XLA sum), an optional PROLOGUE folding
+#   the direction update p = z + beta*p_prev (beta a scalar in SMEM; the halo rows
 #   recompute the update redundantly so the window stays exact), and
 #   an optional second Ap.Ap self-dot (BiCGStab's t.t). The x-window
 #   layout/DMA pipeline is the plain dia_spmv kernel's; operands

@@ -47,9 +47,6 @@ import jax.numpy as jnp
 from . import pallas_spmv as _ps
 
 
-flat_gather_ok = _ps.flat_gather_ok
-
-
 def fused_runtime_on() -> bool:
     """Would the fused Pallas kernels run here (compiled on a TPU, or
     under the interpreter-forcing test hook)?"""
@@ -358,656 +355,3 @@ def fused_smooth(data, b, x, taus, dinv=None, with_residual=True):
     if out is not None:
         return out
     return swell_fused_smooth(A, b, x, taus, dinv, with_residual)
-
-
-# ---------------------------------------------------------------------------
-# cycle fusion: grid-transfer epilogues + VMEM-resident coarse tail
-# ---------------------------------------------------------------------------
-
-
-def _coarse_window_tables(crmin, crmax, n: int, ncr: int, offsets):
-    """Per-candidate-block-size coarse window sizes + base tables from
-    per-128-lane-row coarse-ROW min/max reach arrays (sentinel `big`
-    min / -1 max for rows referencing nothing). Shared by the
-    aggregation and the general-CSR slab builders so the window math
-    the plans budget against can never fork."""
-    import numpy as np
-    L = _ps.LANES
-    rows128 = max(1, -(-n // L))
-    big = np.int64(1) << 60
-    mr0, Mr0 = _ps.smooth_halo_rows(offsets)
-    K1 = _ps.SMOOTH_MAX_APPS * mr0
-    K2 = _ps.SMOOTH_MAX_APPS * Mr0
-
-    def _block_minmax(lo_off, hi_off, br, nb):
-        mn = np.full(nb, big)
-        mx = np.full(nb, np.int64(-1))
-        for i in range(nb):
-            lo = max(0, i * br + lo_off)
-            hi = min(rows128, i * br + br + hi_off)
-            if hi > lo:
-                mn[i] = crmin[lo:hi].min()
-                mx[i] = crmax[lo:hi].max()
-        return mn, mx
-
-    windows = []
-    bases = {}
-    for br in _ps.smooth_br_candidates(n):
-        nb = -(-rows128 // br)
-        if nb > 4096:
-            continue        # base-table build cost guard (tiny brs at
-            # huge n are never picked by the plans anyway)
-        mn, mx = _block_minmax(0, 0, br, nb)
-        mn = np.where(mx < 0, 0, np.minimum(mn, ncr - 1))
-        mx = np.maximum(mx, mn)
-        cw = int(min(ncr, -(-int((mx - mn).max() + 1) // 8) * 8))
-        cb = np.clip(mn, 0, ncr - cw).astype(np.int32)
-        mn2, mx2 = _block_minmax(-K1, K2, br, nb)
-        mn2 = np.where(mx2 < 0, 0, np.minimum(mn2, ncr - 1))
-        mx2 = np.maximum(mx2, mn2)
-        pcw = int(min(ncr, -(-int((mx2 - mn2).max() + 1) // 8) * 8))
-        pcb = np.clip(mn2, 0, ncr - pcw).astype(np.int32)
-        windows.append((br, cw, pcw))
-        bases[br] = (jnp.asarray(cb), jnp.asarray(pcb))
-    return tuple(windows), bases
-
-
-def build_transfer_slabs(A, agg, nc: int):
-    """Structure-only transfer payloads for the fused grid-transfer
-    kernels (host numpy build, one device upload per (re)setup):
-    child-index slab ctab[j][c] = fine slot of aggregate c's j-th
-    child (-1 absent), aggregate-id slab atab[slot] = coarse id (-1 at
-    padding), and the per-candidate-block-size coarse window bases the
-    kernels DMA coarse rows through. Returns None when A has no
-    eligible DIA layout or an aggregate exceeds TRANSFER_MAX_CHILD."""
-    import numpy as np
-    if not _slab_eligible(A) or A.dia_offsets is None:
-        return None
-    offsets = A.dia_offsets
-    n = A.num_rows
-    agg = np.asarray(agg).ravel().astype(np.int64)
-    if agg.shape[0] != n or nc < 1:
-        return None
-    counts = np.bincount(agg, minlength=nc)
-    m = int(counts.max()) if n else 0
-    if m < 1 or m > _ps.TRANSFER_MAX_CHILD:
-        return None
-    ncr = _ps.coarse_pad_rows(nc)
-    L = _ps.LANES
-    order = np.argsort(agg, kind="stable")
-    starts = np.zeros(nc + 1, np.int64)
-    starts[1:] = np.cumsum(counts)
-    pos = np.arange(n, dtype=np.int64) - starts[agg[order]]
-    ctab = np.full((m, ncr * L), -1, np.int32)
-    ctab[pos, agg[order]] = order.astype(np.int32)
-    ctab = ctab.reshape(m, ncr, L)
-    aqf, aqc, aqb = _ps.transfer_quota_rows(offsets, n)
-    atab = np.full(((aqf + aqc + aqb) * L,), -1, np.int32)
-    atab[aqf * L: aqf * L + n] = agg
-    atab = atab.reshape(-1, L)
-    # per-fine-row coarse row min/max -> per-block window bases for
-    # every block size the plans could pick
-    rows128 = max(1, -(-n // L))
-    aggp = np.full((rows128 * L,), -1, np.int64)
-    aggp[:n] = agg
-    a2 = aggp.reshape(rows128, L)
-    big = np.int64(1) << 60
-    crmin = np.where(a2 >= 0, a2 // L, big).min(axis=1)
-    crmax = np.where(a2 >= 0, a2 // L, -1).max(axis=1)
-    windows, bases = _coarse_window_tables(crmin, crmax, n, ncr,
-                                           offsets)
-    if not windows:
-        return None
-    return _ps.TransferSlabs(jnp.asarray(ctab), jnp.asarray(atab),
-                             bases, int(nc), ncr, m, windows)
-
-
-def build_csr_transfer_slabs(A, P, R, dtype=None):
-    """WEIGHTED row-segment transfer payloads for the fused
-    grid-transfer kernels over general CSR interpolation (classical
-    Ruge-Stuben levels; host numpy build, one device upload). `dtype`
-    emits the weight slabs (cwt/pwt) in the hierarchy's effective
-    precision (precision.py) — the index tables stay int32 either way.
-    The aggregation slabs generalize entrywise:
-
-    - restriction (R = P^T, nc x n): ctab[j][c] = fine slot of R row
-      c's j-th entry (-1 absent), cwt[j][c] = its weight — the kernel
-      epilogue computes bc[c] = sum_j cwt[j][c] * r[ctab[j][c]];
-    - prolongation (P, n x nc): ptab[j][slot] / pwt[j][slot] = the
-      j-th (coarse id, weight) entry of P's row at that fine slot,
-      quota-padded like atab — the prologue folds
-      x += sum_j pwt[j] * xc[ptab[j]] into the postsmoother's first
-      application.
-
-    Classical structure reuse keeps P/R (values included) across
-    value resetups, so these slabs are structure-lifetime payloads
-    exactly like the aggregation child tables. Returns None when A
-    has no eligible DIA layout, P/R shapes disagree with A, or a row
-    exceeds the child caps (CSR_TRANSFER_MAX_CHILD restriction /
-    TRANSFER_MAX_CHILD prolongation)."""
-    import numpy as np
-    if not _slab_eligible(A) or A.dia_offsets is None:
-        return None
-    if P is None or R is None or getattr(P, "is_block", True):
-        return None
-    offsets = A.dia_offsets
-    n = A.num_rows
-    nc = int(P.num_cols)
-    if int(P.num_rows) != n or nc < 1 or int(R.num_rows) != nc \
-            or int(R.num_cols) != n:
-        return None
-    pro = np.asarray(P.row_offsets).astype(np.int64)
-    pci = np.asarray(P.col_indices).astype(np.int64)
-    pv = np.asarray(P.values)
-    rro = np.asarray(R.row_offsets).astype(np.int64)
-    rci = np.asarray(R.col_indices).astype(np.int64)
-    rv = np.asarray(R.values)
-    rlen = np.diff(rro)
-    plen = np.diff(pro)
-    m = int(rlen.max()) if nc else 0
-    mp = int(plen.max()) if n else 0
-    if m < 1 or m > _ps.CSR_TRANSFER_MAX_CHILD \
-            or mp < 1 or mp > _ps.TRANSFER_MAX_CHILD:
-        return None
-    ncr = _ps.coarse_pad_rows(nc)
-    L = _ps.LANES
-    # restriction row segments, entry j of R row c
-    jpos = np.arange(rci.shape[0], dtype=np.int64) \
-        - np.repeat(rro[:-1], rlen)
-    crow = np.repeat(np.arange(nc, dtype=np.int64), rlen)
-    ctab = np.full((m, ncr * L), -1, np.int32)
-    cwt = np.zeros((m, ncr * L), rv.dtype)
-    ctab[jpos, crow] = rci.astype(np.int32)
-    cwt[jpos, crow] = rv
-    ctab = ctab.reshape(m, ncr, L)
-    cwt = cwt.reshape(m, ncr, L)
-    # prolongation row segments, entry j of P row i, quota-padded
-    aqf, aqc, aqb = _ps.transfer_quota_rows(offsets, n)
-    rows_q = aqf + aqc + aqb
-    jp = np.arange(pci.shape[0], dtype=np.int64) \
-        - np.repeat(pro[:-1], plen)
-    prow = np.repeat(np.arange(n, dtype=np.int64), plen)
-    ptab = np.full((mp, rows_q * L), -1, np.int32)
-    pwt = np.zeros((mp, rows_q * L), pv.dtype)
-    ptab[jp, aqf * L + prow] = pci.astype(np.int32)
-    pwt[jp, aqf * L + prow] = pv
-    ptab = ptab.reshape(mp, rows_q, L)
-    pwt = pwt.reshape(mp, rows_q, L)
-    # per-fine-slot coarse reach (min/max coarse id P's row touches)
-    # -> per-128-row coarse-ROW reach -> per-block window bases
-    big = np.int64(1) << 60
-    minc = np.full(n, big, np.int64)
-    maxc = np.full(n, np.int64(-1), np.int64)
-    np.minimum.at(minc, prow, pci)
-    np.maximum.at(maxc, prow, pci)
-    rows128 = max(1, -(-n // L))
-    minp = np.full((rows128 * L,), big, np.int64)
-    maxp = np.full((rows128 * L,), np.int64(-1), np.int64)
-    minp[:n] = minc
-    maxp[:n] = maxc
-    mn2 = minp.reshape(rows128, L)
-    mx2 = maxp.reshape(rows128, L)
-    crmin = np.where(mx2 >= 0, mn2 // L, big).min(axis=1)
-    crmax = np.where(mx2 >= 0, mx2 // L, -1).max(axis=1)
-    windows, bases = _coarse_window_tables(crmin, crmax, n, ncr,
-                                           offsets)
-    if not windows:
-        return None
-    wavg = max(1, -(-int(rlen.sum()) // max(nc, 1)))
-    pavg = max(1, -(-int(plen.sum()) // max(n, 1)))
-    if dtype is not None:
-        # numpy-side cast (ml_dtypes covers bfloat16): the weight
-        # slabs upload already-narrow, no full-precision twin
-        cwt = cwt.astype(jnp.dtype(dtype))
-        pwt = pwt.astype(jnp.dtype(dtype))
-    return _ps.TransferSlabs(
-        jnp.asarray(ctab), None, bases, int(nc), ncr, m, windows,
-        cwt=jnp.asarray(cwt), ptab=jnp.asarray(ptab),
-        pwt=jnp.asarray(pwt), mp=mp, wavg=wavg, pavg=pavg)
-
-
-def _xla_restrict_single(A, taus, b, x, dinv, xfer):
-    from .batched import smooth_restrict_dia_multi
-    X, BC = smooth_restrict_dia_multi(A, b[None], x[None], taus, dinv,
-                                      xfer)
-    return X[0], BC[0]
-
-
-def _xla_corr_single(A, taus, b, x, xc, dinv, xfer):
-    from .batched import corr_smooth_dia_multi
-    return corr_smooth_dia_multi(A, b[None], x[None], xc[None], taus,
-                                 dinv, xfer)[0]
-
-
-@functools.lru_cache(maxsize=None)
-def _fused_restrict_fn(has_dinv: bool):
-    """custom_vmap-wrapped fused presmooth+restrict call: vector-only
-    batches (solve_many) take the multi-RHS slab form in ops/batched.py;
-    batched matrices take the vmapped XLA compose."""
-    tu = jax.tree_util
-
-    if has_dinv:
-        @jax.custom_batching.custom_vmap
-        def call(A, xfer, vals_q, dinv_q, dinv, taus, b, x):
-            return _ps._dia_smooth_restrict_call(
-                vals_q, dinv_q, taus, b, x, xfer, A.dia_offsets,
-                A.num_rows, interpret=_ps._FORCE_INTERPRET)
-
-        @call.def_vmap
-        def _rule(axis_size, in_batched, A, xfer, vals_q, dinv_q, dinv,
-                  taus, b, x):
-            mat_b = any(tu.tree_leaves(in_batched[:6]))
-            b_b, x_b = in_batched[6], in_batched[7]
-            if not mat_b:
-                from .batched import smooth_restrict_dia_multi
-                B = b if b_b else jnp.broadcast_to(
-                    b, (axis_size,) + b.shape)
-                X = x if x_b else jnp.broadcast_to(
-                    x, (axis_size,) + x.shape)
-                return (smooth_restrict_dia_multi(A, B, X, taus, dinv,
-                                                  xfer), (True, True))
-            axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                         for ib in in_batched)
-            fn = lambda A_, xf_, vq_, dq_, dv_, t_, b_, x_: \
-                _xla_restrict_single(A_, t_, b_, x_, dv_, xf_)  # noqa: E731
-            y = jax.vmap(fn, in_axes=axes, axis_size=axis_size)(
-                A, xfer, vals_q, dinv_q, dinv, taus, b, x)
-            return y, (True, True)
-    else:
-        @jax.custom_batching.custom_vmap
-        def call(A, xfer, vals_q, taus, b, x):
-            return _ps._dia_smooth_restrict_call(
-                vals_q, None, taus, b, x, xfer, A.dia_offsets,
-                A.num_rows, interpret=_ps._FORCE_INTERPRET)
-
-        @call.def_vmap
-        def _rule(axis_size, in_batched, A, xfer, vals_q, taus, b, x):
-            mat_b = any(tu.tree_leaves(in_batched[:4]))
-            b_b, x_b = in_batched[4], in_batched[5]
-            if not mat_b:
-                from .batched import smooth_restrict_dia_multi
-                B = b if b_b else jnp.broadcast_to(
-                    b, (axis_size,) + b.shape)
-                X = x if x_b else jnp.broadcast_to(
-                    x, (axis_size,) + x.shape)
-                return (smooth_restrict_dia_multi(A, B, X, taus, None,
-                                                  xfer), (True, True))
-            axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                         for ib in in_batched)
-            fn = lambda A_, xf_, vq_, t_, b_, x_: \
-                _xla_restrict_single(A_, t_, b_, x_, None, xf_)  # noqa: E731
-            y = jax.vmap(fn, in_axes=axes, axis_size=axis_size)(
-                A, xfer, vals_q, taus, b, x)
-            return y, (True, True)
-
-    return call
-
-
-def _xb_dot(y, b):
-    """The x'.b dot epilogue's XLA twin (the cycle-borne r.z of the
-    Krylov shell): accumulation-dtype reduction over the last axis, so
-    the batched/vmapped routes agree with the kernel's f32 partials."""
-    cdt = _ps.compute_dtype(y.dtype)
-    return jnp.sum(y.astype(cdt) * b.astype(cdt), axis=-1)
-
-
-@functools.lru_cache(maxsize=None)
-def _fused_corr_fn(has_dinv: bool, with_dot: bool = False):
-    """custom_vmap-wrapped prolongation-prologue+postsmooth call.
-    `with_dot` appends the x'.b dot epilogue (the Krylov shell's
-    cycle-borne r.z reduction — b IS the preconditioner rhs r and x'
-    IS z, so x'.b = r.z) and makes every route return (x', dot)."""
-    tu = jax.tree_util
-    ob = (True, True) if with_dot else True
-
-    if has_dinv:
-        @jax.custom_batching.custom_vmap
-        def call(A, xfer, vals_q, dinv_q, dinv, taus, b, x, xc):
-            return _ps._dia_prolong_smooth_call(
-                vals_q, dinv_q, taus, b, x, xc, xfer, A.dia_offsets,
-                A.num_rows, with_dot=with_dot,
-                interpret=_ps._FORCE_INTERPRET)
-
-        @call.def_vmap
-        def _rule(axis_size, in_batched, A, xfer, vals_q, dinv_q, dinv,
-                  taus, b, x, xc):
-            mat_b = any(tu.tree_leaves(in_batched[:6]))
-            b_b, x_b, xc_b = in_batched[6], in_batched[7], in_batched[8]
-            if not mat_b:
-                from .batched import corr_smooth_dia_multi
-                B = b if b_b else jnp.broadcast_to(
-                    b, (axis_size,) + b.shape)
-                X = x if x_b else jnp.broadcast_to(
-                    x, (axis_size,) + x.shape)
-                XC = xc if xc_b else jnp.broadcast_to(
-                    xc, (axis_size,) + xc.shape)
-                y = corr_smooth_dia_multi(A, B, X, XC, taus, dinv,
-                                          xfer)
-                return ((y, _xb_dot(y, B)) if with_dot else y), ob
-
-            def fn(A_, xf_, vq_, dq_, dv_, t_, b_, x_, xc_):
-                y_ = _xla_corr_single(A_, t_, b_, x_, xc_, dv_, xf_)
-                return (y_, _xb_dot(y_, b_)) if with_dot else y_
-
-            axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                         for ib in in_batched)
-            y = jax.vmap(fn, in_axes=axes, axis_size=axis_size)(
-                A, xfer, vals_q, dinv_q, dinv, taus, b, x, xc)
-            return y, ob
-    else:
-        @jax.custom_batching.custom_vmap
-        def call(A, xfer, vals_q, taus, b, x, xc):
-            return _ps._dia_prolong_smooth_call(
-                vals_q, None, taus, b, x, xc, xfer, A.dia_offsets,
-                A.num_rows, with_dot=with_dot,
-                interpret=_ps._FORCE_INTERPRET)
-
-        @call.def_vmap
-        def _rule(axis_size, in_batched, A, xfer, vals_q, taus, b, x,
-                  xc):
-            mat_b = any(tu.tree_leaves(in_batched[:4]))
-            b_b, x_b, xc_b = in_batched[4], in_batched[5], in_batched[6]
-            if not mat_b:
-                from .batched import corr_smooth_dia_multi
-                B = b if b_b else jnp.broadcast_to(
-                    b, (axis_size,) + b.shape)
-                X = x if x_b else jnp.broadcast_to(
-                    x, (axis_size,) + x.shape)
-                XC = xc if xc_b else jnp.broadcast_to(
-                    xc, (axis_size,) + xc.shape)
-                y = corr_smooth_dia_multi(A, B, X, XC, taus, None,
-                                          xfer)
-                return ((y, _xb_dot(y, B)) if with_dot else y), ob
-
-            def fn(A_, xf_, vq_, t_, b_, x_, xc_):
-                y_ = _xla_corr_single(A_, t_, b_, x_, xc_, None, xf_)
-                return (y_, _xb_dot(y_, b_)) if with_dot else y_
-
-            axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                         for ib in in_batched)
-            y = jax.vmap(fn, in_axes=axes, axis_size=axis_size)(
-                A, xfer, vals_q, taus, b, x, xc)
-            return y, ob
-
-    return call
-
-
-def _restrict_call(A, fused, xfer, taus, b, x, dinv):
-    if dinv is not None:
-        return _fused_restrict_fn(True)(
-            A, xfer, fused["vals_q"], fused["dinv_q"], dinv, taus, b, x)
-    return _fused_restrict_fn(False)(A, xfer, fused["vals_q"], taus,
-                                     b, x)
-
-
-def _corr_call(A, fused, xfer, taus, b, x, xc, dinv, with_dot=False):
-    if dinv is not None:
-        return _fused_corr_fn(True, with_dot)(
-            A, xfer, fused["vals_q"], fused["dinv_q"], dinv, taus, b,
-            x, xc)
-    return _fused_corr_fn(False, with_dot)(A, xfer, fused["vals_q"],
-                                           taus, b, x, xc)
-
-
-def _transfer_ready(data, xfer, dinv):
-    A = data["A"]
-    from ..matrix import CsrMatrix
-    if not isinstance(A, CsrMatrix) or A.is_block:
-        return None
-    fused = data.get("fused")
-    if xfer is None or fused is None \
-            or getattr(A, "dia_vals", None) is None:
-        return None
-    if dinv is not None and "dinv_q" not in fused:
-        return None
-    return A, fused
-
-
-def fused_smooth_restrict(data, b, x, taus, xfer, dinv=None):
-    """Fused presmooth + restriction: (x', bc) after len(taus) damped
-    sweeps with bc = R (b - A x') emitted by the kernel epilogue, or
-    None when no fused plan applies (caller composes smooth_residual +
-    level.restrict). Oversized schedules chain plain fused sweep
-    chunks, with the restriction riding the final chunk's epilogue."""
-    ready = _transfer_ready(data, xfer, dinv)
-    if ready is None:
-        return None
-    A, fused = ready
-    taus = jnp.asarray(taus, _ps.compute_dtype(x.dtype))
-    n_steps = int(taus.shape[0])
-    if n_steps < 1:
-        return None
-    if not _fused_dtype_ok(A, x.dtype):
-        return None
-    sup_r = functools.partial(_ps.dia_restrict_supported, A, x.dtype,
-                              xfer=xfer)
-    if sup_r(n_steps):
-        return _restrict_call(A, fused, xfer, taus, b, x, dinv)
-    tail = next((c for c in range(
-        min(n_steps - 1, _ps.SMOOTH_MAX_APPS - 1), 0, -1)
-        if sup_r(c)), 0)
-    if not tail or not _ps.dia_smooth_supported(
-            A, x.dtype, n_steps - tail, False):
-        return None
-    head = dia_fused_smooth(A, fused, b, x, taus[:n_steps - tail],
-                            dinv=dinv, with_residual=False)
-    if head is None:
-        return None
-    return _restrict_call(A, fused, xfer, taus[n_steps - tail:], b,
-                          head, dinv)
-
-
-def fused_corr_smooth(data, b, x, xc, taus, xfer, dinv=None,
-                      want_dot=False):
-    """Fused prolongation/correction + postsmooth: x' after len(taus)
-    damped sweeps starting from x + P xc (the correction folded into
-    the first kernel's prologue), or None when no fused plan applies.
-    Oversized schedules run the prologue chunk first, then chain plain
-    fused sweep chunks. `want_dot` asks for the cycle-borne x'.b dot
-    (PCG's r.z) from the LAST kernel's epilogue: the single-call route
-    returns (x', dot); the chunked route returns (x', None) — the dot
-    would have to ride a mid-chain kernel, so the caller reduces it
-    with one standalone pass instead."""
-    ready = _transfer_ready(data, xfer, dinv)
-    if ready is None:
-        return None
-    A, fused = ready
-    taus = jnp.asarray(taus, _ps.compute_dtype(x.dtype))
-    n_steps = int(taus.shape[0])
-    if n_steps < 1:
-        return None
-    if not _fused_dtype_ok(A, x.dtype):
-        return None
-    sup_p = functools.partial(_ps.dia_prolong_supported, A, x.dtype,
-                              xfer=xfer)
-    if sup_p(n_steps):
-        return _corr_call(A, fused, xfer, taus, b, x, xc, dinv,
-                          with_dot=want_dot)
-    head = next((c for c in range(
-        min(n_steps - 1, _ps.SMOOTH_MAX_APPS), 0, -1) if sup_p(c)), 0)
-    if not head or not _ps.dia_smooth_supported(
-            A, x.dtype, n_steps - head, False):
-        return None
-    x = _corr_call(A, fused, xfer, taus[:head], b, x, xc, dinv)
-    x = dia_fused_smooth(A, fused, b, x, taus[head:], dinv=dinv,
-                         with_residual=False)
-    return (x, None) if want_dot else x
-
-
-# ---------------------------------------------------------------------------
-# VMEM-resident coarse-tail dispatch
-# ---------------------------------------------------------------------------
-
-
-def _tail_single_xla(arrs, b, x, spec):
-    from .batched import tail_cycle_multi
-    return tail_cycle_multi(arrs, b[None], x[None], spec)[0]
-
-
-@functools.lru_cache(maxsize=None)
-def _tail_fn(spec, with_dot: bool = False):
-    """custom_vmap-wrapped coarse-tail call for one static TailSpec:
-    vector-only batches (solve_many's shared-hierarchy shape) take the
-    slab form in ops/batched.py; batched hierarchies (multi-matrix
-    solves) take the vmapped XLA compose. `with_dot` appends the x'.b
-    dot epilogue (cycle-borne r.z) on every route."""
-    tu = jax.tree_util
-    ob = (True, True) if with_dot else True
-
-    @jax.custom_batching.custom_vmap
-    def call(arrs, b, x):
-        return _ps._dia_coarse_tail_call(arrs, b, x, spec,
-                                         with_dot=with_dot,
-                                         interpret=_ps._FORCE_INTERPRET)
-
-    @call.def_vmap
-    def _rule(axis_size, in_batched, arrs, b, x):
-        mat_b = any(tu.tree_leaves(in_batched[0]))
-        b_b, x_b = in_batched[1], in_batched[2]
-        if not mat_b:
-            from .batched import tail_cycle_multi
-            B = b if b_b else jnp.broadcast_to(b, (axis_size,) + b.shape)
-            X = x if x_b else jnp.broadcast_to(x, (axis_size,) + x.shape)
-            y = tail_cycle_multi(arrs, B, X, spec)
-            return ((y, _xb_dot(y, B)) if with_dot else y), ob
-
-        def one(a_, b_, x_):
-            y_ = _tail_single_xla(a_, b_, x_, spec)
-            return (y_, _xb_dot(y_, b_)) if with_dot else y_
-
-        axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                     for ib in in_batched)
-        y = jax.vmap(one, in_axes=axes, axis_size=axis_size)(arrs, b, x)
-        return y, ob
-
-    return call
-
-
-def _tail_taus(taus, dtype):
-    """(padded taus array, static application count): zero-sweep levels
-    carry a 1-entry dummy the kernel never reads (0-sized VMEM operands
-    are not portable)."""
-    n = int(taus.shape[0])
-    if n == 0:
-        return jnp.zeros((1,), dtype), 0
-    return taus.astype(dtype), n
-
-
-def coarse_tail_cycle(amg, shape: str, data, lvl: int, b, x,
-                      want_dot=False):
-    """Run the whole sub-cycle at levels >= lvl as ONE pallas_call with
-    every intermediate vector VMEM-resident, or None when the tail is
-    ineligible (caller recurses per level). Eligible when: fixed cycle
-    shape, f32, every tail level is an aggregation/DIA level with
-    transfer+fused slabs and a fused-capable smoother, the coarse
-    solver is NOSOLVER or exposes its dense inverse, the entry level is
-    under cycle_fusion_tail_rows, and everything fits the VMEM budget
-    together. `want_dot` (Krylov shell) makes the megakernel also emit
-    the x'.b dot — the whole-cycle-resident case's cycle-borne r.z —
-    and the return becomes (x', dot)."""
-    if shape not in ("V", "W", "F") or not _ps.flat_gather_ok():
-        return None
-    if jnp.dtype(x.dtype).name not in _ps.SMOOTH_DTYPES:
-        return None
-    levels = amg.levels
-    nlv = len(levels)
-    if lvl >= nlv:
-        return None
-    if levels[lvl].A.num_rows > int(
-            getattr(amg, "cycle_fusion_tail_rows", 0)):
-        return None
-    specs = []
-    arrs = []
-    total = 0
-    for i in range(lvl, nlv):
-        lv = levels[i]
-        ld = data["levels"][i]
-        if "R" in ld or "P" in ld:
-            return None
-        xfer = ld.get("xfer")
-        smd = ld.get("smoother")
-        if xfer is None or smd is None:
-            return None
-        if xfer.ptab is not None:
-            # weighted (classical) slabs: _tail_compute's gathers are
-            # unit-weight — those levels keep per-level kernels
-            return None
-        fused = smd.get("fused")
-        mfst = smd.get("stencil")
-        A = ld["A"]
-        if mfst is None and (fused is None
-                             or not _ps.smooth_dtype_ok(A, x.dtype)):
-            return None
-        spec_fn = getattr(lv.smoother, "fused_tail_spec", None)
-        if spec_fn is None:
-            return None
-        cdt = _ps.compute_dtype(x.dtype)
-        pre = spec_fn(smd, amg._sweeps(i, pre=True), cdt)
-        post = spec_fn(smd, amg._sweeps(i, pre=False), cdt)
-        if pre is None or post is None:
-            return None
-        taus_pre, n_pre = _tail_taus(pre[0], cdt)
-        taus_post, n_post = _tail_taus(post[0], cdt)
-        dinv = pre[1]
-        offsets = A.dia_offsets
-        qf, qc, _ = _ps.smooth_quota_rows(offsets, A.num_rows)
-        aqf = _ps.transfer_quota_rows(offsets, A.num_rows)[0]
-        ar = {
-            "taus_pre": taus_pre,
-            "taus_post": taus_post,
-            "ctab": xfer.ctab,
-            "atab_c": jax.lax.slice_in_dim(xfer.atab, aqf, aqf + qc,
-                                           1, 0),
-        }
-        if mfst is not None:
-            # matrix-free level: k coefficients instead of the value
-            # slab; dinv is synthesized in-kernel from the stencil
-            ar["coeffs"] = mfst.coeffs.astype(cdt)
-            specs.append(_ps.TailLevelSpec(
-                offsets=tuple(int(o) for o in offsets), n=A.num_rows,
-                qc=qc, has_dinv=False, n_pre=n_pre, n_post=n_post,
-                nc=xfer.nc, ncr=xfer.ncr, m=xfer.m, mf=mfst.spec()))
-            total += sum(v.size * v.dtype.itemsize
-                         for v in jax.tree_util.tree_leaves(ar))
-            arrs.append(ar)
-            continue
-        ar["vals"] = jax.lax.slice_in_dim(fused["vals_q"], qf, qf + qc,
-                                          1, 1)
-        if dinv is not None:
-            if "dinv_q" not in fused:
-                return None
-            ar["dinv"] = jax.lax.slice_in_dim(fused["dinv_q"], qf,
-                                              qf + qc, 1, 0)
-        specs.append(_ps.TailLevelSpec(
-            offsets=tuple(int(o) for o in offsets), n=A.num_rows,
-            qc=qc, has_dinv=dinv is not None, n_pre=n_pre,
-            n_post=n_post, nc=xfer.nc, ncr=xfer.ncr, m=xfer.m))
-        total += sum(v.size * v.dtype.itemsize
-                     for v in jax.tree_util.tree_leaves(ar))
-        arrs.append(ar)
-    cd = data["coarse"]
-    cs = amg.coarse_solver
-    nz = specs[-1].nc
-    ncrz = _ps.coarse_pad_rows(nz)
-    if getattr(cs, "name", "") in ("NOSOLVER", "DUMMY"):
-        coarse = ("none", nz, ncrz)
-    elif "inv" in cd and cd["inv"].shape == (nz, nz) \
-            and cd["inv"].dtype == jnp.float32:
-        F = ncrz * _ps.LANES
-        invT = jnp.zeros((F, F), jnp.float32)
-        invT = jax.lax.dynamic_update_slice(invT, cd["inv"].T, (0, 0))
-        arrs.append({"invT": invT})
-        total += F * F * 4
-        coarse = ("inv", nz, ncrz)
-    else:
-        return None
-    # all slabs + ~2x working vectors must co-reside in VMEM
-    if 2 * total > _ps._SMOOTH_VMEM_BUDGET:
-        return None
-    spec = _ps.TailSpec(shape, tuple(specs), coarse)
-    # telemetry: remember (at trace time, zero solve-phase cost) the
-    # outermost level the VMEM tail megakernel absorbed — SolveReport's
-    # per-level activity table reads it back (telemetry/report.py)
-    prev = getattr(amg, "_tail_entry_level", None)
-    amg._tail_entry_level = lvl if prev is None else min(prev, lvl)
-    return _tail_fn(spec, want_dot)(tuple(arrs), b, x)

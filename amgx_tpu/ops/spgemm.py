@@ -25,9 +25,10 @@ phases: the STRUCTURE phase runs once per pattern (host numpy: the
 (A·P) expansion gather indices, the lexsorted coalesce order, segment
 boundaries, and the output CSR pattern, memoized in a digest-keyed
 cache) and the VALUE phase recomputes all numerics from the current
-coefficients through those static indices — one fused Pallas kernel on
-TPU (ops/pallas_spgemm.py), a sort-free gather/segment-sum program on
-XLA rigs, or a reduceat sweep on host numpy hierarchies. `spgemm_plan=0`
+coefficients through those static indices — a sort-free
+gather/segment-sum XLA program on the device, or the native flat-FMA
+sweep (a reduceat pass without the toolchain) on host numpy
+hierarchies. `spgemm_plan=0`
 short-circuits before any plan machinery runs, restoring the eager
 composition bit-for-bit.
 """
@@ -218,10 +219,10 @@ class RapPlan:
       C = R·T (`sr`/`st` + `seg2`); the output mirrors the eager
       `galerkin_rap` CSR (the caller init()s it).
 
-    Index arrays live as host numpy (the numpy reduceat route and the
-    kernel-chunk builder read them); `dev()` uploads device twins once
-    per plan (the slab/kernel routes), exactly like the GEO structure
-    cache — a warm setup re-uploads nothing."""
+    Index arrays live as host numpy (the numpy route reads them);
+    `dev()` uploads device twins once per plan (the slab route),
+    exactly like the GEO structure cache — a warm setup re-uploads
+    nothing."""
 
     kind = "rap"
 
@@ -243,7 +244,6 @@ class RapPlan:
         self.num_rows = int(num_rows)
         self.num_cols = int(num_cols)
         self._dev = None
-        self._kernel = None       # None = unbuilt, False = declined
 
     def nbytes(self) -> int:
         total = 0
@@ -569,7 +569,7 @@ def _rap_values_numpy(plan: RapPlan, af, r_vals, p_vals):
                                              "has_r"))
 def _rap_values_slab(af, r_vals, p_vals, sa, sp, seg1, sr, st, seg2,
                      nT: int, nU: int, has1: bool, has_r: bool):
-    """XLA value phase (CPU meshes / f64 / kernel-declined): gathers +
+    """XLA value phase (every device-resident operand): gathers +
     sorted segment-sums through the static plan indices — zero sort /
     argsort / unique primitives in the jaxpr (the acceptance contract
     of the plan split's CPU route)."""
@@ -597,10 +597,9 @@ def _fold_values(plan, A: CsrMatrix, np_route: bool):
 
 def rap_values(plan: RapPlan, A: CsrMatrix, R=None, P=None):
     """Value phase dispatch: recompute the product's numerics from the
-    CURRENT coefficients through the plan. Route order: host numpy
-    (host-resident operands outside a forced-device setup), the fused
-    Pallas kernel (TPU / interpret-forced, f32, within budget —
-    ops/pallas_spgemm.py), the XLA slab program otherwise."""
+    CURRENT coefficients through the plan. Two roads: host numpy
+    (host-resident operands outside a forced-device setup), the XLA
+    slab program otherwise."""
     r_vals = None if R is None else R.values
     p_vals = None if P is None else P.values
     if _on_host(A) and (R is None or _on_host(R)) \
@@ -611,9 +610,6 @@ def rap_values(plan: RapPlan, A: CsrMatrix, R=None, P=None):
             None if r_vals is None else np.asarray(r_vals),
             None if p_vals is None else np.asarray(p_vals))
     af = _fold_values(plan, A, np_route=False)
-    from . import pallas_spgemm as _pk
-    if _pk.rap_kernel_ready(plan, af.dtype):
-        return _pk.rap_value_call(plan, af, r_vals, p_vals)
     d = plan.dev()
     s1 = plan.stage1
     return _rap_values_slab(

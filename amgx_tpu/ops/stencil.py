@@ -23,16 +23,16 @@ This module is the matrix-free core:
   anchor value, every off-grid entry is zero; the anchor row is the
   first row where the shift is in-grid, so the check subsumes the
   GEO wrap check) and one tiny transfer (a bool + k scalars).
-- XLA composes (`stencil_spmv`, `stencil_fused_smooth`, the transfer
-  forms): masked shifted adds `y = sum_t where(ok_t, c_t * shift(x)),
-  0)` — the f64 / batched / non-TPU route, and the route the paired
-  CPU bench measures. The per-offset masks are the same static-bound
+- XLA composes (`stencil_spmv`, `stencil_fused_smooth`): masked
+  shifted adds `y = sum_t where(ok_t, c_t * shift(x)), 0)` — the f64 /
+  batched / non-TPU route, and the route the paired CPU bench
+  measures. The per-offset masks are the same static-bound
   grid comparisons the Pallas kernels evaluate in-register
   (ops/pallas_spmv.py `_mf_*` helpers).
 - Pallas dispatch: the fused kernels' `coeffs` mode reads the k
   scalars from SMEM and synthesizes the value rows from the masks, so
   the A-operand stream (and its VMEM window) vanishes; plan math in
-  `dia_smooth_plan(..., coeffs=True)` and friends.
+  `dia_smooth_plan(..., coeffs=True)`.
 - `stencil_dia_vals` / `stencil_matrix`: in-trace materialization of
   the equivalent DIA slab — the escape hatch for consumers that
   genuinely need a matrix (residual monitoring, K-cycle coarse SpMV,
@@ -297,31 +297,6 @@ def _xla_smooth(spec, coeffs, taus, b, x, with_residual):
     return y
 
 
-def _xla_restrict(spec, coeffs, taus, b, x, ctab, nc):
-    """Smooth + unit-weight child-gather restriction (the aggregation
-    transfer slab's XLA twin)."""
-    y, r = _xla_smooth(spec, coeffs, taus, b, x, True)
-    cdt = _ps.compute_dtype(x.dtype)
-    rf = r.astype(cdt)
-    bc = jnp.zeros((ctab.shape[1] * ctab.shape[2],), cdt)
-    for j in range(ctab.shape[0]):
-        idx = ctab[j].reshape(-1)
-        valid = idx >= 0
-        g = jnp.take(rf, jnp.where(valid, idx, 0))
-        bc = bc + jnp.where(valid, g, jnp.zeros((), cdt))
-    return y, bc[:nc].astype(x.dtype)
-
-
-def _xla_corr(spec, coeffs, taus, b, x, xc, aggc):
-    """Correction prologue (x += xc[agg]) + smooth."""
-    cdt = _ps.compute_dtype(x.dtype)
-    valid = aggc >= 0
-    corr = jnp.take(xc.astype(cdt), jnp.where(valid, aggc, 0))
-    xs = x.astype(cdt) + jnp.where(valid, corr, jnp.zeros((), cdt))
-    return _xla_smooth(spec, coeffs, taus, b, xs.astype(x.dtype),
-                       False)
-
-
 # ---------------------------------------------------------------------------
 # dispatch (Pallas coeffs mode with XLA fallback under one custom_vmap)
 # ---------------------------------------------------------------------------
@@ -342,27 +317,6 @@ def stencil_smooth_supported(spec, x_dtype, n_steps: int,
         return False
     return _ps.dia_smooth_plan(
         spec.offsets, len(spec.offsets), spec.n, n_steps, with_residual,
-        itemsize=jnp.dtype(x_dtype).itemsize, coeffs=True) is not None
-
-
-def stencil_restrict_supported(spec, x_dtype, n_steps: int,
-                               xfer) -> bool:
-    if xfer is None or xfer.cwt is not None \
-            or not _ps.flat_gather_ok() or not _dtype_ok(x_dtype):
-        return False
-    return _ps.dia_restrict_plan(
-        spec.offsets, len(spec.offsets), spec.n, n_steps, xfer.m,
-        xfer.windows, itemsize=jnp.dtype(x_dtype).itemsize,
-        coeffs=True) is not None
-
-
-def stencil_prolong_supported(spec, x_dtype, n_steps: int,
-                              xfer) -> bool:
-    if xfer is None or xfer.ptab is not None \
-            or not _ps.flat_gather_ok() or not _dtype_ok(x_dtype):
-        return False
-    return _ps.dia_prolong_plan(
-        spec.offsets, len(spec.offsets), spec.n, n_steps, xfer.windows,
         itemsize=jnp.dtype(x_dtype).itemsize, coeffs=True) is not None
 
 
@@ -399,70 +353,6 @@ def _smooth_fn(spec, with_residual: bool):
     return call
 
 
-@functools.lru_cache(maxsize=None)
-def _restrict_fn(spec):
-    tu = jax.tree_util
-
-    @jax.custom_batching.custom_vmap
-    def call(coeffs, taus, b, x, xfer):
-        return _ps._dia_stencil_smooth_restrict_call(
-            coeffs, taus, b, x, xfer, spec,
-            interpret=_ps._FORCE_INTERPRET)
-
-    @call.def_vmap
-    def _rule(axis_size, in_batched, coeffs, taus, b, x, xfer):
-        axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                     for ib in in_batched)
-        y = jax.vmap(
-            lambda c_, t_, b_, x_, xf_: _xla_restrict(
-                spec, c_, t_, b_, x_, xf_.ctab, xf_.nc),
-            in_axes=axes, axis_size=axis_size)(coeffs, taus, b, x,
-                                               xfer)
-        return y, (True, True)
-
-    return call
-
-
-def _xb_dot(y, b):
-    """XLA twin of the x'.b dot epilogue (cycle-borne r.z),
-    accumulation-dtype like the kernel's f32 partials."""
-    cdt = _ps.compute_dtype(y.dtype)
-    return jnp.vdot(y.astype(cdt), b.astype(cdt))
-
-
-@functools.lru_cache(maxsize=None)
-def _corr_fn(spec, with_dot: bool = False):
-    tu = jax.tree_util
-    ob = (True, True) if with_dot else True
-
-    @jax.custom_batching.custom_vmap
-    def call(coeffs, taus, b, x, xc, xfer):
-        return _ps._dia_stencil_prolong_smooth_call(
-            coeffs, taus, b, x, xc, xfer, spec, with_dot=with_dot,
-            interpret=_ps._FORCE_INTERPRET)
-
-    @call.def_vmap
-    def _rule(axis_size, in_batched, coeffs, taus, b, x, xc, xfer):
-        axes = tuple(tu.tree_map(lambda bb: 0 if bb else None, ib)
-                     for ib in in_batched)
-
-        rows = max(1, -(-spec.n // _ps.LANES))
-        aqf = _ps.transfer_quota_rows(spec.offsets, spec.n)[0]
-
-        def one(c_, t_, b_, x_, xc_, xf_):
-            # content region of the quota-padded aggregate-id slab
-            aggc = jax.lax.slice_in_dim(
-                xf_.atab, aqf, aqf + rows, 1, 0).reshape(-1)[:spec.n]
-            y_ = _xla_corr(spec, c_, t_, b_, x_, xc_, aggc)
-            return (y_, _xb_dot(y_, b_)) if with_dot else y_
-
-        y = jax.vmap(one, in_axes=axes, axis_size=axis_size)(
-            coeffs, taus, b, x, xc, xfer)
-        return y, ob
-
-    return call
-
-
 def stencil_fused_smooth(st: StencilOperator, taus, b, x,
                          with_residual=True):
     """Matrix-free smoother dispatch: x' (and r) after len(taus)
@@ -481,58 +371,6 @@ def stencil_fused_smooth(st: StencilOperator, taus, b, x,
             return x, r.astype(x.dtype)
         return x
     return _smooth_fn(spec, with_residual)(coeffs, taus, b, x)
-
-
-def stencil_smooth_restrict(st: StencilOperator, taus, b, x, xfer):
-    """Matrix-free presmooth + restriction epilogue: (x', bc), or None
-    when no fused transfer plan applies (the caller composes
-    stencil_fused_smooth + the level's restriction)."""
-    if xfer is None or xfer.ptab is not None or xfer.cwt is not None:
-        return None
-    spec = st.spec()
-    taus = jnp.asarray(taus, _ps.compute_dtype(x.dtype))
-    n_steps = int(taus.shape[0])
-    if n_steps < 1:
-        return None
-    if stencil_restrict_supported(spec, x.dtype, n_steps, xfer):
-        return _restrict_fn(spec)(st.coeffs, taus, b, x, xfer)
-    tail = next((c for c in range(
-        min(n_steps - 1, _ps.SMOOTH_MAX_APPS - 1), 0, -1)
-        if stencil_restrict_supported(spec, x.dtype, c, xfer)), 0)
-    if not tail:
-        return None
-    head = stencil_fused_smooth(st, taus[:n_steps - tail], b, x,
-                                with_residual=False)
-    return _restrict_fn(spec)(st.coeffs, taus[n_steps - tail:], b,
-                              head, xfer)
-
-
-def stencil_corr_smooth(st: StencilOperator, taus, b, x, xc, xfer,
-                        want_dot: bool = False):
-    """Matrix-free prolongation/correction prologue + postsmooth: x'
-    starting from x + P xc, or None when no fused transfer plan
-    applies. With want_dot, returns (x', dot) where dot is the x'.b
-    epilogue (the cycle-borne r.z); the head-chunked route declines
-    the dot — returns (x', None) — since only the final application
-    could carry it and that is the plain smoother kernel."""
-    if xfer is None or xfer.ptab is not None:
-        return None
-    spec = st.spec()
-    taus = jnp.asarray(taus, _ps.compute_dtype(x.dtype))
-    n_steps = int(taus.shape[0])
-    if n_steps < 1:
-        return None
-    if stencil_prolong_supported(spec, x.dtype, n_steps, xfer):
-        return _corr_fn(spec, want_dot)(st.coeffs, taus, b, x, xc, xfer)
-    head = next((c for c in range(
-        min(n_steps - 1, _ps.SMOOTH_MAX_APPS), 0, -1)
-        if stencil_prolong_supported(spec, x.dtype, c, xfer)), 0)
-    if not head:
-        return None
-    x = _corr_fn(spec)(st.coeffs, taus[:head], b, x, xc, xfer)
-    x = stencil_fused_smooth(st, taus[head:], b, x,
-                             with_residual=False)
-    return (x, None) if want_dot else x
 
 
 # ---------------------------------------------------------------------------

@@ -654,16 +654,6 @@ class Solver(SolveDataOwner):
         st = jax.lax.fori_loop(0, self.max_iters, body, st)
         return st["x"]
 
-    def apply_dot(self, data, rhs):
-        """Preconditioner action PLUS the LOCAL x.rhs scalar when the
-        application's final kernel can emit it as a free epilogue
-        ((x, dot), dot None otherwise — callers then reduce
-        explicitly). PCG reads it as r.z: the preconditioner's rhs is
-        the residual, so the cycle-borne dot saves the iteration's
-        full-vector r.z pass (Krylov shell fusion). Base solvers have
-        no epilogue-capable kernel: (apply, None)."""
-        return self.apply(data, rhs), None
-
     # -- the jitted driver ----------------------------------------------
     def _build_solve_fn(self, diag: bool = True, extras=None):
         """Return the raw (unjitted) solve function; jit happens in

@@ -26,7 +26,6 @@ class DenseLUSolver(Solver):
         super().__init__(cfg, scope, name)
         self.dense_lu_num_rows = int(cfg.get("dense_lu_num_rows", scope))
         self.dense_lu_max_rows = int(cfg.get("dense_lu_max_rows", scope))
-        self.cycle_fusion = bool(int(cfg.get("cycle_fusion", scope)))
 
     def solver_setup(self):
         dense = self.A.to_dense()
@@ -42,25 +41,10 @@ class DenseLUSolver(Solver):
         q, r = jnp.linalg.qr(dense)
         return q.T, r
 
-    # explicit-inverse size cap for the fused coarse-tail kernel: the
-    # padded inverse lives in VMEM during the whole tail sub-cycle
-    _TAIL_INV_MAX_ROWS = 1024
-
     def _build_solve_data(self):
         d = super()._build_solve_data()
         d["qt"] = self._qt
         d["r"] = self._r
-        if self.cycle_fusion and self.A is not None \
-                and self.A.num_rows <= self._TAIL_INV_MAX_ROWS:
-            from ..ops.smooth import fused_runtime_on
-            if fused_runtime_on():
-                # explicit inverse A^{-1} = R^{-1} Q^T for the
-                # VMEM-resident coarse tail (ops/smooth.py): the tail
-                # kernel applies the coarsest solve as one MXU matmul.
-                # The n^2-RHS triangular solve runs where the tree is
-                # assembled: once a (re)setup (solve_data.py)
-                d["inv"] = jsl.solve_triangular(self._r, self._qt,
-                                                lower=False)
         return d
 
     def _direct(self, data, rhs):

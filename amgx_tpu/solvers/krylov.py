@@ -33,9 +33,8 @@ def _ldot(a, b):
 class _KrylovBase(Solver):
     def __init__(self, cfg, scope="default", name="?"):
         super().__init__(cfg, scope, name)
-        # Krylov shell fusion (ops/spmv.spmv_pdot / blas.cg_update /
-        # the preconditioner's cycle-borne r.z): 0 restores the
-        # unfused SpMV + BLAS-1 composition bit-for-bit
+        # Krylov shell fusion (ops/spmv.spmv_pdot / blas.cg_update):
+        # 0 restores the unfused SpMV + BLAS-1 composition bit-for-bit
         self.krylov_fusion = bool(int(cfg.get("krylov_fusion", scope)))
 
     def _precond(self, data, r):
@@ -44,16 +43,9 @@ class _KrylovBase(Solver):
         return r
 
     def _precond_dot(self, data, r):
-        """(z, LOCAL r.z): the dot rides the preconditioner
-        application's last kernel when it can (AMG cycle_dot — the
-        cycle's output IS z and its rhs IS r), the explicit local
-        reduction otherwise; identity preconditioner gives (r, r.r)."""
-        if self.preconditioner is None:
-            return r, _ldot(r, r)
-        z, d = self.preconditioner.apply_dot(data["precond"], r)
-        if d is None:
-            d = _ldot(r, z)
-        return z, d
+        """(z, LOCAL r.z); identity preconditioner gives (r, r.r)."""
+        z = self._precond(data, r)
+        return z, _ldot(r, z)
 
     def _l2_scalar_norm(self) -> bool:
         """True when the driver's monitored norm is the plain scalar L2
@@ -167,10 +159,9 @@ class PCGSolver(_KrylovBase):
 
     def _fused_iteration(self, data, st):
         """Fused-hierarchy PCG iteration: the p-update+SpMV+p.Ap
-        kernel, the x/r-update+r.r kernel, and r.z riding the
-        preconditioner cycle's last kernel — zero standalone
-        full-vector reductions, and the post-alpha scalars (r.r, r.z)
-        share ONE packed psum."""
+        kernel and the x/r-update+r.r kernel; r.z is the one explicit
+        reduction, and the post-alpha scalars (r.r, r.z) share ONE
+        packed psum."""
         A = data["A"]
         x, r, rz = st["x"], st["r"], st["rz"]
         p, Ap, pAp = spmv_pdot(A, st["p"], st["z"], st["beta"])
@@ -234,10 +225,10 @@ class PCGFSolver(_KrylovBase):
         return out
 
     def _fused_iteration(self, data, st):
-        """Fused flexible PCG: same two shell kernels + cycle-borne
-        r.z as PCG; the Polak-Ribiere numerator <z, r_new - r> is the
-        one reduction the kernels cannot absorb (it needs the OLD r
-        after the new one exists) and packs into the same psum bundle."""
+        """Fused flexible PCG: the same two shell kernels as PCG; the
+        Polak-Ribiere numerator <z, r_new - r> (it needs the OLD r
+        after the new one exists) packs into the same psum bundle as
+        r.z."""
         A = data["A"]
         x, r, rz = st["x"], st["r"], st["rz"]
         p, Ap, pAp = spmv_pdot(A, st["p"], st["z"], st["beta"])

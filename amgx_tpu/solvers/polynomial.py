@@ -321,47 +321,3 @@ class ChebyshevPolySolver(Solver):
             if out is not None:
                 return out
         return super().smooth_residual(data, b, x, sweeps)
-
-    # -- cycle fusion (AMGLevel.restrict_fused / prolongate_smooth) ----
-    def smooth_restrict(self, data, b, x, sweeps: int, xfer):
-        if sweeps < 1:
-            return None
-        st = data.get("stencil")
-        if st is not None:
-            from ..ops import stencil as mf
-            return mf.stencil_smooth_restrict(
-                st, self._fused_taus(data, sweeps, x.dtype), b, x,
-                xfer)
-        if self.fused_smoother:
-            from ..ops import smooth as fused
-            return fused.fused_smooth_restrict(
-                data, b, x, self._fused_taus(data, sweeps, x.dtype),
-                xfer)
-        return None
-
-    def smooth_corr(self, data, b, x, xc, sweeps: int, xfer,
-                    want_dot: bool = False):
-        if sweeps < 1:
-            return None
-        st = data.get("stencil")
-        if st is not None:
-            from ..ops import stencil as mf
-            return mf.stencil_corr_smooth(
-                st, self._fused_taus(data, sweeps, x.dtype), b, x, xc,
-                xfer, want_dot=want_dot)
-        if self.fused_smoother:
-            from ..ops import smooth as fused
-            return fused.fused_corr_smooth(
-                data, b, x, xc, self._fused_taus(data, sweeps, x.dtype),
-                xfer, want_dot=want_dot)
-        return None
-
-    def fused_tail_spec(self, data, sweeps: int, dtype):
-        """Tiled tau schedule for the coarse-tail kernel (one smoother
-        application = `order` damped-Richardson steps)."""
-        if not self.fused_smoother or getattr(
-                data["A"], "is_block", True):
-            return None
-        if sweeps <= 0:
-            return jnp.zeros((0,), dtype), None
-        return self._fused_taus(data, sweeps, dtype), None

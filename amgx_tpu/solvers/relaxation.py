@@ -104,56 +104,6 @@ class _FusedJacobiMixin:
                 return out
         return super().smooth_residual(data, b, x, sweeps)
 
-    # -- cycle fusion (AMGLevel.restrict_fused / prolongate_smooth) ----
-    def smooth_restrict(self, data, b, x, sweeps: int, xfer):
-        """(x', bc) with the restriction riding the presmoother
-        kernel's epilogue, or None (caller composes unfused)."""
-        if sweeps < 1:
-            return None
-        st = data.get("stencil")
-        if st is not None:
-            from ..ops import stencil as mf
-            return mf.stencil_smooth_restrict(
-                st, self._fused_taus(sweeps, x.dtype), b, x, xfer)
-        if self._fused_eligible(data):
-            return fused.fused_smooth_restrict(
-                data, b, x, self._fused_taus(sweeps, x.dtype), xfer,
-                dinv=data["dinv"])
-        return None
-
-    def smooth_corr(self, data, b, x, xc, sweeps: int, xfer,
-                    want_dot: bool = False):
-        """smooth(b, x + P xc) with the correction folded into the
-        first kernel application, or None. want_dot additionally
-        requests the x'.b dot epilogue → (x', dot|None)."""
-        if sweeps < 1:
-            return None
-        st = data.get("stencil")
-        if st is not None:
-            from ..ops import stencil as mf
-            return mf.stencil_corr_smooth(
-                st, self._fused_taus(sweeps, x.dtype), b, x, xc, xfer,
-                want_dot=want_dot)
-        if self._fused_eligible(data):
-            return fused.fused_corr_smooth(
-                data, b, x, xc, self._fused_taus(sweeps, x.dtype),
-                xfer, dinv=data["dinv"], want_dot=want_dot)
-        return None
-
-    def fused_tail_spec(self, data, sweeps: int, dtype):
-        """(taus, dinv) schedule for the VMEM-resident coarse-tail
-        kernel, or None when this smoother cannot ride it. Matrix-free
-        levels return dinv=None — the tail kernel synthesizes the
-        diagonal inverse from the level's stencil coefficients."""
-        if not self.fused_smoother or getattr(
-                data["A"], "is_block", True):
-            return None
-        if "stencil" in data:
-            return self._fused_taus(max(sweeps, 0), dtype), None
-        if "dinv" not in data:
-            return None
-        return self._fused_taus(max(sweeps, 0), dtype), data["dinv"]
-
 
 def safe_recip(d):
     """Elementwise 1/d with 0 -> 0 (zero-in-diagonal robustness).

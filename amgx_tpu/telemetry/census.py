@@ -17,9 +17,6 @@ KERNEL_NAME_RE = re.compile(r"name=\"?([A-Za-z_0-9]+)\"?")
 # pallas_call eqns (ops/pallas_spmv.py); extend here when a PR adds a
 # kernel so every suite's counts see it
 KERNEL_KEYS = (
-    "_dia_smooth_restrict_call",
-    "_dia_prolong_smooth_call",
-    "_dia_coarse_tail_call",
     "_dia_geo_restrict_call",
     "_dia_geo_prolong_call",
     "_dia_smooth_call",

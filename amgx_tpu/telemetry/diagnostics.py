@@ -38,10 +38,8 @@ SAME stats vector the monitor already returns. So:
 
 Cost when ON: one extra instrumented cycle per solve — each recorded
 stage is a residual SpMV + an L2 reduction, so roughly 2x one cycle's
-work, once per solve (NOT per iteration). The probe cycle composes the
-stage boundaries explicitly (no VMEM coarse-tail megakernel, unfused
-correction) so every stage exists to measure; the solve iterations
-themselves keep their fused kernels either way.
+work, once per solve (NOT per iteration). The probe cycle is the
+cycle every iteration runs, with a record between its stages.
 
 Recording mechanics: the cycle recursion (amg/cycles.py) is plain
 Python unrolled at trace time, so a thread-local "tape" collects the

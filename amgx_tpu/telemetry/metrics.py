@@ -715,9 +715,7 @@ declare_counter("amg.geo_transfer.onepass",
                 "ran as the one-pass kernels (ops/pallas_geo.py), "
                 "raised after each solve by the iterations that ran "
                 "the cycle x such levels of the hierarchy; 0 where a "
-                "solve's cycle has none. Static, as the road is: where "
-                "the fused restrict-epilogue / prolong-prologue family "
-                "engages (the interpreter only) it runs instead")
+                "solve's cycle has none. Static, as the road is")
 declare_counter("amg.geo_transfer.xla",
                 "GEO levels whose transfers ran as the XLA form (f64, "
                 "extents off the kernels' grid, a CPU), raised "

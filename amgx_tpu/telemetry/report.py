@@ -4,7 +4,7 @@ The reference prints per-iteration solve tables and grid stats through
 its registered print callback; a production consumer needs the same
 information machine-readable. `SolveReport` is that object: everything
 the solve already measured — per-iteration residual norms, the final
-`SolveStatus`, per-level smoother/transfer/tail kernel activity, wall
+`SolveStatus`, per-level smoother kernel activity, wall
 times — assembled HOST-SIDE from data the solver has already pulled
 (the packed stats array) plus static hierarchy metadata (shapes,
 layout kinds, fusion payload presence). Building a report therefore
@@ -67,9 +67,6 @@ class SolveReport:
     cycle: Optional[str] = None      # AMG cycle shape when an AMG member
     #                                  is in the tree
     levels: List[Dict[str, Any]] = dataclasses.field(default_factory=list)
-    tail_entry_level: Optional[int] = None   # first level the VMEM
-    #                                  coarse-tail megakernel absorbed
-    #                                  (None: no tail fired)
     distributed: Optional[Dict[str, Any]] = None
     counters: Optional[Dict[str, Any]] = None
     # structured grid statistics (AMG.grid_stats_dict(): per-level
@@ -161,9 +158,9 @@ def _effective_dtype(amg, A) -> Optional[str]:
     return str(dv.dtype) if dv is not None else None
 
 
-def _level_table_key(amg, tail):
+def _level_table_key(amg):
     levels = getattr(amg, "levels", None) or []
-    return (id(levels), len(levels), tail)
+    return (id(levels), len(levels))
 
 
 def carry_level_table(amg, cached):
@@ -172,8 +169,7 @@ def carry_level_table(amg, cached):
     unchanged (amg/signature.py holds every column of the table), so
     that a time loop's reports stay a list copy."""
     if cached is not None:
-        amg._telemetry_level_cache = (
-            _level_table_key(amg, cached[0][2]), cached[1])
+        amg._telemetry_level_cache = (_level_table_key(amg), cached[1])
 
 
 def _level_table(amg):
@@ -189,15 +185,14 @@ def _level_table(amg):
 
     Memoized on the hierarchy: the table is structure-only, so it
     changes only when the level list is rebuilt (setup / structure
-    resetup — a NEW list object) or the tail boundary is first
-    recorded; per-solve report construction then costs a list copy."""
+    resetup — a NEW list object); per-solve report construction then
+    costs a list copy."""
     from ..ops.pallas_spmv import SMOOTH_DTYPES
     levels = getattr(amg, "levels", None) or []
-    tail0 = getattr(amg, "_tail_entry_level", None)
-    key = _level_table_key(amg, tail0)
+    key = _level_table_key(amg)
     cached = getattr(amg, "_telemetry_level_cache", None)
     if cached is not None and cached[0] == key:
-        return [dict(r) for r in cached[1]], tail0
+        return [dict(r) for r in cached[1]]
     rows: List[Dict[str, Any]] = []
     for lvl, level in enumerate(levels):
         A = level.A
@@ -214,9 +209,7 @@ def _level_table(amg):
         smd = ld.get("smoother") if isinstance(ld, dict) else None
         fused_sm = bool(isinstance(smd, dict)
                         and ("fused" in smd or "dist_fused" in smd))
-        fused_xf = bool(isinstance(ld, dict) and "xfer" in ld)
         row["fused_smoother"] = fused_sm
-        row["fused_transfers"] = fused_xf
         edt = _effective_dtype(amg, A)
         row["dtype"] = edt
         dtype_ok = edt in SMOOTH_DTYPES
@@ -229,12 +222,6 @@ def _level_table(amg):
             # cycle composes unfused (counted fusion.declined_dtype
             # at trace time by ops/smooth.py)
             row["fused_routing"] = "declined_dtype"
-        # a fully fused aggregation/DIA level does its whole per-visit
-        # cycle work (presmooth+restrict, prolong+postsmooth) in
-        # exactly two pallas_calls (PR 5); levels inside the VMEM
-        # coarse tail run in the tail's single kernel instead
-        row["kernels_per_visit"] = 2 if (fused_sm and fused_xf
-                                         and dtype_ok) else None
         rows.append(row)
     coarsest = getattr(amg, "coarsest_A", None)
     if coarsest is not None and levels:
@@ -244,21 +231,13 @@ def _level_table(amg):
             "nnz": _nnz_of(coarsest),
             "layout": _layout_kind(coarsest),
             "fused_smoother": False,
-            "fused_transfers": False,
-            "kernels_per_visit": None,
             "coarse_solver": getattr(amg.coarse_solver, "name", None),
         })
-    tail = getattr(amg, "_tail_entry_level", None)
-    if tail is not None:
-        for row in rows:
-            if row["level"] >= tail:
-                row["kind"] = "vmem_tail"
-                row["kernels_per_visit"] = None
     try:
         amg._telemetry_level_cache = (key, rows)
     except Exception:
         pass
-    return [dict(r) for r in rows], tail
+    return [dict(r) for r in rows]
 
 
 def _scalar(v):
@@ -283,11 +262,10 @@ def build_report(solver, result, hist=None,
     residuals = [] if hist is None else np.asarray(hist).tolist()
     amg = _amg_of(solver)
     levels: List[Dict[str, Any]] = []
-    tail = None
     cycle = None
     hierarchy = None
     if amg is not None and distributed is None:
-        levels, tail = _level_table(amg)
+        levels = _level_table(amg)
         cycle = getattr(amg, "cycle_name", None)
     elif amg is not None:
         cycle = getattr(amg, "cycle_name", None)
@@ -310,7 +288,6 @@ def build_report(solver, result, hist=None,
         solve_time_s=float(getattr(result, "solve_time", 0.0)),
         cycle=cycle,
         levels=levels,
-        tail_entry_level=tail,
         distributed=distributed,
         hierarchy=hierarchy,
         diagnostics=diagnostics,

@@ -82,7 +82,6 @@ DECLARED_SPANS: Tuple[str, ...] = (
     "amg.L*.truncate",
     "amg.L*.layoutP",
     "amg.L*.transposeR",
-    "amg.L*.xfer_slabs",
     # classical device-parallel RS/HMIS first pass: runs INSIDE the
     # amg.L*.cfsplit leaf on the main thread, so it is declared
     # OUTSIDE the amg.* accounted prefix (summing both would

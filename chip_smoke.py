@@ -29,8 +29,7 @@ import time
 import numpy as np
 
 FLAGSHIP_TOL = 1e-8           # presets.FLAGSHIP's own tolerance
-PLAIN = (", fused_smoother=0, cycle_fusion=0, krylov_fusion=0,"
-         " matrix_free=0")
+PLAIN = ", fused_smoother=0, krylov_fusion=0, matrix_free=0"
 CLASSICAL_CFG = "configs/PCG_CLASSICAL_V_JACOBI.json"
 # the distributed example's solver (examples/amgx_mpi_poisson7.py)
 DIST_CFG = (

@@ -104,59 +104,9 @@ def doctor(tag, cfg_str):
     return res
 
 
-def classical_fusion_before_after():
-    """Before/after the classical-path fusion (ISSUE 12): the same
-    classical config traced with `cycle_fusion=0` (the pre-fusion
-    composition) and with the fused classical kernels, with the
-    per-cycle kernel census from each trace. The diagnostics probe
-    runs in both and must attribute the SAME bottleneck level — the
-    fusion is a wall-clock change (HBM passes per cycle), not a
-    numerical one — so the census is where the change shows: the
-    smoothed DIA fine level collapses to exactly two fused kernels
-    and its standalone SpMV/transfer passes disappear."""
-    import re
-
-    import jax
-
-    from amgx_tpu.ops import pallas_spmv as ps
-
-    cfg = (BASE + ", amg:smoother(sm)=JACOBI_L1, sm:max_iters=1,"
-           " amg:strength_threshold=0.25, amg:interp_max_elements=4,"
-           " amg:max_levels=2, amg:min_coarse_rows=16")
-    A = amgx.gallery.poisson("7pt", N, N, N,
-                             dtype=jnp.float32).init()
-    b = jnp.ones(A.num_rows, jnp.float32)
-    print("\n=== classical-path fusion: before / after ===")
-    for tag, extra in (("before (cycle_fusion=0)",
-                        ", amg:cycle_fusion=0"),
-                       ("after  (fused classical)", "")):
-        with ps.force_pallas_interpret():
-            slv = amgx.create_solver(Config.from_string(cfg + extra))
-            slv.setup(A)
-            res = slv.solve(b)
-            pc = slv.preconditioner
-            d = pc.solve_data()
-            jaxpr = str(jax.make_jaxpr(
-                lambda bb, xx: pc.amg.cycle(d["amg"], bb, xx))(
-                    b, jnp.zeros_like(b)))
-        census = {}
-        for nm in re.findall(r'name="?([A-Za-z_0-9]+)"?', jaxpr):
-            if nm.startswith(("_dia_", "_swell_")):
-                census[nm] = census.get(nm, 0) + 1
-        bl = res.report.diagnostics["bottleneck_level"]
-        print(f"{tag}: iters={res.iterations} bottleneck_level={bl}"
-              f" kernels/cycle={census or '{}'}")
-    print("the fused trace runs the smoothed classical level as TWO "
-          "kernels\n(_dia_smooth_restrict_call + "
-          "_dia_prolong_smooth_call) with the standalone\nsmoother/"
-          "SpMV/transfer passes gone; the bottleneck attribution is "
-          "unchanged\n— fusion cuts HBM passes, not iterations.")
-
-
 if __name__ == "__main__":
     healthy = doctor("healthy", CONFIGS["healthy"])
     mistuned = doctor("mistuned", CONFIGS["mistuned"])
     print(f"\nhealthy converged in {healthy.iterations} iters, "
           f"mistuned took {mistuned.iterations} "
           f"({mistuned.status}) — the table above says why.")
-    classical_fusion_before_after()

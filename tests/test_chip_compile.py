@@ -16,7 +16,6 @@ tests live in this one file.
 """
 import os
 import sys
-import types
 
 import numpy as np
 import pytest
@@ -25,7 +24,6 @@ import jax.numpy as jnp
 
 from amgx_tpu.ops import pallas_spmv as ps
 from amgx_tpu.ops import pallas_swell as sw
-from amgx_tpu.ops import smooth as fused
 from amgx_tpu.ops import stencil
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -478,54 +476,20 @@ def test_row_split_operators_compile(rows, cols, w128, kpad, longest,
 
 
 def test_declined_families_decline_on_chip_only(on_tpu):
-    """Every gate of the family Mosaic refuses ("Only 2D gather is
-    supported": ops.pallas_spmv.flat_gather_ok) says no on the
+    """The one family Mosaic still refuses (the bf16 operand windows of
+    the DIA kernels: a slice off the (8, 128) tiling) says no on the
     compiled-for-chip branch, and yes again under the interpreter, so
     the CPU interpret suites of those kernels keep running."""
-    import amgx_tpu as amgx
-    from amgx_tpu import gallery
-    from amgx_tpu.config import Config
-
-    def gates():
-        A = gallery.poisson("7pt", 16, 16, 16, dtype=np.float32).init()
-        slv = amgx.create_solver(Config.from_string(
-            "solver=AMG, algorithm=AGGREGATION, selector=GEO,"
-            " smoother=JACOBI_L1, presweeps=2, postsweeps=1,"
-            " max_iters=1, coarse_solver=DENSE_LU_SOLVER,"
-            " min_coarse_rows=16, max_levels=10, matrix_free=0,"
-            " cycle_fusion_tail_rows=100000"))
-        slv.setup(A)
-        amg, d = slv.amg, slv.solve_data()["amg"]
-        xfer = d["levels"][0].get("xfer")
-        b = jnp.ones(A.num_rows, F32)
-        tail = fused.coarse_tail_cycle(amg, "V", d, 0, b,
-                                       jnp.zeros_like(b))
-        return {
-            "family": ps.flat_gather_ok(),
-            "slabs_built": xfer is not None,
-            "slab_gate": ps._transfer_gate(amg.levels[0].A, F32),
-            "coarse_tail": tail is not None,
-        }
-
-    assert ps.declined_families()
-    assert gates() == {"family": False, "slabs_built": False,
-                       "slab_gate": False, "coarse_tail": False}
     sp = _spec7(16)
-    xfer = types.SimpleNamespace(cwt=None, ptab=None)
-    assert not stencil.stencil_restrict_supported(sp, F32, 2, xfer)
-    assert not stencil.stencil_prolong_supported(sp, F32, 2, xfer)
-    # the plan-split RAP value kernel (1-D gathers too) and the bf16
-    # windows of the DIA kernels
-    from amgx_tpu.ops import pallas_spgemm as pk
-    assert not pk.rap_kernel_ready(object(), F32)
+    assert list(ps.declined_families()) == [
+        "bf16 operand windows of dia_smooth / dia_spmv_dot (slab and "
+        "stencil twins)"]
     assert ps.kernel_dtype_ok(F32) and not ps.kernel_dtype_ok(jnp.bfloat16)
     assert not stencil.stencil_smooth_supported(sp, jnp.bfloat16, 2, True)
     with ps.force_pallas_interpret():
         assert ps.declined_families() == {}
         assert ps.kernel_dtype_ok(jnp.bfloat16)
         assert stencil.stencil_smooth_supported(sp, jnp.bfloat16, 2, True)
-        assert gates() == {"family": True, "slabs_built": True,
-                           "slab_gate": True, "coarse_tail": True}
 
 
 def test_scope_table_puts_every_kernel_of_the_solve_under_a_stage(

@@ -118,6 +118,25 @@ def test_validation_errors():
         Config.from_string("max_iters(foo)=3")
 
 
+@pytest.mark.parametrize("key", ["cycle_fusion",
+                                 "cycle_fusion_tail_rows"])
+def test_removed_cycle_fusion_keys_are_unknown(key):
+    """The knobs of the flat-gather family left the registry with it: a
+    configuration that still names one is told so, in a string, in the
+    cycle's scope and from the registry's own getter, like any other
+    unknown key (`dist_cycle_fusion` is another knob and stays)."""
+    from amgx_tpu.config import parameter_registry
+    assert key not in parameter_registry()
+    assert len(parameter_registry()) == 215
+    for text in (f"{key}=0", f"solver(amg)=AMG, amg:{key}=0"):
+        with pytest.raises(AMGXError, match="unknown parameter"):
+            Config.from_string(text)
+    with pytest.raises(AMGXError, match="unknown parameter"):
+        Config().get(key)
+    assert Config.from_string("dist_cycle_fusion=0").get(
+        "dist_cycle_fusion") == 0
+
+
 def test_case_tolerant_enums():
     cfg = Config.from_string("norm=l2")
     assert cfg.get("norm") == "L2"

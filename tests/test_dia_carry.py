@@ -366,7 +366,7 @@ _CFG = ("config_version=2, solver(s)=PCG, s:max_iters=30,"
         " amg:smoother(sm)=CHEBYSHEV_POLY, sm:chebyshev_polynomial_order=2,"
         " amg:presweeps=1, amg:postsweeps=1, amg:max_iters=1,"
         " amg:min_coarse_rows=8, amg:max_levels=3,"
-        " amg:coarse_solver=DENSE_LU_SOLVER, amg:cycle_fusion=0")
+        " amg:coarse_solver=DENSE_LU_SOLVER")
 
 
 def test_counters_follow_the_plans_of_a_known_hierarchy(small_blocks):

@@ -307,15 +307,16 @@ def test_dist_cycle_fusion_0_bit_for_bit():
 
 
 # ---------------------------------------------------------------------------
-# consolidation boundary -> VMEM coarse tail
+# consolidation boundary
 # ---------------------------------------------------------------------------
 
 
-def test_consolidation_boundary_feeds_vmem_tail():
-    """With coarse-level consolidation, the gathered replicated tail of
-    a distributed GEO/DIA hierarchy runs as ONE VMEM-resident coarse
-    tail megakernel per cycle while the sharded finest level keeps its
-    two halo-folded kernels; fused and unfused solves agree."""
+def test_consolidation_boundary_keeps_the_one_composition():
+    """With coarse-level consolidation, the sharded finest level keeps
+    its two halo-folded kernels and every gathered, replicated level
+    below the boundary runs the single-chip smoother kernel twice a
+    cycle, as it would on one device; fused and unfused solves
+    agree."""
     A = gallery.poisson("7pt", 8, 8, 32, dtype=jnp.float32).init()
     b = np.ones(A.num_rows, np.float32)
     cfg = ("solver=PCG, max_iters=40, monitor_residual=1,"
@@ -330,13 +331,12 @@ def test_consolidation_boundary_feeds_vmem_tail():
            " amg:matrix_consolidation_lower_threshold=300")
     with ps.force_pallas_interpret():
         ds = _setup(cfg, 2, A)
-        s = _cycle_jaxpr(ds)
-        assert _kcount(s, "_dia_coarse_tail_call") == 1, s.count(
-            "pallas_call")
-        assert _kcount(s, "_dia_smooth_call") == 2
+        levels = len(_amg_data(ds)[0].levels)
+        assert levels >= 2
+        assert _kcount(_cycle_jaxpr(ds), "_dia_smooth_call") == 2 * levels
         res = ds.solve(b)
         ds_u = _setup(cfg + ", amg:dist_cycle_fusion=0,"
-                      " amg:cycle_fusion=0, amg:fused_smoother=0", 2, A)
+                      " amg:fused_smoother=0", 2, A)
         res_u = ds_u.solve(b)
     assert res.converged and res_u.converged
     assert res.iterations == res_u.iterations

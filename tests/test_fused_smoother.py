@@ -223,17 +223,12 @@ _CYCLE_CFG = (
 
 def _cycle_pallas_counts(extra_cfg=""):
     """Trace one V-cycle with the Pallas gates forced on; return
-    (n_levels, fused_calls, plain_spmv_calls) from the jaxpr. Pinned
-    to cycle_fusion=0: this file proves the PR-4 smoother+residual
-    composition (which the cycle_fusion knob's escape hatch must keep
-    reproducing); the fused grid-transfer / coarse-tail shapes are
-    proven by tests/test_cycle_fusion.py."""
+    (n_levels, fused_calls, plain_spmv_calls) from the jaxpr."""
     A = gallery.poisson("7pt", 16, 16, 16, dtype=jnp.float32).init()
     b = jnp.ones(A.num_rows, jnp.float32)
     with ps.force_pallas_interpret():
         slv = amgx.create_solver(
-            Config.from_string(_CYCLE_CFG + ", amg:cycle_fusion=0"
-                               + extra_cfg))
+            Config.from_string(_CYCLE_CFG + extra_cfg))
         slv.setup(A)
         pc = slv.preconditioner
         d = pc.solve_data()
@@ -273,14 +268,31 @@ def test_cycle_hbm_passes_fused_removes_residual_spmv():
         f"got {plain_off}"
 
 
-def test_cycle_does_not_retrace_with_fused_smoother():
+_CLASSICAL_CFG = (
+    "solver(s)=PCG, s:max_iters=30, s:tolerance=1e-7,"
+    " s:convergence=RELATIVE_INI, s:monitor_residual=1,"
+    " s:preconditioner(amg)=AMG, amg:algorithm=CLASSICAL,"
+    " amg:selector=PMIS, amg:interpolator=D2,"
+    " amg:smoother=JACOBI_L1, amg:presweeps=2, amg:postsweeps=1,"
+    " amg:max_iters=1, amg:coarse_solver=DENSE_LU_SOLVER,"
+    " amg:min_coarse_rows=16, amg:max_levels=10,"
+    " amg:interp_max_elements=4")
+# the hierarchies a solve is driven through: GEO aggregation (DIA
+# levels, the GEO transfers) and classical (a DIA fine level, SWELL
+# below it, P and R as operators)
+_SOLVE_CFGS = {"geo": _CYCLE_CFG, "classical": _CLASSICAL_CFG}
+_solve_cfgs = pytest.mark.parametrize("kind", sorted(_SOLVE_CFGS))
+
+
+@_solve_cfgs
+def test_cycle_does_not_retrace_with_fused_smoother(kind):
     """One jit trace serves repeated solves (and a value-only change)
     when smooth_residual/fused kernels are enabled."""
     A = gallery.poisson("7pt", 12, 12, 12, dtype=jnp.float32).init()
     n = A.num_rows
     rng = np.random.default_rng(6)
     with ps.force_pallas_interpret():
-        slv = amgx.create_solver(Config.from_string(_CYCLE_CFG))
+        slv = amgx.create_solver(Config.from_string(_SOLVE_CFGS[kind]))
         slv.setup(A)
         r1 = slv.solve(jnp.asarray(rng.standard_normal(n), jnp.float32))
         assert len(slv._jit_cache) == 1
@@ -290,22 +302,79 @@ def test_cycle_does_not_retrace_with_fused_smoother():
         assert r1.converged and r2.converged
 
 
-def test_cycle_fused_matches_unfused_solution():
+def _plain_solve(cfg, A, b):
+    """A fresh set-up and solve with no kernel anywhere: the answer the
+    kernels' cycle is held to."""
+    ref = amgx.create_solver(
+        Config.from_string(cfg + ", fused_smoother=0"))
+    ref.setup(A)
+    return ref.solve(b)
+
+
+@_solve_cfgs
+def test_cycle_fused_matches_unfused_solution(kind):
     """End-to-end: the fused cycle converges to the same answer in the
     same iteration count as the unfused one."""
     A = gallery.poisson("7pt", 12, 12, 12, dtype=jnp.float32).init()
     b = jnp.ones(A.num_rows, jnp.float32)
-    ref = amgx.create_solver(
-        Config.from_string(_CYCLE_CFG + ", fused_smoother=0"))
-    ref.setup(A)
-    r0 = ref.solve(b)
+    r0 = _plain_solve(_SOLVE_CFGS[kind], A, b)
     with ps.force_pallas_interpret():
-        slv = amgx.create_solver(Config.from_string(_CYCLE_CFG))
+        slv = amgx.create_solver(Config.from_string(_SOLVE_CFGS[kind]))
         slv.setup(A)
         r1 = slv.solve(b)
     assert r1.converged
     assert abs(r1.iterations - r0.iterations) <= 1
     assert _rel(r1.x, r0.x) < 1e-4
+
+
+@_solve_cfgs
+def test_solve_many_matches_single_solves(kind):
+    """solve_many drives the cycle under vmap: the kernels' custom_vmap
+    rules land in the slab forms and match per-system solves."""
+    A = gallery.poisson("7pt", 12, 12, 12, dtype=jnp.float32).init()
+    rng = np.random.default_rng(8)
+    Bs = jnp.asarray(rng.standard_normal((3, A.num_rows)), jnp.float32)
+    with ps.force_pallas_interpret():
+        slv = amgx.create_solver(Config.from_string(_SOLVE_CFGS[kind]))
+        slv.setup(A)
+        res = slv.solve_many(Bs)
+        singles = [slv.solve(Bs[i]).x for i in range(3)]
+    for i in range(3):
+        assert _rel(res.x[i], singles[i]) < 1e-5
+
+
+@pytest.mark.parametrize("route", ["value", "structure"])
+def test_resetup_leaves_the_cycle_right(route):
+    """structure_reuse_levels=-1 under the kernels: the value-only
+    splice (GEO + CHEBYSHEV_POLY + DENSE_LU, the flagship's shape) and
+    the structure-reuse rebuild (classical: P and R kept, Galerkin
+    products anew) both solve the new coefficients as a fresh plain
+    set-up on them does."""
+    cfg = {"value": _CYCLE_CFG.replace(
+        "amg:smoother=JACOBI_L1", "amg:smoother=CHEBYSHEV_POLY,"
+        " amg:chebyshev_polynomial_order=2"),
+        "structure": _CLASSICAL_CFG}[route]
+    A = gallery.poisson("7pt", 12, 12, 12, dtype=jnp.float32).init()
+    A2 = A.with_values(A.values * 1.5)
+    A2 = A2 if A2.initialized else A2.init()
+    b = jnp.ones(A.num_rows, jnp.float32)
+    with ps.force_pallas_interpret():
+        slv = amgx.create_solver(Config.from_string(
+            cfg + ", amg:structure_reuse_levels=-1"))
+        slv.setup(A)
+        amg = slv.preconditioner.amg
+        kept = [lv.P for lv in amg.levels] if route == "structure" \
+            else None
+        slv.solve(b)
+        slv.resetup(A2)
+        assert amg._last_resetup_value_only == (route == "value")
+        if kept is not None:
+            assert all(lv.P is p for lv, p in zip(amg.levels, kept))
+        r2 = slv.solve(b)
+    r0 = _plain_solve(cfg, A2, b)
+    assert r2.converged
+    assert abs(int(r2.iterations) - int(r0.iterations)) <= 1
+    assert _rel(r2.x, r0.x) < 1e-4
 
 
 def test_fused_payload_refreshes_on_resetup():

@@ -177,15 +177,11 @@ def _growth(before, name):
 def test_counters_read_the_road_taken(road):
     """The flagship on 128x8x8: L0 is on the kernels' grid, L1 (64x4x4)
     is not. After a solve the two counters hold cycles x levels on each
-    road, the cycles being FGMRES's Arnoldi steps. (cycle_fusion=0 is
-    the cycle the chip runs: its compiler declines the fused
-    restrict-epilogue / prolong-prologue family, which under the
-    interpreter would take the transfers instead of either road.)"""
+    road, the cycles being FGMRES's Arnoldi steps."""
     A = amgx.gallery.poisson("7pt", 128, 8, 8).init()
     b = jnp.ones(A.num_rows)
     with _road(road):
-        slv = amgx.create_solver(Config.from_string(
-            FLAGSHIP + ", amg:cycle_fusion=0"))
+        slv = amgx.create_solver(Config.from_string(FLAGSHIP))
         slv.setup(A)
         before = tm.snapshot()
         res = slv.solve(b)

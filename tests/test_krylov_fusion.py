@@ -1,6 +1,5 @@
 """Krylov-shell fusion test suite (solvers/krylov.py fused iterations,
-ops/spmv.spmv_pdot / spmv_ddot, ops/blas.cg_update / psum_bundle, the
-cycle-borne r.z dot through amg/cycles.run_cycle_dot).
+ops/spmv.spmv_pdot / spmv_ddot, ops/blas.cg_update / psum_bundle).
 
 Kernels run through the Pallas interpreter (force_pallas_interpret, the
 CPU test path); what the chip's compiler accepts of them is in
@@ -9,8 +8,8 @@ Covers: iterate-for-iterate parity of the fused shell against the
 unfused SpMV + BLAS-1 composition for CG/PCG/PCGF/BiCGStab/PBiCGStab
 (f32 through the kernels, f64 through the exact-expression XLA
 fallback); the jaxpr census gate — a fused-hierarchy PCG iteration is
-the cycle's fused kernels plus EXACTLY two shell kernels with zero
-standalone full-vector reductions, and `krylov_fusion=0` emits a jaxpr
+the cycle's kernels plus EXACTLY two shell kernels with r.z the one
+standalone full-vector reduction, and `krylov_fusion=0` emits a jaxpr
 identical to the pre-fusion composition; the CG dead-norm regression
 (internal_res_norm kills the monitor's standalone blas.norm(r) pass on
 BOTH routes); the GMRES CGS2 step routine vs the sequential MGS loop at
@@ -123,9 +122,8 @@ def test_parity_f64_exact(name, pre):
 
 
 def _pcg_iteration_jaxpr(fusion=1, n=16):
-    """Trace ONE PCG iteration on a fused GEO/DIA hierarchy sized so
-    the whole cycle collapses into the VMEM coarse-tail kernel (which
-    then must carry the cycle-borne r.z epilogue)."""
+    """Trace ONE PCG iteration on a GEO/DIA hierarchy; returns the
+    jaxpr, the rows and the hierarchy's level count."""
     A = gallery.poisson("7pt", n, n, n, dtype=jnp.float32).init()
     b = jnp.ones(A.num_rows, jnp.float32)
     cfg = (BASE.format(name="PCG") + AMG_PRE
@@ -138,35 +136,44 @@ def _pcg_iteration_jaxpr(fusion=1, n=16):
         st.update(slv.solve_init(d, b, jnp.zeros_like(b), b))
         jaxpr = jax.make_jaxpr(
             lambda dd, ss: slv.solve_iteration(dd, b, ss))(d, st)
-    return jaxpr, A.num_rows
+    return jaxpr, A.num_rows, len(slv.preconditioner.amg.levels)
+
+
+def _vector_reductions(jaxpr, n):
+    """The census's hits on an n-vector (the 64 x 64 coarse factor of
+    the 16^3 hierarchy has n elements too, and is no vector pass)."""
+    return [h for h in _census.full_vector_reductions(jaxpr, n)
+            if (n,) in h[1]]
 
 
 def test_census_fused_pcg_iteration():
-    """The fused-hierarchy PCG iteration = the cycle's fused kernels +
-    EXACTLY two shell kernels, with ZERO standalone full-vector
-    reductions outside the kernels (every dot is an epilogue)."""
-    jaxpr, n = _pcg_iteration_jaxpr(fusion=1)
+    """The fused-hierarchy PCG iteration = the cycle's two smoother
+    kernels a level + EXACTLY two shell kernels; the one standalone
+    full-vector reduction outside the kernels is r.z (p.Ap and r.r
+    are epilogues)."""
+    jaxpr, n, levels = _pcg_iteration_jaxpr(fusion=1)
     counts = _census.kernel_counts(jaxpr)
+    assert levels >= 2
     assert counts == {"_dia_spmv_dot_call": 1, "_cg_update_call": 1,
-                      "_dia_coarse_tail_call": 1}, counts
-    hits = _census.full_vector_reductions(jaxpr, n)
-    assert hits == [], hits
+                      "_dia_smooth_call": 2 * levels}, counts
+    hits = _vector_reductions(jaxpr, n)
+    assert hits == [("dot_general", [(n,), (n,)])], hits
 
 
 def test_census_unfused_pcg_iteration():
     """krylov_fusion=0: no shell kernels anywhere in the trace; the
-    iteration is the plain SpMV kernel + the cycle's tail kernel with
-    the dots as standalone XLA reductions."""
-    jaxpr, n = _pcg_iteration_jaxpr(fusion=0)
+    iteration is the plain SpMV kernel + the cycle's smoother kernels
+    with the dots as standalone XLA reductions."""
+    jaxpr, n, levels = _pcg_iteration_jaxpr(fusion=0)
     counts = _census.kernel_counts(jaxpr)
     assert counts == {"_dia_spmv_call": 1,
-                      "_dia_coarse_tail_call": 1}, counts
+                      "_dia_smooth_call": 2 * levels}, counts
     s = str(jaxpr)
     assert "_dia_spmv_dot_call" not in s
     assert "_cg_update_call" not in s
     # the unfused composition's standalone dots ARE there (pAp and
     # r.z; the direction/iterate updates run as XLA ops)
-    assert len(_census.full_vector_reductions(jaxpr, n)) == 2
+    assert len(_vector_reductions(jaxpr, n)) == 2
 
 
 # ---------------------------------------------------------------------------

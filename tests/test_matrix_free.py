@@ -2,7 +2,7 @@
 stencil detection (ops/stencil.py), the coeffs-mode fused kernels
 (pallas_spmv's SMEM-scalar operand form, via force_pallas_interpret on
 the CPU rig), the f64/XLA slab-fallback route, hierarchy routing
-(`matrix_free=auto|0|1`, capability surface, level_data forms), the
+(`matrix_free=auto|0|1`, level_data forms), the
 jaxpr census (NO value-slab operand on matrix-free levels;
 `matrix_free=0` jaxpr-identical to the default slab build), the
 value-resetup coefficient refresh, GeoRapPlan.coarse_coeffs, and the
@@ -20,7 +20,6 @@ from amgx_tpu import gallery
 from amgx_tpu.config import Config
 import amgx_tpu.ops.pallas_spmv as ps
 import amgx_tpu.ops.stencil as stencil
-from amgx_tpu.ops import smooth as fused
 from amgx_tpu.ops.spmv import spmv
 from amgx_tpu.solvers.relaxation import safe_recip, l1_strengthened_diag
 
@@ -51,16 +50,6 @@ def _ref_sweeps(A, dinv, taus, b, x, with_residual=False):
     if with_residual:
         return x, b - spmv(A, x)
     return x
-
-
-def _geo_agg(nx, ny, nz):
-    n = nx * ny * nz
-    i = np.arange(n)
-    x, t = i % nx, i // nx
-    y, z = t % ny, t // ny
-    cnx, cny, cnz = (nx + 1) // 2, (ny + 1) // 2, (nz + 1) // 2
-    agg = ((z // 2) * cny + (y // 2)) * cnx + (x // 2)
-    return agg.astype(np.int32), cnx * cny * cnz
 
 
 def _amg_of(slv):
@@ -181,40 +170,12 @@ class TestKernelParity:
         assert mx.dtype == jnp.bfloat16
         assert _rel(mx.astype(jnp.float32), ref_x) < 2e-2
 
-    def test_restrict_and_corr_parity(self):
-        nn = 10
-        A = gallery.poisson("7pt", nn, nn, nn, dtype=np.float32).init()
-        agg, nc = _geo_agg(nn, nn, nn)
-        st = stencil.detect_stencil(A, dinv_mode="l1")
-        rng = np.random.default_rng(5)
-        n = A.num_rows
-        b = jnp.asarray(rng.standard_normal(n), jnp.float32)
-        x0 = jnp.asarray(rng.standard_normal(n), jnp.float32)
-        xc = jnp.asarray(rng.standard_normal(nc), jnp.float32)
-        dinv = jnp.asarray(safe_recip(np.asarray(
-            l1_strengthened_diag(A))), jnp.float32)
-        taus = jnp.full((2,), 0.8, jnp.float32)
-        xr, rr = _ref_sweeps(A, dinv, taus, b, x0, True)
-        bc_ref = jax.ops.segment_sum(rr, jnp.asarray(agg),
-                                     num_segments=nc)
-        xr2 = _ref_sweeps(A, dinv, taus, b, x0 + xc[jnp.asarray(agg)])
-        with ps.force_pallas_interpret():
-            xfer = fused.build_transfer_slabs(A, agg, nc)
-            out = stencil.stencil_smooth_restrict(st, taus, b, x0, xfer)
-            out2 = stencil.stencil_corr_smooth(st, taus, b, x0, xc,
-                                               xfer)
-        assert out is not None and out2 is not None
-        assert _rel(out[0], xr) < 1e-6
-        assert _rel(out[1], bc_ref) < 1e-6
-        assert _rel(out2, xr2) < 1e-6
-
     def test_chained_blocks_under_tight_budget(self):
         """A 9-sweep schedule is longer than SMOOTH_MAX_APPS: the
-        smoother takes the XLA compose and the restriction twin its
-        tail behind it, and both still match the reference."""
+        smoother takes the XLA compose and still matches the
+        reference."""
         nn = 10
         A = gallery.poisson("7pt", nn, nn, nn, dtype=np.float32).init()
-        agg, nc = _geo_agg(nn, nn, nn)
         st = stencil.detect_stencil(A, dinv_mode="l1")
         rng = np.random.default_rng(7)
         n = A.num_rows
@@ -223,24 +184,16 @@ class TestKernelParity:
         dinv = jnp.asarray(safe_recip(np.asarray(
             l1_strengthened_diag(A))), jnp.float32)
         taus9 = jnp.full((9,), 0.8, jnp.float32)
-        xr, rr = _ref_sweeps(A, dinv, taus9, b, x0, True)
-        bc_ref = jax.ops.segment_sum(rr, jnp.asarray(agg),
-                                     num_segments=nc)
+        xr = _ref_sweeps(A, dinv, taus9, b, x0)
         old = ps._SMOOTH_VMEM_BUDGET
         try:
             ps._SMOOTH_VMEM_BUDGET = 300 * 1024
             with ps.force_pallas_interpret():
                 mx = stencil.stencil_fused_smooth(
                     st, taus9, b, x0, with_residual=False)
-                xfer = fused.build_transfer_slabs(A, agg, nc)
-                out = stencil.stencil_smooth_restrict(st, taus9, b,
-                                                      x0, xfer)
         finally:
             ps._SMOOTH_VMEM_BUDGET = old
         assert _rel(mx, xr) < 1e-6
-        if out is not None:      # restrict may decline under the budget
-            assert _rel(out[0], xr) < 1e-6
-            assert _rel(out[1], bc_ref) < 1e-6
 
     def test_f64_slab_fallback_parity(self):
         """f64 is outside SMOOTH_DTYPES: the dispatch must compose the
@@ -355,18 +308,6 @@ class TestRouting:
         res = slv.solve(jnp.ones(n, jnp.float32))
         assert res.converged
 
-    def test_capability_surface(self):
-        """A matrix-free level's supports_fusion advertises the
-        matrix_free capability on top of the level's fusion caps."""
-        A = gallery.poisson("7pt", 12, 12, 12, dtype=np.float32).init()
-        slv = amgx.create_solver(Config.from_string(
-            _GEO_CORE + _SMOOTHERS["l1"] + ", amg:matrix_free=1"))
-        slv.setup(A)
-        amg = _amg_of(slv)
-        lv = amg.levels[0]
-        caps = lv.supports_fusion(amg.solve_data()["levels"][0])
-        assert "matrix_free" in caps
-
 
 # ---------------------------------------------------------------------------
 # jaxpr census
@@ -411,10 +352,9 @@ class TestJaxprCensus:
         assert str(j_off) == str(j_def)
 
     def test_interpret_cycle_keeps_fused_kernels(self):
-        """Under the Pallas runtime the matrix-free cycle still runs
-        the fused kernel set (smoother + transfer epilogues/prologues
-        — the coeffs mode replaces the operand, not the fusion), and
-        solves to the same answer as the slab kernels."""
+        """Under the Pallas runtime the matrix-free cycle runs the
+        slab cycle's kernel set (the coeffs mode replaces the operand,
+        not the kernel), and solves to the same answer."""
         A = gallery.poisson("7pt", 12, 12, 12, dtype=np.float32).init()
         b = jnp.ones(A.num_rows, jnp.float32)
         xs, kernels = {}, {}

@@ -272,44 +272,38 @@ def test_chebyshev_smoother_in_the_hierarchy_drops_the_program():
         assert res.converged and true_residual(A2, res.x, b) < 1e-7
 
 
-# -- (5) what the trace recorded survives a kept rebuild -----------------
-TAIL = (
+# -- (5) what the report memoized survives a kept rebuild ----------------
+TABLE = (
     "solver(s)=PCG, s:max_iters=30, s:tolerance=1e-6,"
     " s:convergence=RELATIVE_INI, s:monitor_residual=1,"
     " s:preconditioner(amg)=AMG, amg:algorithm=AGGREGATION,"
     " amg:selector=GEO, amg:smoother=JACOBI_L1, amg:presweeps=2,"
     " amg:postsweeps=1, amg:max_iters=1,"
     " amg:coarse_solver=DENSE_LU_SOLVER, amg:min_coarse_rows=16,"
-    " amg:max_levels=10, amg:cycle_fusion_tail_rows=600")
+    " amg:max_levels=10")
 
 
-def test_tail_boundary_and_level_table_survive_a_kept_rebuild():
+def test_level_table_survives_a_kept_rebuild():
     A = in_loop(gallery.poisson("7pt", 16, 16, 16,
                                 dtype=jnp.float32).init())
     b = rhs(A).astype(np.float32)
     with ps.force_pallas_interpret():
-        slv = amgx.create_solver(Config.from_string(TAIL))
+        slv = amgx.create_solver(Config.from_string(TABLE))
         slv.setup(A)
         first = slv.solve(b)
         amg = _amg_of(slv)
-        tail = amg._tail_entry_level
-        assert tail is not None and tail >= 1   # recorded by the trace
-        kinds = [r.get("kind") for r in first.report.levels]
-        assert "vmem_tail" in kinds
+        assert len(first.report.levels) == len(amg.levels) + 1
         before = metrics.snapshot()
         slv.resetup(scaled(A, 1.5))
         assert growth(before)["resetup.program_kept"] == 1
-        assert amg._tail_entry_level == tail
         table = amg._telemetry_level_cache
         assert table is not None and table[0][0] == id(amg.levels)
         again = slv.solve(b)
         assert growth(before)["solver.retrace.solve"] == 0
     assert again.converged
-    assert [r.get("kind") for r in again.report.levels] == kinds
     assert again.report.levels == first.report.levels
-    # a rebuild that drops the program drops both, as before
+    # a rebuild that drops the program drops the table, as before
     amg.setup(A)
-    assert amg._tail_entry_level is None
     assert amg._telemetry_level_cache is None
 
 

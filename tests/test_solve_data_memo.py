@@ -122,10 +122,10 @@ def _drop_all(slv):
 
 def _value_leaves(tree):
     """The leaves a value change must replace: floating-point arrays of
-    more than one element, less the transfer operators (`P`, `R`, the
-    fused transfer slabs), which a structure-reuse rebuild keeps WITH
-    the weights of the first setup, on the device too (PR 39)."""
-    kept = ("P", "R", "xfer")
+    more than one element, less the transfer operators (`P`, `R`),
+    which a structure-reuse rebuild keeps WITH the weights of the
+    first setup, on the device too (PR 39)."""
+    kept = ("P", "R")
     out = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         keys = [getattr(k, "key", getattr(k, "name", None)) for k in path]

@@ -1,10 +1,8 @@
 """Plan-split Galerkin RAP (ISSUE 15 tentpole): the RapPlan structure
-phase (ops/spgemm.py), the fused Pallas value kernel
-(ops/pallas_spgemm.py, via force_pallas_interpret on the CPU rig), the
-slab/numpy value routes, the planned level wiring (aggregation + GEO +
-classical), structure-resetup plan carryover, value-resetup refresh,
-the jaxpr proofs (one fused value kernel on the kernel route; zero
-sort/argsort/unique prims on the slab route), and the `spgemm_plan=0`
+phase (ops/spgemm.py), the slab/numpy value roads, the planned level
+wiring (aggregation + GEO + classical), structure-resetup plan
+carryover, value-resetup refresh, the jaxpr proof (zero
+sort/argsort/unique prims on the slab road), and the `spgemm_plan=0`
 eager escape hatch.
 """
 import dataclasses
@@ -19,8 +17,6 @@ from amgx_tpu import gallery
 from amgx_tpu.config import Config
 from amgx_tpu.amg.hierarchy import AMG
 from amgx_tpu.ops import spgemm
-from amgx_tpu.ops import pallas_spgemm as pk
-from amgx_tpu.ops.pallas_spmv import force_pallas_interpret
 from amgx_tpu.ops.spgemm import galerkin_rap
 from amgx_tpu.telemetry import metrics as _tm
 
@@ -190,115 +186,24 @@ def test_host_route_takes_the_native_sweep_in_its_own_precision(
 
 
 # ---------------------------------------------------------------------------
-# the fused value kernel (interpret route)
+# the slab road under vmap
 # ---------------------------------------------------------------------------
 
 
-def test_kernel_parity_interpret_f32():
-    """Kernel route vs the slab reference (rap + agg forms), f32
-    through the Pallas interpreter."""
-    lv = _classical_level(dtype=jnp.float32)
-    plan = spgemm.build_rap_plan(lv.R, lv.A, lv.P)
-    ref = spgemm._rap_values_numpy(
-        plan, np.asarray(lv.A.values), np.asarray(lv.R.values),
-        np.asarray(lv.P.values))
-    with force_pallas_interpret():
-        assert pk.rap_kernel_ready(plan, jnp.float32)
-        out = pk.rap_value_call(plan, jnp.asarray(lv.A.values),
-                                lv.R.values, lv.P.values)
-    assert _rel(out, ref) < 1e-6
-    A = gallery.poisson("7pt", 8, 8, 8, dtype=jnp.float32).init()
-    agg = np.arange(A.num_rows) // 8
-    aplan = spgemm.build_agg_plan(A, agg, int(agg.max()) + 1)
-    aref = spgemm._rap_values_numpy(aplan, np.asarray(A.values),
-                                    None, None)
-    with force_pallas_interpret():
-        assert pk.rap_kernel_ready(aplan, jnp.float32)
-        aout = pk.rap_value_call(aplan, jnp.asarray(A.values), None,
-                                 None)
-    assert _rel(aout, aref) < 1e-6
-
-
-def test_kernel_chained_chunks_parity():
-    """A shrunken VMEM budget forces the chained-block fallback; the
-    chunked calls still reproduce the single-call values."""
-    lv = _classical_level(n=10, dtype=jnp.float32)
-    plan = spgemm.build_rap_plan(lv.R, lv.A, lv.P)
-    ref = spgemm._rap_values_numpy(
-        plan, np.asarray(lv.A.values), np.asarray(lv.R.values),
-        np.asarray(lv.P.values))
-    old_budget, old_min = pk._RAP_VMEM_BUDGET, pk._RAP_MIN_CHUNK
-    try:
-        pk._RAP_VMEM_BUDGET = 1 << 19
-        pk._RAP_MIN_CHUNK = 8
-        with force_pallas_interpret():
-            assert pk.rap_kernel_ready(plan, jnp.float32)
-            assert len(plan._kernel[0]) > 1, "budget did not chunk"
-            out = pk.rap_value_call(plan, jnp.asarray(lv.A.values),
-                                    lv.R.values, lv.P.values)
-    finally:
-        pk._RAP_VMEM_BUDGET, pk._RAP_MIN_CHUNK = old_budget, old_min
-        plan._kernel = None
-    assert _rel(out, ref) < 1e-6
-
-
-def test_kernel_contrib_cap_declines():
-    """A contributor run beyond RAP_MAX_CONTRIB declines the kernel
-    route (the slab segment-sum handles any length) — never a wrong
-    answer."""
-    lv = _classical_level(n=8, dtype=jnp.float32)
-    plan = spgemm.build_rap_plan(lv.R, lv.A, lv.P)
-    old = pk.RAP_MAX_CONTRIB
-    try:
-        pk.RAP_MAX_CONTRIB = 1
-        plan._kernel = None
-        with force_pallas_interpret():
-            assert not pk.rap_kernel_ready(plan, jnp.float32)
-    finally:
-        pk.RAP_MAX_CONTRIB = old
-        plan._kernel = None
-    # the declined plan still evaluates through rap_values (slab route)
-    with force_pallas_interpret():
-        vals = spgemm.rap_values(plan, lv.A, lv.R, lv.P)
-    ref = spgemm._rap_values_numpy(
-        plan, np.asarray(lv.A.values), np.asarray(lv.R.values),
-        np.asarray(lv.P.values))
-    assert _rel(vals, ref) < 1e-6
-
-
-def test_vmap_routes_to_slab_form():
-    """A vmapped coefficient stream over one plan takes the multi
-    slab form in ops/batched.py (no pallas_call in the jaxpr), with
-    per-system parity against the single calls."""
-    A = gallery.poisson("7pt", 8, 8, 8, dtype=jnp.float32).init()
-    agg = np.arange(A.num_rows) // 8
-    plan = spgemm.build_agg_plan(A, agg, int(agg.max()) + 1)
-    with force_pallas_interpret():
-        assert pk.rap_kernel_ready(plan, jnp.float32)
-        fn = lambda af: pk.rap_value_call(plan, af, None, None)  # noqa: E731
-        AF = jnp.stack([jnp.asarray(A.values),
-                        jnp.asarray(A.values) * 2.0])
-        Y = jax.vmap(fn)(AF)
-        jaxpr = str(jax.make_jaxpr(jax.vmap(fn))(AF))
-        single = np.asarray(fn(jnp.asarray(A.values)))
-    assert "pallas_call" not in jaxpr
-    assert _rel(Y[0], single) < 1e-6
-    assert _rel(Y[1], 2.0 * single) < 1e-6
-
-
-def test_batched_slab_is_f64_reference():
-    """rap_values_multi at f64 matches the eager triple product to
-    1e-12 per system (the kernel tests' parity reference)."""
-    from amgx_tpu.ops.batched import rap_values_multi
+def test_vmapped_slab_road_is_f64_exact():
+    """A vmapped coefficient stream over one plan rides the slab road's
+    gathers and sorted segment-sums with the plan's index slabs shared:
+    each system matches the eager triple product to 1e-12."""
     lv = _classical_level()
     plan = spgemm.build_rap_plan(lv.R, lv.A, lv.P)
     eager = galerkin_rap(lv.R, lv.A, lv.P)
     d = plan.dev()
     AF = jnp.stack([jnp.asarray(lv.A.values),
                     jnp.asarray(lv.A.values) * 3.0])
-    Y = rap_values_multi(d, AF, jnp.asarray(lv.R.values),
-                         jnp.asarray(lv.P.values),
-                         plan.stage1["nT"], plan.nU, True, True)
+    Y = jax.vmap(lambda af: spgemm._rap_values_slab(
+        af, jnp.asarray(lv.R.values), jnp.asarray(lv.P.values),
+        d["sa"], d["sp"], d["seg1"], d["sr"], d["st"], d["seg2"],
+        plan.stage1["nT"], plan.nU, True, True))(AF)
     assert _rel(Y[0], eager.values) < 1e-12
     assert _rel(Y[1], 3.0 * np.asarray(eager.values)) < 1e-12
 
@@ -326,28 +231,6 @@ def _outer_prims(closed):
                         walk(q.jaxpr)
     walk(closed.jaxpr)
     return out
-
-
-def test_jaxpr_one_value_kernel_no_symbolic_prims():
-    """THE acceptance proof (kernel route): a planned level's RAP
-    numerics are exactly ONE fused value kernel, with zero standalone
-    sort/argsort/gather/scatter/segment-sum prims outside it — where
-    the eager formulation dispatches the whole sort/gather/segment
-    chain."""
-    lv = _classical_level(dtype=jnp.float32)
-    plan = spgemm.build_rap_plan(lv.R, lv.A, lv.P)
-    with force_pallas_interpret():
-        assert pk.rap_kernel_ready(plan, jnp.float32)
-        jaxpr = jax.make_jaxpr(
-            lambda af: pk.rap_value_call(plan, af, lv.R.values,
-                                         lv.P.values))(
-            jnp.asarray(lv.A.values))
-    prims = _outer_prims(jaxpr)
-    assert prims.count("pallas_call") == 1, prims
-    banned = {"sort", "gather", "scatter", "scatter-add", "argsort",
-              "segment_sum", "cumsum"}
-    hit = [p for p in prims if p in banned]
-    assert not hit, hit
 
 
 def test_jaxpr_slab_route_no_sort_prims():

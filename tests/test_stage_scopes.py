@@ -413,6 +413,39 @@ def test_scope_table_names_every_level_of_the_hierarchy(flagship16):
     assert "/amg.L0/amg.L1/amg.L1.presmooth/" in names[some]
 
 
+@pytest.mark.parametrize("name", [
+    "geo7-cheb-mf", "geo27-gs", "agg-size2", "classical-pmis-d2",
+    "classical-aggr-l1trunc", "classical-nonsym"])
+def test_scope_table_names_the_four_stages(name):
+    """Under the Pallas interpreter, as on the chip: the registered
+    solve program of each of test_cycle_one_path.py's hierarchies puts
+    ops under the four stages of every level and under `amg.coarse`
+    (where the coarse solver does anything), and under no other `amg.`
+    name: the names `benchmark/scope_metrics.py` joins a trace on."""
+    import test_cycle_one_path as one_path
+    from amgx_tpu.ops import pallas_spmv as ps
+    programs._reset()
+    with ps.force_pallas_interpret():
+        slv, A = one_path.set_up(name)
+        slv.solve(jnp.ones(A.num_rows, A.values.dtype))
+    found = {s for s in programs.scopes().values()
+             if s and s.startswith("amg.")}
+    programs._reset()       # an interpreter's program: not the next test's
+    amg = one_path.amg_of(slv)
+    levels = range(len(amg.levels))
+    stages = {f"amg.L{k}.{stage}" for k in levels for stage in
+              ("presmooth", "restrict", "prolong", "postsmooth")}
+    coarse = {"amg.coarse"}
+    wanted = set(stages)
+    if amg.coarse_solver.name == "NOSOLVER":
+        # no coarse correction: nothing reads the deepest level's
+        # coarse right-hand side, and the compiler drops what makes it
+        coarse = set()
+        wanted.discard(f"amg.L{len(amg.levels) - 1}.restrict")
+    assert wanted <= found, wanted - found
+    assert found - stages - {f"amg.L{k}" for k in levels} == coarse
+
+
 def test_scope_of_takes_the_innermost_component():
     assert programs.scope_of(
         "jit(solve_fn)/while/body/krylov.FGMRES.iter/while/body/"

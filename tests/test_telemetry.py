@@ -282,16 +282,15 @@ def test_report_schema_validates(poisson12_3d):
 
 
 def test_report_level_cache_lifecycle(poisson16):
-    """The memoized level table (and the recorded VMEM-tail boundary)
-    must not survive a hierarchy rebuild — a stale memo would report
-    the OLD hierarchy's rows/kinds for the new one."""
+    """The memoized level table must not survive a hierarchy rebuild —
+    a stale memo would report the OLD hierarchy's rows for the new
+    one."""
     from amgx_tpu.telemetry.report import _amg_of
     slv, res = _solve(AMG_PCG, poisson16)
     amg = _amg_of(slv)
     assert amg._telemetry_level_cache is not None   # memoized by report
-    amg.setup(poisson16)          # full rebuild drops memo + tail
+    amg.setup(poisson16)          # full rebuild drops the memo
     assert amg._telemetry_level_cache is None
-    assert amg._tail_entry_level is None
 
 
 def test_telemetry_off_no_report(poisson16):
